@@ -21,9 +21,8 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ------------------------------------------------------------------
 
 def test_cachedir_precedence(monkeypatch, tmp_path):
-    """TPULSAR_CACHE_DIR (canonical) > JAX_COMPILATION_CACHE_DIR
-    (already-pinned) > <repo>/.jax_cache (checkout default)."""
-    monkeypatch.delenv("TPULSAR_CACHE_DIR", raising=False)
+    """JAX_COMPILATION_CACHE_DIR set -> that directory, verbatim;
+    unset -> <checkout>/.jax_cache, a fixed path."""
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert cachedir.resolve() == os.path.join(_REPO, ".jax_cache")
 
@@ -31,26 +30,84 @@ def test_cachedir_precedence(monkeypatch, tmp_path):
                        str(tmp_path / "jaxpin"))
     assert cachedir.resolve() == str(tmp_path / "jaxpin")
 
-    monkeypatch.setenv("TPULSAR_CACHE_DIR", str(tmp_path / "canon"))
-    assert cachedir.resolve() == str(tmp_path / "canon")
+
+@pytest.fixture
+def jax_cache_config():
+    """activate() pushes the path into the live jax config, which
+    outlives monkeypatch's env restore: put it back."""
+    import jax
+
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
 
 
-def test_cachedir_activate_exports_to_jax_env(monkeypatch, tmp_path):
-    """activate() must override a stale JAX_COMPILATION_CACHE_DIR when
-    the operator pinned TPULSAR_CACHE_DIR — the canonical knob wins,
-    otherwise the four-setdefault drift this module replaced comes
-    back through the env."""
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                       str(tmp_path / "stale"))
-    monkeypatch.setenv("TPULSAR_CACHE_DIR", str(tmp_path / "canon"))
+def test_cachedir_activate_leaves_a_set_variable_alone(
+        monkeypatch, tmp_path, jax_cache_config):
+    """activate() uses the set variable and never overwrites it: the
+    cache is placeable from outside, and no code sets another."""
+    pinned = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", pinned)
     got = cachedir.activate()
-    assert got == str(tmp_path / "canon")
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == got
-    assert os.path.isdir(got)
+    assert got == pinned
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == pinned
+    assert os.path.isdir(pinned)
+    import jax
+    assert jax.config.jax_compilation_cache_dir == pinned
+
+
+def test_cachedir_activate_unset_uses_the_checkout(
+        monkeypatch, jax_cache_config):
+    """Unset, activate() exports the fixed checkout path, so a jax
+    imported later — and every child process — lands in the same
+    cache."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = cachedir.activate()
+        assert got == os.path.join(_REPO, ".jax_cache")
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == got
+    finally:
+        # activate() set it behind monkeypatch's back
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+
+
+def test_cachedir_activate_if_configured_only_when_set(monkeypatch,
+                                                       tmp_path):
+    """The library entry (executor.search_beam) turns the persistent
+    cache on only when the variable is set."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert cachedir.activate_if_configured() is None
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+
+
+@pytest.mark.parametrize("pinned", [False, True])
+def test_two_processes_resolve_the_same_path(tmp_path, pinned):
+    """No temp-, pid- or time-derived component: two fresh processes
+    (different pids, different cwds, seconds apart) resolve one
+    path."""
+    import tpulsar
+
+    env = dict(tpulsar.cpu_subprocess_env(), PYTHONPATH=_REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if pinned:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "pin")
+    got = []
+    for cwd in (str(tmp_path), _REPO):
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "from tpulsar.aot import cachedir; "
+             "print(cachedir.resolve())"],
+            capture_output=True, text=True, timeout=60, env=env,
+            cwd=cwd)
+        assert out.returncode == 0, out.stderr[-400:]
+        got.append(out.stdout.strip())
+    want = (str(tmp_path / "pin") if pinned
+            else os.path.join(_REPO, ".jax_cache"))
+    assert got == [want, want]
 
 
 def test_manifest_path_lives_in_cache_dir(monkeypatch, tmp_path):
-    monkeypatch.setenv("TPULSAR_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert cachedir.manifest_path() == str(
         tmp_path / cachedir.MANIFEST_NAME)
 
@@ -166,6 +223,40 @@ def test_gate_groups_cover_only_registered_programs():
     assert "bench.gen_block_chunk" in seen
 
 
+def test_gate_compiles_the_stage_family_the_runtime_dispatches(
+        monkeypatch):
+    """Where the Pallas tiers are on (a TPU backend; forced here by
+    env) the gate holds their slab / chunk programs at the wrappers'
+    own geometry and none of the XLA formulations the runtime would
+    never reach there."""
+    from tpulsar.kernels import pallas_dd
+
+    ctx = registry.make_context(scale=1.0)
+
+    def programs():
+        return {i.program: i for _h, g in registry.gate_groups(
+            ctx, fast=True) for i in g}
+
+    monkeypatch.delenv("TPULSAR_PALLAS", raising=False)
+    xla = programs()
+    assert "dedisperse._form_subbands_jit" in xla
+    assert "pallas_dd._form_subbands_block" not in xla
+
+    monkeypatch.setenv("TPULSAR_PALLAS", "1")
+    tpu = programs()
+    assert "dedisperse._form_subbands_jit" not in tpu
+    assert "dedisperse._dedisperse_subbands_scan" not in tpu
+    sb = tpu["pallas_dd._form_subbands_block"]
+    assert sb.kwargs["interpret"] is False
+    # 960 channels stage at block 1024 (the 16 MB scoped-VMEM rule)
+    assert sb.kwargs["block_t"] == pallas_dd.stage1_block_t(
+        960, 96, 256, 1) == 1024
+    assert sb.args[0].dtype == "bfloat16"        # widened uint8
+    dd2 = tpu["pallas_dd._dedisperse_chunk"]
+    assert dd2.args[1].shape == (32, 96)
+    assert dd2.kwargs["variant"] == "roll"
+
+
 def test_fingerprint_is_stable_and_shape_sensitive():
     from tpulsar.aot import warmstart
 
@@ -200,7 +291,7 @@ def test_two_process_warm_start_zero_misses(tmp_path):
     against the manifest and must report ZERO misses — the acceptance
     contract that a warm child search compiles nothing the gate
     already compiled."""
-    env = {"TPULSAR_CACHE_DIR": str(tmp_path / "cache")}
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
     only = "refine.gather,rfi._cell_stats_chan"
 
     first = _run_gate(["--scale", "0.02", "--only", only], env)
@@ -227,7 +318,7 @@ def test_two_process_warm_start_zero_misses(tmp_path):
 
 
 def test_verify_without_manifest_fails(tmp_path):
-    env = {"TPULSAR_CACHE_DIR": str(tmp_path / "nocache")}
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "nocache")}
     out = _run_gate(["--scale", "0.02", "--only", "refine.gather",
                      "--verify"], env)
     assert out.returncode == 1
@@ -238,7 +329,7 @@ def test_verify_flags_cold_cache_as_miss(tmp_path):
     """Manifest present but cache entries gone (e.g. cache GC'd):
     verify must MISS, not silently recompile — this is precisely the
     round-5 bench scenario as an exit code."""
-    env = {"TPULSAR_CACHE_DIR": str(tmp_path / "cache")}
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
     only = "refine.gather"
     first = _run_gate(["--scale", "0.02", "--only", only], env)
     assert first.returncode == 0, first.stdout[-500:]
@@ -366,7 +457,7 @@ def test_compile_rollup_dedupes_gate_event_pairs(tmp_path):
 def test_only_matching_nothing_is_loud(tmp_path):
     """A typo'd --only must not green-light an unverified cache with
     a vacuous rc-0 (0/0 hits, 0 misses)."""
-    env = {"TPULSAR_CACHE_DIR": str(tmp_path / "cache")}
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
     out = _run_gate(["--scale", "0.02", "--only", "refine.gahter"],
                     env)
     assert out.returncode == 1, out.stdout[-400:]
@@ -376,7 +467,7 @@ def test_only_matching_nothing_is_loud(tmp_path):
 def test_gate_saves_trace_when_enabled(tmp_path):
     """TPULSAR_TRACE=1 gate runs save their aot_compile spans next to
     the manifest so the compile rollup has a real artifact to read."""
-    env = {"TPULSAR_CACHE_DIR": str(tmp_path / "cache"),
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
            "TPULSAR_TRACE": "1"}
     out = _run_gate(["--scale", "0.02", "--only", "refine.gather"],
                     env)

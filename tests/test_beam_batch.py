@@ -154,8 +154,8 @@ def mini_beams(tmp_path_factory):
     from tpulsar.search import executor
 
     base = tmp_path_factory.mktemp("beambatch")
-    cache_was_unset = "TPULSAR_CACHE_DIR" not in os.environ
-    os.environ.setdefault("TPULSAR_CACHE_DIR",
+    cache_was_unset = "JAX_COMPILATION_CACHE_DIR" not in os.environ
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           str(base / "jax_cache"))
     psr = synth.PulsarSpec(period_s=0.05, dm=20.0,
                            snr_per_sample=1.5)
@@ -175,7 +175,7 @@ def mini_beams(tmp_path_factory):
     yield {"base": base, "beams": beams, "params": params,
            "solo": solo}
     if cache_was_unset:
-        os.environ.pop("TPULSAR_CACHE_DIR", None)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
 def _assert_outcome_parity(a, b, label=""):

@@ -1,0 +1,149 @@
+"""Ask the chip's compiler, without the chip: the kernels of the main
+path compiled for a DESCRIBED v5e at the survey's widths.
+
+The TPU compiler is installed next to the CPU backend and compiles for
+a topology that is described, not attached.  Nothing executes, so
+these say nothing about results or speed — they catch what interpret
+mode cannot: a Mosaic lowering the chip refuses, a scoped-VMEM
+overrun, an XLA program that does not fit.
+
+Only one process may load the TPU library, so the topology is
+described inside a module-scoped fixture of THIS file (never at
+import, never in conftest.py) and every case compiles in the test's
+own process.  This is the one file of such tests: under pytest-xdist
+a second file could land on another worker and skip there in silence.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+# the survey's Mock beam (tpulsar.aot.registry) and hi-accel settings
+NSAMP = 3_932_160
+NSUB = 96
+ZMAX = 50.0
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent
+    # cache but cannot be read back without the chip: keep it off
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("ndms,overhang", [(32, 1024), (38, 2048)])
+def test_stage2_dedispersion_kernel(one_chip, ndms, overhang):
+    """pallas_dd._dedisperse_chunk (roll variant) at a ds=1 pass."""
+    from tpulsar.kernels import pallas_dd
+
+    block_t = 4096
+    n_blocks = -(-NSAMP // block_t)
+    compiled = pallas_dd._dedisperse_chunk.lower(
+        _sds(one_chip, (NSUB, n_blocks * block_t + overhang),
+             jnp.float32),
+        _sds(one_chip, (ndms, NSUB), jnp.int32),
+        block_t=block_t, window=block_t + overhang, interpret=False,
+        variant="roll").compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("nchan,nsub,overhang", [
+    (960, 96, 256), (960, 96, 1024),     # Mock
+    (256, 64, 256),                      # WAPP width
+])
+def test_stage1_subband_kernel(one_chip, nchan, nsub, overhang):
+    """pallas_dd._form_subbands_block on one bf16 slab, at the block
+    length the wrapper's VMEM rule picks for that width."""
+    from tpulsar.kernels import pallas_dd
+
+    block_t = pallas_dd.stage1_block_t(nchan, nsub, overhang, 1)
+    n_blocks = 1016
+    compiled = pallas_dd._form_subbands_block.lower(
+        _sds(one_chip, (nchan, n_blocks * block_t + overhang),
+             jnp.bfloat16),
+        _sds(one_chip, (nsub, nchan // nsub), jnp.int32),
+        nsub=nsub, block_t=block_t, window=block_t + overhang,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def tpu_accel_branch(monkeypatch):
+    """accel.py asks jax.default_backend() at trace time and would take
+    its CPU branch here (f32 plane, z-chunk 16): steer it to what the
+    chip takes."""
+    from tpulsar.kernels import accel
+
+    monkeypatch.setattr(accel, "_PLANE_DTYPE_RESOLVED", jnp.bfloat16)
+    monkeypatch.setattr(accel, "_Z_CHUNK_RESOLVED", 4)
+    return accel
+
+
+@pytest.mark.parametrize("program", ["chunk", "row"])
+def test_hi_accel_programs(one_chip, tpu_accel_branch, program):
+    """accel_chunk_topk / accel_row_topk at the survey's segment
+    length, nz = 51 and 8 harmonics; nbins is cut (the full 1,966,081
+    bins compile in about a minute)."""
+    accel = tpu_accel_branch
+    bank = accel.build_template_bank(ZMAX)
+    nz = len(bank.zs)
+    assert (nz, bank.seg) == (51, 8192)
+    nbins = 16_385
+    args = (_sds(one_chip, (4, nbins), jnp.complex64),
+            _sds(one_chip, bank.bank_fft.shape, jnp.complex64),
+            _sds(one_chip, (), jnp.int32))
+    kw = dict(seg=bank.seg, step=bank.step, width=bank.width, nz=nz,
+              max_numharm=8, topk=32)
+    if program == "chunk":
+        lowered = accel.accel_chunk_topk.lower(*args, nrows=2, **kw)
+    else:
+        lowered = accel.accel_row_topk.lower(*args, **kw)
+    compiled = lowered.compile()
+    # the plane really is bf16 on this branch
+    assert "bf16" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_whitening_program(one_chip):
+    """The fused pad -> rfft -> whiten -> scale program at a ds=1 pass
+    chunk (38 trials of the full series)."""
+    from tpulsar.kernels import fourier as fr
+    from tpulsar.plan import ddplan
+
+    nfft = ddplan.choose_n(NSAMP)
+    compiled = fr.whitened_spectrum.lower(
+        _sds(one_chip, (38, NSAMP), jnp.float32), nfft=nfft).compile()
+    assert compiled.memory_analysis().output_size_in_bytes >= \
+        38 * (nfft // 2 + 1) * 8
+
+
+def test_single_pulse_programs(one_chip):
+    """Detrend + boxcar ladder at a ds=1 pass chunk."""
+    from tpulsar.kernels import singlepulse as sp_k
+
+    series = _sds(one_chip, (38, NSAMP), jnp.float32)
+    sp_k.normalize_series.lower(
+        series, estimator=sp_k.detrend_estimator()).compile()
+    compiled = sp_k.boxcar_search.lower(
+        series, tuple(sp_k.DEFAULT_WIDTHS),
+        sp_k.DEFAULT_TOPK).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0

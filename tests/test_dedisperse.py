@@ -288,12 +288,6 @@ def test_pallas_variants_match_gather(monkeypatch):
             subb, shifts, block_t=256, dm_chunk=4, interpret=True))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4,
                                    err_msg=variant)
-    # the smoke cache is variant-keyed: a roll pass must not
-    # validate slice
-    monkeypatch.setenv("TPULSAR_PALLAS_VARIANT", "roll")
-    p_roll = pallas_dd._smoke_cache_path()
-    monkeypatch.setenv("TPULSAR_PALLAS_VARIANT", "slice")
-    assert pallas_dd._smoke_cache_path() != p_roll
     monkeypatch.setenv("TPULSAR_PALLAS_VARIANT", "bogus")
     with pytest.raises(ValueError):
         pallas_dd.kernel_variant()
@@ -424,3 +418,37 @@ def test_pallas_form_subbands_slabbed_matches_single():
         data, shifts, nsub, 3, block_t=256, interpret=True,
         slab_bytes=16 * 2 * 256))
     np.testing.assert_array_equal(one_ds, many_ds)
+
+
+def test_pallas_gates_are_backend_and_env_only(monkeypatch):
+    """No probe, no memo: the Pallas tiers are on exactly on a TPU
+    backend, unless switched off (or forced on) by env."""
+    from tpulsar.kernels import pallas_dd
+
+    monkeypatch.delenv("TPULSAR_PALLAS", raising=False)
+    monkeypatch.delenv("TPULSAR_PALLAS_SB", raising=False)
+    assert not pallas_dd.is_tpu_backend()           # CPU CI
+    assert not pallas_dd.use_pallas()
+    assert not pallas_dd.use_pallas_sb()
+    monkeypatch.setattr(pallas_dd, "is_tpu_backend", lambda: True)
+    assert pallas_dd.use_pallas() and pallas_dd.use_pallas_sb()
+    monkeypatch.setenv("TPULSAR_PALLAS_SB", "0")
+    assert pallas_dd.use_pallas() and not pallas_dd.use_pallas_sb()
+    monkeypatch.setenv("TPULSAR_PALLAS", "0")
+    assert not pallas_dd.use_pallas()
+
+
+def test_pallas_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
+    import pytest
+    from tpulsar.kernels import pallas_dd
+
+    assert pallas_dd._resolve_interpret(None) is True    # CPU CI
+    assert pallas_dd._resolve_interpret(False) is False
+    monkeypatch.setattr(pallas_dd, "is_tpu_backend", lambda: True)
+    assert pallas_dd._resolve_interpret(None) is False
+    with pytest.raises(AssertionError, match="interpret"):
+        pallas_dd._resolve_interpret(True)
+    with pytest.raises(AssertionError, match="interpret"):
+        pallas_dd.dedisperse_subbands_pallas(
+            np.zeros((8, 256), np.float32), np.zeros((2, 8), np.int32),
+            interpret=True)

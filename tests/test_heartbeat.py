@@ -97,28 +97,3 @@ def test_bench_parses_heartbeat_and_budgets(tmp_path, monkeypatch):
         == bench._STAGE_BUDGET_DEFAULT
     monkeypatch.setenv("TPULSAR_STAGE_BUDGET_MULT", "2.5")
     assert bench._stage_budget("hi-accelsearch") == 2.5 * base
-
-
-def test_collect_evidence_folds_failed_attempts(tmp_path):
-    ce = _load("collect_ev_test",
-               os.path.join(_REPO, "tools", "collect_evidence.py"))
-    runs = tmp_path / "runs"
-    adir = runs / "attempts" / "20260801T000000_1_cfg1"
-    adir.mkdir(parents=True)
-    (adir / "attempt.json").write_text(json.dumps({
-        "label": "cfg1", "status": "stage_budget", "rc": -15,
-        "deadline_s": 240.0, "elapsed_s": 900.0,
-        "kill_reason": "stage budget: dedispersing has run 430 s",
-        "stalled_stage": "dedispersing", "stage_elapsed_s": 430.0,
-        "stage_progress": "accel window dm 32/128",
-        "attempt_dir": "bench_runs/attempts/x"}))
-    ok = runs / "attempts" / "20260801T000001_2_cfg1"
-    ok.mkdir(parents=True)
-    (ok / "attempt.json").write_text(json.dumps({"status": "ok"}))
-    recs = ce._attempt_records(str(runs))
-    # ok attempts excluded (their result is in runs{}); the killed
-    # attempt's stage attribution survives into the committed record
-    assert len(recs) == 1
-    assert recs[0]["stalled_stage"] == "dedispersing"
-    assert recs[0]["stage_elapsed_s"] == 430.0
-    assert recs[0]["status"] == "stage_budget"

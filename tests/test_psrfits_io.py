@@ -302,3 +302,73 @@ def test_disjoint_band_grouping_warns(tmp_path):
         except Exception:
             pass
     assert any("disjoint frequency bands" in str(x.message) for x in w)
+
+
+# --------------------------------------------------------------------
+# the block-wise generator: same bytes as the whole-array generator it
+# replaced, at the sizes the tests write
+# --------------------------------------------------------------------
+
+_GOLDEN_BEAMS = {
+    "pulsar_pdot": (
+        dict(nchan=32, nsamp=8192, nbits=4),
+        [dict(period_s=0.05, dm=30.0, snr_per_sample=0.5, pdot=1e-9)],
+        [], True, ["f47b7b497b23dfa6"]),
+    "rfi_descending_8bit": (
+        dict(nchan=16, nsamp=4096, nbits=8, descending_band=True,
+             seed=7),
+        [dict(period_s=0.1, dm=10.0)],
+        [dict(kind="tone", channel=3),
+         dict(kind="burst", t_start_s=0.5, t_len_s=0.2)],
+        True, ["2517f3ef7e42bb64"]),
+    "mock_pair": (
+        dict(nchan=32, nsamp=2048, nbits=4, seed=3),
+        [dict(period_s=0.02, dm=50.0, snr_per_sample=1.0)],
+        [], False, ["19fd662673062aab", "3f17e1b0f14db5c3"]),
+    "default_width": (
+        dict(nchan=96, nsamp=1 << 16, nbits=4),
+        [dict(period_s=0.25, dm=50.0, snr_per_sample=1.0)],
+        [], True, ["daee49db1cc920cf"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_GOLDEN_BEAMS))
+def test_synth_bytes_unchanged(tmp_path, name):
+    """sha256 prefixes recorded from the whole-array generator (the
+    seed of PR 22) — the repair for full-width beams must not move a
+    byte of what the tests write."""
+    import hashlib
+
+    spec_kw, psrs, rfis, merged, want = _GOLDEN_BEAMS[name]
+    fns = synth.synth_beam(
+        str(tmp_path), synth.BeamSpec(**spec_kw),
+        pulsars=[synth.PulsarSpec(**p) for p in psrs],
+        rfi=[synth.RFISpec(**r) for r in rfis], merged=merged)
+    got = [hashlib.sha256(open(f, "rb").read()).hexdigest()[:16]
+           for f in fns]
+    assert got == want
+
+
+def test_synth_multi_block_beam(tmp_path, monkeypatch):
+    """A beam that spans several generator blocks (as the full Mock
+    beam does): every block is written at its place, blocks draw
+    different noise, the file is deterministic, and the in-memory
+    spectrum is the one the file holds."""
+    monkeypatch.setattr(synth, "BLOCK_ELEMS", 16 * 256)
+    monkeypatch.setattr(synth, "LEVEL_ELEMS", 2 * 16 * 256)
+    spec = synth.BeamSpec(nchan=16, nsamp=2048, nsblk=64, nbits=8,
+                          seed=5)
+    assert synth.block_rows(spec) == 256        # 8 blocks
+    psr = synth.PulsarSpec(period_s=0.02, dm=20.0, snr_per_sample=2.0)
+    a, = synth.synth_beam(str(tmp_path / "a"), spec, pulsars=[psr])
+    b, = synth.synth_beam(str(tmp_path / "b"), spec, pulsars=[psr])
+    assert open(a, "rb").read() == open(b, "rb").read()
+
+    block = SpectraInfo([a]).read_all()
+    assert block.shape == (2048, 16)
+    want = synth.make_dynamic_spectrum(spec, pulsars=[psr])
+    # 8-bit digitization: close, not equal
+    assert np.corrcoef(block.ravel(), want.ravel())[0, 1] > 0.99
+    parts = want.reshape(8, 256, 16)
+    assert all(np.abs(parts[i] - parts[j]).max() > 1.0
+               for i in range(8) for j in range(i))

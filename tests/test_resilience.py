@@ -528,6 +528,28 @@ def test_dedisperse_pallas_fault_falls_back():
     degraded.reset()
 
 
+def test_dedisperse_pallas_fault_is_loud_on_a_tpu_backend(monkeypatch):
+    """On a TPU backend a kernel fault fails the beam instead of
+    completing it on the XLA path, and nothing is disabled."""
+    import jax.numpy as jnp
+
+    from tpulsar.kernels import dedisperse as dd
+    from tpulsar.kernels import pallas_dd
+    from tpulsar.search import degraded
+    monkeypatch.setattr(pallas_dd, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(pallas_dd, "use_pallas", lambda: False)
+    subb = jnp.zeros((8, 512), jnp.float32)
+    shifts = jnp.zeros((4, 8), jnp.int32)
+    faults.configure("dedisperse.pallas:unimplemented:count=1")
+    degraded.reset()
+    disabled = dict(pallas_dd._DISABLED_SIGS)
+    with pytest.raises(Exception, match="dedisperse.pallas"):
+        dd.dedisperse_subbands(subb, shifts)
+    assert "pallas_dd_disabled" not in degraded.snapshot()
+    assert pallas_dd._DISABLED_SIGS == disabled
+    degraded.reset()
+
+
 # ----------------------------- orchestrate fault points + policy routes
 
 def test_downloader_transfer_fault_exercises_retry_ledger(tmp_path):

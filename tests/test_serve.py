@@ -168,6 +168,14 @@ def test_serve_once_processes_spool(tmp_path, cfg):
     assert by_id[first]["warm"] is False
     assert by_id[second]["warm"] is True
     assert protocol.read_heartbeat(str(spool))["status"] == "stopped"
+    # every record says where it ran, as jax reports it in the worker,
+    # and how the boot went (no gate ran here)
+    import jax
+    dev = jax.devices()
+    assert r0["device"] == r1["device"] == {
+        "platform": "cpu", "kind": dev[0].device_kind,
+        "count": len(dev)}
+    assert r0["boot_gate_rc"] is None and r0["boot_seconds"] >= 0
     assert srv.beams == {"done": 2, "failed": 0, "skipped": 0}
 
 

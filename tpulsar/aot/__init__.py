@@ -17,9 +17,8 @@ comment-enforced:
                   from ``SearchParams``/``DDPlan``/scale.  Consumed by
                   the gate, the runtime, and the diagnostics.
   ``cachedir``  — the ONE resolver for the persistent compilation
-                  cache location (``TPULSAR_CACHE_DIR``), replacing
-                  four inconsistent ``JAX_COMPILATION_CACHE_DIR``
-                  setdefaults scattered across tools/ and the CLI.
+                  cache location (``JAX_COMPILATION_CACHE_DIR``
+                  when set, else ``<checkout>/.jax_cache``).
   ``warmstart`` — the gate driver: compiles the registered program
                   set, records each program's cache fingerprint in a
                   manifest, verifies warm runs against it, and
@@ -32,7 +31,7 @@ the thin ``tools/aot_check.py`` wrapper (rc 0/1/3 contract).
 
 ``cachedir`` and ``registry``'s table are stdlib-only at import time:
 jax and the kernels load lazily, so the CLI can list programs and
-resolve cache paths without dialing a (possibly wedged) accelerator.
+resolve cache paths without touching the accelerator.
 """
 
 from tpulsar.aot import cachedir  # noqa: F401  (stdlib-only)

@@ -3,8 +3,8 @@
 One table maps a program name to the EXACT module-level jitted
 callable the runtime invokes, and one set of shape-builders derives
 the canonical compile shapes from ``SearchParams``/``DDPlan``/scale.
-The AOT gate (tpulsar.aot.warmstart / tools/aot_check.py), the
-runtime, and the diagnostics (tools/diag_cache_key.py) all consume
+The AOT gate (tpulsar.aot.warmstart / tools/aot_check.py) and the
+runtime both consume
 this table, so the gate-vs-child drift that cost the round-5 campaign
 a 160.6 s silent recompile cannot recur by omission: a jit site is
 either registered here or on the commented :data:`EXEMPT_SITES` list,
@@ -136,7 +136,7 @@ PROGRAMS: tuple[Program, ...] = (
        ("T", "fuse", "detrend_block", "estimator"),
        doc="per-dm_chunk residual layer with the SP detrend fused "
            "into the same program"),
-    # ---- kernels/pallas_dd.py (engage behind their own smoke gates)
+    # ---- kernels/pallas_dd.py (the stage-1/2 tiers of a TPU backend)
     _k("pallas_dd", "_dedisperse_chunk",
        ("block_t", "window", "interpret", "variant")),
     _k("pallas_dd", "_pad_widen", ("pad",)),
@@ -298,10 +298,7 @@ def _bench_gen_jit():
     try:
         import bench as bench_mod
     except ImportError:
-        root = cachedir.repo_root()
-        if root is None:
-            raise
-        sys.path.insert(0, root)
+        sys.path.insert(0, cachedir.repo_root())
         import bench as bench_mod
     return partial(jax.jit, static_argnames=("n", "nc", "dtype"))(
         bench_mod.gen_block_chunk)
@@ -529,8 +526,7 @@ def _config_groups(ctx: GateContext,
                    config: int) -> list[tuple[str, list[Instance]]]:
     """Focused-config gate: the exact programs
     bench.run_focused_config(cfg) will execute (one 128/32-trial pass
-    at ds=1 on the full-length block; the runtime dedisperse path is
-    the XLA scan — Pallas only engages behind its own smoke gate)."""
+    at ds=1 on the full-length block, XLA formulations)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -743,19 +739,11 @@ def _headline_groups(ctx: GateContext,
     for step, T_ds, ndms, pad_pairs, nfft, chunk in block_geoms:
         insts = []
         for pad1, pad2 in sorted(pad_pairs):
-            insts += [
-                Instance("dedisperse._form_subbands_jit",
-                         f"form_subbands ds={step.downsamp} pad={pad1}",
-                         (blk, _sds((NCHAN,), jnp.int32)),
-                         dict(nsub=step.numsub,
-                              downsamp=step.downsamp, pad=pad1)),
-                Instance("dedisperse._dedisperse_subbands_scan",
-                         f"dedisperse_scan ds={step.downsamp} "
-                         f"pad={pad2}",
-                         (_sds((step.numsub, T_ds), jnp.float32),
-                          _sds((ndms, step.numsub), jnp.int32)),
-                         dict(pad=pad2)),
-            ]
+            tag = f"ds={step.downsamp}"
+            insts += _stage1_instances(blk, step.numsub,
+                                       step.downsamp, pad1, tag)
+            insts += _stage2_instances(step.numsub, T_ds, ndms,
+                                       pad2, tag)
         groups.append((f"step downsamp={step.downsamp} (T'={T_ds}, "
                        f"ndms={ndms}, pads={sorted(pad_pairs)}):",
                        insts))
@@ -900,19 +888,82 @@ def _headline_groups(ctx: GateContext,
                   dd._pad_bucket(int(sb.max(initial=0)))))
     insts = []
     for p1, p2 in sorted(pads):
-        insts += [
-            Instance("dedisperse._form_subbands_jit",
-                     f"form_subbands 1dm pad={p1}",
-                     (blk, _sds((NCHAN,), jnp.int32)),
-                     dict(nsub=96, downsamp=1, pad=p1)),
-            Instance("dedisperse._dedisperse_subbands_scan",
-                     f"dedisperse_1dm pad={p2}",
-                     (_sds((96, ctx.nsamp), jnp.float32),
-                      _sds((1, 96), jnp.int32)),
-                     dict(pad=p2)),
-        ]
-    groups.append(("", insts))
+        insts += _stage1_instances(blk, 96, 1, p1, "1dm")
+        insts += _stage2_instances(96, ctx.nsamp, 1, p2, "1dm")
+    # the sweep's pad pairs repeat each bucket: one instance per label
+    groups.append(("", list({i.label: i for i in insts}.values())))
     return groups
+
+
+def _stage1_instances(blk, nsub: int, downsamp: int, pad: int,
+                      tag: str) -> list[Instance]:
+    """Subband formation for one pad bucket, in the family the runtime
+    dispatches (dedisperse.form_subbands): the Pallas slab programs
+    where that tier is on — a TPU backend — and the XLA map
+    elsewhere."""
+    import jax.numpy as jnp
+
+    from tpulsar.kernels import pallas_dd
+
+    nchan, T = blk.shape
+    if not pallas_dd.use_pallas_sb():
+        return [Instance("dedisperse._form_subbands_jit",
+                         f"form_subbands {tag} pad={pad}",
+                         (blk, _sds((nchan,), jnp.int32)),
+                         dict(nsub=nsub, downsamp=downsamp, pad=pad))]
+    itemsize = jnp.dtype(blk.dtype).itemsize
+    S = pallas_dd.stage_overhang(pad)
+    block_t = pallas_dd.stage1_block_t(nchan, nsub, S, itemsize)
+    wide = jnp.bfloat16 if itemsize == 1 else blk.dtype
+    insts = {}
+    for _t0, _ts, take, epad in pallas_dd.stage1_slabs(
+            T, nchan, itemsize, block_t, S):
+        insts[take, epad] = [
+            Instance("pallas_dd._pad_widen",
+                     f"pallas_pad_widen {tag} S={S} "
+                     f"take={take} pad={epad}",
+                     (_sds((nchan, take), blk.dtype),),
+                     dict(pad=epad)),
+            Instance("pallas_dd._form_subbands_block",
+                     f"pallas_subbands {tag} S={S} "
+                     f"cols={take + epad}",
+                     (_sds((nchan, take + epad), wide),
+                      _sds((nsub, nchan // nsub), jnp.int32)),
+                     dict(nsub=nsub, block_t=block_t,
+                          window=block_t + S, interpret=False)),
+        ]
+    return [i for pair in insts.values() for i in pair]
+
+
+def _stage2_instances(nsub: int, T: int, rows: int, pad: int,
+                      tag: str) -> list[Instance]:
+    """Stage-2 dedispersion of a ``rows``-trial chunk for one pad
+    bucket, in the family dedisperse.dedisperse_subbands dispatches:
+    the Pallas sliding-window kernel (always one 32-row call shape)
+    where that tier is on, the XLA scan elsewhere."""
+    import jax.numpy as jnp
+
+    from tpulsar.kernels import pallas_dd
+
+    if not pallas_dd.use_pallas():
+        return [Instance("dedisperse._dedisperse_subbands_scan",
+                         (f"dedisperse_1dm pad={pad}" if tag == "1dm"
+                          else f"dedisperse_scan {tag} pad={pad}"),
+                         (_sds((nsub, T), jnp.float32),
+                          _sds((rows, nsub), jnp.int32)),
+                         dict(pad=pad))]
+    dm_chunk = 32       # dedisperse_subbands_pallas's call shape
+    S = pallas_dd.stage_overhang(pad)
+    block_t = pallas_dd.stage2_block_t(nsub, S, min(dm_chunk, rows))
+    cols = -(-T // block_t) * block_t + S
+    return [Instance("pallas_dd._dedisperse_chunk",
+                     f"pallas_dedisperse {tag} S={S} "
+                     f"block={block_t}",
+                     (_sds((nsub, cols), jnp.float32),
+                      _sds((dm_chunk, nsub), jnp.int32)),
+                     dict(block_t=block_t, window=block_t + S,
+                          interpret=False,
+                          variant=pallas_dd.kernel_variant()))]
 
 
 def _tree_groups(ctx: GateContext, geoms,

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -79,27 +81,77 @@ def dispersion_delays(dm: float, freqs_mhz: np.ndarray,
     return dispersion_delay_s(dm, freqs_mhz, ref_freq_mhz)
 
 
-def make_dynamic_spectrum(spec: BeamSpec,
-                          pulsars: list[PulsarSpec] = (),
-                          rfi: list[RFISpec] = ()) -> np.ndarray:
-    """Float32 (nsamp, nchan) dynamic spectrum, channels ascending in
-    frequency, unit-variance noise plus injected signals."""
-    rng = np.random.default_rng(spec.seed)
-    data = rng.standard_normal((spec.nsamp, spec.nchan)).astype(np.float32)
+#: elements of the dynamic spectrum generated, digitized and written
+#: at a time.  A full Mock beam (3.9 M samples x 960 channels, 15 GB
+#: as float32) is 480 such blocks; every beam the tests write is one.
+BLOCK_ELEMS = 1 << 23
+
+#: the quantisation levels come from (at least) this many leading
+#: elements — the whole beam at every size the tests write
+LEVEL_ELEMS = 1 << 25
+
+_scratch = threading.local()
+
+
+def _buf(name: str, shape: tuple, dtype) -> np.ndarray:
+    """A per-thread work buffer, reused from block to block.  A fresh
+    multi-MB temporary per operation is page-faulted anew every time,
+    and a sandboxed host may never hand the freed pages back: a full
+    beam written with plain expressions churns through ~50 GB."""
+    arr = getattr(_scratch, name, None)
+    if arr is None or arr.shape != shape or arr.dtype != dtype:
+        arr = np.empty(shape, dtype)
+        setattr(_scratch, name, arr)
+    return arr
+
+
+def block_rows(spec: BeamSpec) -> int:
+    """Rows per generated block: a whole number of subints."""
+    rows = (BLOCK_ELEMS // spec.nchan) // spec.nsblk * spec.nsblk
+    return min(spec.nsamp, max(spec.nsblk, rows))
+
+
+def _block(spec: BeamSpec, pulsars, rfi, k: int, rows: int
+           ) -> np.ndarray:
+    """Block ``k`` (rows [k*rows, (k+1)*rows)) of the dynamic
+    spectrum, float32 with channels ascending in frequency:
+    unit-variance noise plus the injected signals.  Each block draws
+    its noise from a stream of its own, (seed, k) — block 0 from
+    ``seed`` alone — so blocks are made in parallel.  The result is
+    this thread's work buffer: use it before the next call."""
+    r0 = k * rows
+    shape = (min(rows, spec.nsamp - r0), spec.nchan)
+    rng = np.random.default_rng([spec.seed, k] if k else spec.seed)
+    f64 = _buf("f64", shape, np.float64)
+    rng.standard_normal(out=f64)
+    data = _buf("data", shape, np.float32)
+    data[...] = f64
     freqs = channel_freqs(spec)
     ref = freqs[-1]
-    t = np.arange(spec.nsamp) * spec.tsamp_s
+    t = (r0 + np.arange(shape[0])) * spec.tsamp_s
 
     for psr in pulsars:
         delays = dispersion_delays(psr.dm, freqs, ref)
-        # Gaussian pulse profile in phase, per channel with its delay.
+        # Gaussian pulse profile in phase, per channel with its
+        # delay:  snr * exp(-0.5 * (min(ph, 1 - ph) / sigma) ** 2),
+        # ph = ((t - delay) / p_inst) % 1  — those operations in that
+        # order, on the work buffers
         sigma_phase = psr.width_frac / 2.35482
-        for c in range(spec.nchan):
-            p_inst = psr.period_s + psr.pdot * t
-            phase = ((t - delays[c]) / p_inst) % 1.0
-            dph = np.minimum(phase, 1.0 - phase)
-            data[:, c] += (psr.snr_per_sample
-                           * np.exp(-0.5 * (dph / sigma_phase) ** 2)).astype(np.float32)
+        p_inst = (psr.period_s + psr.pdot * t)[:, None]
+        ph, tmp = f64, _buf("tmp", shape, np.float64)
+        np.subtract(t[:, None], delays[None, :], out=ph)
+        ph /= p_inst
+        np.mod(ph, 1.0, out=ph)
+        np.subtract(1.0, ph, out=tmp)
+        np.minimum(ph, tmp, out=ph)
+        ph /= sigma_phase
+        np.square(ph, out=ph)
+        ph *= -0.5
+        np.exp(ph, out=ph)
+        ph *= psr.snr_per_sample
+        f32 = _buf("cast", shape, np.float32)
+        f32[...] = ph
+        data += f32
 
     for r in rfi:
         if r.kind == "tone":
@@ -108,39 +160,43 @@ def make_dynamic_spectrum(spec: BeamSpec,
         elif r.kind == "burst":
             i0 = int(r.t_start_s / spec.tsamp_s)
             i1 = min(spec.nsamp, i0 + max(1, int(r.t_len_s / spec.tsamp_s)))
-            data[i0:i1, :] += r.amplitude
+            lo, hi = max(i0 - r0, 0), min(i1 - r0, shape[0])
+            if hi > lo:
+                data[lo:hi, :] += r.amplitude
     return data
 
 
-def _digitize(data: np.ndarray, nbits: int):
-    """Map float data to unsigned nbits ints plus per-channel
-    scale/offset so that decode(scale*x+offset) ~= data."""
+def make_dynamic_spectrum(spec: BeamSpec,
+                          pulsars: list[PulsarSpec] = (),
+                          rfi: list[RFISpec] = ()) -> np.ndarray:
+    """Float32 (nsamp, nchan) dynamic spectrum in one piece."""
+    rows = block_rows(spec)
+    return _stacked(lambda k: _block(spec, pulsars, rfi, k, rows),
+                    -(-spec.nsamp // rows))
+
+
+def _stacked(block, n: int) -> np.ndarray:
+    """Blocks 0..n-1 in one array (copied: a block may be a work
+    buffer that the next call overwrites)."""
+    out = np.concatenate([np.array(block(k)) for k in range(n)])
+    _scratch.__dict__.clear()     # the calling thread outlives the call
+    return out
+
+
+def _levels(data: np.ndarray, nbits: int):
+    """Per-channel scale/offset mapping float data onto unsigned
+    nbits ints so that decode(scale*x+offset) ~= data."""
     lo = np.percentile(data, 0.5, axis=0)
     hi = np.percentile(data, 99.5, axis=0)
     nlev = (1 << nbits) - 1
     scale = np.maximum((hi - lo) / nlev, 1e-6).astype(np.float32)
-    offset = lo.astype(np.float32)
-    q = np.clip(np.round((data - offset) / scale), 0, nlev).astype(np.uint16)
-    return q, scale, offset
+    return scale, lo.astype(np.float32)
 
 
-def write_psrfits(path: str, spec: BeamSpec, data: np.ndarray) -> str:
-    """Write (nsamp, nchan) float data as a search-mode PSRFITS file."""
-    nsub = spec.nsamp // spec.nsblk
-    if nsub * spec.nsblk != spec.nsamp:
-        raise ValueError("nsamp must be a multiple of nsblk")
-    q, scale, offset = _digitize(data, spec.nbits)
-
-    freqs = channel_freqs(spec)
-    if spec.descending_band:
-        freqs = freqs[::-1]
-        q = q[:, ::-1]
-        scale = scale[::-1]
-        offset = offset[::-1]
-
-    nchan, npol, nsblk = spec.nchan, spec.npol, spec.nsblk
-    bytes_per_blk = nsblk * npol * nchan * spec.nbits // 8
-    rowdt = np.dtype([
+def _subint_dtype(spec: BeamSpec) -> np.dtype:
+    nchan, npol = spec.nchan, spec.npol
+    bytes_per_blk = spec.nsblk * npol * nchan * spec.nbits // 8
+    return np.dtype([
         ("TSUBINT", ">f8"), ("OFFS_SUB", ">f8"), ("LST_SUB", ">f8"),
         ("RA_SUB", ">f8"), ("DEC_SUB", ">f8"), ("GLON_SUB", ">f8"),
         ("GLAT_SUB", ">f8"), ("FD_ANG", ">f4"), ("POS_ANG", ">f4"),
@@ -149,22 +205,74 @@ def write_psrfits(path: str, spec: BeamSpec, data: np.ndarray) -> str:
         ("DAT_OFFS", ">f4", (nchan * npol,)), ("DAT_SCL", ">f4", (nchan * npol,)),
         ("DATA", ">u1", (bytes_per_blk,)),
     ])
-    rows = np.zeros(nsub, dtype=rowdt)
+
+
+def _subint_rows(spec: BeamSpec, sub0: int, data: np.ndarray,
+                 freqs: np.ndarray, scale: np.ndarray,
+                 offset: np.ndarray) -> np.ndarray:
+    """The SUBINT table rows for one block of the spectrum starting
+    at subint ``sub0``; freqs/scale/offset are in file channel order.
+    The result is this thread's work buffer."""
+    from tpulsar.io.psrfits import pack_samples
+
+    nlev = (1 << spec.nbits) - 1
+    if spec.descending_band:
+        data = data[:, ::-1]
+    # q = clip(round((data - offset) / scale), 0, nlev)
+    lev = _buf("cast", data.shape, np.result_type(data, np.float32))
+    np.subtract(data, offset, out=lev)
+    lev /= scale
+    np.round(lev, out=lev)
+    np.clip(lev, 0, nlev, out=lev)
+    q = _buf("q", data.shape, np.uint16)
+    q[...] = lev
+    nsub = data.shape[0] // spec.nsblk
+    rows = _buf("rows", (nsub,), _subint_dtype(spec))
+    rows[...] = np.zeros((), rows.dtype)
     tsub = spec.nsblk * spec.tsamp_s
     rows["TSUBINT"] = tsub
-    rows["OFFS_SUB"] = (np.arange(nsub) + 0.5) * tsub
+    rows["OFFS_SUB"] = (sub0 + np.arange(nsub) + 0.5) * tsub
     rows["RA_SUB"] = angles.hms_str_to_deg(spec.ra_str)
     rows["DEC_SUB"] = angles.dms_str_to_deg(spec.dec_str)
     rows["TEL_AZ"] = 180.0
     rows["TEL_ZEN"] = 10.0
     rows["DAT_FREQ"] = freqs
     rows["DAT_WTS"] = 1.0
-    rows["DAT_OFFS"] = np.tile(offset, npol)
-    rows["DAT_SCL"] = np.tile(scale, npol)
+    rows["DAT_OFFS"] = np.tile(offset, spec.npol)
+    rows["DAT_SCL"] = np.tile(scale, spec.npol)
+    rows["DATA"] = pack_samples(
+        q.reshape(nsub, spec.nsblk * spec.npol * spec.nchan),
+        spec.nbits).reshape(nsub, -1)
+    return rows
 
-    from tpulsar.io.psrfits import pack_samples
-    packed = pack_samples(q.reshape(nsub, nsblk * npol * nchan), spec.nbits)
-    rows["DATA"] = packed.reshape(nsub, bytes_per_blk)
+
+def write_psrfits(path: str, spec: BeamSpec, data: np.ndarray) -> str:
+    """Write (nsamp, nchan) float data as a search-mode PSRFITS file."""
+    rows = block_rows(spec)
+    return _write_psrfits(path, spec, rows,
+                          lambda k: data[k * rows:(k + 1) * rows])
+
+
+def _write_psrfits(path: str, spec: BeamSpec, rows: int, block) -> str:
+    """Write the file whose spectrum is ``block(k)`` for k = 0, 1, ...
+    (float (rows, nchan) arrays, rows a whole number of subints,
+    channels ascending).  Blocks are digitized and written on a
+    thread pool, each at its own file offset; the quantisation levels
+    come from the leading LEVEL_ELEMS elements."""
+    nsub = spec.nsamp // spec.nsblk
+    if nsub * spec.nsblk != spec.nsamp:
+        raise ValueError("nsamp must be a multiple of nsblk")
+    nblocks = -(-spec.nsamp // rows)
+    scale, offset = _levels(_stacked(block, min(
+        nblocks, -(-LEVEL_ELEMS // (rows * spec.nchan)))), spec.nbits)
+
+    freqs = channel_freqs(spec)
+    if spec.descending_band:
+        freqs = freqs[::-1]
+        scale = scale[::-1]
+        offset = offset[::-1]
+
+    nchan, npol, nsblk = spec.nchan, spec.npol, spec.nsblk
 
     mjd_i = int(spec.mjd)
     secs = (spec.mjd - mjd_i) * 86400.0
@@ -199,14 +307,35 @@ def write_psrfits(path: str, spec: BeamSpec, data: np.ndarray) -> str:
     )
     # TDIM fastest axis is the packed channel byte count (nchan*nbits/8),
     # valid for 4-, 8- and 16-bit data alike.
+    rowdt = _subint_dtype(spec)
     subhdr = fitscore.bintable_header(
-        "SUBINT", rows,
+        "SUBINT", np.zeros(0, dtype=rowdt),
         tdims={"DATA": (nsblk, npol, nchan * spec.nbits // 8)},
         **subhdr_cards)
+    subhdr.set("NAXIS2", nsub, "number of rows")
+    fitscore.write_fits(path, [fitscore.HDU(primary, None),
+                               fitscore.HDU(subhdr, None)])
 
-    fitscore.write_fits(path, [
-        fitscore.HDU(primary, None), fitscore.HDU(subhdr, rows)])
+    def write_block(k: int) -> None:
+        sub0 = k * rows // nsblk
+        rec = _subint_rows(spec, sub0, block(k), freqs, scale, offset)
+        os.pwrite(fd, rec.view(np.uint8), start + sub0 * rowdt.itemsize)
+
+    start = os.path.getsize(path)
+    nbytes = nsub * rowdt.itemsize
+    fd = os.open(path, os.O_WRONLY)
+    try:
+        # the table zero-padded to a whole FITS block
+        os.ftruncate(fd, start + nbytes + (-nbytes) % fitscore.BLOCK)
+        with ThreadPoolExecutor(_workers()) as pool:
+            list(pool.map(write_block, range(nblocks)))
+    finally:
+        os.close(fd)
     return path
+
+
+def _workers() -> int:
+    return max(1, min(16, (os.cpu_count() or 2) - 1))
 
 
 def mock_filename(spec: BeamSpec, subband: int | None = None) -> str:
@@ -235,10 +364,12 @@ def synth_beam(outdir: str, spec: BeamSpec | None = None,
     """
     spec = spec or BeamSpec()
     os.makedirs(outdir, exist_ok=True)
-    data = make_dynamic_spectrum(spec, pulsars, rfi)
+    rows = block_rows(spec)
     if merged:
         path = os.path.join(outdir, mock_filename(spec))
-        return [write_psrfits(path, spec, data)]
+        return [_write_psrfits(
+            path, spec, rows,
+            lambda k: _block(spec, pulsars, rfi, k, rows))]
 
     # Split into two overlapping halves like the Mock spectrometer:
     # s1 = low half, s0 = high half (PALFA convention), with overlap.
@@ -249,13 +380,14 @@ def synth_beam(outdir: str, spec: BeamSpec | None = None,
     out = []
     for sb, sl in (("1", slice(0, half + overlap)),
                    ("0", slice(half - overlap, spec.nchan))):
-        sub = data[:, sl]
         fsub = freqs[sl]
         subspec = dataclasses.replace(
-            spec, nchan=sub.shape[1],
+            spec, nchan=len(fsub),
             fctr_mhz=float(fsub.mean()),
-            bw_mhz=float(df * sub.shape[1]))
+            bw_mhz=float(df * len(fsub)))
         path = os.path.join(outdir, mock_filename(spec, subband=int(sb)))
-        write_psrfits(path, subspec, sub)
+        _write_psrfits(
+            path, subspec, rows,
+            lambda k: _block(spec, pulsars, rfi, k, rows)[:, sl])
         out.append(path)
     return out

@@ -163,8 +163,11 @@ def form_subbands(data: jnp.ndarray, chan_shifts, nsub: int,
             jax.block_until_ready(out)
             return out
         except Exception as e:
-            if pallas_dd.forced():
-                raise      # TPULSAR_PALLAS=1 = no-fallback (CI mode)
+            # a kernel fault on the chip is a fault, not a cue to run
+            # the beam on the XLA formulation; TPULSAR_PALLAS=1 is the
+            # same no-fallback contract for CI off the chip
+            if pallas_dd.forced() or pallas_dd.is_tpu_backend():
+                raise
             pallas_dd.disable_signature(sig, reason=str(e)[:200])
             from tpulsar.search import degraded
             degraded.note("pallas_sb_disabled",
@@ -172,7 +175,7 @@ def form_subbands(data: jnp.ndarray, chan_shifts, nsub: int,
     elif pallas_dd.is_tpu_backend():
         from tpulsar.search import degraded
         degraded.note("pallas_sb_disabled",
-                      "smoke gate or env off; XLA lax.map subband path")
+                      "switched off by env; XLA lax.map subband path")
     pad = _pad_bucket(int(shifts_np.max(initial=0)))
     return _form_subbands_jit(data, jnp.asarray(shifts_np), nsub,
                               downsamp, pad)
@@ -275,9 +278,12 @@ def dedisperse_subbands(subbands: jnp.ndarray,
                 # the fallback) rather than surfacing downstream
                 jax.block_until_ready(out)
                 return out
-        except Exception as e:   # Mosaic unsupported on this runtime
-            if pallas_dd.forced():
-                raise      # TPULSAR_PALLAS=1 = no-fallback (CI mode)
+        except Exception as e:
+            # on the chip a kernel fault (or an injected one) fails the
+            # beam; the handler below is the off-chip path CPU CI
+            # exercises through the dedisperse.pallas fault point
+            if pallas_dd.forced() or pallas_dd.is_tpu_backend():
+                raise
             pallas_dd.disable_signature(sig, reason=str(e)[:200])
             from tpulsar.search import degraded
             degraded.note("pallas_dd_disabled",
@@ -287,18 +293,13 @@ def dedisperse_subbands(subbands: jnp.ndarray,
     # fault happens not to fire on this call (count exhausted,
     # rate<1) must not swallow the TPU-backend provenance note below
     if pallas_dd.is_tpu_backend() and not noted:
-        # flagship kernel off on the TPU backend (smoke gate, env, or
-        # a signature disabled by an earlier fault): the result must
-        # say which stage-2 path produced it — on EVERY later run too
-        # (the registry resets per search run, the verdict persists
-        # for the process).  Non-TPU backends are NOT degraded: the
-        # XLA path is their only and intended path.
+        # flagship kernel switched off by env on the TPU backend:
+        # the result must say which stage-2 path produced it.
+        # Non-TPU backends are NOT degraded: the XLA path is their
+        # only and intended path.
         from tpulsar.search import degraded
         degraded.note("pallas_dd_disabled",
-                      "smoke gate or TPULSAR_PALLAS=0; XLA scan path"
-                      if not use_p else
-                      "signature disabled after an earlier kernel "
-                      "fault; XLA scan path")
+                      "TPULSAR_PALLAS=0; XLA scan path")
     return _dedisperse_subbands_xla(subbands, sub_shifts)
 
 

@@ -81,7 +81,7 @@ def _build_fft_fn(mesh: Mesh, axis_name: str, N: int):
         # --- FFT along b
         return jnp.fft.fft(full, axis=1)               # [k1_loc, k2]
 
-    from tpulsar.parallel.compat import shard_map
+    from jax import shard_map
     return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis_name),
                              out_specs=P(axis_name, None),
                              check_vma=False))
@@ -210,7 +210,7 @@ def _build_tail_fn(mesh: Mesh, axis_name: str, N: int, topk: int,
         gvals, gidx = jax.lax.top_k(all_vals.reshape(-1), topk)
         return gvals, all_nat.reshape(-1)[gidx]
 
-    from tpulsar.parallel.compat import shard_map
+    from jax import shard_map
     return jax.jit(shard_map(tail, mesh=mesh,
                              in_specs=P(axis_name, None),
                              out_specs=(P(), P()), check_vma=False))

@@ -128,7 +128,7 @@ def sharded_search_step(mesh: Mesh, spec: SearchStepSpec):
                         jax.lax.all_gather(b, "dm", axis=0, tiled=True)[None])
                     for h, (v, b) in res.items()}
 
-        from tpulsar.parallel.compat import shard_map
+        from jax import shard_map
         return shard_map(
             per_shard, mesh=mesh,
             in_specs=(P("beam", None, None), P("beam", "dm", None), P()),
@@ -198,8 +198,10 @@ def _pallas_dd_local(subb, shifts, stage_s: int, interpret: bool,
     which a traced shard cannot).  stage_s must be >= the max shift of
     the FULL pass table (computed host-side once, shared by every
     shard so all shards compile the same kernel)."""
-    from tpulsar.kernels.pallas_dd import _dedisperse_chunk
+    from tpulsar.kernels.pallas_dd import (_dedisperse_chunk,
+                                           _resolve_interpret)
 
+    interpret = _resolve_interpret(interpret)
     ndms_loc = shifts.shape[0]
     T = subb.shape[-1]
     window = block_t + stage_s
@@ -233,7 +235,7 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
     reference's embarrassingly-parallel per-DM loop
     (PALFA2_presto_search.py:532-594, SURVEY.md section 2.4).
     """
-    from tpulsar.parallel.compat import shard_map
+    from jax import shard_map
 
     from tpulsar.kernels import accel as ak
     from tpulsar.kernels import fourier as fr
@@ -388,7 +390,7 @@ def seq_dist_search(mesh: Mesh, subbands, sub_shifts, dms, dt_ds: float,
         return (jax.lax.all_gather(snr, axis_name, axis=2, tiled=True),
                 jax.lax.all_gather(idx, axis_name, axis=2, tiled=True))
 
-    from tpulsar.parallel.compat import shard_map
+    from jax import shard_map
     sp_fn = jax.jit(shard_map(
         sp_body, mesh=mesh, in_specs=P(None, axis_name),
         out_specs=(P(), P()), check_vma=False))

@@ -12,7 +12,7 @@ the `.report` (reference artifact contract:
 PALFA2_presto_search.py:336-372).
 
 Process-global by design: the fallback decisions themselves are
-process-global (smoke-gate verdicts, runtime downgrades), and a
+process-global (env pins, runtime downgrades), and a
 search run snapshots + resets around its own execution.
 
 Two ledgers, one taxonomy:

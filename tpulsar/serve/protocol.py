@@ -51,6 +51,8 @@ Result shape (written by the server):
     {"ticket": ..., "status": "done"|"failed"|"skipped", "rc": int,
      "error": str, "beam_seconds": float, "compile_misses": int,
      "warm": bool, "outdir": ..., "worker": str, "attempts": int,
+     "device": {"platform", "kind", "count"} | null,
+     "boot_gate_rc": int | null, "boot_seconds": float,
      "finished_at": unix_time}
 """
 

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import os
 import signal
+import sys
 import threading
 import time
 
@@ -110,6 +111,9 @@ class SearchServer:
         self.beam_deadline_s = beam_deadline_s
         self.warm_boot = warm_boot
         self.warm_boot_scale = warm_boot_scale
+        self.boot_gate_rc: int | None = None   # None = no gate ran
+        self.boot_seconds = 0.0
+        self._device: dict | None = None
         self.poll_s = poll_s
         self.heartbeat_interval_s = heartbeat_interval_s
         #: injectable for tests: callable(PreparedBeam) ->
@@ -200,6 +204,7 @@ class SearchServer:
     # ------------------------------------------------------------ boot
 
     def boot(self) -> None:
+        t_boot = time.time()
         protocol.ensure_spool(self.spool)
         requeued = self.queue.requeue_stale_claims(
             self.ticket_max_attempts)
@@ -224,13 +229,26 @@ class SearchServer:
                 scale=self.warm_boot_scale,
                 accel=self.cfg.searching.use_hi_accel,
                 echo=lambda s: self.log.info("gate: %s", s))
+            self.boot_gate_rc = rc
             if rc not in (0, 3):
                 # a failed gate is a degraded boot, not a fatal one:
                 # beams still search, they just pay inline compiles
                 # (visible as compile_misses in every result record)
                 self.log.warning("warm-start gate rc %d — serving "
                                  "with a cold cache", rc)
+        self.boot_seconds = round(time.time() - t_boot, 3)
         self._heartbeat("running", force=True)
+
+    def _device_stamp(self) -> dict | None:
+        """Where this worker's beams run, as jax reports it — None in
+        a process that never loaded jax (stub workers)."""
+        jax = sys.modules.get("jax")
+        if self._device is None and jax is not None:
+            devs = jax.devices()
+            self._device = {"platform": devs[0].platform,
+                            "kind": devs[0].device_kind,
+                            "count": len(devs)}
+        return self._device
 
     def _heartbeat(self, status: str, force: bool = False) -> None:
         now = time.time()
@@ -730,6 +748,9 @@ class SearchServer:
                     rc=0 if status in ("done", "skipped") else 1,
                     error=error, beam_seconds=dt, warm=warm,
                     outdir=outdir, worker=self.worker_id,
+                    device=self._device_stamp(),
+                    boot_gate_rc=self.boot_gate_rc,
+                    boot_seconds=self.boot_seconds,
                     **({"worker_class": self.worker_class}
                        if self.worker_class else {}), **extra)
                 break
