@@ -56,6 +56,62 @@ def test_cachedir_activate_leaves_a_set_variable_alone(
     assert jax.config.jax_compilation_cache_dir == pinned
 
 
+def test_scoped_kernels_salt_the_cache_key(monkeypatch):
+    """jax leaves op metadata out of its compile-cache key, and the
+    hot programs' named scopes are metadata: the same program with and
+    without the salt has another key, so an executable cached under
+    other scope names is never loaded.  The salt is a hash of the
+    scope names, goes after an embedder's own hook, and is installed
+    by importing the module that defines the scopes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    from tpulsar.kernels import scopes
+
+    assert cache_key.custom_hook().endswith(scopes.KEY_SALT)   # at import
+    monkeypatch.setattr(cache_key, "custom_hook", lambda: "theirs/")
+
+    def key():
+        lowered = jax.jit(lambda x: x + 1).lower(jnp.zeros(4))
+        backend = jax.devices()[0].client
+        return cache_key.get(
+            lowered.compiler_ir("stablehlo"), np.array(jax.devices()[:1]),
+            compiler.get_compile_options(num_replicas=1, num_partitions=1),
+            backend)
+
+    bare = key()
+    assert scopes.salt_cache_key()
+    assert cache_key.custom_hook() == "theirs/" + scopes.KEY_SALT
+    assert scopes.salt_cache_key()                       # idempotent
+    assert cache_key.custom_hook() == "theirs/" + scopes.KEY_SALT
+    assert key() != bare
+    assert key() == key()
+    # the salt follows the names: no constant to bump after a rename
+    assert all(s in "".join(scopes.SCOPES) for s in ("hiaccel", "sp/"))
+    with pytest.raises(ValueError):
+        scopes.scope("sp/not-a-scope")
+
+
+def test_cachedir_activate_salts_or_refuses(
+        monkeypatch, tmp_path, jax_cache_config):
+    """activate() with jax imported puts the hook in itself, and a jax
+    without the hook is an error once a cache directory is set: an
+    unsalted cache would serve executables with other scope names."""
+    from jax._src import cache_key
+
+    from tpulsar.kernels import scopes
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    monkeypatch.setattr(cache_key, "custom_hook", lambda: "")
+    cachedir.activate()
+    assert cache_key.custom_hook() == scopes.KEY_SALT
+    monkeypatch.delattr(cache_key, "custom_hook")
+    with pytest.raises(RuntimeError, match="custom_hook"):
+        cachedir.activate()
+
+
 def test_cachedir_activate_unset_uses_the_checkout(
         monkeypatch, jax_cache_config):
     """Unset, activate() exports the fixed checkout path, so a jax
@@ -315,6 +371,45 @@ def test_two_process_warm_start_zero_misses(tmp_path):
                                     + second.stderr[-400:])
     assert "0 misses" in second.stdout
     assert "[MISS]" not in second.stdout
+
+
+_SEARCH_ORDER = """
+import jax                                  # the search imports jax first
+from tpulsar.aot import cachedir, registry
+cachedir.activate()                         # ... and activates after
+ctx = registry.make_context(scale=0.02, accel=False, nbeams=0)
+before = cachedir.cache_entries()
+n = 0
+for _header, insts in registry.gate_groups(ctx, config=0, fast=False):
+    for inst in insts:
+        if inst.program in {only!r}:
+            registry.jitted(inst.program).lower(
+                *inst.args, **inst.kwargs).compile()
+            n += 1
+print("compiled", n, "new", len(cachedir.cache_entries() - before))
+"""
+
+
+def test_gate_then_search_order_process_zero_misses(tmp_path):
+    """The gate activates the cache BEFORE it imports jax, a search
+    process after: the keys (salted with the scope names) must not
+    depend on that order, or a cache the gate prebaked is never hit.
+    One of the programs carries named scopes."""
+    import tpulsar
+
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    only = ("singlepulse.boxcar_search", "refine.gather")
+    first = _run_gate(["--scale", "0.02", "--only", ",".join(only)], env)
+    assert first.returncode == 0, (first.stdout[-800:]
+                                   + first.stderr[-400:])
+    second = subprocess.run(
+        [sys.executable, "-c", _SEARCH_ORDER.format(only=only)],
+        capture_output=True, text=True, timeout=540,
+        env=dict(tpulsar.cpu_subprocess_env(), PYTHONPATH=_REPO, **env))
+    assert second.returncode == 0, second.stderr[-800:]
+    compiled, new = (int(x) for x in
+                     second.stdout.split()[1::2])
+    assert compiled >= 2 and new == 0, second.stdout
 
 
 def test_verify_without_manifest_fails(tmp_path):

@@ -79,6 +79,40 @@ def test_store_roundtrip_and_manifest(tmp_path):
     assert not [n for n in os.listdir(root) if n.endswith(".tmp")]
 
 
+def test_overwritten_key_survives_a_kill_between_its_two_writes(
+        tmp_path, monkeypatch):
+    """The stream worker saves its carry under ONE key after every
+    chunk.  A writer killed after the artifact and before the manifest
+    must leave the previous checkpoint loadable (the manifest's hash
+    still matches the file it names), not discard it as corrupt."""
+    root = str(tmp_path / "ck")
+    store = ckpt.CheckpointStore(root, "fp")
+    assert store.save("stream_carry", b"after chunk 0", ext=".npz")
+    assert store.save("stream_carry", b"after chunk 1", ext=".npz")
+
+    class Killed(BaseException):
+        pass
+
+    def die():
+        raise Killed
+
+    monkeypatch.setattr(store, "_write_manifest", die)
+    with pytest.raises(Killed):
+        store.save("stream_carry", b"after chunk 2", ext=".npz")
+    j = _Journal()
+    again = ckpt.CheckpointStore(root, "fp", journal=j)
+    assert again.load("stream_carry") == b"after chunk 1"
+    assert not j.of("checkpoint_invalid")
+    # the two names take turns: never more than two files for the key
+    assert again.save("stream_carry", b"after chunk 2", ext=".npz")
+    assert again.save("stream_carry", b"after chunk 3", ext=".npz")
+    assert ckpt.CheckpointStore(root, "fp").load("stream_carry") == \
+        b"after chunk 3"
+    assert sorted(n for n in os.listdir(root) if "carry" in n) == [
+        "stream_carry.alt.npz", "stream_carry.npz"]
+    assert ckpt.verify_root(root)["ok"]
+
+
 def test_corrupt_artifact_discarded_and_journaled(tmp_path):
     root = str(tmp_path / "ck")
     j = _Journal()

@@ -444,9 +444,9 @@ def traced_beam(tmp_path_factory):
     from tpulsar.plan import ddplan
     from tpulsar.search import executor
 
-    trace.reset()
     root = tmp_path_factory.mktemp("telem")
     os.environ["TPULSAR_TRACE"] = "1"
+    trace.reset()        # the switch is resolved here, not per span
     try:
         spec = synth.BeamSpec(nchan=32, nsamp=1 << 13, nbits=4,
                               tsamp_s=5.24288e-4)
@@ -479,10 +479,13 @@ def test_executor_trace_file_span_tree(traced_beam):
                   "search_block", "dm_chunk"):
         assert stage in names, f"missing span {stage}"
     # per-chunk child spans nest under dm_chunk, which nests under
-    # the search_block root
+    # its pass, which nests under the search_block root
     chunk = next(e for e in events if e["name"] == "dm_chunk")
-    assert chunk["args"]["parent"] == "search_block"
+    assert chunk["args"]["parent"] == "pass"
     assert chunk["args"]["n"] == 8
+    its_pass = next(e for e in events if e.get("id") == chunk["parent_id"])
+    assert its_pass["name"] == "pass"
+    assert its_pass["args"]["parent"] == "search_block"
     per_chunk = [e for e in events
                  if e["args"].get("parent") == "dm_chunk"]
     assert {"dedispersing", "single-pulse", "FFT",

@@ -50,7 +50,13 @@ def activate() -> str:
     """Resolve the cache dir and hand it to jax: through the
     environment when the variable is unset (a jax imported later
     reads it there; a set variable is left exactly as it is), and
-    through the live config when jax is already imported."""
+    through the live config when jax is already imported.
+
+    The cache's keys are salted with the hot programs' scope names
+    (``kernels/scopes.py``, which installs the hook when it is
+    imported, so in every process that can compile such a program);
+    with jax already here the hook goes in now, and a jax without one
+    is an error rather than a cache that serves other scopes' names."""
     d = ensured()
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", d)
     jax = sys.modules.get("jax")
@@ -65,6 +71,8 @@ def activate() -> str:
                 "jax_persistent_cache_min_compile_time_secs", 0.0)
         except Exception:
             pass
+        from tpulsar.kernels import scopes
+        scopes.salt_cache_key()
     return d
 
 

@@ -295,7 +295,16 @@ class CheckpointStore:
         checkpoint is an optimization, never a dependency)."""
         if self.disabled:
             return False
-        path = os.path.join(self.root, key + ext)
+        prev = self._entries.get(key)
+        name = key + ext
+        if prev is not None and prev.get("file") == name:
+            # an overwritten key (the stream's carry, saved per chunk)
+            # is written BESIDE the file the manifest names, the two
+            # names taking turns: a writer killed between the artifact
+            # and the manifest leaves the previous checkpoint whole and
+            # verifiable, not new bytes under the old hash
+            name = key + ".alt" + ext
+        path = os.path.join(self.root, name)
         try:
             # deterministic write-failure injection: shaped as the
             # OSError a failing disk raises (errno= specs pick the
@@ -304,12 +313,15 @@ class CheckpointStore:
                         detail=key)
             self._atomic_write(path, data)
             self._entries[key] = {
-                "file": key + ext, "kind": kind, "bytes": len(data),
+                "file": name, "kind": kind, "bytes": len(data),
                 "sha256": hashing.sha256_bytes(data),
                 "written_at": round(time.time(), 3), **meta}
             self._write_manifest()
         except OSError as e:
-            self._entries.pop(key, None)
+            if prev is None:
+                self._entries.pop(key, None)
+            else:
+                self._entries[key] = prev    # still whole on disk
             if e.errno in _DISABLE_ERRNOS:
                 self._disable(key, e)
             else:

@@ -30,6 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpulsar.kernels import scopes
+from tpulsar.obs import trace
+
 DZ = 2.0  # z-plane step in bins (PRESTO's accelsearch grid spacing)
 
 
@@ -261,19 +264,22 @@ def _accel_plane_topk(spectrum, bank_fft, seg, step, width, nz,
     round-1 hi-accel schedule's dominant cost (verdict weakness #4)."""
     from tpulsar.kernels.fourier import blockmax_topk, harmonic_stages
 
-    plane = _correlate_segments(spectrum, bank_fft, seg, step, width)
-    maxes = _harmonic_stage_maxes(
-        plane, tuple(harmonic_stages(max_numharm)), nz)
+    with scopes.scope("hiaccel/correlate"):
+        plane = _correlate_segments(spectrum, bank_fft, seg, step, width)
+    with scopes.scope("hiaccel/harmsum"):
+        maxes = _harmonic_stage_maxes(
+            plane, tuple(harmonic_stages(max_numharm)), nz)
     vals_all, rbin_all, zi_all = [], [], []
-    for h in harmonic_stages(max_numharm):
-        zmax, zarg = maxes[h]                        # (L,), (L,)
-        v, r = blockmax_topk(zmax[None], topk)
-        v, r = v[0], r[0]
-        vals_all.append(v)
-        rbin_all.append(r.astype(jnp.int32))
-        zi_all.append(zarg[jnp.clip(r, 0, zarg.shape[0] - 1)])
-    return (jnp.stack(vals_all), jnp.stack(rbin_all),
-            jnp.stack(zi_all))
+    with scopes.scope("hiaccel/topk"):
+        for h in harmonic_stages(max_numharm):
+            zmax, zarg = maxes[h]                        # (L,), (L,)
+            v, r = blockmax_topk(zmax[None], topk)
+            v, r = v[0], r[0]
+            vals_all.append(v)
+            rbin_all.append(r.astype(jnp.int32))
+            zi_all.append(zarg[jnp.clip(r, 0, zarg.shape[0] - 1)])
+        return (jnp.stack(vals_all), jnp.stack(rbin_all),
+                jnp.stack(zi_all))
 
 
 PLANE_HBM_BUDGET = int(float(os.environ.get(
@@ -538,20 +544,25 @@ def _accel_block_topk(specs, bank_fft, seg, step, width, nz,
     round-1 hi-accel schedule's dominant cost (verdict weakness #4)."""
     from tpulsar.kernels.fourier import blockmax_topk, harmonic_stages
 
-    plane = _correlate_block(specs, bank_fft, seg, step, width, nz)
+    # named scopes: trace-time names on the device's operations (the
+    # per-layer metrics read device seconds by scope; PERF.md)
+    with scopes.scope("hiaccel/correlate"):
+        plane = _correlate_block(specs, bank_fft, seg, step, width, nz)
     stages = tuple(harmonic_stages(max_numharm))
-    maxes = jax.vmap(
-        lambda p: _harmonic_stage_maxes(p, stages, nz))(plane)
+    with scopes.scope("hiaccel/harmsum"):
+        maxes = jax.vmap(
+            lambda p: _harmonic_stage_maxes(p, stages, nz))(plane)
     vals_all, rbin_all, zi_all = [], [], []
-    for h in stages:
-        zmax, zarg = maxes[h]                          # (nd, L)
-        v, r = blockmax_topk(zmax, topk)               # (nd, topk)
-        vals_all.append(v)
-        rbin_all.append(r.astype(jnp.int32))
-        zi_all.append(jnp.take_along_axis(
-            zarg, jnp.clip(r, 0, zarg.shape[1] - 1), axis=1))
-    return (jnp.stack(vals_all, axis=1), jnp.stack(rbin_all, axis=1),
-            jnp.stack(zi_all, axis=1))
+    with scopes.scope("hiaccel/topk"):
+        for h in stages:
+            zmax, zarg = maxes[h]                          # (nd, L)
+            v, r = blockmax_topk(zmax, topk)               # (nd, topk)
+            vals_all.append(v)
+            rbin_all.append(r.astype(jnp.int32))
+            zi_all.append(jnp.take_along_axis(
+                zarg, jnp.clip(r, 0, zarg.shape[1] - 1), axis=1))
+        return (jnp.stack(vals_all, axis=1),
+                jnp.stack(rbin_all, axis=1), jnp.stack(zi_all, axis=1))
 
 
 # --- batch-path verdict ----------------------------------------------
@@ -857,9 +868,10 @@ def accel_search_batch(spectra: jnp.ndarray, bank: TemplateBank,
         # device_get, not at the enqueue the row/chunk closures
         # already bound.  Only the fetch runs on the watched thread —
         # an abandoned overdue fetch can never write into vals/rbins.
-        fetched = run_with_deadline(
-            lambda: jax.device_get(pending), deadline_s,
-            label="accel window sync")
+        with trace.span("accel-sync", chunks=len(pending)):
+            fetched = run_with_deadline(
+                lambda: jax.device_get(pending), deadline_s,
+                label="accel window sync")
         for s0, nrows, tup in fetched:
             vals[s0:s0 + nrows] = tup[0]
             rbins[s0:s0 + nrows] = tup[1]
@@ -943,27 +955,31 @@ def accel_search_batch(spectra: jnp.ndarray, bank: TemplateBank,
                 except REFUSED:
                     _note_refused_batch(s0)
 
-        for s0 in plan.starts:
-            if bstate["pinned"]:
-                fallback.update(plan.rows_of(s0))
-                continue
-            try:
-                pending.append(_attempt(s0))
-            except REFUSED:
-                # a dispatch-time refusal may belong to a PRIOR async
-                # dispatch: flush the window, then one sync retry of
-                # THIS batch before degrading its rows
-                _drain_batches()
+        # the enqueue loop as one span (a window that fills inside it
+        # nests its accel-sync here), the closing drain beside it
+        with trace.span("accel-dispatch", chunks=plan.nbatches,
+                        rows=ndms):
+            for s0 in plan.starts:
                 if bstate["pinned"]:
                     fallback.update(plan.rows_of(s0))
                     continue
                 try:
-                    _drain_ok([_attempt(s0)])
-                    bstate["consec"] = 0
+                    pending.append(_attempt(s0))
                 except REFUSED:
-                    _note_refused_batch(s0)
-            if len(pending) >= SYNC_WINDOW:
-                _drain_batches()
+                    # a dispatch-time refusal may belong to a PRIOR
+                    # async dispatch: flush the window, then one sync
+                    # retry of THIS batch before degrading its rows
+                    _drain_batches()
+                    if bstate["pinned"]:
+                        fallback.update(plan.rows_of(s0))
+                        continue
+                    try:
+                        _drain_ok([_attempt(s0)])
+                        bstate["consec"] = 0
+                    except REFUSED:
+                        _note_refused_batch(s0)
+                if len(pending) >= SYNC_WINDOW:
+                    _drain_batches()
         _drain_batches()
         from tpulsar.search import degraded
         # count(), not note(): clean batched calls feed the

@@ -39,6 +39,7 @@ import numpy as np
 
 from tpulsar.constants import KDM
 from tpulsar.kernels.fold import FoldResult, FoldRules, fold_rules
+from tpulsar.obs import trace
 
 
 # ------------------------------------------------------------- device pieces
@@ -299,67 +300,73 @@ def fold_subbands_batch(subbands, sub_freqs_mhz, dt: float,
     for lo in range(0, len(cands), max_batch):
         chunk = cands[lo: lo + max_batch]
         nc = len(chunk)
-        bins = np.empty((nc, Tp), np.int32)
-        r_dm_l, dps_l, dpds_l, ddms_l = [], [], [], []
-        for i, (period, dm) in enumerate(chunk):
-            ph = np.mod(t64 / period, 1.0)
-            b = np.minimum((ph * rules_nbin).astype(np.int32),
-                           rules_nbin - 1)
-            bins[i, :T] = b
-            bins[i, T:] = 0
-            # grids in profile-bin-drift units (prepfold's unit)
-            dp_unit = period ** 2 / (rules_nbin * T_s)
-            dpd_unit = 2.0 * period ** 2 / (rules_nbin * T_s ** 2)
-            dps = _sym_grid(rules.mp * rules_nbin, rules.pstep) * dp_unit
-            if rules.search_pdot:
-                dpds = _sym_grid(rules.mp * rules_nbin,
-                                 rules.pdstep) * dpd_unit
-            else:
-                dpds = np.zeros(1)
-            ddm_unit = period / (rules_nbin * KDM
-                                 * max(abs(band_span), 1e-12))
-            ddms = _sym_grid(rules.mdm * rules_nbin,
-                             rules.dmstep) * ddm_unit
-            # ABSOLUTE per-subband rotation at each DM trial: folding
-            # unaligned subbands puts subband s's profile at phase
-            # +delay_s/p relative to the aligned fold, so collapsing
-            # at trial DM D rolls by +nbin*delay_s(D)/p (the roll
-            # convention out[b] = x[b + s])
-            D = dm + ddms                                   # (nddm,)
-            r_dm = (rules_nbin * delays_unit[None, :]
-                    * D[:, None] / period)                  # (nddm, nsub)
-            r_dm_l.append(r_dm)
-            dps_l.append(dps)
-            dpds_l.append(dpds)
-            ddms_l.append(ddms)
-        j0 = (r_dm_l[0].shape[0] - 1) // 2   # ddm=0 row (center)
+        # host half (phase bins and grids in float64), device half
+        # (transfers, the fold program, up to its fetches), host half
+        # (the results): spans per candidate chunk, never per row
+        with trace.span("fold-host", n=nc):
+            bins = np.empty((nc, Tp), np.int32)
+            r_dm_l, dps_l, dpds_l, ddms_l = [], [], [], []
+            for i, (period, dm) in enumerate(chunk):
+                ph = np.mod(t64 / period, 1.0)
+                b = np.minimum((ph * rules_nbin).astype(np.int32),
+                               rules_nbin - 1)
+                bins[i, :T] = b
+                bins[i, T:] = 0
+                # grids in profile-bin-drift units (prepfold's unit)
+                dp_unit = period ** 2 / (rules_nbin * T_s)
+                dpd_unit = 2.0 * period ** 2 / (rules_nbin * T_s ** 2)
+                dps = _sym_grid(rules.mp * rules_nbin, rules.pstep) * dp_unit
+                if rules.search_pdot:
+                    dpds = _sym_grid(rules.mp * rules_nbin,
+                                     rules.pdstep) * dpd_unit
+                else:
+                    dpds = np.zeros(1)
+                ddm_unit = period / (rules_nbin * KDM
+                                     * max(abs(band_span), 1e-12))
+                ddms = _sym_grid(rules.mdm * rules_nbin,
+                                 rules.dmstep) * ddm_unit
+                # ABSOLUTE per-subband rotation at each DM trial: folding
+                # unaligned subbands puts subband s's profile at phase
+                # +delay_s/p relative to the aligned fold, so collapsing
+                # at trial DM D rolls by +nbin*delay_s(D)/p (the roll
+                # convention out[b] = x[b + s])
+                D = dm + ddms                                   # (nddm,)
+                r_dm = (rules_nbin * delays_unit[None, :]
+                        * D[:, None] / period)                  # (nddm, nsub)
+                r_dm_l.append(r_dm)
+                dps_l.append(dps)
+                dpds_l.append(dpds)
+                ddms_l.append(ddms)
+            j0 = (r_dm_l[0].shape[0] - 1) // 2   # ddm=0 row (center)
 
-        part_times = ((np.arange(npart, dtype=np.float32) + 0.5)
-                      * (L * dt))
-        bdp, bdpd, bj, chi2, prof, sub0 = _fold_and_optimize_batch(
-            subb, w, jnp.asarray(bins),
-            jnp.asarray(np.stack(r_dm_l), jnp.float32),
-            jnp.asarray(np.stack(dps_l), jnp.float32),
-            jnp.asarray(np.stack(dpds_l), jnp.float32),
-            jnp.asarray([p for p, _ in chunk], jnp.float32),
-            jnp.asarray(part_times),
-            nbin=rules_nbin, npart=npart, L=L, j0=j0)
-        bdp = np.asarray(bdp, np.float64)
-        bdpd = np.asarray(bdpd, np.float64)
-        bj = np.asarray(bj)
-        chi2 = np.asarray(chi2, np.float64)
-        prof = np.asarray(prof)
-        sub0 = np.asarray(sub0)
-        for i, (period, dm) in enumerate(chunk):
-            ddm = float(ddms_l[i][int(bj[i])])
-            out.append(FoldResult(
-                period_s=period - float(bdp[i]),
-                pdot=-float(bdpd[i]), dm=dm + ddm,
-                nbin=rules_nbin, npart=npart,
-                profile=prof[i], subints=sub0[i],
-                reduced_chi2=float(chi2[i]),
-                delta_p=float(bdp[i]), delta_pdot=float(bdpd[i]),
-                delta_dm=ddm))
+            part_times = ((np.arange(npart, dtype=np.float32) + 0.5)
+                          * (L * dt))
+        with trace.span("fold-device", n=nc):
+            bdp, bdpd, bj, chi2, prof, sub0 = _fold_and_optimize_batch(
+                subb, w, jnp.asarray(bins),
+                jnp.asarray(np.stack(r_dm_l), jnp.float32),
+                jnp.asarray(np.stack(dps_l), jnp.float32),
+                jnp.asarray(np.stack(dpds_l), jnp.float32),
+                jnp.asarray([p for p, _ in chunk], jnp.float32),
+                jnp.asarray(part_times),
+                nbin=rules_nbin, npart=npart, L=L, j0=j0)
+            bdp = np.asarray(bdp, np.float64)
+            bdpd = np.asarray(bdpd, np.float64)
+            bj = np.asarray(bj)
+            chi2 = np.asarray(chi2, np.float64)
+            prof = np.asarray(prof)
+            sub0 = np.asarray(sub0)
+        with trace.span("fold-host", n=nc):
+            for i, (period, dm) in enumerate(chunk):
+                ddm = float(ddms_l[i][int(bj[i])])
+                out.append(FoldResult(
+                    period_s=period - float(bdp[i]),
+                    pdot=-float(bdpd[i]), dm=dm + ddm,
+                    nbin=rules_nbin, npart=npart,
+                    profile=prof[i], subints=sub0[i],
+                    reduced_chi2=float(chi2[i]),
+                    delta_p=float(bdp[i]), delta_pdot=float(bdpd[i]),
+                    delta_dm=ddm))
     return out
 
 
@@ -403,7 +410,8 @@ def fold_candidates_by_pass(data, freqs, dt: float, plan, cand_list,
         ch_sh, _ = dd.plan_pass_shifts(freqs, nsub, ppass.subdm,
                                        np.asarray(ppass.dms), dt,
                                        step.downsamp)
-        subb = form_subbands_fn(data, ch_sh, nsub, step.downsamp)
+        with trace.span("fold-device", n=len(group)):
+            subb = form_subbands_fn(data, ch_sh, nsub, step.downsamp)
         subrefs = dd.subband_reference_freqs(freqs, nsub)
         dt_ds = dt * step.downsamp
         # tier-group: one batch program per FoldRules geometry

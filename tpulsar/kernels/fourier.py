@@ -24,6 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from scipy import special as sps
 
+from tpulsar.kernels import scopes
+
 
 # ----------------------------------------------------------------- rfft
 
@@ -276,9 +278,11 @@ def whitened_spectrum(series: jnp.ndarray, nfft: int) -> jnp.ndarray:
     materializing a (rows, nbins)-sized intermediate in HBM.  Fusing
     lets XLA keep the whitening math in registers and gives
     tools/aot_check.py ONE program per shape family to gate."""
-    spec = complex_spectrum(pad_series(series, nfft))
-    powers, wpow = whitened_powers(spec)
-    return scale_spectrum(spec, powers, wpow)
+    with scopes.scope("spectra/fft"):
+        spec = complex_spectrum(pad_series(series, nfft))
+    with scopes.scope("spectra/whiten"):
+        powers, wpow = whitened_powers(spec)
+        return scale_spectrum(spec, powers, wpow)
 
 
 @partial(jax.jit, static_argnames=("nfft",))
@@ -286,9 +290,11 @@ def whitened_spectrum_masked(series: jnp.ndarray, keep: jnp.ndarray,
                              nfft: int) -> jnp.ndarray:
     """whitened_spectrum with a zaplist keep-mask (separate program:
     the mask multiply changes the HLO)."""
-    spec = complex_spectrum(pad_series(series, nfft))
-    powers, wpow = whitened_powers(spec, keep)
-    return scale_spectrum(spec, powers, wpow)
+    with scopes.scope("spectra/fft"):
+        spec = complex_spectrum(pad_series(series, nfft))
+    with scopes.scope("spectra/whiten"):
+        powers, wpow = whitened_powers(spec, keep)
+        return scale_spectrum(spec, powers, wpow)
 
 
 @jax.jit
@@ -391,8 +397,10 @@ def stage_candidates(powers: jnp.ndarray, numharm: int, topk: int):
     powers: (ndms, nbins) whitened.  Returns (values, bins) each of
     shape (ndms, topk); bins are fundamental rfft bin indices.
     """
-    summed = harmonic_sum(powers, numharm)
-    return blockmax_topk(summed, topk)
+    with scopes.scope("lo/harmsum"):
+        summed = harmonic_sum(powers, numharm)
+    with scopes.scope("lo/topk"):
+        return blockmax_topk(summed, topk)
 
 
 @partial(jax.jit, static_argnames=("stages", "topk"))
@@ -415,7 +423,9 @@ def lo_stage_candidates(wspec: jnp.ndarray, stages: tuple[int, ...],
     ~2.5 GB at survey scale — and fusing keeps it out of HBM as a
     materialized intermediate between two separately compiled
     programs."""
-    return all_stage_candidates(interbin_powers(wspec), stages, topk)
+    with scopes.scope("lo/harmsum"):
+        powers = interbin_powers(wspec)
+    return all_stage_candidates(powers, stages, topk)
 
 
 # ----------------------------------------------------------- significance

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpulsar.kernels import scopes
+
 DEFAULT_WIDTHS = (1, 2, 3, 4, 6, 9, 14, 20, 30)
 
 #: device-side top-k events kept per (width, DM) before host dedup —
@@ -50,6 +52,7 @@ def _baseline_stat(x: jnp.ndarray, estimator: str) -> jnp.ndarray:
     raise ValueError(f"unknown SP detrend estimator {estimator!r}")
 
 
+@scopes.scope("sp/detrend")
 def detrend_normalize(series: jnp.ndarray, detrend_block: int = 1000,
                       estimator: str = "median"):
     """The detrend/normalize BODY (traceable, not itself jitted).
@@ -128,6 +131,7 @@ def detrend_estimator(params_value: str | None = None) -> str:
 
 
 @partial(jax.jit, static_argnames=("widths", "topk"))
+@scopes.scope("sp/boxcar")
 def boxcar_search(norm_series: jnp.ndarray,
                   widths: tuple[int, ...] = DEFAULT_WIDTHS,
                   topk: int = DEFAULT_TOPK):

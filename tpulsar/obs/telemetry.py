@@ -61,20 +61,8 @@ def dedisp_trials_total() -> metrics.Counter:
     return metrics.counter(
         "tpulsar_dedisp_trials_total",
         "DM trials dedispersed, by stage-2 kernel family (direct "
-        "shift-and-sum vs log-depth shift tree) — with "
-        "tpulsar_dedisp_stage_seconds this yields trials/sec per "
-        "family",
+        "shift-and-sum vs log-depth shift tree)",
         labelnames=("family",))
-
-
-def dedisp_stage_seconds() -> metrics.Histogram:
-    return metrics.histogram(
-        "tpulsar_dedisp_stage_seconds",
-        "wall seconds of stage-2 dedispersion per pass, by kernel "
-        "family (tree observations include the shared level "
-        "evaluation, the per-chunk residual layers, and the fused "
-        "detrend)",
-        labelnames=("family",), buckets=STAGE_BUCKETS)
 
 
 def dedisp_tree_depth() -> metrics.Gauge:

@@ -9,6 +9,23 @@ The reference never had this (its PRESTO subprocesses were opaque);
 the GPU accel-search lineage (Dimoudi et al. 2018) attributes its
 wins to exactly this per-stage device-time accounting.
 
+One span tree: every span carries ``id``, ``parent_id`` (the
+innermost open span on its thread) and ``call`` (the id of the root
+span it descends from — one per ``search_block``), so the spans of
+two slice calls or two passes are told apart and a span's self time
+can be computed (``self_seconds``, ``uncovered_share``).  The
+parent's *name* and the depth stay in ``args`` for the flat readers
+(``summarize_events``, tools/trace_summarize.py).
+
+The profiler's clock: while tracing is enabled and jax is loaded, a
+span also enters ``jax.profiler.TraceAnnotation`` with its attributes,
+``id``, ``call`` and ``src="tpulsar"`` as stats.  Whatever profiler
+session is running (``TPULSAR_PROFILE=<dir>``, a benchmark's own) then
+holds the program's spans in its host plane, on the same clock as the
+device's operations: a program span can be laid over a device gap.
+This module never imports jax (the orchestrators import it): it takes
+``sys.modules.get("jax")``.
+
 Wall time vs device time: JAX dispatch is async, so a span around an
 enqueue measures dispatch cost, not compute.  Spans are therefore
 wall-clock by default (cheap, safe to leave on), and DEVICE
@@ -18,21 +35,26 @@ attribution is opt-in per span via ``fence(...)`` — an explicit
 device-attributed.  Fencing serializes the pipeline it measures; it
 is enabled only when ``TPULSAR_TRACE_SYNC=1`` (the executor's chunk
 loops call ``fence`` unconditionally — this module makes it a no-op
-unless the operator opted in).
+unless the operator opted in).  Device seconds BY LAYER need no fence:
+the hot programs carry ``jax.named_scope`` names (kernels/accel.py,
+fourier.py, singlepulse.py) that the profiler's device plane keeps.
 
 Enabling: ``TPULSAR_TRACE=1`` in the environment, or ``start()``
-programmatically (tests).  Disabled spans cost two attribute reads —
-cheap enough for per-chunk loops.  Thread safety: events append under
-a lock; span nesting state is thread-local, and each thread's spans
-carry its tid, which is exactly how Perfetto reconstructs nesting
-(same-track time containment).
+programmatically (tests).  The switch is resolved once — at import,
+``start()``, ``stop()`` and ``reset()`` — so a disabled ``span()``
+costs one flag test and a yield: cheap enough for per-chunk loops.
+Thread safety: events append under a lock; span nesting state is
+thread-local, and each thread's spans carry its tid, which is exactly
+how Perfetto reconstructs nesting (same-track time containment).
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -44,51 +66,75 @@ MAX_EVENTS = 200_000
 _LOCK = threading.Lock()
 _EVENTS: list[dict] = []
 _DROPPED = 0
-_ENABLED: bool | None = None     # None = consult TPULSAR_TRACE env
-_T0 = time.time()                # trace epoch (perf counter origin)
+_FORCED: bool | None = None      # start()/stop(); None = the env
+_ON = os.environ.get("TPULSAR_TRACE", "") == "1"   # the resolved switch
+_T0 = time.time()                # trace epoch: unix seconds of ts 0
 _TLS = threading.local()
+_IDS = itertools.count(1)        # span ids, unique in the process
+
+
+def _resolve() -> None:
+    global _ON
+    _ON = (_FORCED if _FORCED is not None
+           else os.environ.get("TPULSAR_TRACE", "") == "1")
 
 
 def enabled() -> bool:
-    if _ENABLED is not None:
-        return _ENABLED
-    return os.environ.get("TPULSAR_TRACE", "") == "1"
+    return _ON
 
 
 def sync_enabled() -> bool:
     """Opt-in device fencing (see module docstring)."""
-    return enabled() and os.environ.get("TPULSAR_TRACE_SYNC", "") == "1"
+    return _ON and os.environ.get("TPULSAR_TRACE_SYNC", "") == "1"
 
 
 def start(clear: bool = True) -> None:
     """Enable tracing programmatically (overrides the env)."""
-    global _ENABLED, _T0
+    global _FORCED, _T0
     with _LOCK:
-        _ENABLED = True
+        _FORCED = True
+        _resolve()
         if clear:
             _EVENTS.clear()
             _T0 = time.time()
 
 
 def stop() -> None:
-    global _ENABLED
+    global _FORCED
     with _LOCK:
-        _ENABLED = False
+        _FORCED = False
+        _resolve()
 
 
 def reset() -> None:
-    """Back to env-controlled, events dropped (tests).  Clears the
-    calling thread's trace-id context too."""
-    global _ENABLED, _T0, _DROPPED
+    """Back to env-controlled (TPULSAR_TRACE is read again, here),
+    events dropped (tests).  Clears the calling thread's trace-id
+    context too."""
+    global _FORCED, _T0, _DROPPED
     with _LOCK:
-        _ENABLED = None
+        _FORCED = None
+        _resolve()
         _EVENTS.clear()
         _DROPPED = 0
         _T0 = time.time()
     _TLS.trace_id = ""
 
 
-def _stack() -> list[str]:
+def epoch() -> float:
+    """Unix seconds of the events' ``ts`` 0 (the export's
+    ``trace_epoch_unix_s``)."""
+    return _T0
+
+
+class _Frame:
+    """One open span of a thread's stack."""
+    __slots__ = ("name", "id", "call", "attrs")
+
+    def __init__(self, name: str, id_: int, call: int, attrs: dict):
+        self.name, self.id, self.call, self.attrs = name, id_, call, attrs
+
+
+def _stack() -> list[_Frame]:
     st = getattr(_TLS, "stack", None)
     if st is None:
         st = _TLS.stack = []
@@ -124,7 +170,7 @@ def _ctx_args(args: dict) -> dict:
 def current_span() -> str:
     """Name of the innermost open span on this thread ('' if none)."""
     st = _stack()
-    return st[-1] if st else ""
+    return st[-1].name if st else ""
 
 
 def _append(event: dict) -> None:
@@ -136,34 +182,51 @@ def _append(event: dict) -> None:
         _EVENTS.append(event)
 
 
+def _profiler_note(name: str, attrs: dict, id_: int, call: int):
+    """The span as a ``jax.profiler.TraceAnnotation`` (a no-op outside
+    a profiler session), or a null context where jax is not loaded."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    stats = {k: v for k, v in attrs.items() if k != "name"}
+    stats.update(id=id_, call=call, src="tpulsar")
+    return profiler.TraceAnnotation(name, **stats)
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Record a nested Chrome-trace complete event around the scope.
 
     Exception-safe: the span closes (and records ``error``) when the
-    body raises.  Nesting is per-thread; the parent span's name and
-    depth ride in args so a flat event list still states the tree."""
-    if not enabled():
+    body raises.  Nesting is per-thread.  The event carries ``id``,
+    ``parent_id`` and ``call`` (a root span's own id, inherited by
+    everything under it); the parent's name and the depth ride in args
+    so a flat event list still states the tree."""
+    if not _ON:
         yield
         return
     st = _stack()
-    parent = st[-1] if st else ""
+    parent = st[-1] if st else None
     depth = len(st)
-    st.append(name)
-    t_begin = time.time()
+    sid = next(_IDS)
+    frame = _Frame(name, sid, parent.call if parent else sid, dict(attrs))
+    st.append(frame)
     error = ""
+    t_begin = time.time()
     try:
-        yield
+        with _profiler_note(name, attrs, sid, frame.call):
+            yield
     except BaseException as exc:
         error = f"{type(exc).__name__}: {exc}"[:200]
         raise
     finally:
         t_end = time.time()
-        if st and st[-1] == name:
+        if st and st[-1] is frame:
             st.pop()
-        args = {k: v for k, v in attrs.items()}
+        args = frame.attrs
         if parent:
-            args["parent"] = parent
+            args["parent"] = parent.name
         args["depth"] = depth
         if error:
             args["error"] = error
@@ -172,8 +235,44 @@ def span(name: str, **attrs):
             "ts": round((t_begin - _T0) * 1e6, 1),
             "dur": round((t_end - t_begin) * 1e6, 1),
             "pid": os.getpid(), "tid": threading.get_ident(),
+            "id": sid, "parent_id": parent.id if parent else 0,
+            "call": frame.call,
             "args": _ctx_args(args),
         })
+
+
+def annotate(**attrs) -> None:
+    """Add attributes to the innermost open span's in-memory event —
+    for counts known only inside or at the end of the scope (candidates
+    sifted, bytes checkpointed).  They reach ``events()`` and the
+    Chrome-trace file, not the profiler's annotation, which takes its
+    stats at entry."""
+    if not _ON:
+        return
+    st = _stack()
+    if st:
+        st[-1].attrs.update(attrs)
+
+
+def profile_session(profile_dir: str):
+    """``jax.profiler.trace(profile_dir)`` around a scope, or a null
+    context for an empty ``profile_dir`` (TPULSAR_PROFILE unset).
+    With TPULSAR_TRACE=1 beside it, the one xprof trace holds the
+    program's spans over the device's operations."""
+    if not profile_dir:
+        return contextlib.nullcontext()
+    import jax.profiler
+    return jax.profiler.trace(profile_dir)
+
+
+def _under_current(args: dict) -> tuple[dict, dict]:
+    """(args with the enclosing span's name, {parent_id, call}) for an
+    event recorded without a scope of its own."""
+    st = _stack()
+    if not st:
+        return args, {"parent_id": 0, "call": 0}
+    args["parent"] = st[-1].name
+    return args, {"parent_id": st[-1].id, "call": st[-1].call}
 
 
 def complete(name: str, dur_s: float, **attrs) -> None:
@@ -185,18 +284,16 @@ def complete(name: str, dur_s: float, **attrs) -> None:
     lands on the caller's thread track with the enclosing span noted
     in args, so Perfetto shows the compile inside the stage that
     triggered it."""
-    if not enabled():
+    if not _ON:
         return
     t_end = time.time()
-    args = dict(attrs)
-    parent = current_span()
-    if parent:
-        args["parent"] = parent
+    args, tree = _under_current(dict(attrs))
     _append({
         "name": name, "cat": "tpulsar", "ph": "X",
         "ts": round((t_end - dur_s - _T0) * 1e6, 1),
         "dur": round(dur_s * 1e6, 1),
         "pid": os.getpid(), "tid": threading.get_ident(),
+        "id": next(_IDS), **tree,
         "args": _ctx_args(args),
     })
 
@@ -204,17 +301,14 @@ def complete(name: str, dur_s: float, **attrs) -> None:
 def instant(name: str, **attrs) -> None:
     """Zero-duration marker (circuit transitions, rescue decisions):
     shows as a tick on the Perfetto track."""
-    if not enabled():
+    if not _ON:
         return
-    args = dict(attrs)
-    parent = current_span()
-    if parent:
-        args["parent"] = parent
+    args, tree = _under_current(dict(attrs))
     _append({
         "name": name, "cat": "tpulsar", "ph": "i",
         "ts": round((time.time() - _T0) * 1e6, 1),
         "pid": os.getpid(), "tid": threading.get_ident(),
-        "s": "t", "args": _ctx_args(args),
+        "s": "t", **tree, "args": _ctx_args(args),
     })
 
 
@@ -319,6 +413,66 @@ def render_summary(summary: dict) -> str:
                      f"{100.0 * rec['seconds'] / root_s:5.1f}%  "
                      f"{rec['count']:6d}")
     return "\n".join(lines)
+
+
+def _by_id(trace_events: list[dict]) -> dict[int, dict]:
+    return {e["id"]: e for e in trace_events
+            if e.get("ph") == "X" and e.get("id")}
+
+
+def self_seconds(trace_events: list[dict]) -> dict[int, float]:
+    """{span id: seconds of its own}: a span's duration minus the part
+    of its interval that its children (by ``parent_id``) cover."""
+    spans = _by_id(trace_events)
+    kids: dict[int, list[tuple[float, float]]] = {}
+    for e in spans.values():
+        if e.get("parent_id") in spans:
+            kids.setdefault(e["parent_id"], []).append(
+                (e["ts"], e["ts"] + e["dur"]))
+    out = {}
+    for sid, e in spans.items():
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        covered, edge = 0.0, lo
+        for s, t in sorted(kids.get(sid, ())):
+            s, t = max(s, edge), min(t, hi)
+            if t > s:
+                covered += t - s
+                edge = t
+        out[sid] = (e["dur"] - covered) / 1e6
+    return out
+
+
+def in_window(trace_events: list[dict], t0_unix: float, t1_unix: float,
+              epoch_unix: float | None = None) -> list[dict]:
+    """The events that lie inside [t0_unix, t1_unix] (``time.time()``
+    stamps, as the spans' own clock is).  ``epoch_unix``: the unix
+    seconds of ts 0 — this process's by default, a saved file's
+    ``trace_epoch_unix_s`` otherwise."""
+    base = _T0 if epoch_unix is None else epoch_unix
+    lo, hi = (t0_unix - base) * 1e6, (t1_unix - base) * 1e6
+    return [e for e in trace_events
+            if e["ts"] >= lo and e["ts"] + e.get("dur", 0.0) <= hi]
+
+
+def uncovered_share(trace_events: list[dict], root_id: int,
+                    through: tuple[str, ...] = ()) -> float:
+    """The share of span ``root_id``'s duration that none of its
+    children covers.  Children named in ``through`` are looked
+    through: they group spans and do no work of their own
+    (``dm_chunk``), so their own uncovered time counts as the root's
+    and only their children cover."""
+    spans = _by_id(trace_events)
+    own = self_seconds(trace_events)
+    kids: dict[int, list[int]] = {}
+    for e in spans.values():
+        kids.setdefault(e.get("parent_id", 0), []).append(e["id"])
+
+    def bare(sid: int) -> float:
+        return own[sid] + sum(bare(k) for k in kids.get(sid, ())
+                              if spans[k]["name"] in through)
+
+    dur = spans[root_id]["dur"] / 1e6
+    return bare(root_id) / dur if dur > 0 else 0.0
 
 
 def rollup(trace_events: list[dict] | None = None

@@ -736,7 +736,6 @@ def _group_plan_loop(datas, freqs, dt, plan, params, zaplists, baryvs,
             dt_ds = dt * step.downsamp
             chunk_sz = pass_chunk_size(len(dms), ddplan.choose_n(T_ds),
                                        params)
-            t_dd0 = timers.times.get("dedispersing", 0.0)
             tree_plan = tree_dd.plan_for_pass(sub_shifts, T=T_ds)
             tree_parts = None
             if tree_plan is not None:
@@ -875,9 +874,6 @@ def _group_plan_loop(datas, freqs, dt, plan, params, zaplists, baryvs,
             del tree_parts
             telemetry.dedisp_trials_total().inc(B * len(dms),
                                                 family=fam)
-            telemetry.dedisp_stage_seconds().observe(
-                timers.times.get("dedispersing", 0.0) - t_dd0,
-                family=fam)
             telemetry.passes_total().inc(B)
             telemetry.dm_trials_total().inc(B * len(dms))
             telemetry.beam_batch_trials_total().inc(B * len(dms),
@@ -1015,24 +1011,18 @@ def search_block(data: jnp.ndarray, freqs: np.ndarray, dt: float,
     degraded.reset()   # this run's fallback flags only
     # TPULSAR_PROFILE=<dir>: capture a JAX profiler trace of the whole
     # block search (the TPU-era equivalent of the reference's stage
-    # timers, SURVEY.md 5.1 — view with TensorBoard/xprof)
-    import contextlib
-
-    profile_dir = os.environ.get("TPULSAR_PROFILE", "").strip()
-    if profile_dir:
-        import jax.profiler as _prof
-        _trace = _prof.trace(profile_dir)
-    else:
-        _trace = contextlib.nullcontext()
-    with _trace:
-        # root telemetry span: every stage/chunk span of this search
-        # nests under it in the exported Chrome trace
-        with trace_mod.span("search_block",
-                            npasses=sum(s.numpasses for s in plan)):
-            return _search_block_inner(
-                data, freqs, dt, plan, params, zaplist, baryv, nsub,
-                timers, checkpoint_dir, data_id, checkpoint,
-                checkpoint_journal, progress_cb, mesh)
+    # timers, SURVEY.md 5.1 — view with TensorBoard/xprof); with
+    # TPULSAR_TRACE=1 the spans below land in it, over the device's
+    # operations.  The root span: every pass/chunk/stage span of this
+    # search nests under it and carries its id as `call`.
+    with trace_mod.profile_session(
+            os.environ.get("TPULSAR_PROFILE", "").strip()), \
+            trace_mod.span("search_block",
+                           npasses=sum(s.numpasses for s in plan)):
+        return _search_block_inner(
+            data, freqs, dt, plan, params, zaplist, baryv, nsub,
+            timers, checkpoint_dir, data_id, checkpoint,
+            checkpoint_journal, progress_cb, mesh)
 
 
 def _search_block_inner(data, freqs, dt, plan, params, zaplist, baryv,
@@ -1080,233 +1070,240 @@ def _search_block_inner(data, freqs, dt, plan, params, zaplist, baryv,
             pass_cands_start = len(all_cands)
             pass_sp_start = len(sp_chunks)
             pass_trials_start = num_trials
-            with timers.timing("subbanding"):
-                chan_shifts, sub_shifts = dd.plan_pass_shifts(
-                    freqs, nsub, ppass.subdm, np.asarray(ppass.dms),
-                    dt, step.downsamp)
-                subb = dd.form_subbands(data, jnp.asarray(chan_shifts),
-                                        nsub, step.downsamp)
-            dt_ds = dt * step.downsamp
-            dms = np.asarray(ppass.dms)
-            if mesh is not None:
-                with timers.timing("sharded-search"):
-                    cands, events = _search_pass_sharded(
-                        mesh, subb, sub_shifts, dms, dt_ds, params,
-                        zaplist, baryv, timers=timers)
-                all_cands.extend(cands)
-                if len(events):
-                    sp_chunks.append(events)
-                num_trials += len(dms)
-            else:
-                chunk_sz = pass_chunk_size(
-                    len(dms), ddplan.choose_n(subb.shape[1]), params)
-                # Stage-2 kernel family for THIS pass: the ddplan
-                # cost model picks the log-depth shift tree
-                # (kernels/tree_dd.py) when the pass's DM grid lets
-                # the shared merge levels amortize across its trials
-                # (survey passes: ~4x fewer row-ops), and keeps the
-                # direct shift-and-sum — the oracle — for small or
-                # irregular grids, under TPULSAR_DD_FAMILY override.
-                # Tree passes run the levels ONCE here; each dm_chunk
-                # below only pays its residual layer, with the SP
-                # detrend fused into the same program.
-                t_dd0 = timers.times.get("dedispersing", 0.0)
-                tree_plan = tree_dd.plan_for_pass(
-                    sub_shifts, T=int(subb.shape[1]))
-                tree_parts = None
-                sp_est = sp_k.detrend_estimator(params.sp_detrend)
-                if tree_plan is not None:
-                    with timers.timing("dedispersing"):
-                        tree_parts = tree_dd.tree_levels(subb,
-                                                         tree_plan)
-                        trace_mod.fence(tree_parts)
-                    telemetry.dedisp_tree_depth().set(tree_plan.depth)
-                    telemetry.dedisp_residual_fraction().set(
-                        round(tree_plan.residual_fraction, 4))
-                # SP and lo-stage device outputs are DEFERRED to one
-                # device_get per pass (below): the per-chunk blocking
-                # np.asarray cost one host<->device round-trip per
-                # output.  Only top-k-sized blocks are
-                # held, so the deferral is KBs per chunk.  The hi
-                # stage stays inline: its internal windowed drain is
-                # the per-chunk sync that bounds device memory.
-                pending: list[tuple] = []
-                for lo in range(0, len(dms), chunk_sz):
-                    if len(pending) >= 2:
-                        # Backpressure: without any host sync in the
-                        # loop (hi off), async dispatch would let
-                        # every chunk's full-size series/wspec buffers
-                        # be enqueued concurrently — pass_chunk_size
-                        # budgets for ~one chunk resident.  Blocking
-                        # on the chunk-before-last's lo output bounds
-                        # it to two chunks in flight while still
-                        # overlapping dispatch with compute (with hi
-                        # on the accel drain already finished it;
-                        # this is then instant).
-                        with timers.timing("pipeline-wait"):
-                            jax.block_until_ready(pending[-2][4])
-                    dm_chunk = dms[lo: lo + chunk_sz]
-                    # per-chunk child span: the stage scopes below
-                    # nest under it, so the trace file shows the
-                    # pass/chunk structure, not just stage totals
-                    with trace_mod.span("dm_chunk",
-                                        pass_idx=pass_idx, lo=int(lo),
-                                        n=int(len(dm_chunk)),
-                                        family=("tree" if tree_parts
-                                                is not None
-                                                else "direct")):
-                        norm = None
+            # one span per dedispersion pass: its chunks, stages and
+            # pass-end host halves nest under it; progress_cb stays
+            # outside (the caller's clock stops there)
+            with trace_mod.span("pass", pass_idx=pass_idx,
+                                step_idx=step_idx,
+                                downsamp=int(step.downsamp),
+                                ntrials=len(ppass.dms)):
+                with timers.timing("subbanding"):
+                    chan_shifts, sub_shifts = dd.plan_pass_shifts(
+                        freqs, nsub, ppass.subdm, np.asarray(ppass.dms),
+                        dt, step.downsamp)
+                    subb = dd.form_subbands(data, jnp.asarray(chan_shifts),
+                                            nsub, step.downsamp)
+                dt_ds = dt * step.downsamp
+                dms = np.asarray(ppass.dms)
+                if mesh is not None:
+                    with timers.timing("sharded-search"):
+                        cands, events = _search_pass_sharded(
+                            mesh, subb, sub_shifts, dms, dt_ds, params,
+                            zaplist, baryv, timers=timers)
+                    all_cands.extend(cands)
+                    if len(events):
+                        sp_chunks.append(events)
+                    num_trials += len(dms)
+                else:
+                    chunk_sz = pass_chunk_size(
+                        len(dms), ddplan.choose_n(subb.shape[1]), params)
+                    # Stage-2 kernel family for THIS pass: the ddplan
+                    # cost model picks the log-depth shift tree
+                    # (kernels/tree_dd.py) when the pass's DM grid lets
+                    # the shared merge levels amortize across its trials
+                    # (survey passes: ~4x fewer row-ops), and keeps the
+                    # direct shift-and-sum — the oracle — for small or
+                    # irregular grids, under TPULSAR_DD_FAMILY override.
+                    # Tree passes run the levels ONCE here; each dm_chunk
+                    # below only pays its residual layer, with the SP
+                    # detrend fused into the same program.
+                    tree_plan = tree_dd.plan_for_pass(
+                        sub_shifts, T=int(subb.shape[1]))
+                    tree_parts = None
+                    sp_est = sp_k.detrend_estimator(params.sp_detrend)
+                    if tree_plan is not None:
                         with timers.timing("dedispersing"):
-                            if tree_parts is not None:
-                                series, norm = tree_dd.residual_series(
-                                    tree_parts, tree_plan, lo,
-                                    len(dm_chunk),
-                                    T=int(subb.shape[1]),
-                                    fuse=True, estimator=sp_est)
-                            else:
-                                series = dd.dedisperse_subbands(
-                                    subb,
-                                    jnp.asarray(
-                                        sub_shifts[lo: lo
-                                                   + len(dm_chunk)]))
-                            # opt-in device attribution
-                            # (TPULSAR_TRACE_SYNC=1): fence so the
-                            # scope's exit clock includes the device
-                            # compute this enqueue started.  On the
-                            # tree path series and norm are outputs
-                            # of ONE fused executable, so fencing
-                            # either blocks on both: the fused
-                            # detrend's wall time lands inside
-                            # 'dedispersing' in the report AND the
-                            # trace (a per-chunk detrend/dedisp
-                            # split is unmeasurable for a fused
-                            # program — the bench --dedisp A/B
-                            # carries its marginal cost instead)
-                            trace_mod.fence(series if norm is None
-                                            else (series, norm))
-                        num_trials += len(dm_chunk)
-                        # FFT-friendly padded length (reference: PRESTO
-                        # choose_N via prepsubband -numout,
-                        # PALFA2_presto_search.py:518); one length per
-                        # plan step keeps compile signatures bounded.
-                        nfft = ddplan.choose_n(series.shape[1])
-                        T_s = nfft * dt_ds
+                            tree_parts = tree_dd.tree_levels(subb,
+                                                             tree_plan)
+                            trace_mod.fence(tree_parts)
+                        telemetry.dedisp_tree_depth().set(tree_plan.depth)
+                        telemetry.dedisp_residual_fraction().set(
+                            round(tree_plan.residual_fraction, 4))
+                    # SP and lo-stage device outputs are DEFERRED to one
+                    # device_get per pass (below): the per-chunk blocking
+                    # np.asarray cost one host<->device round-trip per
+                    # output.  Only top-k-sized blocks are
+                    # held, so the deferral is KBs per chunk.  The hi
+                    # stage stays inline: its internal windowed drain is
+                    # the per-chunk sync that bounds device memory.
+                    pending: list[tuple] = []
+                    for lo in range(0, len(dms), chunk_sz):
+                        if len(pending) >= 2:
+                            # Backpressure: without any host sync in the
+                            # loop (hi off), async dispatch would let
+                            # every chunk's full-size series/wspec buffers
+                            # be enqueued concurrently — pass_chunk_size
+                            # budgets for ~one chunk resident.  Blocking
+                            # on the chunk-before-last's lo output bounds
+                            # it to two chunks in flight while still
+                            # overlapping dispatch with compute (with hi
+                            # on the accel drain already finished it;
+                            # this is then instant).
+                            with timers.timing("pipeline-wait"):
+                                jax.block_until_ready(pending[-2][4])
+                        dm_chunk = dms[lo: lo + chunk_sz]
+                        # per-chunk child span: the stage scopes below
+                        # nest under it, so the trace file shows the
+                        # pass/chunk structure, not just stage totals
+                        with trace_mod.span("dm_chunk",
+                                            pass_idx=pass_idx, lo=int(lo),
+                                            n=int(len(dm_chunk)),
+                                            family=("tree" if tree_parts
+                                                    is not None
+                                                    else "direct")):
+                            norm = None
+                            with timers.timing("dedispersing"):
+                                if tree_parts is not None:
+                                    series, norm = tree_dd.residual_series(
+                                        tree_parts, tree_plan, lo,
+                                        len(dm_chunk),
+                                        T=int(subb.shape[1]),
+                                        fuse=True, estimator=sp_est)
+                                else:
+                                    series = dd.dedisperse_subbands(
+                                        subb,
+                                        jnp.asarray(
+                                            sub_shifts[lo: lo
+                                                       + len(dm_chunk)]))
+                                # opt-in device attribution
+                                # (TPULSAR_TRACE_SYNC=1): fence so the
+                                # scope's exit clock includes the device
+                                # compute this enqueue started.  On the
+                                # tree path series and norm are outputs
+                                # of ONE fused executable, so fencing
+                                # either blocks on both: the fused
+                                # detrend's wall time lands inside
+                                # 'dedispersing' in the report AND the
+                                # trace (a per-chunk detrend/dedisp
+                                # split is unmeasurable for a fused
+                                # program — the bench --dedisp A/B
+                                # carries its marginal cost instead)
+                                trace_mod.fence(series if norm is None
+                                                else (series, norm))
+                            num_trials += len(dm_chunk)
+                            # FFT-friendly padded length (reference: PRESTO
+                            # choose_N via prepsubband -numout,
+                            # PALFA2_presto_search.py:518); one length per
+                            # plan step keeps compile signatures bounded.
+                            nfft = ddplan.choose_n(series.shape[1])
+                            T_s = nfft * dt_ds
 
-                        with timers.timing("single-pulse"):
-                            # the device half of single_pulse_search;
-                            # on the tree path the detrend already
-                            # ran fused into the residual program, so
-                            # only the boxcar ladder remains here.
-                            # The host half (events_from_topk) runs
-                            # at pass end either way.
-                            if norm is not None:
-                                sp_pair = sp_k.boxcar_search(
-                                    norm, tuple(params.sp_widths),
-                                    sp_k.DEFAULT_TOPK)
-                            else:
-                                sp_pair = sp_k.device_search(
-                                    series, tuple(params.sp_widths),
-                                    estimator=params.sp_detrend)
-                            trace_mod.fence(sp_pair)
+                            with timers.timing("single-pulse"):
+                                # the device half of single_pulse_search;
+                                # on the tree path the detrend already
+                                # ran fused into the residual program, so
+                                # only the boxcar ladder remains here.
+                                # The host half (events_from_topk) runs
+                                # at pass end either way.
+                                if norm is not None:
+                                    sp_pair = sp_k.boxcar_search(
+                                        norm, tuple(params.sp_widths),
+                                        sp_k.DEFAULT_TOPK)
+                                else:
+                                    sp_pair = sp_k.device_search(
+                                        series, tuple(params.sp_widths),
+                                        estimator=params.sp_detrend)
+                                trace_mod.fence(sp_pair)
 
-                        with timers.timing("FFT"):
-                            nbins = nfft // 2 + 1
-                            keep = fr.zap_mask(nbins, T_s, zaplist,
-                                               baryv) \
-                                if zaplist is not None else None
-                            # One fused pad->rfft->whiten->scale program
-                            # per chunk; the whitened COMPLEX spectrum is
-                            # shared by the lo stage (interbinned powers)
-                            # and the hi stage (correlation input).
-                            # Zapped bins have wpow==0 so they vanish
-                            # from both.
-                            wspec = (fr.whitened_spectrum_masked(
-                                         series, jnp.asarray(keep),
-                                         nfft=nfft)
-                                     if keep is not None else
-                                     fr.whitened_spectrum(series,
-                                                          nfft=nfft))
-                            trace_mod.fence(wspec)
-                        with timers.timing("lo-accelsearch"):
-                            # half-bin detection grid (PRESTO
-                            # ACCEL_DR=0.5 via interbinning) — bin
-                            # indices are in half-bin units, hence
-                            # bin_scale=0.5; one fused program so the
-                            # (rows, 2*nbins) interbinned grid never
-                            # round-trips HBM
-                            res = fr.lo_stage_candidates(
-                                wspec,
-                                tuple(fr.harmonic_stages(
-                                    params.lo_accel_numharm)),
-                                params.topk_per_stage)
-                            trace_mod.fence(res)
+                            with timers.timing("FFT"):
+                                nbins = nfft // 2 + 1
+                                keep = fr.zap_mask(nbins, T_s, zaplist,
+                                                   baryv) \
+                                    if zaplist is not None else None
+                                # One fused pad->rfft->whiten->scale program
+                                # per chunk; the whitened COMPLEX spectrum is
+                                # shared by the lo stage (interbinned powers)
+                                # and the hi stage (correlation input).
+                                # Zapped bins have wpow==0 so they vanish
+                                # from both.
+                                wspec = (fr.whitened_spectrum_masked(
+                                             series, jnp.asarray(keep),
+                                             nfft=nfft)
+                                         if keep is not None else
+                                         fr.whitened_spectrum(series,
+                                                              nfft=nfft))
+                                trace_mod.fence(wspec)
+                            with timers.timing("lo-accelsearch"):
+                                # half-bin detection grid (PRESTO
+                                # ACCEL_DR=0.5 via interbinning) — bin
+                                # indices are in half-bin units, hence
+                                # bin_scale=0.5; one fused program so the
+                                # (rows, 2*nbins) interbinned grid never
+                                # round-trips HBM
+                                res = fr.lo_stage_candidates(
+                                    wspec,
+                                    tuple(fr.harmonic_stages(
+                                        params.lo_accel_numharm)),
+                                    params.topk_per_stage)
+                                trace_mod.fence(res)
 
-                        hi_cands: list = []
-                        if params.run_hi_accel \
-                                and params.hi_accel_zmax > 0:
-                            with timers.timing("hi-accelsearch"):
-                                hi_cands = _hi_accel_pass(
-                                    wspec, dm_chunk, T_s, params)
-                        del wspec
-                        pending.append((dm_chunk, T_s, nbins, sp_pair,
-                                        res, hi_cands))
+                            hi_cands: list = []
+                            if params.run_hi_accel \
+                                    and params.hi_accel_zmax > 0:
+                                with timers.timing("hi-accelsearch"):
+                                    hi_cands = _hi_accel_pass(
+                                        wspec, dm_chunk, T_s, params)
+                            del wspec
+                            pending.append((dm_chunk, T_s, nbins, sp_pair,
+                                            res, hi_cands))
 
-                # ---- pass end: one transfer per stage family
-                # (charged to its own timer: the first get blocks on
-                # ALL the pass's queued device work, so attributing
-                # it to a compute stage would skew stage_s), then the
-                # host halves in chunk order (candidate/event
-                # ordering is unchanged from the per-chunk layout)
-                with timers.timing("pipeline-drain"):
-                    sp_host = jax.device_get(
-                        [p[3] for p in pending])
-                    lo_host = jax.device_get([p[4] for p in pending])
-                for (dm_chunk, T_s, nbins, _sp, _res,
-                     hi_cands), (snrs, idx), res_h in zip(
-                         pending, sp_host, lo_host):
-                    with timers.timing("single-pulse"):
-                        ev = sp_k.events_from_topk(
-                            snrs, idx, dm_chunk, dt_ds,
-                            threshold=params.sp_threshold,
-                            widths=tuple(params.sp_widths))
-                        if len(ev):
-                            sp_chunks.append(ev)
-                    with timers.timing("lo-accelsearch"):
-                        all_cands.extend(sifting.make_candidates(
-                            res_h, dm_chunk, T_s, _lo_sigma_fn(nbins),
-                            sigma_min=params.sifting.sigma_threshold,
-                            bin_scale=0.5))
-                    all_cands.extend(hi_cands)
-                del pending
-                # per-family throughput instruments: with the trials
-                # counter, the stage-seconds histogram yields
-                # trials/sec per kernel family (the bench A/B's
-                # headline, continuously exported)
-                fam = "tree" if tree_parts is not None else "direct"
-                del tree_parts
-                telemetry.dedisp_trials_total().inc(len(dms),
-                                                    family=fam)
-                telemetry.dedisp_stage_seconds().observe(
-                    timers.times.get("dedispersing", 0.0) - t_dd0,
-                    family=fam)
-            del subb
-            if store is not None:
-                ntr_pass = num_trials - pass_trials_start
-                durable = store.save(
-                    f"pass_{pass_idx:04d}",
-                    _encode_pass(
-                        all_cands[pass_cands_start:],
-                        (np.concatenate(sp_chunks[pass_sp_start:])
-                         if len(sp_chunks) > pass_sp_start
-                         else _EMPTY_SP),
-                        ntr_pass),
-                    kind="pass", ext=".npz", pass_idx=pass_idx)
-                if durable:
-                    # journaled ONLY once the artifact is durable: the
-                    # chaos verifier's no_pass_rerun invariant treats
-                    # this event as "never recompute pass k again"
-                    store.journal("pass_complete", pass_idx=pass_idx,
-                                  npasses=npasses, ntrials=ntr_pass)
+                    # ---- pass end: one transfer per stage family
+                    # (charged to its own timer: the first get blocks on
+                    # ALL the pass's queued device work, so attributing
+                    # it to a compute stage would skew stage_s), then the
+                    # host halves in chunk order (candidate/event
+                    # ordering is unchanged from the per-chunk layout)
+                    with timers.timing("pipeline-drain"):
+                        sp_host = jax.device_get(
+                            [p[3] for p in pending])
+                        lo_host = jax.device_get([p[4] for p in pending])
+                    for (dm_chunk, T_s, nbins, _sp, _res,
+                         hi_cands), (snrs, idx), res_h in zip(
+                             pending, sp_host, lo_host):
+                        with timers.timing("single-pulse"), \
+                                trace_mod.span("sp-events"):
+                            ev = sp_k.events_from_topk(
+                                snrs, idx, dm_chunk, dt_ds,
+                                threshold=params.sp_threshold,
+                                widths=tuple(params.sp_widths))
+                            if len(ev):
+                                sp_chunks.append(ev)
+                            trace_mod.annotate(events=len(ev))
+                        with timers.timing("lo-accelsearch"), \
+                                trace_mod.span("lo-candidates"):
+                            lo_cands = sifting.make_candidates(
+                                res_h, dm_chunk, T_s, _lo_sigma_fn(nbins),
+                                sigma_min=params.sifting.sigma_threshold,
+                                bin_scale=0.5)
+                            trace_mod.annotate(cands=len(lo_cands))
+                        all_cands.extend(lo_cands)
+                        all_cands.extend(hi_cands)
+                    del pending
+                    fam = "tree" if tree_parts is not None else "direct"
+                    del tree_parts
+                    telemetry.dedisp_trials_total().inc(len(dms),
+                                                        family=fam)
+                del subb
+                if store is not None:
+                    ntr_pass = num_trials - pass_trials_start
+                    with trace_mod.span("pass-checkpoint"):
+                        payload = _encode_pass(
+                            all_cands[pass_cands_start:],
+                            (np.concatenate(sp_chunks[pass_sp_start:])
+                             if len(sp_chunks) > pass_sp_start
+                             else _EMPTY_SP),
+                            ntr_pass)
+                        durable = store.save(
+                            f"pass_{pass_idx:04d}", payload,
+                            kind="pass", ext=".npz", pass_idx=pass_idx)
+                        trace_mod.annotate(bytes=len(payload),
+                                           durable=bool(durable))
+                    if durable:
+                        # journaled ONLY once the artifact is durable: the
+                        # chaos verifier's no_pass_rerun invariant treats
+                        # this event as "never recompute pass k again"
+                        store.journal("pass_complete", pass_idx=pass_idx,
+                                      npasses=npasses, ntrials=ntr_pass)
             telemetry.passes_total().inc()
             telemetry.dm_trials_total().inc(len(dms))
             if progress_cb is not None:
@@ -1323,6 +1320,7 @@ def _search_block_inner(data, freqs, dt, plan, params, zaplist, baryv,
                              num_trials, sifted_state, plan)
 
 
+@trace_mod.span("finish")
 def _sift_fold_finish(data, freqs, dt, params, zaplist, baryv, nsub,
                       timers, store, all_cands, sp_chunks, num_trials,
                       sifted_state, plan):
@@ -1344,6 +1342,7 @@ def _sift_fold_finish(data, freqs, dt, params, zaplist, baryv, nsub,
     else:
         with timers.timing("sifting"):
             final = sifting.sift(all_cands, params.sifting)
+            trace_mod.annotate(n_in=len(all_cands), n_out=len(final))
 
         sp_events = (np.concatenate(sp_chunks) if sp_chunks
                      else _EMPTY_SP)
@@ -1370,6 +1369,7 @@ def _sift_fold_finish(data, freqs, dt, params, zaplist, baryv, nsub,
             from tpulsar.search import refine
 
             with timers.timing("refinement"):
+                trace_mod.annotate(n=len(to_refine))
                 # lo/hi identity by DETECTION z — refinement perturbs
                 # z off exact zero, which must not flip a lo candidate
                 # onto the hi search's nz-times-larger trial count
@@ -1452,6 +1452,7 @@ def _sift_fold_finish(data, freqs, dt, params, zaplist, baryv, nsub,
                 sub_sh[0])
 
     with timers.timing("folding"):
+        trace_mod.annotate(n=len(to_fold))
         if params.fold_by_rules and params.fold_batched and to_fold:
             # Tier-batched pass-grouped folding: candidates fold from
             # their originating pass's subband geometry (subdm +
@@ -1854,11 +1855,14 @@ def _hi_accel_pass(wspec, dm_chunk, T_s, params: SearchParams
     # never become Python objects (sigma_min pre-filter).  The
     # correlation plane is numbetween=2 interpolated: r indices are
     # half-bin units (bin_scale).
-    return sifting.make_candidates(
-        res, dm_chunk, T_s,
-        _hi_sigma_fn(wspec.shape[-1], len(bank.zs)),
-        sigma_min=params.sifting.sigma_threshold,
-        z_min_abs=accel_k.DZ / 2, bin_scale=0.5)
+    with trace_mod.span("accel-candidates"):
+        cands = sifting.make_candidates(
+            res, dm_chunk, T_s,
+            _hi_sigma_fn(wspec.shape[-1], len(bank.zs)),
+            sigma_min=params.sifting.sigma_threshold,
+            z_min_abs=accel_k.DZ / 2, bin_scale=0.5)
+        trace_mod.annotate(cands=len(cands))
+    return cands
 
 
 _BANK_CACHE: dict[int, accel_k.TemplateBank] = {}

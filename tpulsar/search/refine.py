@@ -24,6 +24,7 @@ from __future__ import annotations
 import numpy as np
 
 from tpulsar.kernels.accel import DZ
+from tpulsar.obs import trace
 
 def _response_at(z: float, offsets: np.ndarray) -> np.ndarray:
     """Complex response values of a unit tone drifting z bins,
@@ -266,56 +267,61 @@ def refine_candidates(cands, series_by_dm, dt: float, nfft: int,
     for dm, group in by_dm.items():
         if dm not in series_by_dm:
             continue
-        series = jnp.asarray(series_by_dm[dm])[None, :]
-        if keep_mask is not None:
-            wspec_dev = fr.whitened_spectrum_masked(
-                series, jnp.asarray(keep_mask), nfft=nfft)[0]
-        else:
-            wspec_dev = fr.whitened_spectrum(series, nfft=nfft)[0]
-        nbins = int(wspec_dev.shape[0])
-        ranges: list[tuple[int, int]] = []
-        cand_spans: list[list[tuple[int, int]]] = []
-        for c in group:
-            spans = _harmonic_windows(c.freq_hz * T_s, c.z,
-                                      c.numharm, nbins)
-            cand_spans.append(spans)
-            ranges.extend(spans)
-        # Jitted gathers in fixed _NWIN chunks at a bucketed width:
-        # eager per-window slicing of a complex device array is
-        # rejected by some TPU runtimes (see accel.accel_row_topk),
-        # and per-window slice programs would be unbounded
-        # data-dependent compiles — the fixed (count, width) buckets
-        # keep the program set closed so the AOT gate covers it.
-        # All chunks are dispatched async, then ONE device_get drains
-        # them together (a blocking get per chunk would serialize
-        # ceil(n/64) round-trips).
-        import jax
+        # the device half: spectrum, window gathers, the ONE
+        # device_get; then the host half, the per-harmonic simplex
+        with trace.span("refine-device", dm=float(dm), n=len(group)):
+            series = jnp.asarray(series_by_dm[dm])[None, :]
+            if keep_mask is not None:
+                wspec_dev = fr.whitened_spectrum_masked(
+                    series, jnp.asarray(keep_mask), nfft=nfft)[0]
+            else:
+                wspec_dev = fr.whitened_spectrum(series, nfft=nfft)[0]
+            nbins = int(wspec_dev.shape[0])
+            ranges: list[tuple[int, int]] = []
+            cand_spans: list[list[tuple[int, int]]] = []
+            for c in group:
+                spans = _harmonic_windows(c.freq_hz * T_s, c.z,
+                                          c.numharm, nbins)
+                cand_spans.append(spans)
+                ranges.extend(spans)
+            # Jitted gathers in fixed _NWIN chunks at a bucketed width:
+            # eager per-window slicing of a complex device array is
+            # rejected by some TPU runtimes (see accel.accel_row_topk),
+            # and per-window slice programs would be unbounded
+            # data-dependent compiles — the fixed (count, width) buckets
+            # keep the program set closed so the AOT gate covers it.
+            # All chunks are dispatched async, then ONE device_get drains
+            # them together (a blocking get per chunk would serialize
+            # ceil(n/64) round-trips).
+            import jax
 
-        width = _width_bucket(max(hi - lo for lo, hi in ranges))
-        lows_all = np.fromiter((lo for lo, _ in ranges), np.int32,
-                               len(ranges))
-        gather = _gather_jit()
-        chunks_dev = []
-        for s in range(0, len(ranges), _NWIN):
-            lows = lows_all[s: s + _NWIN]
-            lows = np.pad(lows, (0, _NWIN - len(lows)))
-            chunks_dev.append(gather(wspec_dev,
-                                     jnp.asarray(lows, np.int32),
-                                     width=width))
-        fetched = np.concatenate(
-            [np.asarray(c[..., 0] + 1j * c[..., 1])
-             for c in jax.device_get(chunks_dev)],
-            axis=0)
-        windows = [(lo, fetched[i][: min(width, nbins - lo)])
-                   for i, (lo, _hi) in enumerate(ranges)]
-        i = 0
-        for c, spans in zip(group, cand_spans):
-            view = _WindowedSpectrum(
-                nbins, windows[i: i + len(spans)])
-            i += len(spans)
-            r0 = c.freq_hz * T_s
-            r, z, power = refine_peak(view, r0, c.z,
-                                      numharm=c.numharm)
-            c.r, c.z, c.power = r, z, power
-            c.freq_hz = r / T_s
-            c.period_s = T_s / r
+            width = _width_bucket(max(hi - lo for lo, hi in ranges))
+            lows_all = np.fromiter((lo for lo, _ in ranges), np.int32,
+                                   len(ranges))
+            gather = _gather_jit()
+            chunks_dev = []
+            for s in range(0, len(ranges), _NWIN):
+                lows = lows_all[s: s + _NWIN]
+                lows = np.pad(lows, (0, _NWIN - len(lows)))
+                chunks_dev.append(gather(wspec_dev,
+                                         jnp.asarray(lows, np.int32),
+                                         width=width))
+            fetched = np.concatenate(
+                [np.asarray(c[..., 0] + 1j * c[..., 1])
+                 for c in jax.device_get(chunks_dev)],
+                axis=0)
+        with trace.span("refine-host", dm=float(dm), n=len(group),
+                        nharm=sum(c.numharm for c in group)):
+            windows = [(lo, fetched[i][: min(width, nbins - lo)])
+                       for i, (lo, _hi) in enumerate(ranges)]
+            i = 0
+            for c, spans in zip(group, cand_spans):
+                view = _WindowedSpectrum(
+                    nbins, windows[i: i + len(spans)])
+                i += len(spans)
+                r0 = c.freq_hz * T_s
+                r, z, power = refine_peak(view, r0, c.z,
+                                          numharm=c.numharm)
+                c.r, c.z, c.power = r, z, power
+                c.freq_hz = r / T_s
+                c.period_s = T_s / r
