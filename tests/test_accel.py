@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpulsar.kernels import accel
 
@@ -395,3 +396,215 @@ def test_per_dm_fallback_total_refusal_raises(monkeypatch):
     monkeypatch.setattr(accel, "accel_row_topk", refuse)
     with pytest.raises(accel.AccelStageRefused):
         accel.accel_search_batch(specs, bank, max_numharm=2, topk=8)
+
+
+# --- the tiled harmonic-sum kernel (accel._harmsum_zmax) --------------
+# A program lowered for a TPU runs the kernel; lowered for anything else
+# (these tests, the host rescue) it runs the strided form.  So the
+# kernel's definition is held here directly, in Pallas's interpreter.
+
+def _kernel_maxes(plane, stages, nz):
+    """The kernel, interpreted, on one (nz, nr) plane or a block."""
+    planes = plane if plane.ndim == 3 else plane[None]
+    out = accel._harmsum_zmax(planes, tuple(stages), nz, interpret=True)
+    if plane.ndim == 3:
+        return out
+    return {h: (m[0], a[0]) for h, (m, a) in out.items()}
+
+
+def _oracle_stage(plane, h, nz):
+    """(max over z, argmax over z) of the strided per-stage sum."""
+    old = np.asarray(accel._harmonic_sum_plane(plane, h, nz))
+    return old.max(axis=0), old.argmax(axis=0)
+
+
+def _random_plane(shape, dtype, seed=5):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.normal(size=shape).astype(np.float32) ** 2
+                       ).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("nz,nr,numharm", [
+    (51, 4096, 16), (9, 1000, 8), (201, 2048, 16), (51, 777, 4),
+    # 2 mod 8 x 128, like all three survey planes (3,932,162;
+    # 1,966,082; 4,194,306): every stage ends in a ragged edge tile
+    (51, 3074, 8),
+])
+def test_harmsum_kernel_bit_identical_to_strided_oracle(nz, nr, numharm,
+                                                        dtype):
+    """The tiled kernel's per-stage (max over z, argmax over z) are
+    the strided oracle's bit for bit — same f32 addition order, same
+    z clamping, same first-index argmax, same L = nr // h — for f32
+    and bf16 planes; and what the program lowers to off the TPU
+    (_harmonic_stage_maxes here) is the same bits again."""
+    from tpulsar.kernels.fourier import harmonic_stages
+
+    plane = _random_plane((nz, nr), dtype)
+    stages = tuple(harmonic_stages(numharm))
+    maxes = _kernel_maxes(plane, stages, nz)
+    lowered_here = accel._harmonic_stage_maxes(plane, stages, nz)
+    assert set(maxes) == set(lowered_here) == set(stages)
+    for h in stages:
+        want_max, want_arg = _oracle_stage(plane, h, nz)
+        assert maxes[h][0].dtype == jnp.float32
+        assert maxes[h][1].dtype == jnp.int32
+        for got in (maxes, lowered_here):
+            np.testing.assert_array_equal(np.asarray(got[h][0]), want_max)
+            np.testing.assert_array_equal(np.asarray(got[h][1]), want_arg)
+
+
+@pytest.mark.parametrize("nd", [1, 2, 3])
+def test_harmsum_kernel_gives_block_topk_its_candidates(nd):
+    """nd DM rows at once: the kernel on _correlate_block's plane,
+    then the block-max top-k, is _accel_block_topk's result bit for
+    bit (the chunk program's body, which lowers the strided form
+    here); a stage the plane has no column for comes back empty."""
+    from tpulsar.kernels.fourier import blockmax_topk, harmonic_stages
+
+    rng = np.random.default_rng(nd)
+    nbins = 3001
+    specs = (rng.normal(size=(nd, nbins)) + 1j * rng.normal(size=(nd, nbins))
+             ).astype(np.complex64)
+    specs[:, 500] += 20.0
+    bank = accel.build_template_bank(8.0, seg=1 << 11)
+    nz = len(bank.zs)
+    bank_fft = jnp.asarray(bank.bank_fft)
+    kw = dict(seg=bank.seg, step=bank.step, width=bank.width, nz=nz)
+    vals, rbins, zidx = accel._accel_block_topk(
+        jnp.asarray(specs), bank_fft, max_numharm=8, topk=16, **kw)
+    plane = accel._correlate_block(jnp.asarray(specs), bank_fft, **kw)
+    # the stated invariant of the selection matmul: a finite plane
+    assert bool(jnp.isfinite(plane.astype(jnp.float32)).all())
+    stages = harmonic_stages(8)
+    maxes = _kernel_maxes(plane, stages, nz)
+    for si, h in enumerate(stages):
+        zmax, zarg = maxes[h]
+        assert zmax.shape == (nd, 2 * nbins // h)
+        v, r = blockmax_topk(zmax, 16)
+        np.testing.assert_array_equal(np.asarray(vals[:, si]),
+                                      np.asarray(v))
+        np.testing.assert_array_equal(np.asarray(rbins[:, si]),
+                                      np.asarray(r))
+        np.testing.assert_array_equal(
+            np.asarray(zidx[:, si]),
+            np.take_along_axis(np.asarray(zarg), np.asarray(r), axis=1))
+    for narrow in (_kernel_maxes(plane[0][:, :3], (1, 2, 4), nz),
+                   accel._harmonic_stage_maxes(plane[0][:, :3], (1, 2, 4),
+                                               nz)):
+        assert narrow[4][0].shape == narrow[4][1].shape == (0,)
+    assert set(accel.harmsum_plan(nz, 3, (1, 2, 4), "float32").stages
+               ) == {1, 2}
+
+
+def test_harmsum_kernel_ties_take_first_z():
+    """Equal sums at several z: argmax's first-index rule."""
+    nz, nr = 9, 600
+    base = _random_plane((1, nr), "float32", seed=2)
+    plane = jnp.tile(base, (nz, 1))           # every z row the same
+    plane = plane.at[3:6, 100:200].add(1.0)   # a three-way tie above
+    maxes = _kernel_maxes(plane, (1, 2, 4), nz)
+    for h in (1, 2, 4):
+        want_max, want_arg = _oracle_stage(plane, h, nz)
+        np.testing.assert_array_equal(np.asarray(maxes[h][0]), want_max)
+        np.testing.assert_array_equal(np.asarray(maxes[h][1]), want_arg)
+    arg1 = np.asarray(maxes[1][1])
+    assert (arg1[100:200] == 3).all() and (arg1[:100] == 0).all()
+
+
+def test_harmsum_kernel_nonfinite_stays_in_its_column_group():
+    """What the docstring of _harmonic_stage_maxes states of a
+    non-finite plane value: 0 x inf reaches only the 128 output
+    columns of its selection matmul, at harmonics >= 2; stage 1 (a
+    plain read) and every other group keep the oracle's bits."""
+    nz, nr = 9, 4096
+    plane = _random_plane((nz, nr), "float32", seed=3)
+    c = 1801
+    plane = plane.at[4, c].set(jnp.inf)
+    maxes = _kernel_maxes(plane, (1, 2, 4), nz)
+    want_max, want_arg = _oracle_stage(plane, 1, nz)
+    np.testing.assert_array_equal(np.asarray(maxes[1][0]), want_max)
+    np.testing.assert_array_equal(np.asarray(maxes[1][1]), want_arg)
+    for h in (2, 4):
+        want_max, want_arg = _oracle_stage(plane, h, nz)
+        touched = np.zeros(nr // h, bool)
+        if c < nr // h:
+            touched[c] = True                 # hh = 1 reads it as r = c
+        for hh in range(2, h + 1):
+            g = c // (128 * hh)
+            touched[g * 128:(g + 1) * 128] = True
+        got = np.asarray(maxes[h][0])
+        np.testing.assert_array_equal(got[~touched], want_max[~touched])
+        np.testing.assert_array_equal(
+            np.asarray(maxes[h][1])[~touched], want_arg[~touched])
+        assert np.isfinite(got[~touched]).all()
+
+
+def test_strided_form_is_lowered_only_off_the_tpu():
+    """No knob chooses the form: lax.platform_dependent does, per
+    lowering.  Here (CPU) the chunk program holds no Pallas call;
+    tests/test_chip_compile.py sees the kernel in the same program
+    lowered for a v5e."""
+    bank = accel.build_template_bank(8.0, seg=1 << 11)
+    text = accel._accel_block_topk.lower(
+        jnp.zeros((2, 3001), jnp.complex64), jnp.asarray(bank.bank_fft),
+        seg=bank.seg, step=bank.step, width=bank.width, nz=len(bank.zs),
+        max_numharm=8, topk=16).compile().as_text()
+    assert "harmsum_zmax" not in text
+
+
+# the three survey planes (Mock ds=1, Mock ds=2, WAPP ds=1), the
+# benchmark's nz and BASELINE config 3's
+@pytest.mark.parametrize("nz,numharm", [(51, 8), (201, 8), (201, 16)])
+@pytest.mark.parametrize("ncols", [3_932_162, 1_966_082, 4_194_306])
+def test_harmsum_plan_covers_every_column_once_within_vmem(ncols, nz,
+                                                           numharm):
+    """No chip: tile, padding and VMEM bytes the kernel derives stay
+    under the scoped-VMEM limit it requests (and that under a v5e's
+    128 MiB), every output column of every stage is written by
+    exactly one grid step, every source block starts inside the
+    plane, and the strided z reads stay inside the scratch."""
+    from tpulsar.kernels.fourier import harmonic_stages
+
+    stages = tuple(harmonic_stages(numharm))
+    p = accel.harmsum_plan(nz, ncols, stages, jnp.bfloat16)
+    assert p.stages == stages and p.tile % 128 == 0
+    assert p.nzb % 16 == 0 and 0 <= p.nzb - nz < 16
+    assert p.vmem_bytes <= p.vmem_limit - (4 << 20)
+    assert p.vmem_limit <= 100 << 20
+    T = p.tile
+    for si, h in enumerate(stages):
+        L = ncols // h
+        nt = p.ntiles[si]
+        # tiles [j*T, (j+1)*T) for j < nt: disjoint, and they cover
+        # [0, L) with the last one ragged, none wholly outside
+        assert (nt - 1) * T < L <= nt * T
+        assert nt <= p.ntiles[0]
+    for hh in range(1, numharm + 1):
+        si = p.stage_of(hh)
+        assert stages[si] >= hh and (si == 0 or stages[si - 1] < hh)
+        last = p.ntiles[si] - 1
+        assert hh * T * last < ncols          # the block starts inside
+        # every source column of a real output is inside the plane
+        assert hh * (ncols // stages[si] - 1) < ncols
+        center = (nz - 1) // 2
+        for r0 in range(0, nz, 8):
+            lo = p.margin + center + hh * (r0 - center)
+            lo_zi = -(-(center * (hh - 1)) // hh)
+            hi_zi = (nz - 1 + center * (hh - 1)) // hh
+            if r0 + 7 >= lo_zi and r0 <= hi_zi:   # a strided read
+                assert 0 <= lo and lo + 7 * hh < p.nzb + 2 * p.margin
+
+
+@pytest.mark.parametrize("dtype", ["float16", "int8", "float64"])
+def test_harmsum_plan_refuses_other_dtypes(dtype):
+    """A dtype the selection matmul is not exact for is refused
+    loudly, not routed around."""
+    with pytest.raises(ValueError, match="dtype"):
+        accel.harmsum_plan(51, 4096, (1, 2, 4, 8), np.dtype(dtype))
+
+
+def test_harmsum_plan_refuses_what_vmem_cannot_hold():
+    with pytest.raises(ValueError, match="VMEM"):
+        accel.harmsum_plan(4001, 1 << 20, (1, 2, 4, 8, 16, 32),
+                           jnp.float32)

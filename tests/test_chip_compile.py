@@ -118,9 +118,31 @@ def test_hi_accel_programs(one_chip, tpu_accel_branch, program):
     else:
         lowered = accel.accel_row_topk.lower(*args, **kw)
     compiled = lowered.compile()
-    # the plane really is bf16 on this branch
+    # the plane really is bf16 on this branch, and its harmonic sums
+    # are the Mosaic kernel (lax.platform_dependent took the TPU side)
     assert "bf16" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("nd,nz,ncols,numharm", [
+    (2, 51, 3_932_162, 8),       # Mock ds=1: the benchmark's chunk
+    (2, 51, 1_966_082, 8),       # Mock ds=2
+    (2, 51, 4_194_306, 8),       # WAPP ds=1
+    (1, 201, 3_932_162, 16),     # zmax 200 (BASELINE config 3)
+])
+def test_hi_accel_harmsum_kernel(one_chip, nd, nz, ncols, numharm):
+    """accel._harmsum_zmax at the survey's full plane widths: Mosaic
+    takes the blocks, the strided z reads and the scoped-VMEM limit
+    that harmsum_plan derives (interpret mode cannot say)."""
+    from tpulsar.kernels import accel
+    from tpulsar.kernels.fourier import harmonic_stages
+
+    stages = tuple(harmonic_stages(numharm))
+    compiled = accel._harmsum_zmax.lower(
+        _sds(one_chip, (nd, nz, ncols), jnp.bfloat16), stages=stages,
+        nz=nz, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_whitening_program(one_chip):

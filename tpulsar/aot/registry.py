@@ -173,7 +173,14 @@ PROGRAMS: tuple[Program, ...] = (
        ("nbin", "npart", "L", "j0")),
     # ---- kernels/accel.py
     _k("accel", "_correlate_segments", ("seg", "step", "width")),
-    _k("accel", "_harmonic_sum_plane", ("numharm", "nz")),
+    _k("accel", "_harmonic_sum_plane", ("numharm", "nz"),
+       doc="the strided harmonic sum of one plane: the test oracle of "
+           "_harmsum_zmax, run by no search"),
+    _k("accel", "_harmsum_zmax", ("stages", "nz", "interpret"),
+       doc="the tiled harmonic-sum kernel (Pallas): per-stage max and "
+           "argmax over z; traced inside the chunk and row programs, "
+           "whose gate shapes carry it (its tile derives from the "
+           "plane's shape: accel.harmsum_plan)"),
     _k("accel", "_accel_plane_topk",
        ("seg", "step", "width", "nz", "max_numharm", "topk")),
     _k("accel", "_correlate_block", ("seg", "step", "width", "nz")),

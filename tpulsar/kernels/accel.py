@@ -16,8 +16,14 @@ TPU realization: templates are generated host-side once per (zmax,
 segment) signature as an FFT-domain bank; the correlation runs as
 overlap-save — segment FFTs of the spectrum, a broadcast complex
 multiply against all templates at once, and a batched inverse FFT.
-Everything is statically shaped and jit-compiled; the DM axis rides
-the same sharding as dedispersion.
+The harmonic sums are one Pallas kernel (_harmsum_zmax): it tiles the
+output over r, reads each harmonic's contiguous source columns of the
+plane, takes every hh-th of them on the chip with a 0/1 selection
+matrix on the MXU, and returns only each stage's max and argmax over
+z.  The strided-gather form it is bit-identical to stays as the test
+oracle (_harmonic_sum_plane) and as what the same programs lower to
+off the TPU (_stage_maxes_strided).  Everything is statically shaped
+and jit-compiled; the DM axis rides the same sharding as dedispersion.
 """
 
 from __future__ import annotations
@@ -29,6 +35,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from tpulsar.kernels import scopes
 from tpulsar.obs import trace
@@ -179,7 +187,9 @@ def _zero_z_index(bank: TemplateBank) -> int:
 
 @partial(jax.jit, static_argnames=("numharm", "nz"))
 def _harmonic_sum_plane(plane: jnp.ndarray, numharm: int, nz: int) -> jnp.ndarray:
-    """Sum (h*r, h*z) over harmonics h=1..numharm.
+    """Sum (h*r, h*z) over harmonics h=1..numharm: the plain strided
+    form, kept as the TEST ORACLE of the tiled kernel below (no search
+    runs it: on a TPU its lane-strided gathers cost ~1.4 ns an element).
 
     plane: (nz, nr) powers.  z index mapping: zi -> center + h*(zi-center)
     clamped to the grid; r mapping via strided gather.
@@ -225,18 +235,14 @@ def _stage_z_rows(plane: jnp.ndarray, hh: int, nz: int) -> jnp.ndarray:
     return jnp.concatenate(parts, axis=0) if len(parts) > 1 else mid
 
 
-def _harmonic_stage_maxes(plane: jnp.ndarray, stages: tuple[int, ...],
-                          nz: int):
-    """Per-stage (zmax[L_h], zargmax[L_h]) of the harmonic-summed
-    plane, all stages in ONE incremental pass.
-
-    Stage 2h's sum re-uses stage h's accumulator truncated to its
-    column range, then adds terms hh = h+1 .. 2h — the same
-    left-to-right f32 addition order as summing hh = 1..2h from
-    scratch, so the results are bit-identical to calling
-    _harmonic_sum_plane per stage (asserted by tests).  Terms slice
-    their z rows statically (_stage_z_rows) instead of gathering, and
-    nothing larger than the plane itself is materialized."""
+def _stage_maxes_strided(plane: jnp.ndarray, stages: tuple[int, ...],
+                         nz: int):
+    """_harmonic_stage_maxes of one (nz, nr) plane as plain XLA
+    strided slices: what a program lowers to OFF the TPU (see
+    _harmonic_stage_maxes on why), never on one.  Same incremental
+    order as the kernel — stage 2h continues stage h's accumulator,
+    then adds hh = h+1 .. 2h — so the same bits.  Terms slice their z
+    rows statically (_stage_z_rows) instead of gathering."""
     nr = plane.shape[1]
     out = {}
     acc = None
@@ -251,6 +257,308 @@ def _harmonic_stage_maxes(plane: jnp.ndarray, stages: tuple[int, ...],
         out[h] = (acc.max(axis=0), acc.argmax(axis=0).astype(jnp.int32))
         prev = h
     return out
+
+
+# --- harmonic sums: decimate contiguous tiles on the chip -------------
+# One Pallas kernel computes every stage's (max over z, argmax over z)
+# of the harmonic-summed plane.  The output is tiled over r; for the
+# output tile [j*T, (j+1)*T) harmonic hh needs the CONTIGUOUS source
+# columns [hh*j*T, hh*(j+1)*T): block j, of width hh*T, of the same
+# plane (the plane is passed once per harmonic).  Every hh-th column
+# is taken while the block is in VMEM, by a 0/1 selection matrix on
+# the MXU: for each group of 128 output columns
+# x[:, g*hh*128:(g+1)*hh*128] @ S_hh, S_hh[c, k] = (c == hh*k), with
+# float32 accumulation.  One product by 1.0 and zeros per output: exact.
+# The z rows center + hh*(zi - center), edge-clamped, are then read
+# from the decimated tile by sublane-strided loads, added in float32
+# in the oracle's left-to-right order, and only each stage's
+# (max[T], argmax[T]) leaves the kernel: no (nz, L) float32
+# accumulator, no decimated copy and no lane-strided gather in HBM.
+
+_LANES = 128          # output columns per selection matmul
+_ZROW_PAD = 16        # z rows per block: a whole packed bf16 tile
+_HARMSUM_TILES = (1024, 512, 256, 128)
+#: block bytes the tile is chosen for / the most the kernel may ask
+#: of a v5e's 128 MiB of VMEM; beyond it the shape is refused
+_HARMSUM_VMEM_TARGET = 24 << 20
+_HARMSUM_VMEM_MAX = 96 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class HarmsumPlan:
+    """Tile, padding and VMEM bytes of the harmonic-sum kernel, derived
+    from what it can see of its input: (nz, ncols, stages, dtype)."""
+    nz: int
+    ncols: int
+    stages: tuple[int, ...]   # those with a column to give
+    nzb: int                  # z rows per block (nz padded to 16)
+    tile: int                 # output columns per grid step, T
+    ntiles: tuple[int, ...]   # per stage: grid steps that write it
+    margin: int               # never-written rows around the decimated
+                              # tile that a strided load may touch
+    vmem_bytes: int           # blocks x2 + scratch + live values
+    vmem_limit: int           # the scoped-VMEM limit it requests
+
+    @property
+    def numharm(self) -> int:
+        return self.stages[-1]
+
+    def stage_of(self, hh: int) -> int:
+        """Index of the stage whose sum harmonic hh first enters."""
+        return next(i for i, h in enumerate(self.stages) if h >= hh)
+
+
+def _sel_row(hh: int) -> int:
+    """First row of S_hh in the scratch that stacks S_2 .. S_H, each
+    (hh * 128, 128); _sel_row(H + 1) is the scratch's height."""
+    return _LANES * (hh * (hh - 1) // 2 - 1)
+
+
+def _harmsum_vmem_bytes(nzb: int, tile: int, numharm: int,
+                        nstages: int, margin: int, itemsize: int) -> int:
+    tri = numharm * (numharm + 1) // 2
+    blocks = 2 * nzb * tile * itemsize * tri       # inputs, 2 buffers
+    outs = 2 * 2 * nstages * 8 * tile * 4          # (1, T) pads to 8 rows
+    sel = _sel_row(numharm + 1) * _LANES * itemsize
+    scratch = (nzb + (nzb + 2 * margin)) * tile * 4      # acc + decimated
+    # live values of one harmonic: the masked block, its stacked
+    # copy, the matmul's float32 result
+    live = nzb * tile * (2 * numharm * itemsize + 4)
+    return blocks + outs + sel + scratch + live
+
+
+def harmsum_plan(nz: int, ncols: int, stages: tuple[int, ...],
+                 dtype) -> HarmsumPlan:
+    """The kernel's tiling for one plane shape.  Stages too high for
+    the plane to have a column (ncols // h == 0) are dropped.  A dtype
+    or size the kernel cannot take is refused here, loudly."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        raise ValueError(
+            f"harmonic-sum kernel: plane dtype {dtype} is not bfloat16 "
+            "or float32 (the selection matmul is exact only for those)")
+    stages = tuple(h for h in stages if ncols // h > 0)
+    if not stages or stages[0] != 1 or any(
+            b <= a for a, b in zip(stages, stages[1:])):
+        raise ValueError(
+            f"harmonic-sum kernel: stages {stages} must start at 1 and "
+            f"increase, over a plane with a column (ncols={ncols})")
+    numharm = stages[-1]
+    nzb = -(-nz // _ZROW_PAD) * _ZROW_PAD
+    margin = 8 * numharm
+    widest = -(-ncols // _LANES) * _LANES
+    for tile in _HARMSUM_TILES:
+        need = _harmsum_vmem_bytes(nzb, tile, numharm, len(stages),
+                                   margin, dtype.itemsize)
+        if tile <= widest and need <= _HARMSUM_VMEM_TARGET:
+            break
+    if need > _HARMSUM_VMEM_MAX:
+        raise ValueError(
+            f"harmonic-sum kernel: nz={nz}, {numharm} harmonics of "
+            f"{dtype} need {need} B of VMEM at the smallest tile, over "
+            f"the {_HARMSUM_VMEM_MAX} B the kernel may ask for")
+    ntiles = tuple(-(-(ncols // h) // tile) for h in stages)
+    return HarmsumPlan(
+        nz=nz, ncols=ncols, stages=stages, nzb=nzb, tile=tile,
+        ntiles=ntiles, margin=margin, vmem_bytes=need,
+        vmem_limit=max(32 << 20, need + (8 << 20)))
+
+
+def _harmsum_kernel(p: HarmsumPlan, dtype):
+    """The kernel body for one plan: refs are the numharm source
+    blocks (hh = 1..numharm), then (max, argmax) per stage, then the
+    selection matrices, the accumulator and the decimated tile."""
+    nz, nzb, T, M = p.nz, p.nzb, p.tile, p.margin
+    H, G, ns = p.numharm, p.tile // _LANES, len(p.stages)
+    center = (nz - 1) // 2
+    # an f32 plane: six bf16 passes carry all 24 bits of x * 1.0
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.dtype(dtype) == jnp.float32 else None)
+
+    def kernel(*refs):
+        x_refs = refs[:H]
+        out_refs = refs[H:H + 2 * ns]
+        sel_ref, acc_ref, dec_ref = refs[H + 2 * ns:]
+        j = pl.program_id(1)
+
+        if H > 1:
+            @pl.when(j == 0)
+            def _selection_matrices():
+                for hh in range(2, H + 1):
+                    shape = (hh * _LANES, _LANES)
+                    c = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                    sel_ref[_sel_row(hh):_sel_row(hh + 1), :] = (
+                        c == hh * k).astype(jnp.float32).astype(dtype)
+
+        def add_harmonic(hh):
+            x_ref = x_refs[hh - 1]
+            W = hh * _LANES
+            # columns past the plane's end hold whatever the DMA left:
+            # 0 x NaN would reach real columns through the matmul
+            limit = p.ncols - j * (hh * T)
+
+            def masked():
+                x = x_ref[...]
+                col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+                return jnp.where(col < limit, x, jnp.zeros_like(x))
+
+            x = jax.lax.cond(limit < hh * T, masked, lambda: x_ref[...])
+            # the G column groups stacked on the rows: one stationary
+            # S_hh serves them all (nzb is whole tiles: no data moves)
+            lhs = x if G == 1 else jnp.concatenate(
+                [x[:, g * W:(g + 1) * W] for g in range(G)], axis=0)
+            dec = jnp.dot(lhs, sel_ref[_sel_row(hh):_sel_row(hh + 1), :],
+                          preferred_element_type=jnp.float32,
+                          precision=precision)
+            dec_ref[:, M:M + nzb, :] = dec.reshape(G, nzb, _LANES)
+            # z rows center + hh*(zi - center), clamped to the grid:
+            # zi in [lo_zi, hi_zi] is a strided read, the rest the
+            # two edge rows (the oracle's jnp.clip)
+            lo_zi = -(-(center * (hh - 1)) // hh)
+            hi_zi = (nz - 1 + center * (hh - 1)) // hh
+            eight = (G, 8, _LANES)
+            row8 = jax.lax.broadcasted_iota(jnp.int32, eight, 1)
+            lo_row = jnp.broadcast_to(dec_ref[:, M:M + 1, :], eight)
+            hi_row = jnp.broadcast_to(dec_ref[:, M + nz - 1:M + nz, :],
+                                      eight)
+            for r0 in range(0, nz, 8):
+                if r0 + 7 < lo_zi:
+                    term = lo_row
+                elif r0 > hi_zi:
+                    term = hi_row
+                else:
+                    # may start in the margin: those rows are
+                    # replaced below, never added
+                    term = dec_ref[:, pl.ds(
+                        M + center + hh * (r0 - center), 8, stride=hh), :]
+                    if r0 < lo_zi:
+                        term = jnp.where(row8 < lo_zi - r0, lo_row, term)
+                    if r0 + 7 > hi_zi:
+                        term = jnp.where(row8 > hi_zi - r0, hi_row, term)
+                acc_ref[:, r0:r0 + 8, :] = acc_ref[:, r0:r0 + 8, :] + term
+
+        prev = 0
+        for si, h in enumerate(p.stages):
+            def stage(si=si, h=h, prev=prev):
+                for hh in range(prev + 1, h + 1):
+                    if hh == 1:
+                        x = x_refs[0][...].astype(jnp.float32)
+                        for g in range(G):
+                            acc_ref[g] = x[:, g * _LANES:(g + 1) * _LANES]
+                    else:
+                        add_harmonic(hh)
+                acc = acc_ref[...]
+                row = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+                acc = jnp.where(row < nz, acc, -jnp.inf)
+                top = jnp.max(acc, axis=1, keepdims=True)
+                # the first z index that holds the max (argmax's rule)
+                arg = jnp.min(jnp.where(acc == top, row, nzb), axis=1,
+                              keepdims=True)
+                for g in range(G):
+                    cols = slice(g * _LANES, (g + 1) * _LANES)
+                    out_refs[2 * si][:, cols] = top[g]
+                    out_refs[2 * si + 1][:, cols] = arg[g]
+            # a tile past a stage's range adds nothing to it; stage
+            # 2h's range lies inside stage h's, so acc carries over
+            pl.when(j < p.ntiles[si])(stage)
+            prev = h
+
+    return kernel
+
+
+@partial(jax.jit, static_argnames=("stages", "nz", "interpret"))
+def _harmsum_zmax(planes: jnp.ndarray, stages: tuple[int, ...], nz: int,
+                  interpret: bool):
+    """planes (nd, nz, ncols) -> per stage (max over z, argmax over
+    z), each (nd, ncols // h): the Pallas call itself (a stage the
+    plane has no column for is answered empty, outside the call)."""
+    nd, _, ncols = planes.shape
+    p = harmsum_plan(nz, ncols, stages, planes.dtype)
+    kernel = _harmsum_kernel(p, planes.dtype)
+    T = p.tile
+
+    def clamped(last):
+        # past a stage's last tile the block index stays: no DMA, and
+        # the finished output block is not touched again
+        return lambda d, j: (d, 0, jnp.minimum(j, last))
+
+    in_specs = [pl.BlockSpec((None, p.nzb, hh * T),
+                             clamped(p.ntiles[p.stage_of(hh)] - 1))
+                for hh in range(1, p.numharm + 1)]
+    out_specs, out_shape = [], []
+    for si, h in enumerate(p.stages):
+        for dt in (jnp.float32, jnp.int32):
+            out_specs.append(pl.BlockSpec((None, 1, T),
+                                          clamped(p.ntiles[si] - 1)))
+            out_shape.append(
+                jax.ShapeDtypeStruct((nd, 1, ncols // h), dt))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(nd, p.ntiles[0]),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((max(_sel_row(p.numharm + 1), 8), _LANES),
+                       planes.dtype),
+            pltpu.VMEM((T // _LANES, p.nzb, _LANES), jnp.float32),
+            pltpu.VMEM((T // _LANES, p.nzb + 2 * p.margin, _LANES),
+                       jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=p.vmem_limit),
+        interpret=interpret, name="harmsum_zmax",
+    )(*([planes] * p.numharm))
+    out = {h: (outs[2 * si][:, 0], outs[2 * si + 1][:, 0])
+           for si, h in enumerate(p.stages)}
+    for h in stages[len(p.stages):]:
+        out[h] = (jnp.zeros((nd, 0), jnp.float32),
+                  jnp.zeros((nd, 0), jnp.int32))
+    return out
+
+
+def _harmonic_stage_maxes(plane: jnp.ndarray, stages: tuple[int, ...],
+                          nz: int):
+    """Per-stage (zmax[L_h], zargmax[L_h]), L_h = nr // h, of the
+    harmonic-summed plane — (nz, nr), or (nd, nz, nr) for a DM block —
+    all stages in one pass of the tiled kernel above.
+
+    Stage 2h's sum continues stage h's accumulator over its own
+    column range, then adds terms hh = h+1 .. 2h: the same
+    left-to-right float32 addition order as summing hh = 1..2h from
+    scratch, so the results are bit-identical to _harmonic_sum_plane
+    per stage (the test oracle), argmax's first-index tie rule and
+    z clamping included, for bf16 and float32 planes alike.
+
+    The plane must be finite.  A selection matmul multiplies every
+    source column of a 128-column output group by 0 or 1, so an inf or
+    NaN at source column c of harmonic hh >= 2 turns the z row's sum
+    NaN in the whole output group c // (128*hh), where the strided
+    form kept it in column c // hh; what the max over z reports for
+    that group's columns is then not defined (hh = 1 is a plain read:
+    stage 1, and every other group, keep the oracle's bits — held by
+    a test).  The plane is |ifft|^2 of a whitened, finite spectrum,
+    itself finite (asserted by tests).
+
+    Off the TPU the program lowers the strided form instead
+    (_stage_maxes_strided; tests hold the kernel, run in Pallas's
+    interpreter, to the same bits).  The interpreter is no product
+    path: it copies every operand at every grid step, which made the
+    row program 130x slower at 200,001 bins and would make the host
+    rescue of ONE Mock row take an hour (PERF.md, PR 26)."""
+    planes = plane if plane.ndim == 3 else plane[None]
+    stages = tuple(stages)
+    # The kernel wherever the program is lowered for a TPU — chosen
+    # per lowering, not by jax.default_backend(): the host rescue
+    # (resilience/rescue.py) places the row program on the CPU device
+    # of a TPU process, and no setting can put the strided form on a
+    # chip.
+    out = jax.lax.platform_dependent(
+        planes,
+        tpu=lambda x: _harmsum_zmax(x, stages, nz, interpret=False),
+        default=jax.vmap(lambda p: _stage_maxes_strided(p, stages, nz)))
+    if plane.ndim == 3:
+        return out
+    return {h: (m[0], a[0]) for h, (m, a) in out.items()}
 
 
 @partial(jax.jit, static_argnames=("seg", "step", "width", "nz",
@@ -414,7 +722,13 @@ def plane_dm_chunk(nbins: int, nz: int, max_chunk: int = 32) -> int:
     in f32 even for a bf16 plane), and the complex64 overlap-save
     intermediates (segs + their FFT at ~16 B/bin plus the
     (z_chunk(), seg) product/ifft at ~32 B/bin per z-row in the
-    chunk, with batch padding slop)."""
+    chunk, with batch padding slop).
+
+    Since the tiled harmonic-sum kernel the float32 stage
+    intermediates are no longer written (only (max, argmax) per stage
+    leave it), so the `+ 4` below overcounts by nz * 2*nbins * 4 bytes
+    a row (802 MB at the Mock ds=1 shape); the value is kept until
+    the rows per program are re-budgeted on the chip (ROADMAP S2)."""
     # x2 throughout: the numbetween=2 plane is 2*nbins wide and the
     # interpolated iffts are 2*seg long.  The ifft-intermediate term
     # scales with z_chunk(): at the TPU's zc=4 it is the original
@@ -550,8 +864,7 @@ def _accel_block_topk(specs, bank_fft, seg, step, width, nz,
         plane = _correlate_block(specs, bank_fft, seg, step, width, nz)
     stages = tuple(harmonic_stages(max_numharm))
     with scopes.scope("hiaccel/harmsum"):
-        maxes = jax.vmap(
-            lambda p: _harmonic_stage_maxes(p, stages, nz))(plane)
+        maxes = _harmonic_stage_maxes(plane, stages, nz)
     vals_all, rbin_all, zi_all = [], [], []
     with scopes.scope("hiaccel/topk"):
         for h in stages:
