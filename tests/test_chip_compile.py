@@ -125,6 +125,35 @@ def test_hi_accel_programs(one_chip, tpu_accel_branch, program):
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 
+@pytest.mark.parametrize("zmax,numharm,nbins", [
+    (50.0, 8, 1_966_081),        # Mock ds=1: mock_ds1_hiaccel's chunk
+    (200.0, 16, 1_966_081),      # BASELINE config 3: z200_ds1_hiaccel's
+])
+def test_hi_accel_chunk_program_at_full_width(one_chip, tpu_accel_branch,
+                                              zmax, numharm, nbins):
+    """The WHOLE chunk program at the survey's width, with the rows
+    plane_dm_chunk gives it: it compiles for the chip, the harmonic
+    sums are the Mosaic kernel, and the temporaries the compiler
+    counts are what plane_dm_chunk budgets the rows by
+    (plane_row_bytes), within 20%."""
+    accel = tpu_accel_branch
+    bank = accel.build_template_bank(zmax)
+    nz = len(bank.zs)
+    rows = accel.plane_dm_chunk(nbins, nz)
+    assert rows == {51: 2, 201: 1}[nz]
+    counted = rows * accel.plane_row_bytes(nbins, nz, accel.z_chunk())
+    assert counted <= accel.PLANE_HBM_BUDGET      # fits by its own count
+    compiled = accel.accel_chunk_topk.lower(
+        _sds(one_chip, (48, nbins), jnp.complex64),
+        _sds(one_chip, bank.bank_fft.shape, jnp.complex64),
+        _sds(one_chip, (), jnp.int32), nrows=rows, seg=bank.seg,
+        step=bank.step, width=bank.width, nz=nz, max_numharm=numharm,
+        topk=32).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp - counted) <= 0.2 * counted, (temp, counted)
+
+
 @pytest.mark.parametrize("nd,nz,ncols,numharm", [
     (2, 51, 3_932_162, 8),       # Mock ds=1: the benchmark's chunk
     (2, 51, 1_966_082, 8),       # Mock ds=2

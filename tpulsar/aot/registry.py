@@ -600,36 +600,45 @@ def _config_groups(ctx: GateContext,
                           estimator=fr.whiten_estimator())),
         ]
         from tpulsar.kernels import accel_batch as abp
+        from tpulsar.plan import ddplan
+        from tpulsar.search import executor
 
-        bank = ak.build_template_bank(200.0)
+        # the deep search as search_block dispatches it (the
+        # benchmark's palfa_mock_z200 configuration): a ds=1 pass of 76
+        # trials splits into the chunks pass_chunk_size gives, each
+        # chunk's spectra block is padded to the ladder, and the
+        # planner's own arithmetic (abp.batch_rows) sets the rows of a
+        # chunk program — the gate compiles the signatures the run
+        # dispatches, topk included
+        deep = executor.SearchParams(hi_accel_zmax=200,
+                                     hi_accel_numharm=16)
+        bank = ak.build_template_bank(float(deep.hi_accel_zmax))
         nz = len(bank.zs)
-        # the batch planner's own arithmetic: quantized batch size,
-        # quantized padded block rows — the gate compiles the exact
-        # signatures accel_search_batch dispatches
-        dmc = abp.batch_rows(ndms, nbins, nz)
-        q_rows = abp.quantize_rows_up(ndms)
+        nfft = ddplan.choose_n(nsamp)
+        nbins = nfft // 2 + 1
+        rows = executor.pass_chunk_size(76, nfft, deep)
+        dmc = abp.batch_rows(rows, nbins, nz)
+        q_rows = abp.quantize_rows_up(rows)
         spec_sh = _sds((q_rows, nbins), jnp.complex64)
         bank_sh = _sds(bank.bank_fft.shape, jnp.complex64)
         i32 = _sds((), jnp.int32)
+        accel_kw = dict(seg=bank.seg, step=bank.step, width=bank.width,
+                        nz=nz, max_numharm=deep.hi_accel_numharm,
+                        topk=deep.topk_per_stage)
         # accel_search_batch's chunk/row programs: full (quantized)
         # spectra argument + dynamic slice (the argument buffer is
         # part of the gated footprint)
         accel_insts = [
             Instance("accel.accel_chunk_topk", "accel_chunk_z200",
                      (spec_sh, bank_sh, i32),
-                     dict(nrows=dmc, seg=bank.seg, step=bank.step,
-                          width=bank.width, nz=nz, max_numharm=16,
-                          topk=64)),
+                     dict(nrows=dmc, **accel_kw)),
             Instance("accel.accel_row_topk", "accel_row_z200",
-                     (spec_sh, bank_sh, i32),
-                     dict(seg=bank.seg, step=bank.step,
-                          width=bank.width, nz=nz, max_numharm=16,
-                          topk=64)),
+                     (spec_sh, bank_sh, i32), dict(accel_kw)),
         ]
-        if q_rows != ndms:
+        if q_rows != rows:
             accel_insts.append(Instance(
                 "accel._pad_block", "accel_pad_z200",
-                (_sds((ndms, nbins), jnp.complex64),),
+                (_sds((rows, nbins), jnp.complex64),),
                 dict(rows=q_rows)))
         accel_insts += _accel_native_instances(
             dmc, nbins, bank, nz, label="z200")

@@ -61,7 +61,9 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "stalled call is classified as a refusal (retry -> rescue) "
        "instead of hanging the beam"),
     _k("TPULSAR_ACCEL_HBM_GB", "float", "4",
-       "assumed device HBM for correlation-plane chunk sizing"),
+       "GiB one hi-accel chunk program may hold live (its planes "
+       "and overlap-save intermediates, accel.plane_row_bytes): "
+       "sizes its DM rows; on a TPU a row over it is refused"),
     _k("TPULSAR_ACCEL_NATIVE", "enum(0)", "on",
        "0 disables the native host accel consumer (CPU backend), "
        "keeping the pure XLA dispatch path"),

@@ -676,8 +676,7 @@ def _batch_breaker_threshold() -> int:
 # per backend: 16 on CPU (25% faster at survey shapes — fewer, larger
 # FFT batches amortize dispatch and padding overhead; host RAM
 # absorbs the 4x bigger intermediate), 4 on the TPU (the proven
-# on-chip shape — the bigger intermediate would also have to be
-# re-accounted in plane_dm_chunk's HBM budget before raising it).
+# on-chip shape — plane_row_bytes counts the intermediate by it).
 # TPULSAR_ACCEL_Z_CHUNK pins either backend for A/B runs.
 _Z_CHUNK_RESOLVED = None
 
@@ -710,33 +709,57 @@ def z_chunk() -> int:
 FFT_BATCH_PAD = 64
 
 
+# The compiler's count of a chunk program's temporaries may pass
+# plane_row_bytes' by this share (tests/test_chip_compile.py holds the
+# two that close), so a program is given rows only while their count,
+# raised by it, stays inside PLANE_HBM_BUDGET.
+PLANE_COUNT_SLACK = 0.2
+
+
+def plane_row_bytes(nbins: int, nz: int, zc: int) -> int:
+    """Bytes one DM row holds live in the batched correlate program
+    (_correlate_block) with pieces of `zc` z rows, at its larger
+    moment: while the last piece is made — the plane_dtype() plane
+    (nz, 2*nbins) in its pieces, beside the complex64 overlap-save
+    intermediates (the interleaved segments and their FFT; per z row
+    of a piece the product, its inverse FFT and the powers cut from
+    it: ~16 + 105 * zc B a bin, batch padding included) — or while the
+    pieces are assembled: the plane twice (pieces, and the transposed,
+    concatenated, padded plane the harmonic sums read), beside what
+    the harmonic-sum kernel lets leave, (max, argmax) per stage.  No
+    float32 stage intermediates: the tiled kernel writes none.
+
+    Read from the compiler (memory_analysis() of accel_chunk_topk
+    compiled for a described v5e, bf16 plane, pieces of 4, 1,966,081
+    bins; PERF.md, PR 27): 1.258 GB a row at nz 51 (1.258 here), 3.292
+    at nz 201 (3.287 here).  At pieces of 8 its schedule holds both
+    moments at once at nz 201 (5.118 GB): re-read before z_chunk() is
+    raised on a TPU (ROADMAP S2)."""
+    plane = nz * 2 * nbins * plane_itemsize()
+    return max(plane + nbins * (16 + 105 * zc), 2 * plane + nbins * 64)
+
+
 def plane_dm_chunk(nbins: int, nz: int, max_chunk: int = 32) -> int:
-    """DM rows to search per dispatch, sized so the (chunk, nz, nbins)
-    correlation planes + per-stage intermediates fit the HBM budget
-    (round-1 used a fixed chunk of 4 -> ~318 dispatches per beam).
+    """DM rows to search per dispatch: as many as fit PLANE_HBM_BUDGET
+    by plane_row_bytes' count (with its slack), at most `max_chunk`
+    (round-1 used a fixed chunk of 4 -> ~318 dispatches per beam).  At
+    the budget's default, 4 GiB, a TPU gets 2 rows at the survey's
+    nz = 51 (Mock and WAPP ds=1 widths) and 1 at nz = 201.
 
-    Live bytes per DM in the batched path: the plane_dtype() plane
-    (once in the per-z-chunk pieces and once more while
-    jnp.concatenate builds the full plane), the summed/zmax stage
-    intermediates (ALWAYS float32 — _harmonic_sum_plane accumulates
-    in f32 even for a bf16 plane), and the complex64 overlap-save
-    intermediates (segs + their FFT at ~16 B/bin plus the
-    (z_chunk(), seg) product/ifft at ~32 B/bin per z-row in the
-    chunk, with batch padding slop).
-
-    Since the tiled harmonic-sum kernel the float32 stage
-    intermediates are no longer written (only (max, argmax) per stage
-    leave it), so the `+ 4` below overcounts by nz * 2*nbins * 4 bytes
-    a row (802 MB at the Mock ds=1 shape); the value is kept until
-    the rows per program are re-budgeted on the chip (ROADMAP S2)."""
-    # x2 throughout: the numbetween=2 plane is 2*nbins wide and the
-    # interpolated iffts are 2*seg long.  The ifft-intermediate term
-    # scales with z_chunk(): at the TPU's zc=4 it is the original
-    # ~128 B/bin (+64 fixed), a bigger CPU zc raises it in step.
-    per_dm = (nz * nbins * 2 * (2 * plane_itemsize() + 4)
-              + nbins * (64 + 32 * z_chunk()))
-    chunk = max(1, min(max_chunk, PLANE_HBM_BUDGET // max(per_dm, 1)))
-    return chunk
+    Where not even one row fits, a TPU program is refused here,
+    loudly: the budget is device memory there, and a row reckoned too
+    large is not sent anyway.  Other backends hold the row in host
+    RAM and get 1."""
+    per_dm = plane_row_bytes(nbins, nz, z_chunk()) * (1 + PLANE_COUNT_SLACK)
+    chunk = min(max_chunk, int(PLANE_HBM_BUDGET // per_dm))
+    if chunk < 1 and jax.default_backend() == "tpu":
+        raise ValueError(
+            f"hi-accel plane: one DM row at nz={nz}, nbins={nbins} "
+            f"holds {plane_row_bytes(nbins, nz, z_chunk())} bytes "
+            f"({jnp.dtype(plane_dtype()).name} plane, pieces of "
+            f"{z_chunk()} z rows), over the budget of "
+            f"{PLANE_HBM_BUDGET} bytes (TPULSAR_ACCEL_HBM_GB)")
+    return max(1, chunk)
 
 
 def _pad_rows(x2d: jnp.ndarray, multiple: int) -> jnp.ndarray:
@@ -1271,7 +1294,8 @@ def accel_search_batch(spectra: jnp.ndarray, bank: TemplateBank,
         # the enqueue loop as one span (a window that fills inside it
         # nests its accel-sync here), the closing drain beside it
         with trace.span("accel-dispatch", chunks=plan.nbatches,
-                        rows=ndms):
+                        rows=ndms, nz=nz,
+                        zpieces=-(-nz // z_chunk())):
             for s0 in plan.starts:
                 if bstate["pinned"]:
                     fallback.update(plan.rows_of(s0))

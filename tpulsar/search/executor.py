@@ -1144,10 +1144,17 @@ def _search_block_inner(data, freqs, dt, plan, params, zaplist, baryv,
                         dm_chunk = dms[lo: lo + chunk_sz]
                         # per-chunk child span: the stage scopes below
                         # nest under it, so the trace file shows the
-                        # pass/chunk structure, not just stage totals
+                        # pass/chunk structure, not just stage totals.
+                        # hi_rows: DM rows per hi-accel chunk program as
+                        # accel_search_batch dispatches them (the
+                        # planner's own arithmetic), 0 with hi-accel off
+                        hi_rows = (_hi_rows(len(dm_chunk),
+                                            int(subb.shape[1]), params)
+                                   if trace_mod.enabled() else 0)
                         with trace_mod.span("dm_chunk",
                                             pass_idx=pass_idx, lo=int(lo),
                                             n=int(len(dm_chunk)),
+                                            hi_rows=hi_rows,
                                             family=("tree" if tree_parts
                                                     is not None
                                                     else "direct")):
@@ -1763,6 +1770,19 @@ def _dedisperse_single(data, freqs, nsub, dm, dt):
     subb = dd.form_subbands(data, jnp.asarray(chan_shifts), nsub, 1)
     return np.asarray(dd.dedisperse_subbands(
         subb, jnp.asarray(sub_shifts)))[0]
+
+
+def _hi_rows(ndms: int, T: int, params: SearchParams) -> int:
+    """DM rows per hi-accel chunk program for a chunk of `ndms` series
+    of length T, by the batch planner's own arithmetic
+    (accel_batch.batch_rows); 0 with hi-accel off."""
+    if not (params.run_hi_accel and params.hi_accel_zmax > 0):
+        return 0
+    from tpulsar.kernels import accel_batch
+
+    return accel_batch.batch_rows(
+        ndms, ddplan.choose_n(T) // 2 + 1,
+        len(accel_k.z_grid(params.hi_accel_zmax)))
 
 
 def _hi_accel_pass(wspec, dm_chunk, T_s, params: SearchParams
