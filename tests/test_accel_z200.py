@@ -241,3 +241,40 @@ def test_dm_chunk_says_no_hi_rows_with_hi_accel_off(drifting_beam):
     chunks = [e for e in events if e["name"] == "dm_chunk"]
     assert chunks and all(e["args"]["hi_rows"] == 0 for e in chunks)
     assert not [e for e in events if e["name"] == "accel-dispatch"]
+
+
+def test_dm_chunk_says_stage2_calls_and_rows(drifting_beam, monkeypatch):
+    """dm_chunk carries dd_calls x dd_rows, the stage-2 program calls
+    for the chunk and the rows of a call as dispatched: no padded row
+    (docs/operations.md); both 0 where the XLA scan is the path."""
+    from tpulsar.kernels import pallas_dd
+    from tpulsar.obs import trace
+    from tpulsar.search import executor
+
+    beams, freqs, dt, plan, _T_s = drifting_beam
+    params = executor.SearchParams(
+        nsub=16, run_hi_accel=False, topk_per_stage=16,
+        max_cands_to_fold=0, make_plots=False)
+
+    def chunks():
+        trace.reset()
+        trace.start()
+        try:
+            executor.search_block(beams[100.0][1], freqs, dt, plan, params)
+            return [e["args"] for e in trace.events()
+                    if e["name"] == "dm_chunk"]
+        finally:
+            trace.reset()
+
+    assert not pallas_dd.use_pallas()                   # CPU CI
+    assert {(a["dd_calls"], a["dd_rows"]) for a in chunks()} == {(0, 0)}
+    monkeypatch.setenv("TPULSAR_PALLAS", "1")           # interpreted
+    got = chunks()
+    assert got
+    for a in got:
+        assert a["dd_calls"] == -(-a["n"] // pallas_dd.STAGE2_MAX_ROWS)
+        assert a["dd_rows"] == -(-a["n"] // a["dd_calls"])
+        assert 0 <= a["dd_calls"] * a["dd_rows"] - a["n"] < a["dd_calls"]
+    # the wrapper writes them, so a chunk the kernel did not run says 0
+    monkeypatch.setattr(pallas_dd, "signature_enabled", lambda sig: False)
+    assert {(a["dd_calls"], a["dd_rows"]) for a in chunks()} == {(0, 0)}

@@ -308,9 +308,24 @@ def test_gate_compiles_the_stage_family_the_runtime_dispatches(
     assert sb.kwargs["block_t"] == pallas_dd.stage1_block_t(
         960, 96, 256, 1) == 1024
     assert sb.args[0].dtype == "bfloat16"        # widened uint8
-    dd2 = tpu["pallas_dd._dedisperse_chunk"]
-    assert dd2.args[1].shape == (32, 96)
-    assert dd2.kwargs["variant"] == "roll"
+    # stage 2 in the wrapper's own split: the executor's chunk (76
+    # trials run as 38 + 38) as 19-row programs and a fold's series as
+    # one row, never a call padded up to 32
+    dd2 = {i.args[2].shape[0]: i
+           for _h, g in registry.gate_groups(ctx, fast=True)
+           for i in g if i.program == "pallas_dd._dedisperse_chunk"}
+    assert sorted(dd2) == [1, 19]
+    for rows, n in ((19, 38), (1, 1)):
+        kw = dd2[rows].kwargs
+        seg = dd2[rows].args[0].shape[2]
+        plan = pallas_dd.stage2_plan(96, kw["window"] - seg - 128, n,
+                                     ctx.nsamp)
+        assert (plan.rows, plan.seg, plan.window) == (rows, seg,
+                                                      kw["window"])
+        assert dd2[rows].args[0].shape == (96, ctx.nsamp // seg, seg)
+        assert kw["vmem_bytes"] == plan.vmem_bytes
+        assert kw["interpret"] is False
+    assert tpu["pallas_dd._segment_layout"].kwargs["seg"] == plan.seg
 
 
 def test_fingerprint_is_stable_and_shape_sensitive():

@@ -50,20 +50,37 @@ def _sds(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-@pytest.mark.parametrize("ndms,overhang", [(32, 1024), (38, 2048)])
-def test_stage2_dedispersion_kernel(one_chip, ndms, overhang):
-    """pallas_dd._dedisperse_chunk (roll variant) at a ds=1 pass."""
+@pytest.mark.parametrize("nsub,T,rows,overhang", [
+    (NSUB, NSAMP, 19, 256),           # Mock ds=1: a 38-row chunk's call
+    (NSUB, NSAMP, 32, 256),
+    (NSUB, NSAMP, 19, 2048),
+    (NSUB, NSAMP, 32, 2048),
+    (64, 4_194_304, 19, 256),         # WAPP ds=1
+    (NSUB, 393_216, 26, 2048),        # Mock ds=10, its real overhang
+    (NSUB, NSAMP, 1, 256),            # one row at full resolution
+    (NSUB, NSAMP, 1, 8192),           # ... at the AOT gate's deepest
+    (NSUB, NSAMP, 1, 16384),          # overhangs: the last two take
+    (NSUB, NSAMP, 1, 32768),          # the subbands in groups
+])
+def test_stage2_dedispersion_kernel(one_chip, nsub, T, rows, overhang):
+    """pallas_dd._dedisperse_chunk at the geometry pallas_dd.stage2_plan
+    derives for the survey's shapes: Mosaic takes it, and the scoped
+    VMEM it is given is the plan's own request."""
     from tpulsar.kernels import pallas_dd
 
-    block_t = 4096
-    n_blocks = -(-NSAMP // block_t)
+    plan = pallas_dd.stage2_plan(nsub, overhang, rows, T)
+    assert (plan.calls, plan.rows) == (1, rows)
     compiled = pallas_dd._dedisperse_chunk.lower(
-        _sds(one_chip, (NSUB, n_blocks * block_t + overhang),
-             jnp.float32),
-        _sds(one_chip, (ndms, NSUB), jnp.int32),
-        block_t=block_t, window=block_t + overhang, interpret=False,
-        variant="roll").compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        _sds(one_chip, (nsub, plan.n_seg, plan.seg), jnp.float32),
+        _sds(one_chip, (nsub, 8, 128), jnp.float32),
+        _sds(one_chip, (rows, nsub), jnp.int32),
+        interpret=False, **plan.kernel_args()).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert plan.vmem_bytes <= pallas_dd.STAGE2_VMEM_BUDGET
+    assert (plan.group < nsub) == (overhang >= 16384)
+    # the custom call's scoped-memory request, as XLA prints it
+    assert f'"size":"{plan.vmem_bytes}"' in text
 
 
 @pytest.mark.parametrize("nchan,nsub,overhang", [

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpulsar.io import synth
 from tpulsar.kernels import dedisperse as dd
@@ -242,9 +243,95 @@ def test_tree_stage2_edge_clamp_and_fallback():
         jnp.asarray(subb), wild, m=4) is None
 
 
+def _sequential_sum(subb, shifts):
+    """out[d, t] = sum_s subb[s, min(t + shift[d, s], T-1)], float32
+    additions in subband order from zero: what the stage-2 kernel must
+    equal element for element."""
+    nsub, T = subb.shape
+    t = np.arange(T)
+    out = np.zeros((shifts.shape[0], T), np.float32)
+    for d in range(shifts.shape[0]):
+        for s in range(nsub):
+            out[d] += subb[s, np.minimum(t + shifts[d, s], T - 1)]
+    return out
+
+
+# (rows, nsub, T, largest shift), named for what the case is there for
+_STAGE2_CASES = [
+    pytest.param(19, 96, 1100, 119, id="19-rows-mock-subbands"),
+    pytest.param(32, 96, 1100, 119, id="a-full-call"),
+    pytest.param(19, 64, 1100, 100, id="19-rows-wapp-subbands"),
+    pytest.param(1, 16, 3000, 128, id="one-row"),
+    pytest.param(3, 8, 1500, 700, id="overhang-of-several-segments"),
+    pytest.param(5, 8, 1237, 256, id="ragged-T-and-shift-equal-to-S"),
+    pytest.param(3, 4, 400, 350, id="shifts-that-clamp-at-the-edge"),
+    pytest.param(4, 6, 20000, 1000, id="segment-2048-odd-unroll"),
+    pytest.param(38, 8, 1200, 200, id="two-calls-of-19"),
+    pytest.param(76, 8, 1100, 300, id="three-calls-26-26-24"),
+    pytest.param(2, 4, 40000, 16384, id="segment-4096-in-column-pieces"),
+]
+
+
+@pytest.mark.parametrize("rows,nsub,T,smax", _STAGE2_CASES)
+def test_pallas_dedisperse_equals_sequential_sum(rows, nsub, T, smax):
+    """The stage-2 Pallas kernel (interpret mode off-TPU) against the
+    sequential float32 sum in subband order, EQUAL element for
+    element: the golden candidate lists hang on these bits."""
+    from tpulsar.kernels import pallas_dd
+
+    rng = np.random.default_rng(rows * 1000 + nsub)
+    subb = rng.standard_normal((nsub, T)).astype(np.float32)
+    shifts = rng.integers(0, smax + 1, size=(rows, nsub)).astype(np.int32)
+    shifts[0, 0] = smax
+    shifts[-1, -1] = 0
+    shifts[rows // 2, :] = smax
+    got = np.asarray(pallas_dd.dedisperse_subbands_pallas(
+        subb, shifts, interpret=True))
+    np.testing.assert_array_equal(got, _sequential_sum(subb, shifts))
+
+
+def test_pallas_dedisperse_in_subband_groups_equals_sequential_sum(
+        monkeypatch):
+    """Where a tile of all subbands does not fit the VMEM budget (a
+    fold's series at a very deep overhang) they go through VMEM in
+    groups, summed into the same output block in subband order: the
+    same bits."""
+    from tpulsar.kernels import pallas_dd
+
+    nsub, T, rows, smax = 8, 1500, 3, 300
+    whole = pallas_dd.stage2_plan(nsub, 512, rows, T)
+    assert whole.group == nsub
+    monkeypatch.setattr(pallas_dd, "STAGE2_VMEM_BUDGET",
+                        (4 << 20) + 150_000)
+    plan = pallas_dd.stage2_plan(nsub, 512, rows, T)
+    assert 1 < plan.group < nsub and plan.group % plan.unroll == 0
+    rng = np.random.default_rng(5)
+    subb = rng.standard_normal((nsub, T)).astype(np.float32)
+    shifts = rng.integers(0, smax + 1, size=(rows, nsub)).astype(np.int32)
+    got = np.asarray(pallas_dd.dedisperse_subbands_pallas(
+        subb, shifts, interpret=True))
+    np.testing.assert_array_equal(got, _sequential_sum(subb, shifts))
+
+
+def test_pallas_dedisperse_traced_shifts_equal_sequential_sum():
+    """The mesh path hands the kernel TRACED shifts under a static
+    overhang (parallel/mesh.py::_pallas_dd_local): same bits."""
+    import jax
+    import jax.numpy as jnp
+    from tpulsar.parallel import mesh as pmesh
+
+    rng = np.random.default_rng(23)
+    nsub, T, rows = 8, 1500, 35
+    subb = rng.standard_normal((nsub, T)).astype(np.float32)
+    shifts = rng.integers(0, 301, size=(rows, nsub)).astype(np.int32)
+    fn = jax.jit(lambda a, b: pmesh._pallas_dd_local(a, b, 512, True))
+    got = np.asarray(fn(jnp.asarray(subb), jnp.asarray(shifts)))
+    np.testing.assert_array_equal(got, _sequential_sum(subb, shifts))
+
+
 def test_pallas_dedisperse_matches_gather():
-    """The Pallas sliding-window kernel must agree exactly with the
-    XLA gather formulation (interpret mode off-TPU)."""
+    """The Pallas kernel must agree with the XLA gather formulation
+    (the product path off the TPU)."""
     import jax.numpy as jnp
     from tpulsar.kernels import pallas_dd
     from tpulsar.kernels.dedisperse import _dedisperse_subbands_xla
@@ -259,38 +346,55 @@ def test_pallas_dedisperse_matches_gather():
     want = np.asarray(_dedisperse_subbands_xla(jnp.asarray(subb),
                                                jnp.asarray(shifts)))
     got = np.asarray(pallas_dd.dedisperse_subbands_pallas(
-        subb, shifts, block_t=256, dm_chunk=4, interpret=True))
+        subb, shifts, interpret=True))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
 
 
-def test_pallas_variants_match_gather(monkeypatch):
-    """BOTH kernel formulations — 'roll' (dynamic lane rotate +
-    static slice, the round-5 default built for Mosaic's layout
-    rules) and 'slice' (dynamic lane-dim slice, the rounds-3/4
-    on-chip-failing suspect kept for diagnosis) — agree exactly with
-    the XLA gather in interpret mode, and an unknown variant name
-    fails loudly instead of silently picking one."""
-    import pytest
-    import jax.numpy as jnp
+def test_stage2_row_split_never_pads():
+    """A chunk's rows go through ceil(n / 32) calls of ceil(n / calls)
+    rows and no call is padded up: rows a call is the harness's own
+    reckoning (benchmark/harness/runner.py: chunk / ceil(chunk / 32))
+    for every chunk size pass_chunk_size gives on the three benchmark
+    configurations."""
+    import math
+    from benchmark.harness import cells
     from tpulsar.kernels import pallas_dd
-    from tpulsar.kernels.dedisperse import _dedisperse_subbands_xla
+    from tpulsar.plan import ddplan
+    from tpulsar.search import executor
 
-    rng = np.random.default_rng(11)
-    nsub, T, ndms = 8, 1200, 5
-    subb = rng.standard_normal((nsub, T)).astype(np.float32)
-    shifts = rng.integers(0, 290, size=(ndms, nsub)).astype(np.int32)
-    want = np.asarray(_dedisperse_subbands_xla(jnp.asarray(subb),
-                                               jnp.asarray(shifts)))
-    for variant in ("roll", "slice"):
-        monkeypatch.setenv("TPULSAR_PALLAS_VARIANT", variant)
-        assert pallas_dd.kernel_variant() == variant
-        got = np.asarray(pallas_dd.dedisperse_subbands_pallas(
-            subb, shifts, block_t=256, dm_chunk=4, interpret=True))
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4,
-                                   err_msg=variant)
-    monkeypatch.setenv("TPULSAR_PALLAS_VARIANT", "bogus")
-    with pytest.raises(ValueError):
-        pallas_dd.kernel_variant()
+    def split(n):
+        plan = pallas_dd.stage2_plan(96, 256, n, 3_932_160)
+        rows = plan.call_rows(n)
+        assert len(rows) == plan.calls and max(rows) == plan.rows
+        return rows
+
+    assert split(38) == [19, 19]
+    assert split(64) == [32, 32]
+    assert split(32) == [32]
+    assert split(1) == [1]
+    assert split(6) == [6]
+    assert split(76) == [26, 26, 24]
+    for n in range(1, 200):
+        rows = split(n)
+        assert sum(rows) == n and max(rows) <= 32      # no padded row
+        assert len(rows) == math.ceil(n / 32)
+        assert len(rows) * max(rows) - n < len(rows)   # within one a call
+
+    chunks = set()
+    for name in ("mock_ds1_hiaccel", "wapp_steps_noaccel",
+                 "z200_ds1_hiaccel", "mock_steps_noaccel"):
+        cell = cells.load_cell(name)
+        params = cells.search_params(cell)
+        for step in ddplan.survey_plan(cell.config["backend"]):
+            nfft = ddplan.choose_n(cell.nsamp // step.downsamp)
+            chunks.add(executor.pass_chunk_size(step.dms_per_pass, nfft,
+                                                params))
+    assert {38, 64, 76} <= chunks
+    for chunk in chunks:
+        rows = split(chunk)
+        harness = chunk / math.ceil(chunk / 32)
+        assert sum(rows) / len(rows) == pytest.approx(harness)
+        assert max(rows) == math.ceil(harness)
 
 
 def test_pallas_dedisperse_edge_clamp():
@@ -307,7 +411,7 @@ def test_pallas_dedisperse_edge_clamp():
     want = np.asarray(_dedisperse_subbands_xla(jnp.asarray(subb),
                                                jnp.asarray(shifts)))
     got = np.asarray(pallas_dd.dedisperse_subbands_pallas(
-        subb, shifts, block_t=128, interpret=True))
+        subb, shifts, interpret=True))
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 

@@ -305,10 +305,10 @@ def test_sharded_pallas_dd_local_matches_gather():
     """_pallas_dd_local (interpret mode) == the XLA gather stage-2."""
     rng = np.random.default_rng(23)
     subb = jnp.asarray(rng.standard_normal((8, 4096)).astype(np.float32))
-    shifts = (np.arange(40).reshape(5, 8) * 13).astype(np.int32)
+    # 35 rows: two calls (18 + 17) of the kernel wrapper's own split
+    shifts = (np.arange(280).reshape(35, 8) * 3).astype(np.int32)
     got = np.asarray(pmesh._pallas_dd_local(
-        subb, jnp.asarray(shifts), stage_s=1024, interpret=True,
-        dm_chunk=2))
+        subb, jnp.asarray(shifts), stage_s=1024, interpret=True))
     want = np.asarray(dd._dedisperse_subbands_xla(subb,
                                                   jnp.asarray(shifts)))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)

@@ -258,6 +258,21 @@ def test_annotate_adds_to_the_innermost_open_span():
     assert by["retro"]["call"] == by["outer"]["call"] == by["outer"]["id"]
 
 
+def test_annotate_by_name_reaches_the_enclosing_span_of_that_name():
+    """A layer reports what it did to the scope it was called in
+    (pallas_dd's dd_calls on the executor's dm_chunk): the innermost
+    open span of the name, and nothing where there is none."""
+    trace.start()
+    with trace.span("dm_chunk", n=1):
+        with trace.span("dm_chunk", n=2):
+            with trace.span("dedispersing"):
+                trace.annotate("dm_chunk", dd_calls=2)
+                trace.annotate("no-such-span", lost=1)
+    by_n = {e["args"].get("n"): e["args"] for e in trace.events()}
+    assert by_n[2]["dd_calls"] == 2 and "dd_calls" not in by_n[1]
+    assert not any("lost" in e["args"] for e in trace.events())
+
+
 def test_span_decorates_a_function_with_a_fresh_span_each_call():
     @trace.span("work", kind="decorated")
     def work(x):

@@ -137,8 +137,9 @@ PROGRAMS: tuple[Program, ...] = (
        doc="per-dm_chunk residual layer with the SP detrend fused "
            "into the same program"),
     # ---- kernels/pallas_dd.py (the stage-1/2 tiers of a TPU backend)
+    _k("pallas_dd", "_segment_layout", ("seg",)),
     _k("pallas_dd", "_dedisperse_chunk",
-       ("block_t", "window", "interpret", "variant")),
+       ("window", "group", "unroll", "vmem_bytes", "interpret")),
     _k("pallas_dd", "_pad_widen", ("pad",)),
     _k("pallas_dd", "_form_subbands_block",
        ("nsub", "block_t", "window", "interpret")),
@@ -758,8 +759,11 @@ def _headline_groups(ctx: GateContext,
             tag = f"ds={step.downsamp}"
             insts += _stage1_instances(blk, step.numsub,
                                        step.downsamp, pad1, tag)
-            insts += _stage2_instances(step.numsub, T_ds, ndms,
-                                       pad2, tag)
+            # stage 2 is called once per DM chunk, with its rows
+            for rows in sorted({chunk, ndms % chunk} - {0},
+                               reverse=True):
+                insts += _stage2_instances(step.numsub, T_ds, rows,
+                                           pad2, tag)
         groups.append((f"step downsamp={step.downsamp} (T'={T_ds}, "
                        f"ndms={ndms}, pads={sorted(pad_pairs)}):",
                        insts))
@@ -955,8 +959,9 @@ def _stage2_instances(nsub: int, T: int, rows: int, pad: int,
                       tag: str) -> list[Instance]:
     """Stage-2 dedispersion of a ``rows``-trial chunk for one pad
     bucket, in the family dedisperse.dedisperse_subbands dispatches:
-    the Pallas sliding-window kernel (always one 32-row call shape)
-    where that tier is on, the XLA scan elsewhere."""
+    the Pallas kernel (the segment layout, then one program per
+    distinct row count of pallas_dd.stage2_plan's calls) where that
+    tier is on, the XLA scan elsewhere."""
     import jax.numpy as jnp
 
     from tpulsar.kernels import pallas_dd
@@ -968,18 +973,19 @@ def _stage2_instances(nsub: int, T: int, rows: int, pad: int,
                          (_sds((nsub, T), jnp.float32),
                           _sds((rows, nsub), jnp.int32)),
                          dict(pad=pad))]
-    dm_chunk = 32       # dedisperse_subbands_pallas's call shape
     S = pallas_dd.stage_overhang(pad)
-    block_t = pallas_dd.stage2_block_t(nsub, S, min(dm_chunk, rows))
-    cols = -(-T // block_t) * block_t + S
-    return [Instance("pallas_dd._dedisperse_chunk",
-                     f"pallas_dedisperse {tag} S={S} "
-                     f"block={block_t}",
-                     (_sds((nsub, cols), jnp.float32),
-                      _sds((dm_chunk, nsub), jnp.int32)),
-                     dict(block_t=block_t, window=block_t + S,
-                          interpret=False,
-                          variant=pallas_dd.kernel_variant()))]
+    plan = pallas_dd.stage2_plan(nsub, S, rows, T)
+    segs = _sds((nsub, plan.n_seg, plan.seg), jnp.float32)
+    edge = _sds((nsub, 8, 128), jnp.float32)
+    return [Instance("pallas_dd._segment_layout",
+                     f"pallas_segments {tag} seg={plan.seg}",
+                     (_sds((nsub, T), jnp.float32),),
+                     dict(seg=plan.seg))] + [
+        Instance("pallas_dd._dedisperse_chunk",
+                 f"pallas_dedisperse {tag} S={S} seg={plan.seg} rows={n}",
+                 (segs, edge, _sds((n, nsub), jnp.int32)),
+                 dict(plan.kernel_args(), interpret=False))
+        for n in sorted(set(plan.call_rows(rows)), reverse=True)]
 
 
 def _tree_groups(ctx: GateContext, geoms,

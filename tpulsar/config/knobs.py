@@ -161,9 +161,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
     _k("TPULSAR_PALLAS_SB", "enum(0|1)", "auto",
        "stage-1 (subband) Pallas tier override, after "
        "TPULSAR_PALLAS gates both tiers"),
-    _k("TPULSAR_PALLAS_VARIANT", "enum(roll|slice)", "roll",
-       "Pallas kernel formulation (slice kept as the bisect "
-       "control; it failed its on-chip smoke)"),
     _k("TPULSAR_PROFILE", "path", "unset",
        "directory for a JAX profiler trace of the search block"),
     _k("TPULSAR_QUEUE_BUSY_TIMEOUT_S", "float", "5 (resilience "

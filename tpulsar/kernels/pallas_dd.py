@@ -1,29 +1,31 @@
-"""Pallas TPU kernel for stage-2 incoherent dedispersion.
+"""Pallas TPU kernels for incoherent dedispersion.
 
-Replaces the XLA gather formulation of `dedisperse_subbands`
+Stage 2 replaces the XLA gather formulation of `dedisperse_subbands`
 (tpulsar/kernels/dedisperse.py) on TPU.  The reference's equivalent
 native component is PRESTO's `prepsubband` C program (invoked at
 lib/python/PALFA2_presto_search.py:514-529), which re-reads the
 subband file once per DM pass; the XLA gather likewise re-reads the
 (nsub, T) array once per DM trial.
 
-This kernel restructures the sweep around HBM bandwidth (the TPU
-bottleneck): time is tiled into blocks; each grid step DMAs one
-(nsub, B + S) sliding window into VMEM *once* and accumulates every
-DM trial's shifted sum out of that tile, so HBM input traffic drops
-from ndms*nsub*T to nsub*T per pass (~76x for the survey plan).
-The integer shift table rides in SMEM via scalar prefetch.
+The kernel stages each time block in VMEM *once* and accumulates
+every DM trial's shifted sum out of that tile, so HBM input traffic
+drops from ndms*nsub*T to nsub*T per pass (~76x for the survey plan),
+and it works on full (8, 128) vector registers: a grid step handles 8
+consecutive time segments of `seg` samples, one on each sublane, so
+one scalar shift moves all 8 at once (`_kernel_dd`).  The integer
+shift table rides in SMEM via scalar prefetch.
 
 Semantics match the gather version exactly:
     out[d, t] = sum_s subb[s, min(t + shift[d, s], T-1)]
-(edge clamp realized by padding the staged window with the last
-sample).
+summed in subband order from zero in float32 (the edge clamp is
+applied where a segment's overhang is put beside it in VMEM).
 """
 
 from __future__ import annotations
 
 import functools
 import os
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -32,73 +34,94 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpulsar.obs import trace
 
-def _kernel(shift_ref, sub_hbm, out_ref, tile, sem, *, nsub, ndms,
-            block_t, window):
-    """One grid step: stage (nsub, window) at t0 = i*block_t, then
-    out[d, :] = sum_s tile[s, shift[d,s] : shift[d,s]+block_t].
 
-    'slice' variant: the shifted read is a dynamic slice whose runtime
-    offset lands on the LANE (minor) dimension at arbitrary (non-128-
-    aligned) positions.  CONFIRMED on-chip (v5e, 2026-08-01 campaign):
-    Mosaic rejects it at compile time with "prove that index in
-    dimension 1 is a multiple of 128" on the generated vector.load —
-    exactly the suspected unaligned lane-dim dynamic slice.  Kept
-    selectable via TPULSAR_PALLAS_VARIANT=slice as the negative
-    control for the diagnosis."""
-    i = pl.program_id(0)
-    dma = pltpu.make_async_copy(
-        sub_hbm.at[:, pl.ds(i * block_t, window)], tile, sem)
-    dma.start()
-    dma.wait()
+#: registers of the stage-2 accumulator: a wider segment is summed in
+#: column pieces of this many 128-lane chunks
+_ACC_CHUNKS = 16
 
-    def dm_body(d, _):
-        def sb_body(s, acc):
-            sh = shift_ref[d, s]
-            return acc + tile[pl.ds(s, 1), pl.ds(sh, block_t)]
 
-        acc0 = jnp.zeros((1, block_t), jnp.float32)
-        out_ref[pl.ds(d, 1), :] = jax.lax.fori_loop(
-            0, nsub, sb_body, acc0)
+def _kernel_dd(shift_ref, seg_ref, nxt_ref, edge_ref, out_ref, slab, *,
+               nsub, group, rows, seg, window, n_seg, unroll):
+    """One grid step (i, g): 8 consecutive time segments of `seg`
+    samples, one on each sublane, for every row of the call, summed
+    over the g-th group of `group` subbands.
+
+    seg_ref: the (group, 8, seg) block i of the segment-layout
+    subbands; nxt_ref: the first min(window - seg, seg) lanes of block
+    i+1; edge_ref: (group, 8, 128), each subband's last sample.  First
+    each subband's slab[s] is put together in VMEM as window / 128
+    registers (chunk, 8, 128): sublane j holds segment 8i+j followed
+    by its overhang, the start of segments 8i+j+1, ... (a sublane
+    rotate of the two blocks), or the subband's last sample past the
+    end of the series (the edge clamp).  Then
+        out[d] += sum_s slab[s] at lanes shift[d,s] : shift[d,s] + seg
+    in subband order, from zero at the first group (the output block
+    stays in VMEM across the groups, so the float32 additions are the
+    sequential sum's whatever the grouping).  The shifted read takes
+    the chunks from shift // 128 on (a dynamic index on the untiled
+    axis), rotates every register's lanes by the rest in one
+    operation and selects between neighbours: its cost does not
+    depend on the overhang, and a subband is a handful of operations
+    to trace and lower however wide the segment.  `unroll` subbands go
+    into one loop iteration so that their rotates overlap."""
+    nc = seg // 128                     # chunks of a segment
+    pc = min(nc, _ACC_CHUNKS)           # ... of a column piece
+    i, g = pl.program_id(0), pl.program_id(1)
+    first = g * group       # this group's first subband
+
+    def fill(s, _):
+        a = seg_ref[s]
+        b = nxt_ref[s]
+        for k in range(nc):
+            slab[s, k] = a[:, k * 128:(k + 1) * 128]
+        for m in range(1, -(-(window - seg) // seg) + 1):
+            w = min(seg, window - m * seg)
+            sub = jax.lax.broadcasted_iota(jnp.int32, (8, w), 0)
+            # roll's amount must not be negative: 8 - m is -m (mod 8)
+            nxt = jnp.where(sub < 8 - m,
+                            pltpu.roll(a[:, :w], 8 - m, 0),
+                            pltpu.roll(b[:, :w], 8 - m, 0))
+            edge = jnp.concatenate([edge_ref[s]] * (w // 128), axis=1)
+            nxt = jnp.where(8 * i + sub + m >= n_seg, edge, nxt)
+            for k in range(w // 128):
+                slab[s, m * nc + k] = nxt[:, k * 128:(k + 1) * 128]
         return 0
 
-    jax.lax.fori_loop(0, ndms, dm_body, 0)
+    jax.lax.fori_loop(0, group, fill, 0)
 
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
 
-def _kernel_roll(shift_ref, sub_hbm, out_ref, tile, sem, *, nsub,
-                 ndms, block_t, window):
-    """Same math as _kernel, expressed with primitives Mosaic lowers
-    on every TPU generation: the shifted read
-    tile[s, sh : sh+block_t] becomes a dynamic-scalar LANE ROTATE
-    (pltpu.roll, tpu.dynamic_rotate) followed by a STATIC slice of
-    the first block_t lanes — no dynamic lane-dimension slicing.
-    Exact because rolled[j] = row[(j + sh) mod window] and
-    j + sh < block_t + S = window for all j < block_t, sh <= S
-    (no wraparound enters the kept region).  The sublane index s
-    stays a supported dynamic sublane slice."""
-    i = pl.program_id(0)
-    dma = pltpu.make_async_copy(
-        sub_hbm.at[:, pl.ds(i * block_t, window)], tile, sem)
-    dma.start()
-    dma.wait()
+    def shifted(s, sh, c0):
+        rest = sh & 127
+        win = slab[s, pl.ds((sh >> 7) + c0, pc + 1)]
+        rot = pltpu.roll(win, (128 - rest) & 127, 2)
+        return jnp.where((lane < 128 - rest)[None], rot[:-1], rot[1:])
 
     def dm_body(d, _):
-        def sb_body(s, acc):
-            sh = shift_ref[d, s]
-            row = tile[pl.ds(s, 1), :]               # (1, window)
-            # window - sh, not -sh: roll's contract forbids negative
-            # amounts (only checkable for static ints — a traced
-            # negative would bypass validation and reach the chip),
-            # and (window - sh) ≡ -sh (mod window) is always positive
-            rolled = pltpu.roll(row, window - sh, 1)
-            return acc + rolled[:, :block_t]
+        for c0 in range(0, nc, pc):
+            def sb_body(it, acc):
+                def one(u, acc):
+                    s = it * unroll + u
+                    return acc + shifted(s, shift_ref[d, first + s], c0)
 
-        acc0 = jnp.zeros((1, block_t), jnp.float32)
-        out_ref[pl.ds(d, 1), :] = jax.lax.fori_loop(
-            0, nsub, sb_body, acc0)
+                # traced once, unrolled when the kernel is lowered
+                return jax.lax.fori_loop(0, unroll, one, acc, unroll=True)
+
+            def cols(k):
+                return slice((c0 + k) * 128, (c0 + k + 1) * 128)
+
+            acc0 = jnp.zeros((pc, 8, 128), jnp.float32)
+            if group < nsub:    # later groups add to the block
+                acc0 = jnp.where(g == 0, acc0, jnp.stack(
+                    [out_ref[d, :, cols(k)] for k in range(pc)]))
+            acc = jax.lax.fori_loop(0, group // unroll, sb_body, acc0)
+            for k in range(pc):
+                out_ref[d, :, cols(k)] = acc[k]
         return 0
 
-    jax.lax.fori_loop(0, ndms, dm_body, 0)
+    jax.lax.fori_loop(0, rows, dm_body, 0)
 
 
 def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
@@ -106,10 +129,9 @@ def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
     """Stage-1 subband formation, one grid step: stage the whole
     (nchan, window) channel block at t0 = i*block_t once, then
         out[b, :] = sum_c tile[b*cps + c, sh[b,c] : sh[b,c]+block_t]
-    with the shifted read expressed as the roll variant's dynamic
-    lane rotate + static slice (the on-chip-proven formulation — the
-    slice form is Mosaic-rejected for unaligned lane-dim dynamic
-    slices).  Replaces the XLA `lax.map` formulation that serializes
+    with the shifted read expressed as a dynamic lane rotate + static
+    slice (Mosaic rejects a dynamic lane-dim slice that is not
+    provably 128-aligned).  Replaces the XLA `lax.map` formulation that serializes
     96 subbands and measured 160.6 s of config 1's 176.5 s on-chip
     (bench_runs/rung_cfg1_full.json, 2026-08-01); the same sweep as a
     VMEM-staged Pallas program is the stage-2 kernel that does 12x
@@ -144,7 +166,9 @@ def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
             sh = shift_ref[b, c]
             row = tile_f32[pl.ds(b * cps + c, 1), :]
             # window - sh, not -sh: roll's contract forbids negative
-            # amounts (see _kernel_roll)
+            # amounts (only checkable for static ints — a traced
+            # negative would bypass validation and reach the chip),
+            # and (window - sh) = -sh (mod window) is always positive
             rolled = pltpu.roll(row, window - sh, 1)
             return acc + rolled[:, :block_t]
 
@@ -156,60 +180,73 @@ def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
     jax.lax.fori_loop(0, nsub, sb_body, 0)
 
 
-_KERNEL_VARIANTS = {"slice": _kernel, "roll": _kernel_roll}
-
-
-def kernel_variant() -> str:
-    """TPULSAR_PALLAS_VARIANT: which kernel formulation the Pallas
-    path (and its smoke probe — the subprocess inherits the env) uses.
-    Default 'roll': the slice variant failed its on-chip smoke in
-    rounds 3-4; the 2026-08-01 v5e campaign captured the error
-    ("prove that index in dimension 1 is a multiple of 128" — the
-    unaligned lane-dim dynamic slice) and the roll formulation
-    PASSES its on-chip smoke ("variant=roll: ok"), so roll is the
-    production TPU tier.  The campaign probes BOTH and records each
-    variant's detail."""
-    val = os.environ.get("TPULSAR_PALLAS_VARIANT", "roll").strip()
-    if val not in _KERNEL_VARIANTS:
-        raise ValueError(
-            f"TPULSAR_PALLAS_VARIANT must be one of "
-            f"{sorted(_KERNEL_VARIANTS)}, got {val!r}")
-    return val
+@functools.partial(jax.jit, static_argnames=("seg",))
+def _segment_layout(subbands: jnp.ndarray, seg: int):
+    """(nsub, T) -> the (nsub, n_seg, seg) segment layout stage 2
+    reads (n_seg a multiple of 8: one grid step's 8 sublanes), and
+    each subband's last sample as (nsub, 8, 128).  One relayout copy
+    in HBM where T is a multiple of 8 * seg (every Mock length and the
+    WAPP's 2^22), a pad before it elsewhere."""
+    nsub, T = subbands.shape
+    edge = jnp.broadcast_to(subbands[:, -1][:, None, None],
+                            (nsub, 8, 128))
+    pad = -T % (8 * seg)        # to Stage2Plan.n_seg segments
+    if pad:
+        subbands = jnp.pad(subbands, ((0, 0), (0, pad)), mode="edge")
+    return subbands.reshape(nsub, -1, seg), edge
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_t", "window", "interpret",
-                                    "variant"))
-def _dedisperse_chunk(subb_padded: jnp.ndarray, shifts: jnp.ndarray,
-                      block_t: int, window: int,
-                      interpret: bool,
-                      variant: str = "roll") -> jnp.ndarray:
-    """subb_padded: (nsub, n_blocks*block_t + S) f32, edge-padded.
-    shifts: (ndms_c, nsub) int32, all in [0, S].
-    Returns (ndms_c, n_blocks*block_t) f32."""
-    nsub, tp = subb_padded.shape
-    ndms = shifts.shape[0]
-    n_blocks = (tp - (window - block_t)) // block_t
+                   static_argnames=("window", "group", "unroll",
+                                    "vmem_bytes", "interpret"))
+def _dedisperse_chunk(segs: jnp.ndarray, edge: jnp.ndarray,
+                      shifts: jnp.ndarray, window: int, group: int,
+                      unroll: int, vmem_bytes: int,
+                      interpret: bool) -> jnp.ndarray:
+    """segs, edge: `_segment_layout`'s.  shifts: (rows, nsub) int32,
+    all in [0, window - seg - 128].  group: subbands in VMEM at a time
+    (a divisor of nsub; nsub itself wherever they fit).  Returns
+    (rows, n_seg * seg) f32 (the wrapper cuts it to T)."""
+    nsub, n_seg, seg = segs.shape
+    rows = shifts.shape[0]
+    n_blocks = n_seg // 8
+
+    def block(index_map, width=seg):
+        return pl.BlockSpec((group, 8, width), index_map,
+                            memory_space=pltpu.VMEM)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ndms, block_t), lambda i, s_ref: (0, i),
+        grid=(n_blocks, nsub // group),
+        # the same array twice: a step's 8 segments, and of the 8
+        # after them the heads that are the step's overhang (whole
+        # segments only where the overhang is longer than one), both
+        # pipelined
+        in_specs=[block(lambda i, g, s_ref: (g, i, 0)),
+                  block(lambda i, g, s_ref: (
+                      g, jnp.minimum(i + 1, n_blocks - 1), 0),
+                      min(window - seg, seg)),
+                  block(lambda i, g, s_ref: (g, 0, 0), 128)],
+        # the same block for every group: it is summed in VMEM
+        out_specs=pl.BlockSpec((rows, 8, seg),
+                               lambda i, g, s_ref: (0, i, 0),
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((nsub, window), jnp.float32),
-            pltpu.SemaphoreType.DMA(()),
-        ],
+        scratch_shapes=[pltpu.VMEM((group, window // 128, 8, 128),
+                                   jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(_KERNEL_VARIANTS[variant], nsub=nsub,
-                          ndms=ndms, block_t=block_t, window=window),
-        out_shape=jax.ShapeDtypeStruct((ndms, n_blocks * block_t),
-                                       jnp.float32),
+    out = pl.pallas_call(
+        functools.partial(_kernel_dd, nsub=nsub, group=group, rows=rows,
+                          seg=seg, window=window, n_seg=n_seg,
+                          unroll=unroll),
+        out_shape=jax.ShapeDtypeStruct((rows, n_seg, seg), jnp.float32),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
-    )(shifts, subb_padded)
+    )(shifts, segs, segs, edge)
+    # not free: XLA relays the (n_seg, seg) tiles out to (rows, T) rows
+    return out.reshape(rows, n_seg * seg)
 
 
 # --- wrapper geometry ------------------------------------------------
@@ -225,16 +262,81 @@ def stage_overhang(smax: int) -> int:
     return max(256, 1 << int(np.ceil(np.log2(max(smax, 1)))))
 
 
-def stage2_block_t(nsub: int, S: int, rows: int) -> int:
-    """Stage-2 time block: prefer 4096 (fewer grid steps amortize the
-    DMA better), downshifting while the scoped-VMEM estimate for
-    (tile + out block) approaches the 16 MB stack limit Mosaic
-    enforces."""
-    block_t = 4096
-    while block_t > 1024 and (
-            4 * (nsub * (block_t + S) + rows * block_t)) > 13_000_000:
-        block_t //= 2
-    return block_t
+#: most rows of one stage-2 program call: bounds the SMEM shift
+#: table and the VMEM output block
+STAGE2_MAX_ROWS = 32
+
+#: what stage 2 may ask of VMEM: half of a v5e's 128 MiB
+STAGE2_VMEM_BUDGET = 64 << 20
+
+
+class Stage2Plan(typing.NamedTuple):
+    """Geometry of the stage-2 calls for one chunk of DM rows."""
+    seg: int         # samples of one time segment (a sublane's share)
+    n_seg: int       # segments of the padded series, a multiple of 8
+    window: int      # lanes of a segment with its overhang
+    group: int       # subbands in VMEM at a time (divides nsub)
+    unroll: int      # subbands per loop iteration (divides group)
+    calls: int       # program calls for the chunk
+    rows: int        # rows of a call (the last takes what is left)
+    vmem_bytes: int  # scoped-VMEM request of a call
+
+    def call_rows(self, n: int) -> list[int]:
+        """Rows of each call for a chunk of n rows: never padded."""
+        return [min(self.rows, n - c0) for c0 in range(0, n, self.rows)]
+
+    def kernel_args(self) -> dict:
+        """`_dedisperse_chunk`'s static arguments but `interpret`."""
+        return dict(window=self.window, group=self.group,
+                    unroll=self.unroll, vmem_bytes=self.vmem_bytes)
+
+
+def stage2_plan(nsub: int, S: int, rows: int, T: int) -> Stage2Plan:
+    """Everything static about stage 2 for `rows` DM rows of (nsub, T)
+    subbands with overhang S, from the shapes alone.
+
+    Rows: ceil(rows / 32) calls of ceil(rows / calls) rows, never a
+    call padded up (38 -> 2 x 19; 76 -> 26 + 26 + 24; 1 -> 1 x 1).
+    Segment: 2048 samples where the series is long enough to fill 8 of
+    them (measured on a v5e at Mock ds=1: 25.7 ms a 19-row call
+    against 30.5 at 1024 and 41.6 at 512), and never so short that an
+    overhang spans more than 7 segments (the 8 sublanes of the next
+    block are all the kernel has): 4096 at S 16384, 8192 at 32768.
+    Group: all subbands where their tile fits the VMEM budget (every
+    pass of the survey plans), else the largest divisor of nsub that
+    does (a fold's series at an overhang of 16384 and more; the XLA
+    scan takes 22.4 ms for that one row whatever the overhang, this
+    kernel 9.8-10.0).  Unroll: the most subbands per loop iteration
+    that divide the group, up to 32 (same call: 55.5 ms at 1, 29.9 at
+    4, 25.7 at 8, 22.5 at 32, 22.0 at all 96: the rotates of one
+    subband wait for nothing of the next); they are unrolled when the
+    kernel is lowered, not when it is traced, which is what a
+    process pays for each program on its first call."""
+    calls = -(-rows // STAGE2_MAX_ROWS)
+    per_call = -(-rows // calls)
+    over = S + 128      # a 128-aligned start and one vreg for the rest
+    seg = 2048
+    while seg > 128 and 8 * seg > T:
+        seg //= 2
+    while -(-over // seg) > 7:
+        seg *= 2
+
+    def vmem(group):
+        # both input blocks and the output block double-buffered, the
+        # slab and the edge once, and room for Mosaic's own scratch
+        words = (2 * group * 8 * (seg + min(over, seg))
+                 + group * 8 * (seg + over)
+                 + 2 * per_call * 8 * seg + 2 * group * 8 * 128)
+        return 4 * words + (4 << 20)
+
+    divisors = [g for g in range(nsub, 0, -1) if nsub % g == 0]
+    group = next((g for g in divisors
+                  if vmem(g) <= STAGE2_VMEM_BUDGET), 1)
+    unroll = max(u for u in range(1, 33) if group % u == 0)
+    return Stage2Plan(seg=seg, n_seg=-(-T // (8 * seg)) * 8,
+                      window=seg + over, group=group, unroll=unroll,
+                      calls=calls, rows=per_call,
+                      vmem_bytes=max(16 << 20, vmem(group)))
 
 
 def stage1_block_t(nchan: int, nsub: int, S: int, itemsize: int) -> int:
@@ -271,26 +373,15 @@ def stage1_slabs(T: int, nchan: int, itemsize: int, block_t: int,
 
 
 def dedisperse_subbands_pallas(subbands, sub_shifts,
-                               block_t: int | None = None,
-                               dm_chunk: int = 32,
                                interpret: bool | None = None):
     """(nsub, T) + (ndms, nsub) int32 -> (ndms, T) f32.
 
-    DM trials are processed `dm_chunk` at a time to bound the SMEM
-    shift table and the VMEM output block.  A standalone 76-row call
-    measures 22 vs 35 ms/trial against 32-row chunks, but the
-    executor's pass chunking feeds at most ~38 rows per call, so a
-    larger default only forces a new compile family without ever
-    making the large calls (a 76-default run regressed to 448 s
-    end-to-end); 32 stays the default.
-
-    block_t None = adaptive: prefer 4096 (measured 28 vs 47 ms/trial
-    against 2048 at survey full scale, 2026-08-01 on-chip probe —
-    fewer grid steps amortize the DMA better), downshifting when the
-    scoped-VMEM estimate for (tile + out block) would approach the
-    16 MB stack limit Mosaic enforces (observed: 17.5 MB request
-    rejected with 'exceeded scoped vmem limit').
-    """
+    The rows go through `stage2_plan`'s calls: at most 32 a call (the
+    SMEM shift table and the VMEM output block are bounded by it) and
+    only the rows there are, so a chunk costs what its rows cost
+    (the executor's 38-row chunk runs as 19 + 19, a fold's one series
+    as one row).  The subbands are put into the segment layout once
+    for all of them."""
     interpret = _resolve_interpret(interpret)
     subbands = jnp.asarray(subbands, jnp.float32)
     shifts_np = np.asarray(sub_shifts, np.int32)
@@ -298,24 +389,17 @@ def dedisperse_subbands_pallas(subbands, sub_shifts,
     ndms = shifts_np.shape[0]
 
     S = stage_overhang(int(shifts_np.max(initial=0)))
-    if block_t is None:
-        block_t = stage2_block_t(nsub, S, min(dm_chunk, ndms))
-    window = block_t + S
-    n_blocks = -(-T // block_t)
-    pad = n_blocks * block_t + S - T
-    subb_padded = jnp.pad(subbands, ((0, 0), (0, pad)), mode="edge")
-
+    plan = stage2_plan(nsub, S, ndms, T)
+    segs, edge = _segment_layout(subbands, plan.seg)
     outs = []
-    for c0 in range(0, ndms, dm_chunk):
-        chunk = shifts_np[c0:c0 + dm_chunk]
-        nrows = chunk.shape[0]
-        if nrows < dm_chunk:   # keep one compiled (ndms, ...) shape
-            chunk = np.pad(chunk, ((0, dm_chunk - nrows), (0, 0)))
-        res = _dedisperse_chunk(subb_padded, jnp.asarray(chunk),
-                                block_t, window, interpret,
-                                variant=kernel_variant())
-        outs.append(res[:nrows, :T])
-    return jnp.concatenate(outs, axis=0)
+    for c0 in range(0, ndms, plan.rows):
+        res = _dedisperse_chunk(
+            segs, edge, jnp.asarray(shifts_np[c0:c0 + plan.rows]),
+            interpret=interpret, **plan.kernel_args())
+        outs.append(res if res.shape[1] == T else res[:, :T])
+    # what ran, on the executor's chunk span (docs/operations.md)
+    trace.annotate("dm_chunk", dd_calls=len(outs), dd_rows=plan.rows)
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("pad",))

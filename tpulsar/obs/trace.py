@@ -241,17 +241,20 @@ def span(name: str, **attrs):
         })
 
 
-def annotate(**attrs) -> None:
-    """Add attributes to the innermost open span's in-memory event —
+def annotate(_span: str = "", **attrs) -> None:
+    """Add attributes to the innermost open span's in-memory event
+    (with ``_span``: to the innermost open span of that name, for a
+    layer that reports what it did to the scope it was called in) —
     for counts known only inside or at the end of the scope (candidates
     sifted, bytes checkpointed).  They reach ``events()`` and the
     Chrome-trace file, not the profiler's annotation, which takes its
     stats at entry."""
     if not _ON:
         return
-    st = _stack()
-    if st:
-        st[-1].attrs.update(attrs)
+    for frame in reversed(_stack()):
+        if not _span or frame.name == _span:
+            frame.attrs.update(attrs)
+            return
 
 
 def profile_session(profile_dir: str):

@@ -188,33 +188,31 @@ class PassSpec:
     #                             dd_pad <= T'/n_dm.
 
 
-def _pallas_dd_local(subb, shifts, stage_s: int, interpret: bool,
-                     block_t: int = 2048, dm_chunk: int = 32):
-    """Per-shard stage-2 dedispersion via the Pallas sliding-window
-    kernel (tpulsar/kernels/pallas_dd.py) — same HBM-bandwidth win as
-    the single-device product path, expressed with static staging
+def _pallas_dd_local(subb, shifts, stage_s: int, interpret: bool):
+    """Per-shard stage-2 dedispersion via the Pallas kernel
+    (tpulsar/kernels/pallas_dd.py) — same HBM-bandwidth win as the
+    single-device product path, expressed with static staging
     geometry so it traces inside shard_map (the host wrapper
     dedisperse_subbands_pallas inspects the shift table with NumPy,
     which a traced shard cannot).  stage_s must be >= the max shift of
     the FULL pass table (computed host-side once, shared by every
-    shard so all shards compile the same kernel)."""
-    from tpulsar.kernels.pallas_dd import (_dedisperse_chunk,
-                                           _resolve_interpret)
+    shard so all shards compile the same kernel); the rest of the
+    geometry is pallas_dd.stage2_plan's, from the shard's shapes."""
+    from tpulsar.kernels import pallas_dd
 
-    interpret = _resolve_interpret(interpret)
+    interpret = pallas_dd._resolve_interpret(interpret)
     ndms_loc = shifts.shape[0]
-    T = subb.shape[-1]
-    window = block_t + stage_s
-    n_blocks = -(-T // block_t)
-    pad = n_blocks * block_t + stage_s - T
-    subbp = jnp.pad(subb.astype(jnp.float32), ((0, 0), (0, pad)),
-                    mode="edge")
+    nsub, T = subb.shape
+    plan = pallas_dd.stage2_plan(nsub, stage_s, ndms_loc, T)
+    segs, edge = pallas_dd._segment_layout(subb.astype(jnp.float32),
+                                           plan.seg)
     rows = []
-    for c0 in range(0, ndms_loc, dm_chunk):
-        n = min(dm_chunk, ndms_loc - c0)
-        chunk = jax.lax.dynamic_slice_in_dim(shifts, c0, n, axis=0)
-        rows.append(_dedisperse_chunk(subbp, chunk, block_t, window,
-                                      interpret)[:, :T])
+    for c0 in range(0, ndms_loc, plan.rows):
+        chunk = jax.lax.dynamic_slice_in_dim(
+            shifts, c0, min(plan.rows, ndms_loc - c0), axis=0)
+        rows.append(pallas_dd._dedisperse_chunk(
+            segs, edge, chunk, interpret=interpret,
+            **plan.kernel_args())[:, :T])
     return jnp.concatenate(rows, axis=0) if len(rows) > 1 else rows[0]
 
 

@@ -1151,10 +1151,15 @@ def _search_block_inner(data, freqs, dt, plan, params, zaplist, baryv,
                         hi_rows = (_hi_rows(len(dm_chunk),
                                             int(subb.shape[1]), params)
                                    if trace_mod.enabled() else 0)
+                        # dd_calls x dd_rows: stage-2 program calls for
+                        # this chunk and rows a call; the Pallas wrapper
+                        # writes what it dispatched (0 where it did not
+                        # run: the XLA scan, the tree family)
                         with trace_mod.span("dm_chunk",
                                             pass_idx=pass_idx, lo=int(lo),
                                             n=int(len(dm_chunk)),
                                             hi_rows=hi_rows,
+                                            dd_calls=0, dd_rows=0,
                                             family=("tree" if tree_parts
                                                     is not None
                                                     else "direct")):
