@@ -1,9 +1,10 @@
 """Batch-of-beams tests: the host planner's ladder/budget/compat
-arithmetic (jax-free), bit-exact per-beam parity of the coalesced
-path against the solo executor (candidates, SP events, checkpoint
-artifacts), and mid-batch kill + resume — a beam searched inside a
-batch must leave byte-identical checkpoint artifacts and resume
-behaviour to the same beam searched solo."""
+arithmetic (jax-free), bit-exact per-beam parity of a group through
+the pass loop against a solo search (candidates, SP events, checkpoint
+artifacts; a toy pair in tier-1, three survey-planned beams `slow`),
+and mid-batch kill + resume — a beam searched inside a batch must
+leave byte-identical checkpoint artifacts and resume behaviour to the
+same beam searched solo."""
 
 import glob
 import os
@@ -203,6 +204,113 @@ def _assert_checkpoint_parity(dir_a, dir_b, label=""):
             for member in za.namelist():
                 assert za.read(member) == zb.read(member), \
                     (label, nm, member)
+
+
+# ---- the group loop at a size tier-1 can pay: two DIFFERENT beams of
+# one geometry over a toy plan, every branch a group adds to the one
+# pass loop switched on (hi-accel, a zaplist with per-beam baryv so
+# the 2-D keep rows differ, checkpoints, three chunks a pass so the
+# two-in-flight bound engages)
+
+_TOY_PARAM_KW = dict(nsub=16, hi_accel_zmax=8, topk_per_stage=8,
+                     max_dms_per_chunk=4, max_cands_to_fold=1,
+                     make_plots=False)
+
+
+def _toy_plan():
+    from tpulsar.plan import ddplan
+    return [ddplan.DedispStep(lodm=40.0, dmstep=2.0, dms_per_pass=12,
+                              numpasses=2, numsub=16, downsamp=1)]
+
+
+@pytest.fixture(scope="module")
+def toy_pair(tmp_path_factory):
+    """Two toy beams searched each alone (search_beam) and together
+    (search_beam_batch), checkpoint stores kept."""
+    from tpulsar.io import synth
+    from tpulsar.kernels.fourier import parse_zaplist
+    from tpulsar.search import executor
+
+    base = tmp_path_factory.mktemp("toypair")
+    zap = parse_zaplist(os.path.join(
+        os.path.dirname(executor.__file__), "..", "data",
+        "default.zaplist"))
+    psrs = [synth.PulsarSpec(period_s=0.15, dm=60.0,
+                             snr_per_sample=0.6, width_frac=0.05),
+            synth.PulsarSpec(period_s=0.09, dm=50.0,
+                             snr_per_sample=0.7, width_frac=0.05)]
+    # baryv apart by more than a Fourier bin at the zapped lines (far
+    # beyond a real sky's 1e-4: the two keep masks must differ)
+    beams, baryvs = [], [0.0, 5e-3]
+    for i, psr in enumerate(psrs):
+        spec = synth.BeamSpec(nchan=32, nsamp=1 << 13, nbits=4,
+                              tsamp_s=5.24288e-4, scan=200 + i)
+        beams.append(synth.synth_beam(str(base / f"data{i}"), spec,
+                                      pulsars=[psr], merged=True))
+    params = executor.SearchParams(**_TOY_PARAM_KW)
+    with pytest.MonkeyPatch.context() as mp:
+        # search_beam_batch plans from the header; give both entry
+        # points the toy plan the same way
+        mp.setattr(executor.ddplan, "plan_for",
+                   lambda si, **kw: (_toy_plan(), None, 16))
+        solo = [executor.search_beam(
+            fns, str(base / f"w_s{i}"), str(base / f"r_s{i}"), params,
+            zaplist=zap, baryv=baryvs[i],
+            checkpoint_dir=str(base / f"ck_s{i}"))
+            for i, fns in enumerate(beams)]
+        progress = []
+        batched = executor.search_beam_batch(
+            [executor.BeamSpec(
+                fns=fns, workdir=str(base / f"w_b{i}"),
+                resultsdir=str(base / f"r_b{i}"), zaplist=zap,
+                baryv=baryvs[i],
+                checkpoint_dir=str(base / f"ck_b{i}"))
+             for i, fns in enumerate(beams)],
+            params, progress_cb=progress.append)
+    return {"base": base, "solo": solo, "batched": batched,
+            "progress": progress, "zap": zap, "baryvs": baryvs}
+
+
+def test_group_of_two_rode_one_loop(toy_pair):
+    res = toy_pair["batched"]
+    assert [(r.path, r.group_size, r.fallout) for r in res] \
+        == [("batched", 2, "")] * 2, [(r.path, r.error) for r in res]
+    # the solo's progress keys, plus nbeams; trials are one beam's
+    assert [p["pass_idx"] for p in toy_pair["progress"]] == [1, 2]
+    assert set(toy_pair["progress"][-1]) == {
+        "pass_idx", "npasses", "step_idx", "ntrials_done", "ncands",
+        "stage_s", "nbeams"}
+    assert toy_pair["progress"][-1]["ntrials_done"] == 24
+    assert toy_pair["progress"][-1]["nbeams"] == 2
+    # the beams differ, so a slice taken from the wrong rows shows
+    a, b = (o.candidates for o in toy_pair["solo"])
+    assert a and b and a[0].period_s != b[0].period_s
+    # and so do their keep masks (per-row 2-D masks in the group)
+    from tpulsar.kernels import fourier as fr
+    nfft = 1 << 13
+    masks = [fr.zap_mask(nfft // 2 + 1, nfft * 5.24288e-4,
+                         toy_pair["zap"], bv)
+             for bv in toy_pair["baryvs"]]
+    assert not np.array_equal(*masks) and not masks[0].all()
+
+
+@pytest.mark.parametrize("i", [0, 1])
+def test_group_member_outcome_is_its_solo_outcome(toy_pair, i):
+    _assert_outcome_parity(toy_pair["solo"][i],
+                           toy_pair["batched"][i].outcome, f"beam{i}")
+    assert toy_pair["solo"][i].num_dm_trials == 24
+
+
+@pytest.mark.parametrize("i", [0, 1])
+def test_group_member_checkpoints_are_its_solo_checkpoints(toy_pair,
+                                                           i):
+    base = toy_pair["base"]
+    _assert_checkpoint_parity(str(base / f"ck_s{i}"),
+                              str(base / f"ck_b{i}"), f"beam{i}")
+    names = sorted(os.path.basename(p) for p in
+                   glob.glob(str(base / f"ck_b{i}" / "*.npz")))
+    assert {"pass_0000.npz", "pass_0001.npz", "sifted.npz"} \
+        <= set(names)
 
 
 @pytest.mark.slow

@@ -202,47 +202,6 @@ def test_shift_rows_clamps_and_matches_reference():
     np.testing.assert_allclose(got, want)
 
 
-def test_tree_stage2_matches_scan():
-    """The two-level shift-pattern tree equals the flat scan up to
-    float summation order (group-first vs subband-sequential; it is
-    an exact index restructuring, not an approximation) on a
-    survey-geometry pass: 96 subbands, narrow per-pass DM span."""
-    rng = np.random.default_rng(21)
-    nsub, T = 96, 8192
-    subb = rng.standard_normal((nsub, T)).astype(np.float32)
-    freqs = np.linspace(1214.0, 1536.0, 10 * nsub)
-    dms = 100.0 + np.arange(76) * 0.1     # survey step-0 span
-    _, sub_sh = dd.plan_pass_shifts(freqs, nsub, 100.0, dms,
-                                    65.476e-6, 1)
-    plan = dd.build_tree_plan(sub_sh)
-    assert plan is not None
-    assert plan.patterns.shape[1] <= dd.TREE_MAX_PATTERNS
-    got = dd.dedisperse_subbands_tree(jnp.asarray(subb), sub_sh)
-    want = dd._dedisperse_subbands_xla(jnp.asarray(subb), sub_sh)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-6, atol=2e-5)
-
-
-def test_tree_stage2_edge_clamp_and_fallback():
-    rng = np.random.default_rng(22)
-    subb = rng.standard_normal((16, 512)).astype(np.float32)
-    # shifts large enough to hit the edge-replicated tail
-    sh = (np.arange(5)[:, None] * np.linspace(0, 90, 16)[None, :]
-          ).astype(np.int32)
-    got = dd.dedisperse_subbands_tree(jnp.asarray(subb), sh, m=4)
-    assert got is not None
-    want = dd._dedisperse_subbands_xla(jnp.asarray(subb), sh)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-6, atol=2e-5)
-    # inapplicable shapes return None (caller falls back)
-    assert dd.dedisperse_subbands_tree(
-        jnp.asarray(subb[:3]), sh[:, :3], m=4) is None
-    # a pattern explosion returns None too
-    wild = rng.integers(0, 400, size=(80, 16)).astype(np.int32)
-    assert dd.dedisperse_subbands_tree(
-        jnp.asarray(subb), wild, m=4) is None
-
-
 def _sequential_sum(subb, shifts):
     """out[d, t] = sum_s subb[s, min(t + shift[d, s], T-1)], float32
     additions in subband order from zero: what the stage-2 kernel must

@@ -506,6 +506,60 @@ def test_rescue_off_zero_fills_and_flags(small_spectra, perdm_path,
     degraded.reset()
 
 
+@pytest.mark.parametrize("persistent", [False, True])
+def test_refused_stacked_hi_accel_degrades_per_beam(
+        small_spectra, perdm_path, monkeypatch, persistent):
+    """A group's stacked hi-accel dispatch that the runtime refuses is
+    split: each beam's rows go down the one-beam ladder alone.  A
+    refusal that ends with the stacked call costs nothing (each beam's
+    candidates are those of a clean call on its rows); one that
+    persists costs each beam its own chunk, loudly, in the ledger."""
+    import jax.numpy as jnp
+
+    from tpulsar.kernels import accel as ak
+    from tpulsar.search import degraded, executor
+    spec, bank = small_spectra           # 6 rows: 2 beams x 3 DMs
+    monkeypatch.setitem(executor._BANK_CACHE, 8, bank)
+    monkeypatch.setenv("TPULSAR_HOST_RESCUE", "0")
+    params = executor.SearchParams(hi_accel_zmax=8, hi_accel_numharm=4,
+                                   topk_per_stage=8)
+    params.sifting.sigma_threshold = 1.0
+    wspec, dms, T_s = jnp.asarray(spec), np.array([10., 12., 14.]), 2.0
+
+    def ident(per_beam):
+        return [[(c.r, c.z, c.power, c.numharm, c.dm) for c in cands]
+                for cands in per_beam]
+
+    clean = ident([executor._hi_accel_chunk(
+        wspec[b * 3:(b + 1) * 3], dms, 1, T_s, params)[0]
+        for b in range(2)])
+    assert clean[0] and clean[1] and clean[0] != clean[1]
+    assert ident(executor._hi_accel_chunk(wspec, dms, 2, T_s,
+                                          params)) == clean
+
+    # what the stacked call spends before it gives up
+    faults.configure("accel.row_dispatch:unimplemented:rate=1.0")
+    with pytest.raises(ak.AccelStageRefused):
+        _accel_run(spec, bank)
+    spent = faults.fired("accel.row_dispatch")
+    assert spent > 0
+    faults.configure("accel.row_dispatch:unimplemented:"
+                     + ("rate=1.0" if persistent else f"count={spent}"))
+    degraded.reset()
+    if persistent:
+        with pytest.warns(UserWarning, match="hi-accel chunk skipped"):
+            out = executor._hi_accel_chunk(wspec, dms, 2, T_s, params)
+        assert out == [[], []]
+        lost = degraded.snapshot()["accel_hi_chunk_skipped"]
+        assert lost.startswith("6/6 across 2 call(s)"), lost
+    else:
+        out = executor._hi_accel_chunk(wspec, dms, 2, T_s, params)
+        assert faults.fired("accel.row_dispatch") == spent
+        assert ident(out) == clean
+        assert "accel_hi_chunk_skipped" not in degraded.snapshot()
+    degraded.reset()
+
+
 # ------------------------------------- dedisperse fault point (CPU)
 
 def test_dedisperse_pallas_fault_falls_back():

@@ -168,6 +168,78 @@ def test_a_checkpointed_pass_has_its_checkpoint_span(toy_search):
     assert all(c["args"]["durable"] is False for c in cks)
 
 
+def test_a_groups_tree_is_the_solos_with_nbeams(tmp_path, monkeypatch):
+    """Two beams through search_beam_batch: the one pass loop's tree
+    under the group's root — pass > dm_chunk > stages, the pass end's
+    host halves and checkpoint once per beam — with nbeams on pass and
+    dm_chunk, and no span of the group's own besides the root."""
+    from tpulsar.io import synth
+    from tpulsar.plan import ddplan
+    from tpulsar.search import executor
+
+    plan = [ddplan.DedispStep(lodm=40.0, dmstep=2.0, dms_per_pass=10,
+                              numpasses=2, numsub=16, downsamp=1)]
+    monkeypatch.setattr(executor.ddplan, "plan_for",
+                        lambda si, **kw: (plan, None, 16))
+    params = executor.SearchParams(
+        nsub=16, hi_accel_zmax=8, topk_per_stage=8, max_dms_per_chunk=5,
+        max_cands_to_fold=1, make_plots=False)
+    psr = synth.PulsarSpec(period_s=0.15, dm=60.0, snr_per_sample=0.6,
+                           width_frac=0.05)
+    specs = []
+    for i in range(2):
+        fns = synth.synth_beam(
+            str(tmp_path / f"beam{i}"),
+            synth.BeamSpec(nchan=32, nsamp=1 << 13, nbits=4,
+                           tsamp_s=5.24288e-4, scan=300 + i),
+            pulsars=[psr], merged=True)
+        specs.append(executor.BeamSpec(
+            fns=fns, workdir=str(tmp_path / f"w{i}"),
+            resultsdir=str(tmp_path / f"r{i}"), baryv=0.0,
+            checkpoint_dir=str(tmp_path / f"ck{i}")))
+    trace.start()
+    res = executor.search_beam_batch(specs, params)
+    events = trace.events()
+    assert [r.path for r in res] == ["batched", "batched"], \
+        [(r.fallout, r.error) for r in res]
+
+    (root,) = _spans(events, "search_beam_batch")
+    assert root["args"]["nbeams"] == 2 and root["args"]["npasses"] == 2
+    kids = _children(events, root)
+    assert [k["name"] for k in kids] == ["pass", "pass", "finish",
+                                         "finish"]
+    assert {e["name"] for e in _spans(events)}.isdisjoint(
+        {"beam_batch_chunk", "search_block"})
+    for p in kids[:2]:
+        assert p["args"]["nbeams"] == 2 and p["args"]["ntrials"] == 10
+        under = _children(events, p)
+        chunks = [k for k in under if k["name"] == "dm_chunk"]
+        assert [(c["args"]["n"], c["args"]["nbeams"], c["args"]["family"])
+                for c in chunks] == [(5, 2, "direct")] * 2
+        assert {"hi_rows", "dd_calls", "dd_rows", "lo", "pass_idx"} \
+            <= set(chunks[0]["args"])
+        for c in chunks:
+            stages = {k["name"]: k for k in _children(events, c)}
+            assert set(stages) == {"dedispersing", "single-pulse", "FFT",
+                                   "lo-accelsearch", "hi-accelsearch"}
+            hi = _children(events, stages["hi-accelsearch"])
+            # one stacked dispatch of 2 x 5 rows, candidates per beam
+            assert [k["args"]["rows"] for k in hi
+                    if k["name"] == "accel-dispatch"] == [10]
+            assert [k["name"] for k in hi].count("accel-candidates") == 2
+        # pass end: both host halves once per (chunk, beam), one
+        # checkpoint per beam
+        names = [k["name"] for k in under]
+        assert names.count("pass-checkpoint") == 2
+        assert names.count("single-pulse") == names.count(
+            "lo-accelsearch") == 4
+        inner = [c["name"] for k in under
+                 if k["name"] in ("single-pulse", "lo-accelsearch")
+                 for c in _children(events, k)]
+        assert inner.count("sp-events") == inner.count(
+            "lo-candidates") == 4
+
+
 def test_stage_timers_totals_equal_the_trace_rollup(two_calls):
     events, timers = two_calls
     roll = trace.rollup(events)

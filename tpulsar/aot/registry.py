@@ -127,7 +127,6 @@ PROGRAMS: tuple[Program, ...] = (
     _k("dedisperse", "_dedisperse_subbands_scan", ("pad",),
        doc="stage-2 XLA-scan dedispersion over DM trials"),
     _k("dedisperse", "dedisperse_window_scan", ("out_len",)),
-    _k("dedisperse", "_dedisperse_tree", ("m", "pad1", "pad2")),
     # ---- kernels/tree_dd.py (the log-depth shift-tree family)
     _k("tree_dd", "_tree_levels_jit", ("moffs", "pad"),
        doc="shared merge levels of a tree pass — run once, reused by "
@@ -201,19 +200,6 @@ PROGRAMS: tuple[Program, ...] = (
            "persistent-cache key (see module docstring)"),
     _k("accel", "accel_row_topk",
        ("seg", "step", "width", "nz", "max_numharm", "topk")),
-    # ---- kernels/beam_batch.py (batch-of-beams; lazy factory so the
-    # host planner imports without touching a backend)
-    Program(
-        name="beam_batch.dd_beams_scan",
-        module="tpulsar.kernels.beam_batch",
-        attr="_get_dd_beams_scan",
-        site="tpulsar/kernels/beam_batch.py::_get_dd_beams_scan",
-        statics=("pad",),
-        factory=True,
-        doc="coalesced stage-2 dedispersion: the solo scan with a "
-            "leading beam axis (bit-equal per beam); beam-group "
-            "sizes ride the shared BATCH_QUANTA ladder so the "
-            "signature set stays bounded"),
     # ---- search/refine.py (lazy factory: the module imports jax-free)
     Program(
         name="refine.gather",
@@ -392,12 +378,11 @@ def _beam_batch_groups(ctx: GateContext
                        ) -> list[tuple[str, list[Instance]]]:
     """The batch-of-beams coalesced signatures an ``nbeams``-wide
     admission batch dispatches: beam-group sizes from the SAME
-    plan_beam_groups ladder decomposition the executor runs, stage
-    1/2 with the beam axis folded in (stage 1 = the registered
-    _form_subbands_jit at nsub' = B*nsub; stage 2 = the
-    beam_batch scan program), and the row-batched spectral stages at
-    B x chunk rows — the gate-vs-runtime lockstep discipline, one
-    axis up."""
+    plan_beam_groups ladder decomposition the executor runs, and the
+    row-batched spectral stages at B x chunk rows — the
+    gate-vs-runtime lockstep discipline, one axis up.  Stage 1/2 run
+    per beam with the solo programs (the pass loop's rule), so their
+    instances are the solo groups' above."""
     import jax.numpy as jnp
 
     from tpulsar.kernels import beam_batch as bb
@@ -410,31 +395,13 @@ def _beam_batch_groups(ctx: GateContext
     groups: list[tuple[str, list[Instance]]] = []
     geoms = step_geometries(ctx)
     for B in rungs:
-        blk = _sds((B * NCHAN, ctx.nsamp), ctx.blk_dtype)
         insts: list[Instance] = []
-        for step, T_ds, ndms, pad_pairs, nfft, chunk in geoms:
+        for step, T_ds, ndms, _pads, nfft, chunk in geoms:
             nbins = nfft // 2 + 1
-            for pad1, pad2 in sorted(pad_pairs):
-                insts += [
-                    Instance("dedisperse._form_subbands_jit",
-                             f"bb_form_subbands B={B} "
-                             f"ds={step.downsamp} pad={pad1}",
-                             (blk, _sds((B * NCHAN,), jnp.int32)),
-                             dict(nsub=B * step.numsub,
-                                  downsamp=step.downsamp, pad=pad1)),
-                ]
             sizes = [min(chunk, ndms)]
             if chunk < ndms and ndms % chunk:
                 sizes.append(ndms % chunk)
             for rows in sizes:
-                for pad1, pad2 in sorted(pad_pairs):
-                    insts.append(Instance(
-                        "beam_batch.dd_beams_scan",
-                        f"bb_dd_scan B={B} ds={step.downsamp} "
-                        f"rows={rows} pad={pad2}",
-                        (_sds((B, step.numsub, T_ds), jnp.float32),
-                         _sds((rows, step.numsub), jnp.int32)),
-                        dict(pad=pad2)))
                 sers = _sds((B * rows, T_ds), jnp.float32)
                 tag = f"B={B} ds={step.downsamp} rows={rows}"
                 insts += [

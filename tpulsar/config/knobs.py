@@ -135,9 +135,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
     _k("TPULSAR_DD_FAMILY", "enum(auto|direct|tree)", "auto",
        "stage-2 dedispersion kernel family; auto = the per-pass "
        "cost-model dispatch"),
-    _k("TPULSAR_DD_TREE", "enum(1)", "off",
-       "1 forces the tree family regardless of the cost model "
-       "(the A/B and parity-test pin)"),
     _k("TPULSAR_FAULTS", "spec", "unset",
        "deterministic fault-injection spec: point:mode[:k=v,..] "
        "(';'-separated); unknown points/modes fail loudly at parse"),

@@ -1,5 +1,5 @@
-"""Batch-of-beams: the host planner + coalesced device programs for
-searching B compatible beams through one dispatch stream.
+"""Batch-of-beams: the host planner for searching B compatible beams
+through one dispatch stream.
 
 PR 13's ``accel_batch`` planner proved the repo's recipe for batching
 one axis of the search: quantized batch rungs so compile signatures
@@ -10,31 +10,20 @@ regime (FAST parallel-PRESTO scale: thousands of small beams/day)
 where per-dispatch overhead, not per-beam compute, dominates the
 wall clock.
 
-The load-bearing design decision is HOW the beam axis rides the
-device programs.  The acceptance contract is *exact* per-beam
-candidate parity and *byte-identical* checkpoint artifacts whether a
-beam ran batched or solo, so the beam axis is realized as structures
-whose per-beam float arithmetic is IDENTICAL to the solo path — not
-a generic ``vmap`` whose reduction order XLA may re-associate:
+The acceptance contract is *exact* per-beam candidate parity and
+*byte-identical* checkpoint artifacts whether a beam ran batched or
+solo, which decides how the beam axis rides the device programs (the
+executor's one pass loop, ``search/executor.py::_plan_loop``):
 
-  * stage-1 subbanding folds the beam axis into the SUBBAND axis:
-    B beams' channel blocks stack to ``(B*nchan, T)`` and the
-    existing ``_form_subbands_jit`` program runs with ``nsub' =
-    B*nsub`` — each output subband sums exactly the same channels in
-    exactly the same order as the solo call (the per-group compute
-    graph is shape-identical), so the coalesced subbands are
-    bit-equal to B solo calls;
-  * stage-2 dedispersion runs :func:`_dd_beams_scan` — the solo
-    ``_dedisperse_subbands_scan`` with one leading beam axis on the
-    accumulator.  The scan's sequential accumulation order (the only
-    float summation) is preserved per (beam, trial, sample), so the
-    output is bit-equal to B solo scans;
+  * stage 1 (subbanding) and stage 2 (dedispersion, the tree's levels
+    included) run PER BEAM with the solo programs — the only form
+    whose per-beam float arithmetic is the solo path's on every
+    platform;
   * the spectral stages (fused SP detrend/boxcar, FFT/whiten, lo
-    harmonic stages, the batched FDAS) are already row-independent
-    per DM trial — the executor simply hands them ``B*chunk`` rows
-    (beam-major) instead of ``chunk``, the exact trick
-    ``accel_batch`` uses for DM rows, with per-beam slices bit-equal
-    by construction.
+    harmonic stages, the batched FDAS) are row-independent per DM
+    trial — the loop hands them ``B*chunk`` rows (beam-major) instead
+    of ``chunk``, the exact trick ``accel_batch`` uses for DM rows,
+    with per-beam slices bit-equal by construction.
 
 Signature discipline: coalesced row counts are ``B * chunk`` where
 ``chunk`` is the SOLO pass chunk size (chunk boundaries must match
@@ -46,14 +35,12 @@ BATCH_QUANTA` ladder: a fleet batching 5 beams dispatches groups of
 
 Per-beam degradation: a beam that cannot ride the batch (checkpoint
 resume state, incompatible geometry, a poisoned input, or any failure
-inside the coalesced section) FALLS OUT to the proven single-beam
-path — it never fails its batchmates, and its solo results are
-byte-identical to the batched ones it would have produced.  That
-rule lives in the executor (search_beam_batch); this module only
-plans and dispatches.
+inside the coalesced section) FALLS OUT to the single-beam call — it
+never fails its batchmates, and its solo results are byte-identical
+to the batched ones it would have produced.  That rule lives in the
+executor (search_beam_batch); this module only plans.
 
-Planning is pure host arithmetic (no jax import at module top level
-beyond the jitted programs' own lazy use), mirrored by the AOT
+Planning is pure host arithmetic (no jax import), mirrored by the AOT
 registry's shape-builders so the gate compiles the exact coalesced
 signatures a batched run dispatches.
 """
@@ -63,7 +50,7 @@ from __future__ import annotations
 import dataclasses
 import os
 
-from tpulsar.kernels.accel_batch import BATCH_QUANTA, quantize_batch
+from tpulsar.kernels.accel_batch import quantize_batch
 
 #: default coalesced working-set budget (bytes) the beam planner
 #: sizes B against — the beam-batch analogue of SearchParams.
@@ -109,20 +96,6 @@ def beam_budget_bytes() -> int:
         raise ValueError(
             f"TPULSAR_BEAM_BATCH_BYTES must be > 0, got {val}")
     return val
-
-
-def coalesce_dd_ok() -> bool:
-    """May stage 1/2 run beam-coalesced with bit-parity to the solo
-    path?  Only the XLA formulations are beam-foldable (their per-beam
-    compute graphs are shape-identical to the solo calls); a solo path
-    that would route to the Pallas kernels (TPU) or the opt-in
-    two-level tree must run stage 1/2 PER BEAM — the spectral stages
-    still coalesce either way."""
-    if os.environ.get("TPULSAR_DD_TREE", "0") == "1":
-        return False
-    from tpulsar.kernels import pallas_dd
-
-    return not (pallas_dd.use_pallas() or pallas_dd.use_pallas_sb())
 
 
 # ------------------------------------------------------------- compat key
@@ -219,94 +192,3 @@ def budget_beams(block_bytes: int, chunk_rows: int, nfft: int,
     per_beam = (3 * max(1, block_bytes)    # block + subbands + series
                 + 2 * chunk_rows * per_trial)
     return max(1, int(budget // max(1, per_beam)))
-
-
-# ------------------------------------------------------- device programs
-
-def stack_blocks(blocks) -> "object":
-    """Concatenate B beams' (nchan, T) device blocks into the
-    (B*nchan, T) stage-1 input (beam-major rows)."""
-    import jax.numpy as jnp
-
-    return jnp.concatenate(list(blocks), axis=0)
-
-
-def form_subbands_beams(stacked, chan_shifts, nbeams: int, nsub: int,
-                        downsamp: int):
-    """Coalesced stage 1: (B*nchan, T) -> (B*nsub, T') by folding the
-    beam axis into the subband axis — the tiled shift table repeats
-    the per-channel shifts per beam, and each output subband group
-    sums exactly one beam's channels (bit-equal to the solo call)."""
-    import numpy as np
-
-    from tpulsar.kernels import dedisperse as dd
-
-    tiled = np.tile(np.asarray(chan_shifts), nbeams)
-    return dd.form_subbands(stacked, tiled, nbeams * nsub, downsamp)
-
-
-def _dd_beams_scan_impl(subbands, sub_shifts, pad: int):
-    """The solo ``_dedisperse_subbands_scan`` with one leading beam
-    axis: subbands (B, nsub, T), shifts (ndms, nsub) shared across
-    beams -> (B, ndms, T).  The scan's sequential accumulation order
-    is unchanged per (beam, trial), so every beam's series is
-    bit-equal to its solo scan."""
-    import jax
-    import jax.numpy as jnp
-
-    from tpulsar.kernels import dedisperse as dd
-
-    B, nsub, T = subbands.shape
-    padded = jax.vmap(lambda rows: dd._edge_pad(rows, pad))(subbands)
-    starts = jnp.minimum(sub_shifts.astype(jnp.int32), pad)
-
-    def body(acc, inp):
-        rows, s = inp            # rows (B, L), s (ndms,)
-        sl = jax.vmap(lambda st: jax.lax.dynamic_slice_in_dim(
-            rows, st, T, axis=1))(s)            # (ndms, B, T)
-        return acc + sl, None
-
-    acc0 = jnp.zeros((starts.shape[0], B, T), jnp.float32)
-    acc, _ = jax.lax.scan(body, acc0,
-                          (padded.transpose(1, 0, 2), starts.T))
-    return acc.transpose(1, 0, 2)               # (B, ndms, T)
-
-
-_dd_beams_scan = None
-
-
-def _get_dd_beams_scan():
-    """The jitted coalesced stage-2 program (lazy so importing the
-    planner never touches a backend); module-level cache keeps ONE
-    jit wrapper so the persistent-cache key is stable (the registry
-    resolves this exact object)."""
-    global _dd_beams_scan
-    if _dd_beams_scan is None:
-        import jax
-        _dd_beams_scan = jax.jit(_dd_beams_scan_impl,
-                                 static_argnames=("pad",))
-    return _dd_beams_scan
-
-
-def dedisperse_beams(subb_stacked, sub_shifts, nbeams: int):
-    """Coalesced stage 2: (B*nsub, T') subbands + one (ndms, nsub)
-    shift table -> (B*ndms, T') beam-major DM series, bit-equal per
-    beam to ``dedisperse_subbands`` on that beam's subbands alone.
-    ``sub_shifts`` must be concrete (pad derives from its max, the
-    same bucketing as the solo path)."""
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    from tpulsar.kernels import dedisperse as dd
-
-    shifts_np = np.asarray(sub_shifts)
-    pad = dd._pad_bucket(int(shifts_np.max(initial=0)))
-    nsub_total, T = subb_stacked.shape
-    if nsub_total % nbeams:
-        raise ValueError(
-            f"stacked subband rows {nsub_total} not divisible by "
-            f"nbeams {nbeams}")
-    sub3 = subb_stacked.reshape(nbeams, nsub_total // nbeams, T)
-    out = _get_dd_beams_scan()(sub3, jnp.asarray(shifts_np), pad)
-    return out.reshape(nbeams * shifts_np.shape[0], T)

@@ -19,8 +19,6 @@ matching the convention the synthesizer and oracle use.
 
 from __future__ import annotations
 
-import dataclasses
-import os
 from functools import partial
 
 import jax
@@ -240,24 +238,6 @@ def dedisperse_subbands(subbands: jnp.ndarray,
     """
     from tpulsar.kernels import pallas_dd
 
-    # TPULSAR_DD_TREE=1 opts into the two-level shift-pattern tree:
-    # same terms as the flat scan, group-first summation order
-    # (~1 ulp differences), ~nsub/G times less accumulator traffic.
-    # The explicit opt-in takes precedence over the Pallas path — its
-    # purpose is the on-chip A/B, which measuring Pallas vs Pallas
-    # would silently defeat.  Off by default until that A/B confirms
-    # the win (flipping it reorders float sums, so the golden
-    # candidate lists would have to be regenerated).
-    if os.environ.get("TPULSAR_DD_TREE", "0") == "1":
-        out = dedisperse_subbands_tree(subbands, sub_shifts)
-        if out is not None:
-            return out
-        import warnings
-        warnings.warn(
-            "TPULSAR_DD_TREE=1 but the tree declined this pass "
-            "(pattern explosion or partial-tensor budget); using the "
-            "standard stage-2 path", stacklevel=2)
-
     from tpulsar.resilience import faults
 
     sig = (tuple(subbands.shape), tuple(np.asarray(sub_shifts).shape))
@@ -301,137 +281,6 @@ def dedisperse_subbands(subbands: jnp.ndarray,
         degraded.note("pallas_dd_disabled",
                       "TPULSAR_PALLAS=0; XLA scan path")
     return _dedisperse_subbands_xla(subbands, sub_shifts)
-
-
-# ---------------------------------------------------- two-level tree stage 2
-
-#: fall back to the flat scan when a pass needs more distinct
-#: relative-shift patterns per group than this (non-survey plans with
-#: huge per-pass DM spans)
-TREE_MAX_PATTERNS = 64
-
-
-@dataclasses.dataclass(frozen=True)
-class TreePlan:
-    """Host-side plan for the two-level shift-pattern tree.
-
-    Within one dedispersion pass the DM span is small, so the vector
-    of RELATIVE shifts inside a group of `m` adjacent subbands,
-    rel[d, s] = shift[d, s] - shift[d, s_ref(g)], takes only a
-    handful of distinct values across the pass's DM trials.  Level 1
-    computes each group's partial sum once per distinct pattern;
-    level 2 combines G partials per trial instead of nsub subbands —
-    the composed index is exactly shift[d, s] (same terms as the flat
-    shift-and-sum, group-first summation order, so float results
-    agree to ~1 ulp) with ~nsub/G times less accumulator traffic.
-    """
-    m: int                    # subbands per group
-    patterns: np.ndarray      # (G, K, m) int32 relative shifts
-    pidx: np.ndarray          # (ndms, G) int32 pattern index
-    shift2: np.ndarray        # (ndms, G) int32 group reference shift
-    pad1: int                 # bucketed max relative shift
-    pad2: int                 # bucketed max group shift
-
-
-def build_tree_plan(sub_shifts, m: int = 8) -> TreePlan | None:
-    """Group the (ndms, nsub) stage-2 shift table for the tree; None
-    when the tree does not apply (nsub not divisible by m, or too
-    many distinct patterns in some group)."""
-    sh = np.asarray(sub_shifts, np.int32)
-    ndms, nsub = sh.shape
-    if nsub % m or nsub <= m:
-        return None
-    G = nsub // m
-    grouped = sh.reshape(ndms, G, m)
-    # reference = min shift in the group per trial (keeps rel >= 0
-    # regardless of channel ordering)
-    ref = grouped.min(axis=2)                       # (ndms, G)
-    rel = grouped - ref[:, :, None]                 # (ndms, G, m)
-    patterns = []
-    pidx = np.empty((ndms, G), np.int32)
-    kmax = 0
-    for g in range(G):
-        uniq, inv = np.unique(rel[:, g, :], axis=0,
-                              return_inverse=True)
-        if len(uniq) > TREE_MAX_PATTERNS:
-            return None
-        patterns.append(uniq)
-        pidx[:, g] = inv.astype(np.int32)
-        kmax = max(kmax, len(uniq))
-    K = max(1, 1 << int(np.ceil(np.log2(kmax))))
-    pat = np.zeros((G, K, m), np.int32)
-    for g, uniq in enumerate(patterns):
-        pat[g, : len(uniq)] = uniq
-        pat[g, len(uniq):] = uniq[-1]               # harmless repeats
-    return TreePlan(
-        m=m, patterns=pat, pidx=pidx, shift2=ref.astype(np.int32),
-        pad1=_pad_bucket(int(pat.max(initial=0))),
-        pad2=_pad_bucket(int(ref.max(initial=0))))
-
-
-@partial(jax.jit, static_argnames=("m", "pad1", "pad2"))
-def _dedisperse_tree(subbands: jnp.ndarray, patterns: jnp.ndarray,
-                     pidx: jnp.ndarray, shift2: jnp.ndarray,
-                     m: int, pad1: int, pad2: int) -> jnp.ndarray:
-    """Two-level tree (see TreePlan).  All shifts compose on an
-    edge-padded copy, so no clamping is ever needed; the output sums
-    exactly the same terms as _dedisperse_subbands_scan but in
-    group-first order, so results agree only up to float summation
-    order (~1 ulp — golden candidate lists must be regenerated if
-    this becomes the default path)."""
-    nsub, T = subbands.shape
-    G = nsub // m
-    grouped = _edge_pad(subbands, pad1 + pad2).reshape(
-        G, m, T + pad1 + pad2)
-
-    # level 1: per-group partials at each distinct relative pattern
-    def one_group(args):
-        rows, pats = args     # (m, T+pad1+pad2), (K, m)
-        return dedisperse_window_scan(rows, pats, T + pad2)
-
-    partials = jax.lax.map(one_group, (grouped, patterns))
-    # (G, K, T+pad2)
-
-    # level 2: per-trial gather of each group's pattern at the group
-    # reference shift
-    def body(acc, inp):
-        part, pi, s2 = inp    # (K, T+pad2), (ndms,), (ndms,)
-        sl = jax.vmap(
-            lambda k, st: jax.lax.dynamic_slice(part, (k, st),
-                                                (1, T))[0]
-        )(pi, s2)
-        return acc + sl, None
-
-    acc0 = jnp.zeros((pidx.shape[0], T), jnp.float32)
-    acc, _ = jax.lax.scan(
-        body, acc0,
-        (partials, pidx.T.astype(jnp.int32),
-         jnp.minimum(shift2.T.astype(jnp.int32), pad2)))
-    return acc
-
-
-#: level-1 partial tensor budget: the tree declines (returns None)
-#: when (G, K, T+pad2) float32 would exceed this
-TREE_PARTIAL_BUDGET = 2 << 30
-
-
-def dedisperse_subbands_tree(subbands: jnp.ndarray, sub_shifts,
-                             m: int = 8) -> jnp.ndarray | None:
-    """Tree-structured stage 2; None when the tree does not apply
-    (pattern explosion, indivisible groups, or a level-1 partial
-    tensor beyond TREE_PARTIAL_BUDGET — full-length survey passes
-    need time tiling before the tree can take them; caller falls
-    back to the flat scan)."""
-    plan = build_tree_plan(sub_shifts, m=m)
-    if plan is None:
-        return None
-    nsub, T = subbands.shape
-    G, K = plan.patterns.shape[0], plan.patterns.shape[1]
-    if G * K * (T + plan.pad2) * 4 > TREE_PARTIAL_BUDGET:
-        return None
-    return _dedisperse_tree(
-        subbands, jnp.asarray(plan.patterns), jnp.asarray(plan.pidx),
-        jnp.asarray(plan.shift2), plan.m, plan.pad1, plan.pad2)
 
 
 def subband_reference_freqs(freqs_mhz: np.ndarray, nsub: int) -> np.ndarray:

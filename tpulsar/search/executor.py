@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import tarfile
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -488,12 +489,12 @@ def search_beam_batch(specs: list[BeamSpec],
                       params: SearchParams | None = None,
                       cap: int = 0,
                       progress_cb=None) -> list[BeamBatchResult]:
-    """Search B beams, coalescing compatibility-keyed groups into one
-    dispatch stream (kernels/beam_batch.py): RFI-masked subbanding and
-    dedispersion run with a folded beam axis, and the spectral stages
-    (fused SP detrend, FFT/whiten, lo harmonic stages, the batched
-    FDAS) see ``B x chunk`` beam-major rows per dispatch — the
-    accel_batch recipe one axis up.
+    """Search B beams, coalescing compatibility-keyed groups
+    (kernels/beam_batch.py plans them) into one run of the pass loop:
+    subbanding and dedispersion run per beam with the solo programs,
+    and the spectral stages (fused SP detrend, FFT/whiten, lo harmonic
+    stages, the batched FDAS) see ``B x chunk`` beam-major rows per
+    dispatch — the accel_batch recipe one axis up.
 
     Per-beam results discipline is preserved: every beam keeps its own
     results directory, checkpoint store (pass artifacts sliced out of
@@ -626,8 +627,6 @@ def _search_group(entries: list[dict], params: SearchParams,
     the zaplist/baryv-derived keep mask, the checkpoint store, and
     everything after the plan loop (sift/refine/fold/artifacts) —
     which runs through the exact helpers the solo path runs."""
-    from tpulsar.kernels import beam_batch as bb
-
     B = len(entries)
     specs = [e["spec"] for e in entries]
     pres = [e["pre"] for e in entries]
@@ -640,7 +639,7 @@ def _search_group(entries: list[dict], params: SearchParams,
     metrics_base = telemetry.metrics.REGISTRY.snapshot()
     timers = StageTimers()
 
-    stores, datas, masks = [], [], []
+    beams, masks = [], []
     for spec, pre in zip(specs, pres):
         obj, si, basenm, _plan, _nsub, baryv, data_id = pre
         os.makedirs(spec.workdir, exist_ok=True)
@@ -654,17 +653,16 @@ def _search_group(entries: list[dict], params: SearchParams,
                 spec.checkpoint_journal)
         data, mask = _read_and_mask(si, params, basenm,
                                     spec.resultsdir, store, timers)
-        stores.append(store)
-        datas.append(data)
+        beams.append(_Beam(data, spec.zaplist, baryv, store))
         masks.append(mask)
 
     telemetry.beam_batch_occupancy().set(B)
     with trace_mod.span("search_beam_batch", nbeams=B,
                         npasses=sum(s.numpasses for s in plan)):
-        per = _group_plan_loop(datas, freqs, dt, plan, params,
-                               [s.zaplist for s in specs],
-                               [p[5] for p in pres], nsub, timers,
-                               stores, progress_cb)
+        _plan_loop(beams, freqs, dt, plan, params, nsub, timers,
+                   progress_cb)
+        telemetry.beam_batch_trials_total().inc(
+            sum(b.ntrials for b in beams), path="batched")
 
         # per-beam attribution past this point: the plan loop's delta
         # is SHARED (one coalesced dispatch stream served the whole
@@ -675,272 +673,19 @@ def _search_group(entries: list[dict], params: SearchParams,
         group_delta = telemetry.metrics.diff_snapshots(
             telemetry.metrics.REGISTRY.snapshot(), metrics_base)
         outcomes = []
-        for b, (spec, pre) in enumerate(zip(specs, pres)):
+        for beam, mask, spec, pre in zip(beams, masks, specs, pres):
             obj, si, basenm, _plan, _nsub, baryv, _id = pre
             finish_base = telemetry.metrics.REGISTRY.snapshot()
             timers_b = StageTimers()
             timers_b.times = dict(timers.times)
             final, folded, sp_events, num_trials = _sift_fold_finish(
-                datas[b], freqs, dt, params, spec.zaplist, baryv,
-                nsub, timers_b, stores[b], per[b]["cands"],
-                per[b]["sp"], per[b]["ntr"], None, plan)
+                beam, freqs, dt, params, nsub, timers_b, None, plan)
             outcomes.append(_finalize_results(
                 spec.resultsdir, basenm, obj, si, plan, params,
-                spec.zaplist, baryv, datas[b], masks[b], final,
+                spec.zaplist, baryv, beam.data, mask, final,
                 folded, sp_events, num_trials, timers_b, finish_base,
                 metrics_extra=group_delta))
     return outcomes
-
-
-def _group_plan_loop(datas, freqs, dt, plan, params, zaplists, baryvs,
-                     nsub, timers, stores, progress_cb):
-    """The coalesced plan loop: every pass's stage 1/2 carries a
-    folded beam axis (XLA path) or runs per beam (tree/Pallas solo
-    formulations — bit-parity bounds what may coalesce), and the
-    spectral stages always see B*chunk beam-major rows.  Chunk
-    boundaries are the SOLO pass_chunk_size, so per-beam candidate
-    ordering — and therefore the per-pass checkpoint artifacts sliced
-    out at the end of each pass — are byte-identical to a solo run."""
-    from tpulsar.kernels import beam_batch as bb
-
-    B = len(datas)
-    per = [{"cands": [], "sp": [], "ntr": 0} for _ in range(B)]
-    npasses = sum(s.numpasses for s in plan)
-    pass_idx = -1
-    coalesce_dd = bb.coalesce_dd_ok()
-    hi = params.run_hi_accel and params.hi_accel_zmax > 0
-    sp_est = sp_k.detrend_estimator(params.sp_detrend)
-
-    for step_idx, step in enumerate(plan):
-        for ppass in step.passes():
-            pass_idx += 1
-            starts = [(len(per[b]["cands"]), len(per[b]["sp"]),
-                       per[b]["ntr"]) for b in range(B)]
-            dms = np.asarray(ppass.dms)
-            with timers.timing("subbanding"):
-                chan_shifts, sub_shifts = dd.plan_pass_shifts(
-                    freqs, nsub, ppass.subdm, dms, dt, step.downsamp)
-                if coalesce_dd:
-                    subb_all = bb.form_subbands_beams(
-                        bb.stack_blocks(datas), chan_shifts, B, nsub,
-                        step.downsamp)           # (B*nsub, T')
-                    subs = None
-                    T_ds = int(subb_all.shape[1])
-                else:
-                    subs = [dd.form_subbands(d,
-                                             jnp.asarray(chan_shifts),
-                                             nsub, step.downsamp)
-                            for d in datas]
-                    subb_all = None
-                    T_ds = int(subs[0].shape[1])
-            dt_ds = dt * step.downsamp
-            chunk_sz = pass_chunk_size(len(dms), ddplan.choose_n(T_ds),
-                                       params)
-            tree_plan = tree_dd.plan_for_pass(sub_shifts, T=T_ds)
-            tree_parts = None
-            if tree_plan is not None:
-                # per-beam levels: the exact solo programs, so the
-                # tree family's summation order (the parity contract)
-                # is untouched — only the residual outputs coalesce
-                if subs is None:
-                    subs = [subb_all[b * nsub:(b + 1) * nsub]
-                            for b in range(B)]
-                with timers.timing("dedispersing"):
-                    tree_parts = [tree_dd.tree_levels(s, tree_plan)
-                                  for s in subs]
-                    trace_mod.fence(tree_parts)
-                telemetry.dedisp_tree_depth().set(tree_plan.depth)
-                telemetry.dedisp_residual_fraction().set(
-                    round(tree_plan.residual_fraction, 4))
-
-            # per-beam keep masks for this pass's spectrum length
-            nfft = ddplan.choose_n(T_ds)
-            nbins = nfft // 2 + 1
-            T_s = nfft * dt_ds
-            keeps = None
-            if any(z is not None for z in zaplists):
-                keeps = [fr.zap_mask(nbins, T_s, z, bv)
-                         if z is not None else np.ones(nbins, bool)
-                         for z, bv in zip(zaplists, baryvs)]
-
-            pending: list[tuple] = []
-            for lo in range(0, len(dms), chunk_sz):
-                if len(pending) >= 2:
-                    # same two-chunks-in-flight bound as the solo
-                    # loop: block on the chunk-before-last's LO
-                    # output — the last consumer of its wspec — not
-                    # the earlier SP pair, or 3+ coalesced chunks'
-                    # B-wide series/wspec could be enqueued at once
-                    with timers.timing("pipeline-wait"):
-                        jax.block_until_ready(pending[-2][4])
-                dm_chunk = dms[lo: lo + chunk_sz]
-                n = len(dm_chunk)
-                with trace_mod.span("beam_batch_chunk",
-                                    pass_idx=pass_idx, lo=int(lo),
-                                    n=int(n), nbeams=B):
-                    norm = None
-                    with timers.timing("dedispersing"):
-                        if tree_parts is not None:
-                            pairs = [tree_dd.residual_series(
-                                tp, tree_plan, lo, n, T=T_ds,
-                                fuse=True, estimator=sp_est)
-                                for tp in tree_parts]
-                            series = jnp.concatenate(
-                                [p[0] for p in pairs], axis=0)
-                            norm = jnp.concatenate(
-                                [p[1] for p in pairs], axis=0)
-                        elif coalesce_dd:
-                            series = bb.dedisperse_beams(
-                                subb_all, sub_shifts[lo: lo + n], B)
-                        else:
-                            series = jnp.concatenate(
-                                [dd.dedisperse_subbands(
-                                    s, jnp.asarray(
-                                        sub_shifts[lo: lo + n]))
-                                 for s in subs], axis=0)
-                        trace_mod.fence(series if norm is None
-                                        else (series, norm))
-                    with timers.timing("single-pulse"):
-                        if norm is not None:
-                            sp_pair = sp_k.boxcar_search(
-                                norm, tuple(params.sp_widths),
-                                sp_k.DEFAULT_TOPK)
-                        else:
-                            sp_pair = sp_k.device_search(
-                                series, tuple(params.sp_widths),
-                                estimator=params.sp_detrend)
-                        trace_mod.fence(sp_pair)
-                    with timers.timing("FFT"):
-                        if keeps is not None:
-                            keep_rows = np.concatenate(
-                                [np.broadcast_to(k, (n, nbins))
-                                 for k in keeps])
-                            wspec = fr.whitened_spectrum_masked(
-                                series, jnp.asarray(keep_rows),
-                                nfft=nfft)
-                        else:
-                            wspec = fr.whitened_spectrum(series,
-                                                         nfft=nfft)
-                        trace_mod.fence(wspec)
-                    with timers.timing("lo-accelsearch"):
-                        res = fr.lo_stage_candidates(
-                            wspec,
-                            tuple(fr.harmonic_stages(
-                                params.lo_accel_numharm)),
-                            params.topk_per_stage)
-                        trace_mod.fence(res)
-                    hi_by_beam = None
-                    if hi:
-                        with timers.timing("hi-accelsearch"):
-                            hi_by_beam = _hi_accel_group(
-                                wspec, dm_chunk, B, T_s, params)
-                    del wspec
-                    pending.append((dm_chunk, nbins, T_s, sp_pair,
-                                    res, hi_by_beam))
-
-            with timers.timing("pipeline-drain"):
-                sp_host = jax.device_get([p[3] for p in pending])
-                lo_host = jax.device_get([p[4] for p in pending])
-            for (dm_chunk, nbins, T_s, _sp, _res, hi_by_beam), \
-                    (snrs, idx), res_h in zip(pending, sp_host,
-                                              lo_host):
-                n = len(dm_chunk)
-                for b in range(B):
-                    sl = slice(b * n, (b + 1) * n)
-                    with timers.timing("single-pulse"):
-                        ev = sp_k.events_from_topk(
-                            snrs[:, sl], idx[:, sl], dm_chunk, dt_ds,
-                            threshold=params.sp_threshold,
-                            widths=tuple(params.sp_widths))
-                        if len(ev):
-                            per[b]["sp"].append(ev)
-                    with timers.timing("lo-accelsearch"):
-                        res_b = {h: tuple(np.asarray(a)[sl]
-                                          for a in t)
-                                 for h, t in res_h.items()}
-                        per[b]["cands"].extend(sifting.make_candidates(
-                            res_b, dm_chunk, T_s, _lo_sigma_fn(nbins),
-                            sigma_min=params.sifting.sigma_threshold,
-                            bin_scale=0.5))
-                    if hi_by_beam is not None:
-                        per[b]["cands"].extend(hi_by_beam[b])
-                    per[b]["ntr"] += n
-            del pending
-            if subb_all is not None:
-                del subb_all
-            if subs is not None:
-                del subs
-            fam = "tree" if tree_parts is not None else "direct"
-            del tree_parts
-            telemetry.dedisp_trials_total().inc(B * len(dms),
-                                                family=fam)
-            telemetry.passes_total().inc(B)
-            telemetry.dm_trials_total().inc(B * len(dms))
-            telemetry.beam_batch_trials_total().inc(B * len(dms),
-                                                    path="batched")
-            for b, store in enumerate(stores):
-                if store is None:
-                    continue
-                c0, s0, t0 = starts[b]
-                ntr_pass = per[b]["ntr"] - t0
-                durable = store.save(
-                    f"pass_{pass_idx:04d}",
-                    _encode_pass(
-                        per[b]["cands"][c0:],
-                        (np.concatenate(per[b]["sp"][s0:])
-                         if len(per[b]["sp"]) > s0 else _EMPTY_SP),
-                        ntr_pass),
-                    kind="pass", ext=".npz", pass_idx=pass_idx)
-                if durable:
-                    store.journal("pass_complete", pass_idx=pass_idx,
-                                  npasses=npasses, ntrials=ntr_pass)
-            if progress_cb is not None:
-                progress_cb({
-                    "pass_idx": pass_idx + 1, "npasses": npasses,
-                    "step_idx": step_idx, "nbeams": B,
-                    "ntrials_done": per[0]["ntr"],
-                    "ncands": sum(len(p["cands"]) for p in per),
-                    "stage_s": {k: round(v, 2)
-                                for k, v in timers.times.items()
-                                if v},
-                })
-    return per
-
-
-def _hi_accel_group(wspec, dm_chunk, nbeams: int, T_s,
-                    params: SearchParams) -> list[list]:
-    """The hi-accel FDAS stage over B beams' stacked spectra rows —
-    kernels/accel_batch.py's plan sees ``B x chunk`` rows, extending
-    the DM-trial batch axis across beams.  Per-row results are
-    B-invariant (the accel_batch parity contract), so the per-beam
-    slices are bit-identical to solo calls.  A refused stacked
-    dispatch degrades PER BEAM: each beam's rows ride the proven solo
-    chunk path (retry -> host rescue -> zero-fill) independently, so
-    one beam's poisoned spectra never cost a batchmate its hi-accel
-    science."""
-    bank = _get_bank(params.hi_accel_zmax)
-    n = len(dm_chunk)
-    try:
-        res = accel_k.accel_search_batch(
-            wspec, bank, max_numharm=params.hi_accel_numharm,
-            topk=params.topk_per_stage)
-    except accel_k.AccelStageRefused:
-        return [_hi_accel_pass(wspec[b * n:(b + 1) * n], dm_chunk,
-                               T_s, params) for b in range(nbeams)]
-    out = []
-    sigma_fn = _hi_sigma_fn(wspec.shape[-1], len(bank.zs))
-    for b in range(nbeams):
-        sl = slice(b * n, (b + 1) * n)
-        res_b = {h: tuple(np.asarray(a)[sl] for a in t)
-                 for h, t in res.items()}
-        # clean chunks feed the loss ledger's denominator per beam,
-        # exactly as the solo path does per chunk
-        degraded.count("accel_hi_chunk_skipped", 0, n)
-        out.append(sifting.make_candidates(
-            res_b, dm_chunk, T_s, sigma_fn,
-            sigma_min=params.sifting.sigma_threshold,
-            z_min_abs=accel_k.DZ / 2, bin_scale=0.5))
-    return out
 
 
 def _budget_dm_chunk(nfft: int, hi: bool, budget: int) -> int:
@@ -1019,327 +764,445 @@ def search_block(data: jnp.ndarray, freqs: np.ndarray, dt: float,
             os.environ.get("TPULSAR_PROFILE", "").strip()), \
             trace_mod.span("search_block",
                            npasses=sum(s.numpasses for s in plan)):
-        return _search_block_inner(
-            data, freqs, dt, plan, params, zaplist, baryv, nsub,
-            timers, checkpoint_dir, data_id, checkpoint,
-            checkpoint_journal, progress_cb, mesh)
+        nchan = data.shape[0]
+        nsub = nsub or (params.nsub if nchan % params.nsub == 0
+                        else ddplan.largest_divisor_leq(nchan,
+                                                        params.nsub))
+        store = checkpoint
+        if store is None and checkpoint_dir:
+            shape_id = (f"{tuple(data.shape)}|{dt!r}|{freqs[0]!r}|"
+                        f"{freqs[-1]!r}")
+            store = _open_checkpoint(
+                checkpoint_dir,
+                _ckpt_fingerprint(plan, params, zaplist, baryv, nsub,
+                                  data_id=data_id + "|" + shape_id),
+                checkpoint_journal)
+        beam = _Beam(data, zaplist, baryv, store)
+        # a verified 'sifted' artifact short-circuits the whole plan
+        # loop (+ sifting + refinement): the crash being resumed
+        # happened during folding, and every pass's science is already
+        # inside it
+        sifted_state = (_load_decoded(store, "sifted", _decode_sifted)
+                        if store is not None else None)
+        if sifted_state is None:
+            _plan_loop([beam], freqs, dt, plan, params, nsub, timers,
+                       progress_cb, mesh)
+        return _sift_fold_finish(beam, freqs, dt, params, nsub, timers,
+                                 sifted_state, plan)
 
 
-def _search_block_inner(data, freqs, dt, plan, params, zaplist, baryv,
-                        nsub, timers, checkpoint_dir, data_id,
-                        checkpoint, checkpoint_journal,
-                        progress_cb, mesh):
-    nchan = data.shape[0]
-    nsub = nsub or (params.nsub if nchan % params.nsub == 0
-                    else _largest_divisor_leq(nchan, params.nsub))
+# ------------------------------------------------------- the pass loop
 
-    all_cands: list[sifting.Candidate] = []
-    sp_chunks: list[np.ndarray] = []
-    num_trials = 0
-    pass_idx = -1
-    store = checkpoint
-    if store is None and checkpoint_dir:
-        shape_id = f"{tuple(data.shape)}|{dt!r}|{freqs[0]!r}|{freqs[-1]!r}"
-        store = _open_checkpoint(
-            checkpoint_dir,
-            _ckpt_fingerprint(plan, params, zaplist, baryv, nsub,
-                              data_id=data_id + "|" + shape_id),
-            checkpoint_journal)
+@dataclasses.dataclass
+class _Beam:
+    """One beam through the pass loop and into the finish: what it
+    brings (block, zaplist, baryv, checkpoint store) and what its
+    passes leave (raw candidates, single-pulse event chunks, trials
+    searched)."""
+    data: jnp.ndarray
+    zaplist: np.ndarray | None = None
+    baryv: float = 0.0
+    store: object = None
+    cands: list = dataclasses.field(default_factory=list)
+    sp_chunks: list = dataclasses.field(default_factory=list)
+    ntrials: int = 0
 
+
+@dataclasses.dataclass
+class _Pass:
+    """One dedispersion pass of the loop: the geometry every beam of
+    the group shares, each beam's subbands, and — on one device — how
+    its DM chunks are dispatched (_plan_chunks)."""
+    pass_idx: int
+    dms: np.ndarray
+    sub_shifts: np.ndarray
+    subs: list              # per beam: (nsub, T_ds) device subbands
+    T_ds: int
+    dt_ds: float
+    nfft: int               # FFT-friendly padded length (PRESTO
+    #                         choose_N via prepsubband -numout,
+    #                         PALFA2_presto_search.py:518); one per
+    #                         plan step keeps compile signatures bounded
+    chunk_sz: int = 0
+    keeps: list | None = None       # per beam: (nbins,) zap keep mask
+    tree_plan: object = None
+    tree_parts: list | None = None  # per beam: the tree's levels
+    sp_est: str = ""
+
+    @property
+    def group(self) -> dict:
+        return _group_attrs(len(self.subs))
+
+    @property
+    def nbins(self) -> int:
+        return self.nfft // 2 + 1
+
+    @property
+    def T_s(self) -> float:
+        return self.nfft * self.dt_ds
+
+    @property
+    def family(self) -> str:
+        return "tree" if self.tree_parts is not None else "direct"
+
+
+def _group_attrs(nbeams: int) -> dict:
+    """What a group's `pass` / `dm_chunk` spans and progress dicts carry
+    beyond a solo search's: nothing for a group of one."""
+    return {"nbeams": nbeams} if nbeams > 1 else {}
+
+
+class _Chunk(typing.NamedTuple):
+    """What one dispatched DM chunk leaves for the pass end."""
+    dms: np.ndarray
+    sp_pair: tuple          # device: single-pulse top-k (snr, idx)
+    lo_res: dict            # device: lo stage top-k per harmonic stage
+    hi_cands: list | None   # host: per beam, the hi stage's candidates
+
+
+def _plan_loop(beams: list[_Beam], freqs, dt, plan, params, nsub,
+               timers, progress_cb=None, mesh=None) -> None:
+    """The pass loop, for one beam or a group of compatible ones: a
+    solo search is a group of one.  Every beam's raw candidates,
+    single-pulse events and trial count accumulate on its _Beam.
+
+    With one beam the loop dispatches the solo programs on the solo
+    arguments.  With B > 1, stage 1/2 still run per beam with those
+    programs (bit-parity bounds what may coalesce) and the spectral
+    stages see B*chunk beam-major rows; chunk boundaries are the solo
+    pass_chunk_size, so a beam's candidate order — and its per-pass
+    checkpoint artifacts — are byte-identical to a run of its own.
+    mesh (one beam only) shards each pass's DM trials instead."""
+    B = len(beams)
+    group = _group_attrs(B)
     npasses = sum(s.numpasses for s in plan)
-    # a verified 'sifted' artifact short-circuits the whole plan loop
-    # (+ sifting + refinement): the crash being resumed happened
-    # during folding, and every pass's science is already inside it
-    sifted_state = (_load_decoded(store, "sifted", _decode_sifted)
-                    if store is not None else None)
+    pass_idx = -1
     for step_idx, step in enumerate(plan):
-        if sifted_state is not None:
-            break
         for ppass in step.passes():
             pass_idx += 1
-            if store is not None:
-                done = _load_decoded(store, f"pass_{pass_idx:04d}",
-                                     _decode_pass)
-                if done is not None:
-                    cands, events, ntr = done
-                    all_cands.extend(cands)
-                    if len(events):
-                        sp_chunks.append(events)
-                    num_trials += ntr
-                    continue
-            pass_cands_start = len(all_cands)
-            pass_sp_start = len(sp_chunks)
-            pass_trials_start = num_trials
+            if _resume_pass(beams, pass_idx):
+                continue
+            marks = [(len(b.cands), len(b.sp_chunks), b.ntrials)
+                     for b in beams]
             # one span per dedispersion pass: its chunks, stages and
             # pass-end host halves nest under it; progress_cb stays
             # outside (the caller's clock stops there)
             with trace_mod.span("pass", pass_idx=pass_idx,
                                 step_idx=step_idx,
                                 downsamp=int(step.downsamp),
-                                ntrials=len(ppass.dms)):
-                with timers.timing("subbanding"):
-                    chan_shifts, sub_shifts = dd.plan_pass_shifts(
-                        freqs, nsub, ppass.subdm, np.asarray(ppass.dms),
-                        dt, step.downsamp)
-                    subb = dd.form_subbands(data, jnp.asarray(chan_shifts),
-                                            nsub, step.downsamp)
-                dt_ds = dt * step.downsamp
-                dms = np.asarray(ppass.dms)
+                                ntrials=len(ppass.dms), **group):
+                ps = _stage1(beams, freqs, dt, nsub, step, ppass,
+                             pass_idx, timers)
                 if mesh is not None:
-                    with timers.timing("sharded-search"):
-                        cands, events = _search_pass_sharded(
-                            mesh, subb, sub_shifts, dms, dt_ds, params,
-                            zaplist, baryv, timers=timers)
-                    all_cands.extend(cands)
-                    if len(events):
-                        sp_chunks.append(events)
-                    num_trials += len(dms)
+                    _sharded_pass(mesh, ps, beams[0], params, timers)
                 else:
-                    chunk_sz = pass_chunk_size(
-                        len(dms), ddplan.choose_n(subb.shape[1]), params)
-                    # Stage-2 kernel family for THIS pass: the ddplan
-                    # cost model picks the log-depth shift tree
-                    # (kernels/tree_dd.py) when the pass's DM grid lets
-                    # the shared merge levels amortize across its trials
-                    # (survey passes: ~4x fewer row-ops), and keeps the
-                    # direct shift-and-sum — the oracle — for small or
-                    # irregular grids, under TPULSAR_DD_FAMILY override.
-                    # Tree passes run the levels ONCE here; each dm_chunk
-                    # below only pays its residual layer, with the SP
-                    # detrend fused into the same program.
-                    tree_plan = tree_dd.plan_for_pass(
-                        sub_shifts, T=int(subb.shape[1]))
-                    tree_parts = None
-                    sp_est = sp_k.detrend_estimator(params.sp_detrend)
-                    if tree_plan is not None:
-                        with timers.timing("dedispersing"):
-                            tree_parts = tree_dd.tree_levels(subb,
-                                                             tree_plan)
-                            trace_mod.fence(tree_parts)
-                        telemetry.dedisp_tree_depth().set(tree_plan.depth)
-                        telemetry.dedisp_residual_fraction().set(
-                            round(tree_plan.residual_fraction, 4))
-                    # SP and lo-stage device outputs are DEFERRED to one
-                    # device_get per pass (below): the per-chunk blocking
-                    # np.asarray cost one host<->device round-trip per
-                    # output.  Only top-k-sized blocks are
-                    # held, so the deferral is KBs per chunk.  The hi
-                    # stage stays inline: its internal windowed drain is
-                    # the per-chunk sync that bounds device memory.
-                    pending: list[tuple] = []
-                    for lo in range(0, len(dms), chunk_sz):
-                        if len(pending) >= 2:
-                            # Backpressure: without any host sync in the
-                            # loop (hi off), async dispatch would let
-                            # every chunk's full-size series/wspec buffers
-                            # be enqueued concurrently — pass_chunk_size
-                            # budgets for ~one chunk resident.  Blocking
-                            # on the chunk-before-last's lo output bounds
-                            # it to two chunks in flight while still
-                            # overlapping dispatch with compute (with hi
-                            # on the accel drain already finished it;
-                            # this is then instant).
-                            with timers.timing("pipeline-wait"):
-                                jax.block_until_ready(pending[-2][4])
-                        dm_chunk = dms[lo: lo + chunk_sz]
-                        # per-chunk child span: the stage scopes below
-                        # nest under it, so the trace file shows the
-                        # pass/chunk structure, not just stage totals.
-                        # hi_rows: DM rows per hi-accel chunk program as
-                        # accel_search_batch dispatches them (the
-                        # planner's own arithmetic), 0 with hi-accel off
-                        hi_rows = (_hi_rows(len(dm_chunk),
-                                            int(subb.shape[1]), params)
-                                   if trace_mod.enabled() else 0)
-                        # dd_calls x dd_rows: stage-2 program calls for
-                        # this chunk and rows a call; the Pallas wrapper
-                        # writes what it dispatched (0 where it did not
-                        # run: the XLA scan, the tree family)
-                        with trace_mod.span("dm_chunk",
-                                            pass_idx=pass_idx, lo=int(lo),
-                                            n=int(len(dm_chunk)),
-                                            hi_rows=hi_rows,
-                                            dd_calls=0, dd_rows=0,
-                                            family=("tree" if tree_parts
-                                                    is not None
-                                                    else "direct")):
-                            norm = None
-                            with timers.timing("dedispersing"):
-                                if tree_parts is not None:
-                                    series, norm = tree_dd.residual_series(
-                                        tree_parts, tree_plan, lo,
-                                        len(dm_chunk),
-                                        T=int(subb.shape[1]),
-                                        fuse=True, estimator=sp_est)
-                                else:
-                                    series = dd.dedisperse_subbands(
-                                        subb,
-                                        jnp.asarray(
-                                            sub_shifts[lo: lo
-                                                       + len(dm_chunk)]))
-                                # opt-in device attribution
-                                # (TPULSAR_TRACE_SYNC=1): fence so the
-                                # scope's exit clock includes the device
-                                # compute this enqueue started.  On the
-                                # tree path series and norm are outputs
-                                # of ONE fused executable, so fencing
-                                # either blocks on both: the fused
-                                # detrend's wall time lands inside
-                                # 'dedispersing' in the report AND the
-                                # trace (a per-chunk detrend/dedisp
-                                # split is unmeasurable for a fused
-                                # program — the bench --dedisp A/B
-                                # carries its marginal cost instead)
-                                trace_mod.fence(series if norm is None
-                                                else (series, norm))
-                            num_trials += len(dm_chunk)
-                            # FFT-friendly padded length (reference: PRESTO
-                            # choose_N via prepsubband -numout,
-                            # PALFA2_presto_search.py:518); one length per
-                            # plan step keeps compile signatures bounded.
-                            nfft = ddplan.choose_n(series.shape[1])
-                            T_s = nfft * dt_ds
-
-                            with timers.timing("single-pulse"):
-                                # the device half of single_pulse_search;
-                                # on the tree path the detrend already
-                                # ran fused into the residual program, so
-                                # only the boxcar ladder remains here.
-                                # The host half (events_from_topk) runs
-                                # at pass end either way.
-                                if norm is not None:
-                                    sp_pair = sp_k.boxcar_search(
-                                        norm, tuple(params.sp_widths),
-                                        sp_k.DEFAULT_TOPK)
-                                else:
-                                    sp_pair = sp_k.device_search(
-                                        series, tuple(params.sp_widths),
-                                        estimator=params.sp_detrend)
-                                trace_mod.fence(sp_pair)
-
-                            with timers.timing("FFT"):
-                                nbins = nfft // 2 + 1
-                                keep = fr.zap_mask(nbins, T_s, zaplist,
-                                                   baryv) \
-                                    if zaplist is not None else None
-                                # One fused pad->rfft->whiten->scale program
-                                # per chunk; the whitened COMPLEX spectrum is
-                                # shared by the lo stage (interbinned powers)
-                                # and the hi stage (correlation input).
-                                # Zapped bins have wpow==0 so they vanish
-                                # from both.
-                                wspec = (fr.whitened_spectrum_masked(
-                                             series, jnp.asarray(keep),
-                                             nfft=nfft)
-                                         if keep is not None else
-                                         fr.whitened_spectrum(series,
-                                                              nfft=nfft))
-                                trace_mod.fence(wspec)
-                            with timers.timing("lo-accelsearch"):
-                                # half-bin detection grid (PRESTO
-                                # ACCEL_DR=0.5 via interbinning) — bin
-                                # indices are in half-bin units, hence
-                                # bin_scale=0.5; one fused program so the
-                                # (rows, 2*nbins) interbinned grid never
-                                # round-trips HBM
-                                res = fr.lo_stage_candidates(
-                                    wspec,
-                                    tuple(fr.harmonic_stages(
-                                        params.lo_accel_numharm)),
-                                    params.topk_per_stage)
-                                trace_mod.fence(res)
-
-                            hi_cands: list = []
-                            if params.run_hi_accel \
-                                    and params.hi_accel_zmax > 0:
-                                with timers.timing("hi-accelsearch"):
-                                    hi_cands = _hi_accel_pass(
-                                        wspec, dm_chunk, T_s, params)
-                            del wspec
-                            pending.append((dm_chunk, T_s, nbins, sp_pair,
-                                            res, hi_cands))
-
-                    # ---- pass end: one transfer per stage family
-                    # (charged to its own timer: the first get blocks on
-                    # ALL the pass's queued device work, so attributing
-                    # it to a compute stage would skew stage_s), then the
-                    # host halves in chunk order (candidate/event
-                    # ordering is unchanged from the per-chunk layout)
-                    with timers.timing("pipeline-drain"):
-                        sp_host = jax.device_get(
-                            [p[3] for p in pending])
-                        lo_host = jax.device_get([p[4] for p in pending])
-                    for (dm_chunk, T_s, nbins, _sp, _res,
-                         hi_cands), (snrs, idx), res_h in zip(
-                             pending, sp_host, lo_host):
-                        with timers.timing("single-pulse"), \
-                                trace_mod.span("sp-events"):
-                            ev = sp_k.events_from_topk(
-                                snrs, idx, dm_chunk, dt_ds,
-                                threshold=params.sp_threshold,
-                                widths=tuple(params.sp_widths))
-                            if len(ev):
-                                sp_chunks.append(ev)
-                            trace_mod.annotate(events=len(ev))
-                        with timers.timing("lo-accelsearch"), \
-                                trace_mod.span("lo-candidates"):
-                            lo_cands = sifting.make_candidates(
-                                res_h, dm_chunk, T_s, _lo_sigma_fn(nbins),
-                                sigma_min=params.sifting.sigma_threshold,
-                                bin_scale=0.5)
-                            trace_mod.annotate(cands=len(lo_cands))
-                        all_cands.extend(lo_cands)
-                        all_cands.extend(hi_cands)
-                    del pending
-                    fam = "tree" if tree_parts is not None else "direct"
-                    del tree_parts
-                    telemetry.dedisp_trials_total().inc(len(dms),
-                                                        family=fam)
-                del subb
-                if store is not None:
-                    ntr_pass = num_trials - pass_trials_start
-                    with trace_mod.span("pass-checkpoint"):
-                        payload = _encode_pass(
-                            all_cands[pass_cands_start:],
-                            (np.concatenate(sp_chunks[pass_sp_start:])
-                             if len(sp_chunks) > pass_sp_start
-                             else _EMPTY_SP),
-                            ntr_pass)
-                        durable = store.save(
-                            f"pass_{pass_idx:04d}", payload,
-                            kind="pass", ext=".npz", pass_idx=pass_idx)
-                        trace_mod.annotate(bytes=len(payload),
-                                           durable=bool(durable))
-                    if durable:
-                        # journaled ONLY once the artifact is durable: the
-                        # chaos verifier's no_pass_rerun invariant treats
-                        # this event as "never recompute pass k again"
-                        store.journal("pass_complete", pass_idx=pass_idx,
-                                      npasses=npasses, ntrials=ntr_pass)
-            telemetry.passes_total().inc()
-            telemetry.dm_trials_total().inc(len(dms))
+                    _chunked_pass(ps, beams, params, timers)
+                del ps
+                _checkpoint_pass(beams, marks, pass_idx, npasses)
+            telemetry.passes_total().inc(B)
+            telemetry.dm_trials_total().inc(B * len(ppass.dms))
             if progress_cb is not None:
                 progress_cb({
                     "pass_idx": pass_idx + 1, "npasses": npasses,
-                    "step_idx": step_idx, "ntrials_done": num_trials,
-                    "ncands": len(all_cands),
+                    "step_idx": step_idx,
+                    "ntrials_done": beams[0].ntrials,
+                    "ncands": sum(len(b.cands) for b in beams),
                     "stage_s": {k: round(v, 2)
                                 for k, v in timers.times.items() if v},
-                })
+                    **group})
 
-    return _sift_fold_finish(data, freqs, dt, params, zaplist, baryv,
-                             nsub, timers, store, all_cands, sp_chunks,
-                             num_trials, sifted_state, plan)
+
+def _resume_pass(beams: list[_Beam], pass_idx: int) -> bool:
+    """True when EVERY beam's store holds a verified pass `pass_idx`:
+    its partials come from there and the pass is not run.  A group's
+    stores never do — search_beam_batch sends a beam with resume state
+    to a search of its own, because resuming means skipping passes and
+    a group runs every pass for every member."""
+    done = []
+    for beam in beams:
+        got = (_load_decoded(beam.store, f"pass_{pass_idx:04d}",
+                             _decode_pass)
+               if beam.store is not None else None)
+        if got is None:
+            return False
+        done.append(got)
+    for beam, (cands, events, ntr) in zip(beams, done):
+        beam.cands.extend(cands)
+        if len(events):
+            beam.sp_chunks.append(events)
+        beam.ntrials += ntr
+    return True
+
+
+def _stage1(beams, freqs, dt, nsub, step, ppass, pass_idx,
+            timers) -> _Pass:
+    """Stage 1 of a pass: each beam's block to subbands at the pass's
+    sub-DM, with the solo program."""
+    dms = np.asarray(ppass.dms)
+    with timers.timing("subbanding"):
+        chan_shifts, sub_shifts = dd.plan_pass_shifts(
+            freqs, nsub, ppass.subdm, dms, dt, step.downsamp)
+        subs = [dd.form_subbands(b.data, jnp.asarray(chan_shifts),
+                                 nsub, step.downsamp) for b in beams]
+    T_ds = int(subs[0].shape[1])
+    return _Pass(pass_idx=pass_idx, dms=dms, sub_shifts=sub_shifts,
+                 subs=subs, T_ds=T_ds, dt_ds=dt * step.downsamp,
+                 nfft=ddplan.choose_n(T_ds))
+
+
+def _sharded_pass(mesh, ps: _Pass, beam: _Beam, params, timers) -> None:
+    """One pass of one beam with its DM trials sharded over the mesh."""
+    with timers.timing("sharded-search"):
+        cands, events = _search_pass_sharded(
+            mesh, ps.subs[0], ps.sub_shifts, ps.dms, ps.dt_ds, params,
+            beam.zaplist, beam.baryv, timers=timers)
+    beam.cands.extend(cands)
+    if len(events):
+        beam.sp_chunks.append(events)
+    beam.ntrials += len(ps.dms)
+
+
+def _chunked_pass(ps: _Pass, beams, params, timers) -> None:
+    """One pass on one device: DM chunks dispatched with two in
+    flight, then one drain and the host halves."""
+    _plan_chunks(ps, beams, params, timers)
+    # SP and lo-stage device outputs are DEFERRED to one device_get
+    # per pass (_drain_pass): a per-chunk blocking np.asarray cost one
+    # host<->device round-trip per output.  Only top-k-sized blocks
+    # are held, so the deferral is KBs per chunk.  The hi stage stays
+    # inline: its internal windowed drain is the per-chunk sync that
+    # bounds device memory.
+    pending: list[_Chunk] = []
+    for lo in range(0, len(ps.dms), ps.chunk_sz):
+        if len(pending) >= 2:
+            # Backpressure: without any host sync in the loop (hi
+            # off), async dispatch would let every chunk's full-size
+            # series/wspec buffers be enqueued concurrently —
+            # pass_chunk_size budgets for ~one chunk resident.
+            # Blocking on the chunk-before-last's lo output (the last
+            # consumer of its wspec) bounds it to two chunks in flight
+            # while still overlapping dispatch with compute (with hi
+            # on the accel drain already finished it; this is then
+            # instant).
+            with timers.timing("pipeline-wait"):
+                jax.block_until_ready(pending[-2].lo_res)
+        pending.append(_dispatch_chunk(ps, lo, params, timers))
+    _drain_pass(ps, beams, pending, params, timers)
+    telemetry.dedisp_trials_total().inc(len(beams) * len(ps.dms),
+                                        family=ps.family)
+
+
+def _plan_chunks(ps: _Pass, beams, params, timers) -> None:
+    """How the pass's DM chunks run: their size, each beam's zap keep
+    mask at this pass's spectrum length, and the stage-2 family.  The
+    ddplan cost model picks the log-depth shift tree
+    (kernels/tree_dd.py) when the pass's DM grid lets the shared merge
+    levels amortize across its trials, and keeps the direct
+    shift-and-sum — the oracle — for small or irregular grids, under
+    the TPULSAR_DD_FAMILY override.  A tree pass runs its levels ONCE
+    here, per beam (the solo program, so the family's summation order
+    is untouched); each DM chunk then only pays its residual layer,
+    with the SP detrend fused into the same program."""
+    ps.chunk_sz = pass_chunk_size(len(ps.dms), ps.nfft, params)
+    ps.sp_est = sp_k.detrend_estimator(params.sp_detrend)
+    if any(b.zaplist is not None for b in beams):
+        ps.keeps = [fr.zap_mask(ps.nbins, ps.T_s, b.zaplist, b.baryv)
+                    if b.zaplist is not None
+                    else np.ones(ps.nbins, bool) for b in beams]
+    ps.tree_plan = tree_dd.plan_for_pass(ps.sub_shifts, T=ps.T_ds)
+    if ps.tree_plan is not None:
+        with timers.timing("dedispersing"):
+            ps.tree_parts = [tree_dd.tree_levels(s, ps.tree_plan)
+                             for s in ps.subs]
+            trace_mod.fence(ps.tree_parts)
+        telemetry.dedisp_tree_depth().set(ps.tree_plan.depth)
+        telemetry.dedisp_residual_fraction().set(
+            round(ps.tree_plan.residual_fraction, 4))
+
+
+def _beam_major(parts: list):
+    """Per-beam row blocks as one beam-major array; a group of one
+    keeps its array (no copy program)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts,
+                                                            axis=0)
+
+
+def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
+    """Enqueue one DM chunk's device work — stage 2, single pulse,
+    FFT/whiten, lo- and hi-accelsearch — over every beam's rows.
+    Nothing here waits for the device but the hi stage's own drain.
+
+    The stage timers carry opt-in device attribution
+    (TPULSAR_TRACE_SYNC=1): each fence makes its scope's exit clock
+    include the device compute the enqueue started."""
+    dm_chunk = ps.dms[lo: lo + ps.chunk_sz]
+    n = len(dm_chunk)
+    B = len(ps.subs)
+    hi = params.run_hi_accel and params.hi_accel_zmax > 0
+    # hi_rows: DM rows per hi-accel chunk program as accel_search_batch
+    # dispatches them (the planner's own arithmetic), 0 with hi-accel
+    # off.  dd_calls x dd_rows: a beam's stage-2 program calls for
+    # this chunk and rows a call; the Pallas wrapper writes what it
+    # dispatched (0 where it did not run: the XLA scan, the tree
+    # family)
+    hi_rows = (_hi_rows(B * n, ps.T_ds, params)
+               if trace_mod.enabled() else 0)
+    with trace_mod.span("dm_chunk", pass_idx=ps.pass_idx, lo=int(lo),
+                        n=int(n), hi_rows=hi_rows, dd_calls=0,
+                        dd_rows=0, family=ps.family, **ps.group):
+        with timers.timing("dedispersing"):
+            # on the tree path series and norm are outputs of ONE
+            # fused executable, so the fused detrend's wall time lands
+            # inside 'dedispersing' in the report AND the trace
+            norm = None
+            if ps.tree_parts is not None:
+                pairs = [tree_dd.residual_series(
+                    tp, ps.tree_plan, lo, n, T=ps.T_ds, fuse=True,
+                    estimator=ps.sp_est) for tp in ps.tree_parts]
+                series = _beam_major([p[0] for p in pairs])
+                norm = _beam_major([p[1] for p in pairs])
+            else:
+                shifts = jnp.asarray(ps.sub_shifts[lo: lo + n])
+                series = _beam_major([dd.dedisperse_subbands(s, shifts)
+                                      for s in ps.subs])
+            trace_mod.fence(series if norm is None else (series, norm))
+
+        with timers.timing("single-pulse"):
+            # the device half of single_pulse_search; on the tree path
+            # the detrend already ran fused into the residual program,
+            # so only the boxcar ladder remains here.  The host half
+            # (events_from_topk) runs at pass end either way.
+            if norm is not None:
+                sp_pair = sp_k.boxcar_search(
+                    norm, tuple(params.sp_widths), sp_k.DEFAULT_TOPK)
+            else:
+                sp_pair = sp_k.device_search(
+                    series, tuple(params.sp_widths),
+                    estimator=params.sp_detrend)
+            trace_mod.fence(sp_pair)
+
+        with timers.timing("FFT"):
+            # One fused pad->rfft->whiten->scale program per chunk; the
+            # whitened COMPLEX spectrum is shared by the lo stage
+            # (interbinned powers) and the hi stage (correlation
+            # input).  Zapped bins have wpow==0 so they vanish from
+            # both.  One beam: its 1-D (nbins,) keep mask.  A group:
+            # 2-D per-row masks (batchmates share a zap digest, but
+            # baryv, which shapes the mask, is per beam).
+            if ps.keeps is None:
+                wspec = fr.whitened_spectrum(series, nfft=ps.nfft)
+            else:
+                keep = (ps.keeps[0] if B == 1 else np.concatenate(
+                    [np.broadcast_to(k, (n, ps.nbins))
+                     for k in ps.keeps]))
+                wspec = fr.whitened_spectrum_masked(
+                    series, jnp.asarray(keep), nfft=ps.nfft)
+            trace_mod.fence(wspec)
+
+        with timers.timing("lo-accelsearch"):
+            # half-bin detection grid (PRESTO ACCEL_DR=0.5 via
+            # interbinning) — bin indices are in half-bin units, hence
+            # bin_scale=0.5 at the pass end; one fused program so the
+            # (rows, 2*nbins) interbinned grid never round-trips HBM
+            lo_res = fr.lo_stage_candidates(
+                wspec,
+                tuple(fr.harmonic_stages(params.lo_accel_numharm)),
+                params.topk_per_stage)
+            trace_mod.fence(lo_res)
+
+        hi_cands = None
+        if hi:
+            with timers.timing("hi-accelsearch"):
+                hi_cands = _hi_accel_chunk(wspec, dm_chunk, B, ps.T_s,
+                                           params)
+        del wspec
+    return _Chunk(dm_chunk, sp_pair, lo_res, hi_cands)
+
+
+def _beam_rows(res: dict, b: int, n: int, nbeams: int) -> dict:
+    """Beam b's n rows of a {stage: (arrays, ...)} result stacked
+    beam-major; the result itself for a group of one."""
+    if nbeams == 1:
+        return res
+    sl = slice(b * n, (b + 1) * n)
+    return {h: tuple(np.asarray(a)[sl] for a in t)
+            for h, t in res.items()}
+
+
+def _drain_pass(ps: _Pass, beams, pending: list[_Chunk], params,
+                timers) -> None:
+    """Pass end: one transfer per stage family (charged to its own
+    timer: the first get blocks on ALL the pass's queued device work,
+    so attributing it to a compute stage would skew stage_s), then the
+    host halves per beam in chunk order — a beam's candidate and event
+    order is that of a loop over its chunks alone."""
+    B = len(beams)
+    with timers.timing("pipeline-drain"):
+        sp_host = jax.device_get([c.sp_pair for c in pending])
+        lo_host = jax.device_get([c.lo_res for c in pending])
+    sigma_fn = _lo_sigma_fn(ps.nbins)
+    for chunk, (snrs, idx), res_h in zip(pending, sp_host, lo_host):
+        n = len(chunk.dms)
+        for b, beam in enumerate(beams):
+            rows = slice(b * n, (b + 1) * n)    # all of them for B = 1
+            with timers.timing("single-pulse"), \
+                    trace_mod.span("sp-events"):
+                ev = sp_k.events_from_topk(
+                    snrs[:, rows], idx[:, rows], chunk.dms, ps.dt_ds,
+                    threshold=params.sp_threshold,
+                    widths=tuple(params.sp_widths))
+                if len(ev):
+                    beam.sp_chunks.append(ev)
+                trace_mod.annotate(events=len(ev))
+            with timers.timing("lo-accelsearch"), \
+                    trace_mod.span("lo-candidates"):
+                lo_cands = sifting.make_candidates(
+                    _beam_rows(res_h, b, n, B), chunk.dms, ps.T_s,
+                    sigma_fn, sigma_min=params.sifting.sigma_threshold,
+                    bin_scale=0.5)
+                trace_mod.annotate(cands=len(lo_cands))
+            beam.cands.extend(lo_cands)
+            if chunk.hi_cands is not None:
+                beam.cands.extend(chunk.hi_cands[b])
+            beam.ntrials += n
+
+
+def _checkpoint_pass(beams, marks, pass_idx: int, npasses: int) -> None:
+    """Each beam's partials of the pass just run (everything past its
+    mark) into its own store."""
+    for beam, (c0, s0, t0) in zip(beams, marks):
+        if beam.store is None:
+            continue
+        ntr_pass = beam.ntrials - t0
+        with trace_mod.span("pass-checkpoint"):
+            payload = _encode_pass(
+                beam.cands[c0:],
+                (np.concatenate(beam.sp_chunks[s0:])
+                 if len(beam.sp_chunks) > s0 else _EMPTY_SP),
+                ntr_pass)
+            durable = beam.store.save(
+                f"pass_{pass_idx:04d}", payload,
+                kind="pass", ext=".npz", pass_idx=pass_idx)
+            trace_mod.annotate(bytes=len(payload),
+                               durable=bool(durable))
+        if durable:
+            # journaled ONLY once the artifact is durable: the chaos
+            # verifier's no_pass_rerun invariant treats this event as
+            # "never recompute pass k again"
+            beam.store.journal("pass_complete", pass_idx=pass_idx,
+                               npasses=npasses, ntrials=ntr_pass)
 
 
 @trace_mod.span("finish")
-def _sift_fold_finish(data, freqs, dt, params, zaplist, baryv, nsub,
-                      timers, store, all_cands, sp_chunks, num_trials,
+def _sift_fold_finish(beam: _Beam, freqs, dt, params, nsub, timers,
                       sifted_state, plan):
-    """Everything after the plan loop — sift, refine, checkpoint the
-    sifted list, fold (checkpoint-aware) — shared verbatim by the solo
-    pass loop and the batch-of-beams group loop, so the per-beam tail
-    is identical-by-construction whichever loop fed it."""
+    """Everything after the plan loop, for one beam — sift, refine,
+    checkpoint the sifted list, fold (checkpoint-aware) — whether it
+    went through the loop alone or in a group."""
+    data, zaplist, baryv, store = (beam.data, beam.zaplist, beam.baryv,
+                                   beam.store)
+    all_cands, sp_chunks, num_trials = (beam.cands, beam.sp_chunks,
+                                        beam.ntrials)
     nfft_full = ddplan.choose_n(data.shape[1])
     T_s_full = nfft_full * dt
     _series_for = _BoundedCache(
@@ -1761,13 +1624,6 @@ def _compute_baryv(si) -> float:
         return 0.0
 
 
-def _largest_divisor_leq(n: int, k: int) -> int:
-    for d in range(min(n, k), 0, -1):
-        if n % d == 0:
-            return d
-    return 1
-
-
 def _dedisperse_single(data, freqs, nsub, dm, dt):
     """One full-resolution DM series for folding."""
     chan_shifts, sub_shifts = dd.plan_pass_shifts(freqs, nsub, dm, [dm],
@@ -1790,104 +1646,129 @@ def _hi_rows(ndms: int, T: int, params: SearchParams) -> int:
         len(accel_k.z_grid(params.hi_accel_zmax)))
 
 
-def _hi_accel_pass(wspec, dm_chunk, T_s, params: SearchParams
-                   ) -> list[sifting.Candidate]:
-    """accelsearch zmax>0 over a DM chunk of already-whitened complex
-    spectra (device-batched; the spectrum is shared with the lo
-    stage)."""
+def _hi_accel_chunk(wspec, dm_chunk, nbeams: int, T_s,
+                    params: SearchParams) -> list[list]:
+    """accelsearch zmax>0 over one DM chunk of already-whitened
+    complex spectra (device-batched; shared with the lo stage):
+    `nbeams x len(dm_chunk)` beam-major rows in, each beam's
+    candidates out.  Rows are independent in accel_search_batch (the
+    accel_batch parity contract), so a beam's slice of a stacked
+    dispatch is bit-identical to a dispatch of its own.
+
+    A refused dispatch of ONE beam's rows goes down the ladder
+    (_rescue_refused_chunk: host rescue -> zero-fill).  A refused
+    stacked dispatch degrades PER BEAM: each beam's rows go through
+    this function alone, so one beam's poisoned spectra never cost a
+    batchmate its hi-accel science."""
     bank = _get_bank(params.hi_accel_zmax)
+    n = len(dm_chunk)
     try:
         res = accel_k.accel_search_batch(
             wspec, bank, max_numharm=params.hi_accel_numharm,
             topk=params.topk_per_stage)
     except accel_k.AccelStageRefused as exc:
-        # The runtime refused the whole chunk outright
-        # (UNIMPLEMENTED).
-        # Last resort before losing science: recompute the WHOLE
-        # chunk on the host CPU backend — slower, but a complete
-        # beam.  Skipped when the kernel's own per-row rescue already
-        # ran on these exact spectra and recovered nothing
-        # (rescue_exhausted): repeating the doomed recompute would
-        # double the cost of the skip that is coming anyway.  Only
-        # when no rescue is possible does the chunk's hi stage skip
-        # loudly: the beam keeps its SP, lo, fold, and other chunks'
-        # hi science instead of dying with nothing recorded.
-        import time as _time
-
-        from tpulsar.obs import telemetry
-        from tpulsar.resilience import rescue
-        chunk_res = None
-        t_rescue = _time.perf_counter()
-        if not getattr(exc, "rescue_exhausted", False):
-            with telemetry.trace.span("accel_chunk_rescue",
-                                      n=len(dm_chunk)):
-                chunk_res = rescue.rescue_accel_chunk(
-                    wspec, bank, max_numharm=params.hi_accel_numharm,
-                    topk=params.topk_per_stage)
-        if chunk_res is not None:
-            # observed only when the rescue DELIVERED rows — the
-            # trials counter and this histogram must describe the
-            # same calls or the derived per-path dm_trials_per_sec
-            # skews toward zero on a fleet with failing rescues
-            telemetry.accel_stage_seconds().observe(
-                _time.perf_counter() - t_rescue, path="rescued")
-        if chunk_res is None:
-            degraded.count("accel_hi_chunk_skipped", len(dm_chunk),
-                           len(dm_chunk), extra=str(exc)[:160])
-            telemetry.rescue_rows_total().inc(len(dm_chunk),
-                                              outcome="lost")
-            import warnings
-            warnings.warn(f"hi-accel chunk skipped: {exc}")
-            return []
-        res, lost_rows = chunk_res
-        n_ok = len(dm_chunk) - len(lost_rows)
-        telemetry.rescue_rows_total().inc(n_ok, outcome="rescued")
-        if n_ok:
-            # the kernel raised before its own trials accounting, so
-            # the chunk-rescued rows are counted HERE, once
-            telemetry.accel_batch_trials_total().inc(n_ok,
-                                                     path="rescued")
-        if lost_rows:
-            telemetry.rescue_rows_total().inc(len(lost_rows),
-                                              outcome="lost")
-        degraded.provenance_count(
-            "accel_rows_rescued", n_ok, len(dm_chunk),
-            extra="whole chunk refused by the runtime; recomputed on "
-                  "the host CPU backend — rescued rows were slower "
-                  "but complete")
-        # lost_rows feed the LOSS ledger (and clean rescues feed its
-        # denominator, n=0): a partial chunk rescue is partial
-        # coverage, never dressed as complete
-        degraded.count(
-            "accel_rows_zero_filled", len(lost_rows), len(dm_chunk),
-            extra="chunk-rescue recompute failed for these rows; "
-                  "powers zero-filled — hi-accel coverage is PARTIAL")
-        degraded.count("accel_hi_chunk_skipped", 0, len(dm_chunk))
-        import warnings
-        warnings.warn(
-            f"hi-accel chunk refused by the runtime and recomputed "
-            f"on the host CPU backend ({n_ok}/{len(dm_chunk)} rows"
-            + (f"; {len(lost_rows)} rows lost and zero-filled"
-               if lost_rows else "")
-            + f"; provenance recorded): {exc}")
+        if nbeams > 1:
+            return [_hi_accel_chunk(wspec[b * n:(b + 1) * n], dm_chunk,
+                                    1, T_s, params)[0]
+                    for b in range(nbeams)]
+        res = _rescue_refused_chunk(wspec, bank, dm_chunk, params, exc)
+        if res is None:
+            return [[]]
     else:
-        # clean chunks must feed the denominator too (n=0), or the
-        # recorded loss fraction always reads 100% of the counted
-        # chunks — count()'s own documented contract
-        degraded.count("accel_hi_chunk_skipped", 0, len(dm_chunk))
+        # clean chunks must feed the denominator too (n=0), per beam,
+        # or the recorded loss fraction always reads 100% of the
+        # counted chunks — count()'s own documented contract
+        for _ in range(nbeams):
+            degraded.count("accel_hi_chunk_skipped", 0, n)
 
     # z~0 rows are the lo search's job (z_min_abs); sub-threshold rows
     # never become Python objects (sigma_min pre-filter).  The
     # correlation plane is numbetween=2 interpolated: r indices are
     # half-bin units (bin_scale).
-    with trace_mod.span("accel-candidates"):
-        cands = sifting.make_candidates(
-            res, dm_chunk, T_s,
-            _hi_sigma_fn(wspec.shape[-1], len(bank.zs)),
-            sigma_min=params.sifting.sigma_threshold,
-            z_min_abs=accel_k.DZ / 2, bin_scale=0.5)
-        trace_mod.annotate(cands=len(cands))
-    return cands
+    sigma_fn = _hi_sigma_fn(wspec.shape[-1], len(bank.zs))
+    out = []
+    for b in range(nbeams):
+        with trace_mod.span("accel-candidates"):
+            cands = sifting.make_candidates(
+                _beam_rows(res, b, n, nbeams), dm_chunk, T_s, sigma_fn,
+                sigma_min=params.sifting.sigma_threshold,
+                z_min_abs=accel_k.DZ / 2, bin_scale=0.5)
+            trace_mod.annotate(cands=len(cands))
+        out.append(cands)
+    return out
+
+
+def _rescue_refused_chunk(wspec, bank, dm_chunk, params: SearchParams,
+                          exc):
+    """The ladder under one beam's hi-accel chunk that the runtime
+    refused outright (UNIMPLEMENTED): its result, or None when the
+    chunk's hi stage is skipped.
+
+    Last resort before losing science: recompute the WHOLE chunk on
+    the host CPU backend — slower, but a complete beam.  Skipped when
+    the kernel's own per-row rescue already ran on these exact spectra
+    and recovered nothing (rescue_exhausted): repeating the doomed
+    recompute would double the cost of the skip that is coming anyway.
+    Only when no rescue is possible does the chunk's hi stage skip
+    loudly: the beam keeps its SP, lo, fold, and other chunks' hi
+    science instead of dying with nothing recorded."""
+    import time as _time
+    import warnings
+
+    from tpulsar.resilience import rescue
+    chunk_res = None
+    t_rescue = _time.perf_counter()
+    if not getattr(exc, "rescue_exhausted", False):
+        with trace_mod.span("accel_chunk_rescue",
+                                  n=len(dm_chunk)):
+            chunk_res = rescue.rescue_accel_chunk(
+                wspec, bank, max_numharm=params.hi_accel_numharm,
+                topk=params.topk_per_stage)
+    if chunk_res is not None:
+        # observed only when the rescue DELIVERED rows — the
+        # trials counter and this histogram must describe the
+        # same calls or the derived per-path dm_trials_per_sec
+        # skews toward zero on a fleet with failing rescues
+        telemetry.accel_stage_seconds().observe(
+            _time.perf_counter() - t_rescue, path="rescued")
+    if chunk_res is None:
+        degraded.count("accel_hi_chunk_skipped", len(dm_chunk),
+                       len(dm_chunk), extra=str(exc)[:160])
+        telemetry.rescue_rows_total().inc(len(dm_chunk),
+                                          outcome="lost")
+        warnings.warn(f"hi-accel chunk skipped: {exc}")
+        return None
+    res, lost_rows = chunk_res
+    n_ok = len(dm_chunk) - len(lost_rows)
+    telemetry.rescue_rows_total().inc(n_ok, outcome="rescued")
+    if n_ok:
+        # the kernel raised before its own trials accounting, so
+        # the chunk-rescued rows are counted HERE, once
+        telemetry.accel_batch_trials_total().inc(n_ok,
+                                                 path="rescued")
+    if lost_rows:
+        telemetry.rescue_rows_total().inc(len(lost_rows),
+                                          outcome="lost")
+    degraded.provenance_count(
+        "accel_rows_rescued", n_ok, len(dm_chunk),
+        extra="whole chunk refused by the runtime; recomputed on "
+              "the host CPU backend — rescued rows were slower "
+              "but complete")
+    # lost_rows feed the LOSS ledger (and clean rescues feed its
+    # denominator, n=0): a partial chunk rescue is partial
+    # coverage, never dressed as complete
+    degraded.count(
+        "accel_rows_zero_filled", len(lost_rows), len(dm_chunk),
+        extra="chunk-rescue recompute failed for these rows; "
+              "powers zero-filled — hi-accel coverage is PARTIAL")
+    degraded.count("accel_hi_chunk_skipped", 0, len(dm_chunk))
+    warnings.warn(
+        f"hi-accel chunk refused by the runtime and recomputed "
+        f"on the host CPU backend ({n_ok}/{len(dm_chunk)} rows"
+        + (f"; {len(lost_rows)} rows lost and zero-filled"
+           if lost_rows else "")
+        + f"; provenance recorded): {exc}")
+    return res
 
 
 _BANK_CACHE: dict[int, accel_k.TemplateBank] = {}
@@ -2077,7 +1958,8 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
             # internally, so the result is identical)
             wspec = fr.whitened_spectrum_masked(
                 series, jnp.asarray(keep), nfft=nfft)
-            cands.extend(_hi_accel_pass(wspec, dm_chunk, T_s, params))
+            cands.extend(_hi_accel_chunk(wspec, dm_chunk, 1, T_s,
+                                         params)[0])
     events = sp_k.events_from_topk(
         sp_snr[:, :ndms], sp_idx[:, :ndms], dms, dt_ds,
         threshold=params.sp_threshold, widths=tuple(params.sp_widths))
