@@ -426,9 +426,10 @@ def sharded_beam(args, size: dict, root: str) -> dict:
             if not compiled:
                 compiled.append(fn.lower(*a).compile())
                 ins = compiled[0].input_shardings[0]
+                # (the taps are None off a TPU: no sharding to read)
                 spans["in"].update(
                     frozenset(d.id for d in sh.device_set)
-                    for sh in ins)
+                    for sh in jax.tree_util.tree_leaves(ins))
                 # the DM table (argument 1) is split, not replicated
                 spans["dm_split"] &= not ins[1].is_fully_replicated
             out = fn(*a)

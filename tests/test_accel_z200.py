@@ -16,6 +16,7 @@ from tpulsar.kernels import accel_batch as abp
 # the survey's spectrum lengths at downsamp 1 (Mock, WAPP)
 MOCK_NBINS = 1_966_081
 WAPP_NBINS = 2_097_153
+ROWS_NZ51 = 1       # a TPU's rows a chunk program at zmax 50 (see below)
 
 
 # ----------------------------------------------- |z| = 100 in search_block
@@ -86,9 +87,11 @@ def test_pulsar_at_z100_is_not_found_with_the_bank_cut_to_zmax50(
 
 @pytest.fixture
 def chip_settings(monkeypatch):
-    """What the chip resolves: a bf16 plane, pieces of 4 z rows."""
+    """What the chip resolves: a bf16 plane and the direct correlation
+    (the FFT form's pieces of 4 z rows reach none of its programs)."""
     monkeypatch.setattr(accel, "_PLANE_DTYPE_RESOLVED", jnp.bfloat16)
     monkeypatch.setattr(accel, "_Z_CHUNK_RESOLVED", 4)
+    monkeypatch.setattr(accel, "corr_form", lambda: "direct")
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -131,24 +134,52 @@ def test_chunk_programs_plane_is_the_per_dm_plane(monkeypatch, dtype,
 
 # ------------------------------------------- rows per chunk program
 
+# Since PR 30 a TPU's chunk program makes its plane by the direct form
+# (accel.corr_plane): the complex64 overlap-save intermediates and the
+# plane's second copy are gone, so a row is counted as the plane and
+# ~56 B a bin beside it (the compiler's figures: tests/
+# test_chip_compile.py), and the 4 GiB budget holds 7 rows at nz 51 (2
+# until then) and 2 at nz 201 (1 until then).  The chip found no use for
+# them in a chunk program (one row a program was as fast at nz 51 and 4%
+# faster at nz 201: accel.PLANE_ROWS_DIRECT), which is given 1 at both
+# depths; the DM-sharded mesh program, whose rows share every stage of
+# one program, takes what the budget holds (max_chunk=32).
+
 @pytest.mark.parametrize("nbins", [MOCK_NBINS, WAPP_NBINS])
-def test_rows_per_program_stay_2_at_the_surveys_default_depth(
-        chip_settings, nbins):
-    assert accel.plane_dm_chunk(nbins, 51) == 2
-    assert abp.batch_rows(38, nbins, 51) == 2
+def test_rows_per_program_at_the_surveys_default_depth(chip_settings,
+                                                       nbins):
+    assert accel.plane_dm_chunk(nbins, 51) == ROWS_NZ51
+    assert abp.batch_rows(38, nbins, 51) == ROWS_NZ51
+    # a CPU process keeps the FFT form and its count
+    assert accel.plane_row_bytes(nbins, 51, 4) > 2 * accel.plane_row_bytes(
+        nbins, 51, None)
 
 
-def test_one_row_at_zmax_200_fits_by_its_own_count(chip_settings):
+def test_rows_at_zmax_200_fit_by_their_own_count(chip_settings):
     nz = len(accel.z_grid(200.0))
     assert nz == 201
     assert accel.plane_dm_chunk(MOCK_NBINS, nz) == 1
     assert abp.batch_rows(38, MOCK_NBINS, nz) == 1
-    row = accel.plane_row_bytes(MOCK_NBINS, nz, accel.z_chunk())
-    assert (row * (1 + accel.PLANE_COUNT_SLACK) <= accel.PLANE_HBM_BUDGET
-            < 2 * row)
-    # the plane twice (pieces and assembled, 1.58 GB each) beside the
-    # harmonic sums' outputs: no float32 stage term
-    assert row == 2 * (nz * 2 * MOCK_NBINS * 2) + MOCK_NBINS * 64
+    assert accel.plane_dm_chunk(MOCK_NBINS, nz, max_chunk=32) == 2
+    row = accel.plane_row_bytes(MOCK_NBINS, nz, None)
+    assert (2 * row * (1 + accel.PLANE_COUNT_SLACK)
+            <= accel.PLANE_HBM_BUDGET < 3 * row)
+    # the plane once (1.58 GB): the kernel writes it and the harmonic
+    # sums read it in place; no piece, no second copy, no z-piece term
+    assert row == nz * 2 * MOCK_NBINS * 2 + MOCK_NBINS * 56
+
+
+def test_the_count_is_a_rung_of_the_planners_ladder(chip_settings,
+                                                    monkeypatch):
+    """plane_dm_chunk says what a program is GIVEN (the benchmark's
+    cost function reads it as such): 7 rows fit the budget at nz 51,
+    the planner would dispatch 6."""
+    row = accel.plane_row_bytes(MOCK_NBINS, 51, None)
+    assert int(accel.PLANE_HBM_BUDGET
+               // (row * (1 + accel.PLANE_COUNT_SLACK))) == 7
+    assert accel.plane_dm_chunk(MOCK_NBINS, 51, max_chunk=32) == 6
+    assert (accel.plane_dm_chunk(MOCK_NBINS, 51)
+            == accel.PLANE_ROWS_DIRECT == 1)
 
 
 def test_a_row_too_large_is_refused_on_a_tpu_and_held_by_the_host(
@@ -156,7 +187,7 @@ def test_a_row_too_large_is_refused_on_a_tpu_and_held_by_the_host(
     """Never 1 for a row reckoned too large where the budget is device
     memory; on the CPU the row lives in host RAM, as it always did."""
     nz = 201
-    row = accel.plane_row_bytes(MOCK_NBINS, nz, 4)
+    row = accel.plane_row_bytes(MOCK_NBINS, nz, None)
     monkeypatch.setattr(accel, "PLANE_HBM_BUDGET", row)     # no slack
     assert jax.default_backend() == "cpu"
     assert accel.plane_dm_chunk(MOCK_NBINS, nz) == 1
@@ -180,8 +211,9 @@ def test_a_deep_cpu_plane_is_not_refused(monkeypatch):
 
 # ------------------------------------------------ spans and counters
 
-def test_spans_say_rows_pieces_and_path(drifting_beam, monkeypatch):
-    """accel-dispatch carries nz and zpieces, dm_chunk the rows per
+def test_spans_say_rows_form_and_path(drifting_beam, monkeypatch):
+    """accel-dispatch carries nz and corr (the correlation's form as
+    this platform lowers it), dm_chunk the rows per
     chunk program as dispatched, and every trial is counted on path
     `batched` (docs/operations.md, "Where a slow beam's seconds are")."""
     from tpulsar.obs import telemetry, trace
@@ -220,7 +252,8 @@ def test_spans_say_rows_pieces_and_path(drifting_beam, monkeypatch):
         assert d["args"]["rows"] == ch["args"]["n"] == 4
         assert d["args"]["chunks"] == -(-4 // hi_rows)
         assert d["args"]["nz"] == 201
-        assert d["args"]["zpieces"] == -(-201 // accel.z_chunk())
+        assert d["args"]["corr"] == accel.corr_form() == "fft"
+        assert "zpieces" not in d["args"]
 
 
 def test_dm_chunk_says_no_hi_rows_with_hi_accel_off(drifting_beam):

@@ -26,10 +26,9 @@ ZMAX = 50.0
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -41,9 +40,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _sds(one_chip, shape, dtype):
@@ -106,12 +112,13 @@ def test_stage1_subband_kernel(one_chip, nchan, nsub, overhang):
 @pytest.fixture
 def tpu_accel_branch(monkeypatch):
     """accel.py asks jax.default_backend() at trace time and would take
-    its CPU branch here (f32 plane, z-chunk 16): steer it to what the
-    chip takes."""
+    its CPU branch here (f32 plane, z-chunk 16, rows counted for the
+    FFT form): steer it to what the chip takes."""
     from tpulsar.kernels import accel
 
     monkeypatch.setattr(accel, "_PLANE_DTYPE_RESOLVED", jnp.bfloat16)
     monkeypatch.setattr(accel, "_Z_CHUNK_RESOLVED", 4)
+    monkeypatch.setattr(accel, "corr_form", lambda: "direct")
     return accel
 
 
@@ -125,50 +132,147 @@ def test_hi_accel_programs(one_chip, tpu_accel_branch, program):
     nz = len(bank.zs)
     assert (nz, bank.seg) == (51, 8192)
     nbins = 16_385
-    args = (_sds(one_chip, (4, nbins), jnp.complex64),
-            _sds(one_chip, bank.bank_fft.shape, jnp.complex64),
-            _sds(one_chip, (), jnp.int32))
+    spec, bank_fft, c0 = (
+        _sds(one_chip, (4, nbins), jnp.complex64),
+        _sds(one_chip, bank.bank_fft.shape, jnp.complex64),
+        _sds(one_chip, (), jnp.int32))
+    taps = _sds(one_chip, accel.corr_taps_shape(nz, bank.width),
+                jnp.float32)
     kw = dict(seg=bank.seg, step=bank.step, width=bank.width, nz=nz,
               max_numharm=8, topk=32)
     if program == "chunk":
-        lowered = accel.accel_chunk_topk.lower(*args, nrows=2, **kw)
+        part = _sds(one_chip, (4, nbins), jnp.float32)
+        lowered = accel.accel_chunk_topk.lower((part, part), bank_fft,
+                                               taps, c0, nrows=2, **kw)
     else:
-        lowered = accel.accel_row_topk.lower(*args, **kw)
-    compiled = lowered.compile()
+        lowered = accel.accel_row_topk.lower(spec, bank_fft, c0, **kw)
+    text = lowered.compile().as_text()
     # the plane really is bf16 on this branch, and its harmonic sums
-    # are the Mosaic kernel (lax.platform_dependent took the TPU side)
-    assert "bf16" in compiled.as_text()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes > 0
+    # are the Mosaic kernel (lax.platform_dependent took the TPU side);
+    # the chunk program's correlation is the other kernel and no FFT is
+    # left in it, the per-DM row program keeps the overlap-save FFTs
+    assert "bf16" in text
+    assert text.count("tpu_custom_call") == {"chunk": 2, "row": 1}[program]
+    assert ("jit(fft)" in text) == (program == "row")
 
 
-@pytest.mark.parametrize("zmax,numharm,nbins", [
-    (50.0, 8, 1_966_081),        # Mock ds=1: mock_ds1_hiaccel's chunk
-    (200.0, 16, 1_966_081),      # BASELINE config 3: z200_ds1_hiaccel's
+@pytest.mark.parametrize("zmax,numharm,nbins,rows", [
+    (50.0, 8, 1_966_081, 1),     # Mock ds=1: mock_ds1_hiaccel's chunk
+    (200.0, 16, 1_966_081, 1),   # BASELINE config 3: z200_ds1_hiaccel's
+    (50.0, 8, 2_097_153, 1),     # WAPP ds=1 (wapp_ds1_hiaccel is owed)
+    (50.0, 8, 1_966_081, 6),     # what a mesh device's hi stage holds
+    (200.0, 16, 1_966_081, 2),
 ])
 def test_hi_accel_chunk_program_at_full_width(one_chip, tpu_accel_branch,
-                                              zmax, numharm, nbins):
-    """The WHOLE chunk program at the survey's width, with the rows
-    plane_dm_chunk gives it: it compiles for the chip, the harmonic
-    sums are the Mosaic kernel, and the temporaries the compiler
-    counts are what plane_dm_chunk budgets the rows by
-    (plane_row_bytes), within 20%."""
+                                              zmax, numharm, nbins, rows):
+    """The WHOLE chunk program at the survey's width, with the row
+    plane_dm_chunk gives it (and with the rows it gives a device of the
+    DM-sharded mesh): it compiles for the chip, the correlation and the
+    harmonic sums are the two Mosaic kernels with no FFT beside them,
+    and the temporaries the compiler counts are what plane_dm_chunk
+    budgets the rows by (plane_row_bytes), within 20%."""
     accel = tpu_accel_branch
     bank = accel.build_template_bank(zmax)
     nz = len(bank.zs)
-    rows = accel.plane_dm_chunk(nbins, nz)
-    assert rows == {51: 2, 201: 1}[nz]
-    counted = rows * accel.plane_row_bytes(nbins, nz, accel.z_chunk())
+    assert rows == accel.plane_dm_chunk(nbins, nz,
+                                        max_chunk=None if rows == 1 else 32)
+    counted = rows * accel.plane_row_bytes(nbins, nz, None)
     assert counted <= accel.PLANE_HBM_BUDGET      # fits by its own count
+    part = _sds(one_chip, (48, nbins), jnp.float32)
     compiled = accel.accel_chunk_topk.lower(
-        _sds(one_chip, (48, nbins), jnp.complex64),
-        _sds(one_chip, bank.bank_fft.shape, jnp.complex64),
+        (part, part), _sds(one_chip, bank.bank_fft.shape, jnp.complex64),
+        _sds(one_chip, accel.corr_taps_shape(nz, bank.width), jnp.float32),
         _sds(one_chip, (), jnp.int32), nrows=rows, seg=bank.seg,
         step=bank.step, width=bank.width, nz=nz, max_numharm=numharm,
         topk=32).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "fft" not in text
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert abs(temp - counted) <= 0.2 * counted, (temp, counted)
+
+
+@pytest.mark.parametrize("zmax,numharm", [(50.0, 8), (200.0, 16)])
+def test_dm_sharded_pass_program_on_four_chips(v5e, tpu_accel_branch,
+                                               zmax, numharm):
+    """The DM-sharded mesh program (parallel/mesh.sharded_pass_fn: the
+    whole pass in one program, the hi stage the chunk program's
+    _accel_block_topk) for the four chips of a described v5e 2x2, at
+    the Mock ds=1 width, with the rows a device that
+    executor._search_pass_sharded gives it: its hi stage is the two
+    Mosaic kernels there too, with no inverse FFT beside them, the
+    rows' planes fit the budget by the count they were sized with, and
+    the program fits a chip."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpulsar.kernels import fourier as fr
+    from tpulsar.kernels import pallas_dd
+    from tpulsar.kernels import singlepulse as sp_k
+    from tpulsar.parallel import mesh as pmesh
+    from tpulsar.plan import ddplan
+    from tpulsar.search import executor
+
+    accel = tpu_accel_branch
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 4), ("beam", "dm"))
+    params = executor.SearchParams(hi_accel_zmax=zmax,
+                                   hi_accel_numharm=numharm)
+    bank = accel.build_template_bank(zmax)
+    nz = len(bank.zs)
+    nfft = ddplan.choose_n(NSAMP)
+    nbins = nfft // 2 + 1
+    rows = accel.plane_dm_chunk(nbins, nz, max_chunk=32)
+    assert rows == {51: 6, 201: 2}[nz]
+    assert (rows * accel.plane_row_bytes(nbins, nz, None)
+            * (1 + accel.PLANE_COUNT_SLACK) <= accel.PLANE_HBM_BUDGET)
+    spec = pmesh.PassSpec(
+        nfft=nfft, max_numharm=params.lo_accel_numharm,
+        topk=params.topk_per_stage, sp_widths=tuple(params.sp_widths),
+        sp_topk=sp_k.DEFAULT_TOPK,
+        sp_detrend=sp_k.detrend_estimator(params.sp_detrend),
+        whiten_est=fr.whiten_estimator(), hi=True, hi_numharm=numharm,
+        hi_seg=bank.seg, hi_step=bank.step, hi_width=bank.width,
+        hi_nz=nz, pallas_dd=True,
+        dd_stage_s=pallas_dd.stage_overhang(200), dd_interpret=False,
+        dd_pad=256)
+
+    def sds(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    compiled = pmesh.sharded_pass_fn(mesh, spec).lower(
+        sds((NSUB, NSAMP), jnp.float32),
+        sds((4 * rows, NSUB), jnp.int32, "dm", None),
+        sds((nbins,), jnp.float32),
+        sds(bank.bank_fft.shape, jnp.complex64),
+        sds(accel.corr_taps_shape(nz, bank.width), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "corr_plane" in text and "harmsum_zmax" in text
+    assert "fft_type=IFFT" not in text and "all-gather" in text
+    mem = compiled.memory_analysis()        # bytes on each device
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 14 << 30
+
+
+@pytest.mark.parametrize("rows,zmax,nbins", [
+    (2, 50.0, 1_966_081), (1, 200.0, 1_966_081), (2, 50.0, 2_097_153),
+    (2, 8.0, 65_537),            # a toy bank 64 bins wide (chip_smoke's)
+])
+def test_hi_accel_correlation_kernel(one_chip, tpu_accel_branch, rows,
+                                     zmax, nbins):
+    """accel._corr_plane alone: Mosaic takes the shifted window reads,
+    the float32 product, the sublane-strided relayout and the scoped
+    VMEM corr_plan asks for, at every width a bank has."""
+    accel = tpu_accel_branch
+    width, nz = accel.template_width(zmax), len(accel.z_grid(zmax))
+    plan = accel.corr_plan(nbins, nz, width, rows)
+    part = _sds(one_chip, (rows, nbins), jnp.float32)
+    compiled = accel._corr_plane.lower(
+        part, part,
+        _sds(one_chip, accel.corr_taps_shape(nz, width), jnp.float32),
+        width=width, nz=nz, interpret=False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "corr_plane" in text
+    assert f'"size":"{plan.vmem_limit}"' in text
 
 
 @pytest.mark.parametrize("nd,nz,ncols,numharm", [

@@ -184,10 +184,18 @@ PROGRAMS: tuple[Program, ...] = (
     _k("accel", "_accel_plane_topk",
        ("seg", "step", "width", "nz", "max_numharm", "topk")),
     _k("accel", "_correlate_block", ("seg", "step", "width", "nz")),
+    _k("accel", "_corr_plane", ("width", "nz", "interpret"),
+       doc="the direct correlation kernel (Pallas, corr_plane): the "
+           "chunk program's plane on a TPU; traced inside the chunk "
+           "program, whose gate shapes carry it (its tiles derive "
+           "from the chunk's shape: accel.corr_plan)"),
     _k("accel", "_correlate_pieces", ("seg", "step", "width", "nz")),
     _k("accel", "_correlate_zpieces", ("seg", "step", "width", "nz"),
        doc="overlap-save powers still split by z-chunk (tuple, no "
            "concatenate) — the native ZSegSrc consumer's input"),
+    _k("accel", "_split_block",
+       doc="a spectra block's real and imaginary parts, once per "
+           "block: what the chunk programs slice their rows from"),
     _k("accel", "_pad_block", ("rows",),
        doc="zero-pad a spectra block to a quantized row count "
            "(accel_batch ladder) so ragged pass chunks reuse "
@@ -596,10 +604,9 @@ def _config_groups(ctx: GateContext,
         # accel_search_batch's chunk/row programs: full (quantized)
         # spectra argument + dynamic slice (the argument buffer is
         # part of the gated footprint)
-        accel_insts = [
-            Instance("accel.accel_chunk_topk", "accel_chunk_z200",
-                     (spec_sh, bank_sh, i32),
-                     dict(nrows=dmc, **accel_kw)),
+        accel_insts = _chunk_instance(
+            "_z200", spec_sh, bank_sh, bank, nz,
+            dict(nrows=dmc, **accel_kw)) + [
             Instance("accel.accel_row_topk", "accel_row_z200",
                      (spec_sh, bank_sh, i32), dict(accel_kw)),
         ]
@@ -613,6 +620,28 @@ def _config_groups(ctx: GateContext,
         groups.append((f"accel z200 (nz={nz}, nbins={nbins}, "
                        f"dm_chunk={dmc}):", accel_insts))
     return groups
+
+
+def _chunk_instance(tag: str, spec_sh, bank_sh, bank,
+                    nz: int, kw: dict) -> list[Instance]:
+    """accel_chunk_topk with the operands accel.chunk_operands gives
+    it in this process (corr_form): for the direct form the block's
+    float32 parts and the taps, with the _split_block that makes the
+    parts; for the FFT form the complex block and no taps."""
+    import jax.numpy as jnp
+
+    from tpulsar.kernels import accel as ak
+
+    i32 = _sds((), jnp.int32)
+    if ak.corr_form() != "direct":
+        return [Instance("accel.accel_chunk_topk", f"accel_chunk{tag}",
+                         (spec_sh, bank_sh, None, i32), kw)]
+    part_sh = _sds(spec_sh.shape, jnp.float32)
+    taps_sh = _sds(ak.corr_taps_shape(nz, bank.width), jnp.float32)
+    return [Instance("accel._split_block", f"accel_split{tag}",
+                     (spec_sh,), {}),
+            Instance("accel.accel_chunk_topk", f"accel_chunk{tag}",
+                     ((part_sh, part_sh), bank_sh, taps_sh, i32), kw)]
 
 
 def _accel_native_instances(dmc: int, nbins: int, bank, nz: int,
@@ -802,15 +831,12 @@ def _headline_groups(ctx: GateContext,
                 dmc = abp.batch_rows(rows, nbins, nz)
                 q_rows = abp.quantize_rows_up(rows)
                 spec_sh = _sds((q_rows, nbins), jnp.complex64)
-                insts += [
-                    Instance("accel.accel_chunk_topk",
-                             f"accel_chunk {tag}",
-                             (spec_sh, bank_sh, i32),
-                             dict(nrows=dmc, seg=bank.seg,
-                                  step=bank.step, width=bank.width,
-                                  nz=nz,
-                                  max_numharm=_sp.hi_accel_numharm,
-                                  topk=_sp.topk_per_stage)),
+                insts += _chunk_instance(
+                    f" {tag}", spec_sh, bank_sh, bank, nz,
+                    dict(nrows=dmc, seg=bank.seg, step=bank.step,
+                         width=bank.width, nz=nz,
+                         max_numharm=_sp.hi_accel_numharm,
+                         topk=_sp.topk_per_stage)) + [
                     Instance("accel.accel_row_topk",
                              f"accel_row {tag}",
                              (spec_sh, bank_sh, i32),

@@ -62,8 +62,9 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "instead of hanging the beam"),
     _k("TPULSAR_ACCEL_HBM_GB", "float", "4",
        "GiB one hi-accel chunk program may hold live (its planes "
-       "and overlap-save intermediates, accel.plane_row_bytes): "
-       "sizes its DM rows; on a TPU a row over it is refused"),
+       "and what its correlation holds beside them, "
+       "accel.plane_row_bytes): bounds its DM rows; on a TPU a row "
+       "over it is refused"),
     _k("TPULSAR_ACCEL_NATIVE", "enum(0)", "on",
        "0 disables the native host accel consumer (CPU backend), "
        "keeping the pure XLA dispatch path"),
@@ -74,8 +75,10 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "hi-accel chunk programs enqueued before one blocking drain "
        "(1 serializes dispatch and fetch)"),
     _k("TPULSAR_ACCEL_Z_CHUNK", "int [1,64]", "auto",
-       "forced z-axis chunk height of the accel correlation "
-       "programs (plane-memory / dispatch-count trade)"),
+       "forced z-axis chunk height of the accel correlation's FFT "
+       "form (plane-memory / dispatch-count trade): the CPU's "
+       "programs; a TPU's programs correlate directly "
+       "(accel.corr_plane) and have no z pieces"),
     _k("TPULSAR_ALERT_INTERVAL_S", "float", "5",
        "health-doctor detector tick period inside the fleet "
        "controller and `tpulsar doctor --watch`; <= 0 disables the "

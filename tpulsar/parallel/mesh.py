@@ -220,7 +220,9 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
     """Build the jitted sharded per-pass search.
 
     Returns fn(subbands[nsub, T'], sub_shifts[ndms, nsub],
-               keep_mask[nbins] float, bank_fft[nz, seg] complex)
+               keep_mask[nbins] float, bank_fft[nz, seg] complex,
+               taps: accel.corr_taps of the bank where the hi stage
+               correlates directly (accel.corr_form), else None)
     -> dict of gathered arrays:
          lo_vals/lo_bins: (nstages_lo, ndms, topk)
          sp_snr/sp_idx:   (nwidths, ndms, sp_topk)
@@ -262,7 +264,7 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
         return jax.lax.all_to_all(series_loc, "dm", split_axis=0,
                                   concat_axis=1, tiled=True)
 
-    def body(subb, shifts, keep, bank):
+    def body(subb, shifts, keep, bank, taps):
         if spec.seq_sharded:
             series = seq_dedisperse_a2a(subb, shifts)
         elif spec.pallas_dd:
@@ -300,7 +302,7 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
         if spec.hi:
             hv, hr, hz = ak._accel_block_topk(
                 wspec, bank, spec.hi_seg, spec.hi_step, spec.hi_width,
-                spec.hi_nz, spec.hi_numharm, spec.topk)
+                spec.hi_nz, spec.hi_numharm, spec.topk, taps=taps)
             out["hi_vals"] = g(hv, 0)
             out["hi_rbins"] = g(hr, 0)
             out["hi_zidx"] = g(hz, 0)
@@ -310,8 +312,8 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
                  (("lo_vals", "lo_bins", "sp_snr", "sp_idx")
                   + (("hi_vals", "hi_rbins", "hi_zidx")
                      if spec.hi else ()))}
-    in_specs = ((P(None, "dm"), P(), P(), P()) if spec.seq_sharded
-                else (P(), P("dm", None), P(), P()))
+    in_specs = ((P(None, "dm"), P(), P(), P(), P()) if spec.seq_sharded
+                else (P(), P("dm", None), P(), P(), P()))
     return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=in_specs,

@@ -1884,6 +1884,13 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     keep_arr = jnp.asarray(keep.astype(np.float32))
     bank_arr = (jnp.asarray(bank.bank_fft) if hi_sharded
                 else jnp.zeros((1, 1), jnp.complex64))
+    # the hi stage's taps where it correlates directly (a TPU mesh):
+    # sent once a pass, to every device
+    taps_arr = None
+    if hi_sharded and accel_k.corr_form() == "direct":
+        taps_arr = jax.device_put(
+            accel_k.corr_taps(bank),
+            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
 
     padded = pmesh.shard_dm_table(np.asarray(sub_shifts), n_dm)
     ndms_pad, ndms = len(padded), len(dms)
@@ -1891,7 +1898,10 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     # accel-plane HBM budget and the configured DM chunk.
     chunk = params.max_dms_per_chunk
     if hi_sharded:
-        chunk = min(chunk, accel_k.plane_dm_chunk(nbins, nz) * n_dm)
+        # as many rows a device as the budget holds: here they share
+        # one program's every stage, not the hi stage's alone
+        chunk = min(chunk, n_dm * accel_k.plane_dm_chunk(
+            nbins, nz, max_chunk=32))
     chunk = max(n_dm, (chunk // n_dm) * n_dm)
     chunk = min(chunk, ndms_pad)
 
@@ -1912,7 +1922,7 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     for c0 in range(0, ndms_pad, chunk):
         s0 = min(c0, ndms_pad - chunk)   # clamp: keep one compile
         out = fn(subb, jnp.asarray(padded[s0:s0 + chunk]), keep_arr,
-                 bank_arr)
+                 bank_arr, taps_arr)
         sl = slice(s0, s0 + chunk)
         lo_vals[:, sl] = np.asarray(out["lo_vals"])
         lo_bins[:, sl] = np.asarray(out["lo_bins"])
