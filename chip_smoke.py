@@ -27,8 +27,9 @@ fsck`` is clean.  Each phase prints one JSON line; the last line is
 stamped it on the result record.  Nothing is caught into an exit 0.
 
 ``--chips 4`` runs, in this one process, ``executor.search_beam`` on
-the same beam and DM window with ``mesh=make_mesh(n_dm=4)`` and again
-with ``mesh=None``, checks that the sharded pass really spans four
+the same beam and DM window with ``SearchParams(dm_shards=4)`` (the
+layout a served worker takes from ``searching.dm_shards``) and again
+with ``dm_shards=1``, checks that the sharded pass really spans four
 devices and that the two candidate lists match one-to-one, prints
 both wall-clocks, and nothing else.
 """
@@ -440,17 +441,17 @@ def sharded_beam(args, size: dict, root: str) -> dict:
             return out
         return call
 
-    params = executor.SearchParams(dm_max=DM_MAX, make_plots=False)
     walls, lists = {}, {}
-    for name, mesh in (("mesh", pmesh.make_mesh(n_dm=args.chips)),
-                       ("single", None)):
+    for name, shards in (("mesh", args.chips), ("single", 1)):
+        params = executor.SearchParams(dm_max=DM_MAX, make_plots=False,
+                                       dm_shards=shards)
         out = os.path.join(root, f"out_{name}")
         pmesh.sharded_pass_fn = spying
         t0 = time.time()
         try:
             res = executor.search_beam(
                 [beam], os.path.join(root, f"work_{name}"), out,
-                params=params, mesh=mesh)
+                params=params)
         finally:
             pmesh.sharded_pass_fn = make_fn
         walls[name] = round(time.time() - t0, 2)

@@ -171,6 +171,11 @@ class SearchingConfig:
     dm_max: float = 0.0                # the plan at whole-pass
     #                                    granularity (DDplan2b's -l/-d
     #                                    range args); dm_max 0 = no cap
+    dm_shards: int = 1                 # chips one beam's DM trials are
+    #                                    sharded over (SearchParams.
+    #                                    dm_shards): 4 on a v5e-4 host;
+    #                                    a worker on a host with fewer
+    #                                    refuses to start
 
 
 @dataclasses.dataclass
@@ -316,6 +321,8 @@ class TpulsarConfig:
             problems.append("email.enabled but email.recipient empty")
         if self.searching.nsub < 1:
             problems.append("searching.nsub must be >= 1")
+        if self.searching.dm_shards < 1:
+            problems.append("searching.dm_shards must be >= 1")
         if not (0 <= self.frontdoor.gateway_port <= 65535):
             problems.append("frontdoor.gateway_port out of range")
         if self.frontdoor.results_query_limit < 1:

@@ -171,6 +171,27 @@ def beam_batch_trials_total() -> metrics.Counter:
         labelnames=("path",))
 
 
+def mesh_rows_total() -> metrics.Counter:
+    return metrics.counter(
+        "tpulsar_mesh_rows_total",
+        "DM rows the DM-sharded mesh pass computed "
+        "(executor._search_pass_sharded), by kind: searched = a "
+        "trial's first search, recomputed = rows a chunk call "
+        "computed beyond those — the clamped last call going back "
+        "over rows already searched, and the table's padding to the "
+        "mesh.  searched sums to the passes' trials; "
+        "recomputed / searched is the mesh's wasted share",
+        labelnames=("kind",))
+
+
+def mesh_bytes_placed_total() -> metrics.Counter:
+    return metrics.counter(
+        "tpulsar_mesh_bytes_placed_total",
+        "bytes the mesh pass placed on its devices in `mesh-place`, "
+        "summed over the devices: the subband block, keep mask, "
+        "template bank and taps, once a pass")
+
+
 def accel_undispatched_rows_total() -> metrics.Counter:
     return metrics.counter(
         "tpulsar_accel_undispatched_rows_total",

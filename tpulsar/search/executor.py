@@ -108,6 +108,12 @@ class SearchParams:
     #                                 seq_shard_min_bytes (SURVEY.md
     #                                 section 5.7 long-sequence mapping)
     seq_shard_min_bytes: int = 2 << 30
+    dm_shards: int = 1              # the layout: each pass's DM trials
+    #                                 sharded over a (beam=1, dm=N) mesh
+    #                                 of the first N local devices
+    #                                 (dm_mesh); 1 = one device.  Fewer
+    #                                 local devices than N raises
+    #                                 before any work: no fallback
     block_quantize: str = "auto"    # read beams as uint8 with a
     #                                 per-channel affine map: "on"
     #                                 always, "off" never (float32),
@@ -135,6 +141,10 @@ class SearchParams:
             if v not in ("on", "off", "auto"):
                 raise ValueError(
                     f"{field} must be 'on'/'off'/'auto', got {v!r}")
+        if int(self.dm_shards) != self.dm_shards or self.dm_shards < 1:
+            raise ValueError(
+                f"dm_shards must be a whole number >= 1, got "
+                f"{self.dm_shards!r}")
 
     def provenance(self) -> dict:
         d = dataclasses.asdict(self)
@@ -165,11 +175,45 @@ class SearchParams:
             max_cands_to_fold=searching.max_cands_to_fold,
             low_T_to_search_s=searching.low_T_to_search,
             dm_min=searching.dm_min,
-            dm_max=searching.dm_max)
+            dm_max=searching.dm_max,
+            dm_shards=searching.dm_shards)
 
 
 class TooShortToSearchError(ValueError):
     """Observation below the low_T_to_search threshold."""
+
+
+_DM_MESHES: dict[int, object] = {}
+
+
+def dm_mesh(dm_shards: int):
+    """The (beam=1, dm=dm_shards) mesh of SearchParams.dm_shards: over
+    the first `dm_shards` local devices, built once a process.  A
+    process with fewer devices raises here, before any work: the
+    layout is the deployment's, and a beam searched on fewer chips
+    than it states is a different deployment, not a fallback."""
+    if dm_shards not in _DM_MESHES:
+        from tpulsar.parallel import mesh as pmesh
+
+        devs = jax.local_devices()
+        if len(devs) < dm_shards:
+            raise RuntimeError(
+                f"dm_shards={dm_shards} needs {dm_shards} local "
+                f"devices, this process has {len(devs)} "
+                f"({devs[0].platform}): start it on a host with "
+                f"{dm_shards} chips or set searching.dm_shards to "
+                f"what the host has")
+        _DM_MESHES[dm_shards] = pmesh.make_mesh(
+            n_beam=1, n_dm=dm_shards, devices=devs[:dm_shards])
+    return _DM_MESHES[dm_shards]
+
+
+def _layout_mesh(params: "SearchParams", mesh):
+    """The mesh a search runs on: the caller's, else the one the
+    parameters' layout states, else None (one device)."""
+    if mesh is None and params.dm_shards > 1:
+        mesh = dm_mesh(params.dm_shards)
+    return mesh
 
 
 @dataclasses.dataclass
@@ -219,6 +263,7 @@ def search_beam(fns: list[str], workdir: str, resultsdir: str,
     """
     _activate_runtime()
     params = params or SearchParams()
+    mesh = _layout_mesh(params, mesh)   # too few devices: raises here
     if trace_mod.enabled():
         # one trace file per beam: clear events at beam start so the
         # saved <basenm>_trace.json rollup matches THIS beam's
@@ -510,6 +555,12 @@ def search_beam_batch(specs: list[BeamSpec],
 
     _activate_runtime()
     params = params or SearchParams()
+    if params.dm_shards > 1 and len(specs) > 1:
+        raise ValueError(
+            f"dm_shards={params.dm_shards} shards ONE beam's DM trials "
+            f"over the mesh; a group of {len(specs)} beams cannot ride "
+            f"it: search them one at a time (search_beam) or set "
+            f"dm_shards=1 to coalesce")
     results = [BeamBatchResult(spec=s) for s in specs]
 
     preludes: dict[int, tuple] = {}
@@ -727,11 +778,13 @@ def search_block(data: jnp.ndarray, freqs: np.ndarray, dt: float,
     conversion fuses into the subband reduction).  This is the
     benchmark surface: no file I/O, just the compute chain.
 
-    mesh: a jax.sharding.Mesh with a 'dm' axis — each pass's DM trials
-    are sharded across it (dedispersion, single-pulse, lo- and
-    hi-accel all run per-shard; per-trial top-k blocks are the only
-    ICI traffic).  None = single-device.  Candidates are identical to
-    the single-device path up to float reduction order.
+    params.dm_shards > 1: each pass's DM trials are sharded over the
+    `dm` axis of a mesh of that many local devices (dm_mesh) —
+    dedispersion, single-pulse, lo- and hi-accel all run per shard;
+    per-trial top-k blocks are the only ICI traffic.  Candidates are
+    identical to the single-device path up to float reduction order.
+    mesh: a jax.sharding.Mesh with a 'dm' axis handed in by the caller
+    instead (tests; ROADMAP D4 removes the keyword).
 
     checkpoint_dir: when set, per-pass candidate dumps (plus the
     sifted list and each folded candidate) are written there as
@@ -753,6 +806,7 @@ def search_block(data: jnp.ndarray, freqs: np.ndarray, dt: float,
     """
     params = params or SearchParams()
     timers = timers or StageTimers()
+    mesh = _layout_mesh(params, mesh)   # too few devices: raises here
     degraded.reset()   # this run's fallback flags only
     # TPULSAR_PROFILE=<dir>: capture a JAX profiler trace of the whole
     # block search (the TPU-era equivalent of the reference's stage
@@ -951,11 +1005,12 @@ def _stage1(beams, freqs, dt, nsub, step, ppass, pass_idx,
 
 
 def _sharded_pass(mesh, ps: _Pass, beam: _Beam, params, timers) -> None:
-    """One pass of one beam with its DM trials sharded over the mesh."""
-    with timers.timing("sharded-search"):
-        cands, events = _search_pass_sharded(
-            mesh, ps.subs[0], ps.sub_shifts, ps.dms, ps.dt_ds, params,
-            beam.zaplist, beam.baryv, timers=timers)
+    """One pass of one beam with its DM trials sharded over the mesh:
+    three sibling stages under the `pass` span, `mesh-place`,
+    `sharded-search` and `mesh-candidates` (_search_pass_sharded)."""
+    cands, events = _search_pass_sharded(
+        mesh, ps.subs[0], ps.sub_shifts, ps.dms, ps.dt_ds, params,
+        beam.zaplist, beam.baryv, timers=timers, pass_idx=ps.pass_idx)
     beam.cands.extend(cands)
     if len(events):
         beam.sp_chunks.append(events)
@@ -1785,7 +1840,8 @@ _SHARDED_FN_CACHE: dict[tuple, object] = {}
 
 def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
                          params: SearchParams, zaplist, baryv,
-                         timers: StageTimers | None = None):
+                         timers: StageTimers | None = None,
+                         pass_idx: int = 0):
     """One dedispersion pass with the DM axis sharded over the mesh.
 
     Runs the same pipeline as the single-device chunk loop —
@@ -1793,6 +1849,16 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     correlation — as ONE fused sharded program per DM chunk, then
     converts the gathered top-k blocks with the same host code.
     Returns (candidates, sp_events).
+
+    Three stages, so that each cost has a name: `mesh-place` puts the
+    operands every device reads whole (subband block, keep mask, bank,
+    taps) on the mesh ONCE a pass; `sharded-search` is the chunk calls
+    and their blocking fetches, a `mesh_chunk` span a call with a
+    `mesh-fetch` child; `mesh-candidates` is the host halves.  A call
+    computes `chunk` rows whatever is left of the pass (one compile:
+    the last call is clamped back over rows already searched, and the
+    table is padded to the mesh): tpulsar_mesh_rows_total counts the
+    rows that were a trial's first search, and the rest.
 
     Robustness gates carry over from the single-device path: stage-2
     dedispersion uses the Pallas sliding-window kernel exactly when
@@ -1879,18 +1945,34 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         _SHARDED_FN_CACHE[key] = pmesh.sharded_pass_fn(mesh, spec)
     fn = _SHARDED_FN_CACHE[key]
 
+    timers = timers or StageTimers()
     keep = fr.zap_mask(nbins, T_s, zaplist, baryv) \
         if zaplist is not None else np.ones(nbins, bool)
-    keep_arr = jnp.asarray(keep.astype(np.float32))
-    bank_arr = (jnp.asarray(bank.bank_fft) if hi_sharded
-                else jnp.zeros((1, 1), jnp.complex64))
-    # the hi stage's taps where it correlates directly (a TPU mesh):
-    # sent once a pass, to every device
-    taps_arr = None
-    if hi_sharded and accel_k.corr_form() == "direct":
-        taps_arr = jax.device_put(
-            accel_k.corr_taps(bank),
-            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+    # the operands every device reads whole, placed once a pass (left
+    # to the jitted program, device 0's 1.4 GiB subband block is
+    # copied to the mesh at every chunk call); sequence-parallel
+    # subbands go time-sharded.  The hi stage's taps only where it
+    # correlates directly (a TPU mesh).
+    P = jax.sharding.PartitionSpec
+    whole = jax.sharding.NamedSharding(mesh, P())
+    with timers.timing("mesh-place"):
+        subb_m = jax.device_put(
+            subb, jax.sharding.NamedSharding(mesh, P(None, "dm"))
+            if seq else whole)
+        keep_arr = jax.device_put(keep.astype(np.float32), whole)
+        bank_arr = jax.device_put(
+            bank.bank_fft if hi_sharded
+            else np.zeros((1, 1), np.complex64), whole)
+        taps_arr = None
+        if hi_sharded and accel_k.corr_form() == "direct":
+            taps_arr = jax.device_put(accel_k.corr_taps(bank), whole)
+        placed = [a for a in (subb_m, keep_arr, bank_arr, taps_arr)
+                  if a is not None]
+        jax.block_until_ready(placed)
+        nplaced = sum(sh.data.nbytes for a in placed
+                      for sh in a.addressable_shards)
+        trace_mod.annotate("mesh-place", bytes=nplaced, devices=n_dm)
+    telemetry.mesh_bytes_placed_total().inc(nplaced)
 
     padded = pmesh.shard_dm_table(np.asarray(sub_shifts), n_dm)
     ndms_pad, ndms = len(padded), len(dms)
@@ -1919,60 +2001,79 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         hi_rbins = np.empty_like(hi_vals, dtype=np.int32)
         hi_zidx = np.empty_like(hi_rbins)
 
-    for c0 in range(0, ndms_pad, chunk):
-        s0 = min(c0, ndms_pad - chunk)   # clamp: keep one compile
-        out = fn(subb, jnp.asarray(padded[s0:s0 + chunk]), keep_arr,
-                 bank_arr, taps_arr)
-        sl = slice(s0, s0 + chunk)
-        lo_vals[:, sl] = np.asarray(out["lo_vals"])
-        lo_bins[:, sl] = np.asarray(out["lo_bins"])
-        sp_snr[:, sl] = np.asarray(out["sp_snr"])
-        sp_idx[:, sl] = np.asarray(out["sp_idx"])
-        if hi_sharded:
-            hi_vals[sl] = np.asarray(out["hi_vals"])
-            hi_rbins[sl] = np.asarray(out["hi_rbins"])
-            hi_zidx[sl] = np.asarray(out["hi_zidx"])
+    with timers.timing("sharded-search"):
+        for c0 in range(0, ndms_pad, chunk):
+            s0 = min(c0, ndms_pad - chunk)   # clamp: keep one compile
+            # trials this call is the first to search; the clamped
+            # rows before c0 and the table's padding are recomputed
+            nfirst = max(0, min(c0 + chunk, ndms) - c0)
+            with trace_mod.span("mesh_chunk", pass_idx=pass_idx, lo=c0,
+                                n=nfirst, rows=chunk,
+                                rows_per_device=chunk // n_dm,
+                                devices=n_dm, hi=hi_sharded):
+                out = fn(subb_m, jnp.asarray(padded[s0:s0 + chunk]),
+                         keep_arr, bank_arr, taps_arr)
+                sl = slice(s0, s0 + chunk)
+                with trace_mod.span(
+                        "mesh-fetch",
+                        bytes=sum(x.nbytes for x in out.values())):
+                    lo_vals[:, sl] = np.asarray(out["lo_vals"])
+                    lo_bins[:, sl] = np.asarray(out["lo_bins"])
+                    sp_snr[:, sl] = np.asarray(out["sp_snr"])
+                    sp_idx[:, sl] = np.asarray(out["sp_idx"])
+                    if hi_sharded:
+                        hi_vals[sl] = np.asarray(out["hi_vals"])
+                        hi_rbins[sl] = np.asarray(out["hi_rbins"])
+                        hi_zidx[sl] = np.asarray(out["hi_zidx"])
+            telemetry.mesh_rows_total().inc(nfirst, kind="searched")
+            telemetry.mesh_rows_total().inc(chunk - nfirst,
+                                            kind="recomputed")
+    del subb_m
 
-    # both stages search the numbetween=2 half-bin grid (bin_scale)
-    lo_res = {h: (lo_vals[si, :ndms], lo_bins[si, :ndms])
-              for si, h in enumerate(stages_lo)}
-    cands = sifting.make_candidates(
-        lo_res, dms, T_s, _lo_sigma_fn(nbins),
-        sigma_min=params.sifting.sigma_threshold, bin_scale=0.5)
-    if hi_sharded:
-        zs = np.asarray(bank.zs)
-        hi_res = {h: (hi_vals[:ndms, si], hi_rbins[:ndms, si],
-                      zs[hi_zidx[:ndms, si]])
-                  for si, h in enumerate(stages_hi)}
-        cands.extend(sifting.make_candidates(
-            hi_res, dms, T_s, _hi_sigma_fn(nbins, nz),
-            sigma_min=params.sifting.sigma_threshold,
-            z_min_abs=accel_k.DZ / 2, bin_scale=0.5))
-    elif hi:
+    with timers.timing("mesh-candidates"):
+        # both stages search the numbetween=2 half-bin grid (bin_scale)
+        lo_res = {h: (lo_vals[si, :ndms], lo_bins[si, :ndms])
+                  for si, h in enumerate(stages_lo)}
+        cands = sifting.make_candidates(
+            lo_res, dms, T_s, _lo_sigma_fn(nbins),
+            sigma_min=params.sifting.sigma_threshold, bin_scale=0.5)
+        if hi_sharded:
+            zs = np.asarray(bank.zs)
+            hi_res = {h: (hi_vals[:ndms, si], hi_rbins[:ndms, si],
+                          zs[hi_zidx[:ndms, si]])
+                      for si, h in enumerate(stages_hi)}
+            cands.extend(sifting.make_candidates(
+                hi_res, dms, T_s, _hi_sigma_fn(nbins, nz),
+                sigma_min=params.sifting.sigma_threshold,
+                z_min_abs=accel_k.DZ / 2, bin_scale=0.5))
+        events = sp_k.events_from_topk(
+            sp_snr[:, :ndms], sp_idx[:, :ndms], dms, dt_ds,
+            threshold=params.sp_threshold,
+            widths=tuple(params.sp_widths))
+        trace_mod.annotate("mesh-candidates", cands=len(cands),
+                           events=len(events))
+    if hi and not hi_sharded:
         # Batched path pinned off: run the hi stage through the
         # single-device route (accel_search_batch -> its own proven
         # per-DM fallback), re-dedispersing in chunks.  Slower, but
         # correct on runtimes that reject the batched shapes.
-        from tpulsar.search import degraded
         degraded.note("sharded_hi_fallback",
                       "batched accel path pinned off on the mesh path; hi "
                       "stage re-dedisperses per chunk (2x stage-2 "
                       "cost)")
-        for lo in range(0, ndms, params.max_dms_per_chunk):
-            dm_chunk = dms[lo: lo + params.max_dms_per_chunk]
-            series = dd.dedisperse_subbands(
-                subb, jnp.asarray(np.asarray(sub_shifts)
-                                  [lo: lo + len(dm_chunk)]))
-            # bool mask, NOT float32: the bool-mask program is the one
-            # the AOT gate pre-compiles (whitened_powers casts
-            # internally, so the result is identical)
-            wspec = fr.whitened_spectrum_masked(
-                series, jnp.asarray(keep), nfft=nfft)
-            cands.extend(_hi_accel_chunk(wspec, dm_chunk, 1, T_s,
-                                         params)[0])
-    events = sp_k.events_from_topk(
-        sp_snr[:, :ndms], sp_idx[:, :ndms], dms, dt_ds,
-        threshold=params.sp_threshold, widths=tuple(params.sp_widths))
+        with timers.timing("sharded-search"):
+            for lo in range(0, ndms, params.max_dms_per_chunk):
+                dm_chunk = dms[lo: lo + params.max_dms_per_chunk]
+                series = dd.dedisperse_subbands(
+                    subb, jnp.asarray(np.asarray(sub_shifts)
+                                      [lo: lo + len(dm_chunk)]))
+                # bool mask, NOT float32: the bool-mask program is the
+                # one the AOT gate pre-compiles (whitened_powers casts
+                # internally, so the result is identical)
+                wspec = fr.whitened_spectrum_masked(
+                    series, jnp.asarray(keep), nfft=nfft)
+                cands.extend(_hi_accel_chunk(wspec, dm_chunk, 1, T_s,
+                                             params)[0])
     return cands, events
 
 

@@ -218,6 +218,13 @@ class SearchServer:
 
         cachedir.activate()
         warmstart.install_runtime_monitor()
+        if self.cfg.searching.dm_shards > 1:
+            # the layout is the deployment's: a host with fewer chips
+            # than searching.dm_shards refuses to start (raises)
+            # instead of failing every beam it claims
+            from tpulsar.search import executor
+
+            executor.dm_mesh(self.cfg.searching.dm_shards)
         if self.warm_boot:
             self.log.info("AOT warm-start (scale %g) ...",
                           self.warm_boot_scale)
