@@ -56,6 +56,16 @@ def _sds(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _whitening_loops(text, nbins):
+    """The loops of a compiled program that belong to the whitening: a
+    `while` under its scope or over an nbins-long carry (a search for
+    each bin's block, `while (s32[], s32[nbins], s32[nbins], ..)`,
+    would run in every call of the program).  The compiler's own loop
+    over an FFT's rows is not one of them."""
+    return [line for line in text.splitlines() if " while(" in line
+            and ("whiten" in line or f"[{nbins}]" in line)]
+
+
 @pytest.mark.parametrize("nsub,T,rows,overhang", [
     (NSUB, NSAMP, 19, 256),           # Mock ds=1: a 38-row chunk's call
     (NSUB, NSAMP, 32, 256),
@@ -248,6 +258,7 @@ def test_dm_sharded_pass_program_on_four_chips(v5e, tpu_accel_branch,
     text = compiled.as_text()
     assert "corr_plane" in text and "harmsum_zmax" in text
     assert "fft_type=IFFT" not in text and "all-gather" in text
+    assert not _whitening_loops(text, nbins)    # the solo program's form
     mem = compiled.memory_analysis()        # bytes on each device
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 14 << 30
@@ -297,15 +308,19 @@ def test_hi_accel_harmsum_kernel(one_chip, nd, nz, ncols, numharm):
 
 def test_whitening_program(one_chip):
     """The fused pad -> rfft -> whiten -> scale program at a ds=1 pass
-    chunk (38 trials of the full series)."""
+    chunk (38 trials of the full series): the chip's form of it holds
+    no search for the bins' blocks and no gather at all."""
     from tpulsar.kernels import fourier as fr
     from tpulsar.plan import ddplan
 
     nfft = ddplan.choose_n(NSAMP)
     compiled = fr.whitened_spectrum.lower(
         _sds(one_chip, (38, NSAMP), jnp.float32), nfft=nfft).compile()
+    nbins = nfft // 2 + 1
     assert compiled.memory_analysis().output_size_in_bytes >= \
-        38 * (nfft // 2 + 1) * 8
+        38 * nbins * 8
+    text = compiled.as_text()
+    assert not _whitening_loops(text, nbins) and " gather(" not in text
 
 
 def test_single_pulse_programs(one_chip):

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpulsar.kernels import fourier as fr
 
@@ -159,53 +160,147 @@ def test_whitened_spectrum_fusion_matches_sequence():
     assert np.all(got[:, 100:120] == 0)
 
 
-def test_whiten_level_matches_interp():
-    """The factored-out segment lookup in whiten_powers must equal
-    jnp.interp bin-for-bin (same formula, the search just runs once
-    instead of per row)."""
+def _whiten_blocks(edges, nbins):
+    """(lo, hi, in_tail) of every block the level is estimated over:
+    the tests' own copy of whiten_powers' geometry."""
+    blocks = [(int(lo), int(hi), False)
+              for lo, hi in zip(edges[:-1], edges[1:])]
+    lo, size = int(edges[-1]), fr.MAX_WHITEN_BLOCK
+    while nbins - lo >= size:
+        blocks.append((lo, lo + size, True))
+        lo += size
+    if nbins - lo > 16:
+        blocks.append((lo, nbins, False))
+    return blocks
+
+
+def _interp_whiten_oracle(powers, edges, estimator="median"):
+    """The original formulation of whiten_powers: block levels, then
+    a per-row jnp.interp between the block centres."""
     import jax
-    import jax.numpy as jnp
-    from tpulsar.kernels import fourier as fr
 
-    rng = np.random.default_rng(41)
-    nbins = 40000
-    powers = jnp.asarray(
-        rng.exponential(size=(3, nbins)).astype(np.float32))
-    edges = tuple(int(e) for e in fr._block_edges(nbins))
-    got = np.asarray(fr.whiten_powers(powers, edges))
-
-    # oracle: the original per-row jnp.interp formulation
-    centers, med_parts = [], []
-    for lo, hi in zip(edges[:-1], edges[1:]):
-        centers.append(0.5 * (lo + hi))
-        med_parts.append(jnp.median(powers[..., lo:hi],
-                                    axis=-1)[..., None])
-    tail_start = int(edges[-1])
-    ntail = nbins - tail_start
-    m = ntail // fr.MAX_WHITEN_BLOCK
-    if m > 0:
-        tail = powers[..., tail_start: tail_start
-                      + m * fr.MAX_WHITEN_BLOCK]
-        tail = tail.reshape(powers.shape[:-1]
-                            + (m, fr.MAX_WHITEN_BLOCK))
-        med_parts.append(jnp.median(tail, axis=-1))
-        centers.extend(tail_start + (j + 0.5) * fr.MAX_WHITEN_BLOCK
-                       for j in range(m))
-    rem = ntail - m * fr.MAX_WHITEN_BLOCK
-    if rem > 16:
-        lo = nbins - rem
-        centers.append(0.5 * (lo + nbins))
-        med_parts.append(jnp.median(powers[..., lo:],
-                                    axis=-1)[..., None])
-    med = jnp.concatenate(med_parts, axis=-1) / jnp.log(2.0)
+    nbins = powers.shape[-1]
+    blocks = _whiten_blocks(edges, nbins)
+    med = jnp.stack(
+        [fr._block_level(powers[..., lo:hi], estimator) if in_tail
+         else jnp.median(powers[..., lo:hi], axis=-1) / jnp.log(2.0)
+         for lo, hi, in_tail in blocks], axis=-1)
     med = jnp.maximum(med, 1e-30)
-    carr = jnp.asarray(centers, dtype=jnp.float32)
+    carr = jnp.asarray([0.5 * (lo + hi) for lo, hi, _ in blocks],
+                       dtype=jnp.float32)
     bins = jnp.arange(nbins, dtype=jnp.float32)
     level = jax.vmap(lambda mrow: jnp.interp(bins, carr, mrow))(
         med.reshape(-1, med.shape[-1])).reshape(
             powers.shape[:-1] + (nbins,))
-    want = np.asarray(powers / level)
+    return np.asarray(powers / level)
+
+
+# (leading shape, nbins, estimator): the head's last edge is 17715,
+# then m whole tail blocks of 8192 and a remainder block if rem > 16
+WHITEN_GEOMETRIES = [
+    pytest.param((2,), 7, "median", id="one-centre"),
+    pytest.param((2,), 8, "median", id="two-centres"),
+    pytest.param((2,), 20, "median", id="three-centres"),
+    pytest.param((3,), 9000, "median", id="head-cut-short"),
+    pytest.param((3,), 17725, "median", id="head-only-rem10"),
+    pytest.param((3,), 21715, "median", id="head-rem4000"),
+    pytest.param((3,), 25917, "median", id="one-tail-block-rem10"),
+    pytest.param((3,), 25924, "median", id="one-tail-block-rem17"),
+    pytest.param((3,), 34115, "median", id="two-tail-blocks-rem16"),
+    pytest.param((3,), 40000, "median", id="two-tail-blocks"),
+    pytest.param((2,), 245761, "median", id="mock-like"),
+    pytest.param((2,), 262145, "median", id="wapp-like"),
+    pytest.param((2, 3), 40000, "median", id="batch-2x3"),
+    pytest.param((), 40000, "median", id="one-row"),
+    pytest.param((2,), 60000, "clipped_mean", id="clipped-mean-tail"),
+]
+
+
+@pytest.mark.parametrize("lead,nbins,estimator", WHITEN_GEOMETRIES)
+def test_whiten_level_matches_interp(lead, nbins, estimator):
+    """The level assembled from the static block geometry
+    (_level_pieces: slices of the block levels against weight ramps)
+    must equal jnp.interp bin-for-bin, at every shape of pieces: a
+    head alone, an end segment longer or shorter than a remainder
+    block, one tail block (no broadcast piece), many."""
+    rng = np.random.default_rng(41)
+    red = 1.0 + 40.0 / np.sqrt(np.arange(1, nbins + 1))
+    powers = jnp.asarray((rng.exponential(size=lead + (nbins,))
+                          * red).astype(np.float32))
+    edges = tuple(int(e) for e in fr._block_edges(nbins))
+    got = np.asarray(fr.whiten_powers(powers, edges,
+                                      estimator=estimator))
+    want = _interp_whiten_oracle(powers, edges, estimator)
     np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("nbins", [8, 9000, 25917, 40000, 262145])
+def test_level_pieces_tile_the_spectrum(nbins):
+    """The static pieces cover every bin once, in order, with weights
+    in [0, 1]; the equal-width tail segments are ONE piece."""
+    edges = fr._block_edges(nbins)
+    blocks = _whiten_blocks(edges, nbins)
+    centers = [0.5 * (lo + hi) for lo, hi, _ in blocks]
+    m = sum(in_tail for _, _, in_tail in blocks)
+    rem_centre = not blocks[-1][2]
+    pieces = fr._level_pieces(centers, nbins)
+    assert sum(n * len(ramp) for _, n, ramp in pieces) == nbins
+    assert sum(n for _, n, _ in pieces) == len(centers) - 1
+    k_next = 0
+    for k, n, ramp in pieces:
+        assert k == k_next and ramp.dtype == np.float32
+        assert ramp.min() >= 0.0 and ramp.max() <= 1.0
+        k_next += n
+    if m > 2:
+        # the last tail centre's segment is the end segment (longer,
+        # its weight clipped) unless a remainder centre follows it
+        assert max(n for _, n, _ in pieces) == m - (1 if rem_centre else 2)
+        assert len(pieces) <= len(edges) + 2
+
+
+def _nbins_sized(text, nbins):
+    """Lines of a lowered program holding a loop, or a gather whose
+    result has a dimension of nbins."""
+    bad = []
+    for line in text.splitlines():
+        if "stablehlo.while" in line:
+            bad.append(line.strip()[:200])
+        elif "gather" in line and "stablehlo." in line:
+            result = line.rsplit("->", 1)[-1]
+            if f"{nbins}x" in result:
+                bad.append(line.strip()[:80] + " ... -> " + result)
+    return bad
+
+
+def test_whitening_programs_hold_no_search():
+    """The whitening's level comes from static slices: the lowered
+    programs hold no loop (a binary search over the constant centres
+    ran on the chip in every chunk program call until PR 32) and no
+    per-bin gather."""
+    import jax
+
+    nsamp, nfft = 70000, 80000
+    nbins = nfft // 2 + 1
+    series = jax.ShapeDtypeStruct((3, nsamp), jnp.float32)
+    keep = jax.ShapeDtypeStruct((nbins,), jnp.bool_)
+    edges = tuple(int(e) for e in fr._block_edges(nbins))
+    texts = {
+        "whitened_spectrum": fr.whitened_spectrum.lower(
+            series, nfft=nfft).as_text(),
+        "whitened_spectrum_masked": fr.whitened_spectrum_masked.lower(
+            series, keep, nfft=nfft).as_text(),
+        "_whiten_powers_jit": fr._whiten_powers_jit.lower(
+            jax.ShapeDtypeStruct((3, nbins), jnp.float32), edges,
+            "median").as_text(),
+    }
+    for name, text in texts.items():
+        assert "stablehlo.divide" in text, name
+        assert _nbins_sized(text, nbins) == [], name
+    # the check sees the form it guards against
+    searched = jax.jit(lambda c, b: c[jnp.searchsorted(c, b)]).lower(
+        jax.ShapeDtypeStruct((21,), jnp.float32),
+        jax.ShapeDtypeStruct((nbins,), jnp.float32)).as_text()
+    assert len(_nbins_sized(searched, nbins)) >= 2
 
 
 def test_whiten_clipped_mean_estimator():

@@ -22,6 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from scipy import special as sps
 
 from tpulsar.kernels import scopes
@@ -117,6 +118,32 @@ def _block_level(x: jnp.ndarray, estimator: str) -> jnp.ndarray:
     return jnp.mean(clipped, axis=-1) / (1.0 - float(np.exp(-4.0)))
 
 
+def _level_pieces(centers: list[float],
+                  nbins: int) -> list[tuple[int, int, np.ndarray]]:
+    """The static half of the level's linear interpolation between
+    block centres: pieces (k, n, ramp), in bin order.  A piece covers
+    the n adjacent segments k .. k+n-1 (segment j holds the bins in
+    (centers[j], centers[j+1]]), all len(ramp) bins long and sharing
+    the weights `ramp` of the upper centre.  The equal-width tail
+    (integer centres MAX_WHITEN_BLOCK apart: ramp j / 8192, exact in
+    float32) is ONE piece; each log-spaced head segment is its own.
+    The two end segments run to the spectrum's ends with the weight
+    clipped to 0 / 1: jnp.interp's constant extrapolation."""
+    c = np.asarray(centers, dtype=np.float64)
+    bounds = np.floor(c).astype(np.int64) + 1
+    bounds[0], bounds[-1] = 0, nbins
+    pieces: list[tuple[int, int, np.ndarray]] = []
+    for k in range(len(c) - 1):
+        bins = np.arange(bounds[k], bounds[k + 1], dtype=np.float64)
+        ramp = np.clip((bins - c[k]) / (c[k + 1] - c[k]),
+                       0.0, 1.0).astype(np.float32)
+        if pieces and np.array_equal(ramp, pieces[-1][2]):
+            pieces[-1] = (pieces[-1][0], pieces[-1][1] + 1, ramp)
+        else:
+            pieces.append((k, 1, ramp))
+    return pieces
+
+
 def whiten_powers(powers: jnp.ndarray, edges: tuple[int, ...],
                   estimator: str | None = None) -> jnp.ndarray:
     """Divide powers by a piecewise local noise level estimated from
@@ -177,24 +204,28 @@ def _whiten_powers_jit(powers: jnp.ndarray, edges: tuple[int, ...],
 
     med = jnp.concatenate(med_parts, axis=-1)
     med = jnp.maximum(med, 1e-30)
-    centers = jnp.asarray(centers, dtype=jnp.float32)
-
-    bins = jnp.arange(nbins, dtype=jnp.float32)
-    # The bin -> segment mapping depends only on the STATIC block
-    # geometry, never on the row's medians — so the binary search
-    # runs once for all rows instead of per-row inside a vmap
-    # (jnp.interp re-searched nbins~2M bins per DM trial; the
-    # headline's 12.2 s/pass FFT stage is whiten-dominated).  The
-    # interpolation formula below is jnp.interp's own (constant
-    # extrapolation via the two clips).
-    ncent = centers.shape[0]
-    idx = jnp.clip(jnp.searchsorted(centers, bins) - 1, 0, ncent - 2)
-    span = jnp.maximum(centers[idx + 1] - centers[idx], 1e-30)
-    t = jnp.clip((bins - centers[idx]) / span, 0.0, 1.0)
-    lo_v = med[..., idx]
-    hi_v = med[..., idx + 1]
-    level = lo_v * (1.0 - t) + hi_v * t
-    return powers / level
+    if len(centers) == 1:
+        return powers / med
+    # Only `med` depends on the data: which two centres a bin lies
+    # between, and how far along, is fixed by (edges, nbins).  So each
+    # static piece of the spectrum (_level_pieces) is divided by its
+    # level in the piece's own (segments, bins) shape: slices of `med`
+    # against a constant weight ramp, jnp.interp's formula.  A search
+    # for the segments is not folded away for being over constants:
+    # it would run on the device in every call of the program (nine
+    # sequential 2M-index gathers at a survey spectrum), and per-bin
+    # gathers of `med` cost as much again.
+    lead = powers.shape[:-1]
+    slabs, start = [], 0
+    for k, n, ramp in _level_pieces(centers, nbins):
+        stop = start + n * len(ramp)
+        lo_v = lax.slice_in_dim(med, k, k + n, axis=-1)[..., None]
+        hi_v = lax.slice_in_dim(med, k + 1, k + n + 1, axis=-1)[..., None]
+        slab = powers[..., start:stop].reshape(lead + (n, len(ramp)))
+        slab = slab / (lo_v * (1.0 - ramp) + hi_v * ramp)
+        slabs.append(slab.reshape(lead + (stop - start,)))
+        start = stop
+    return jnp.concatenate(slabs, axis=-1)
 
 
 def whiten(powers: jnp.ndarray,
