@@ -304,9 +304,10 @@ def test_gate_compiles_the_stage_family_the_runtime_dispatches(
     assert "dedisperse._dedisperse_subbands_scan" not in tpu
     sb = tpu["pallas_dd._form_subbands_block"]
     assert sb.kwargs["interpret"] is False
-    # 960 channels stage at block 1024 (the 16 MB scoped-VMEM rule)
-    assert sb.kwargs["block_t"] == pallas_dd.stage1_block_t(
-        960, 96, 256, 1) == 1024
+    # 960 channels stage whole at block 1024 (the scoped-VMEM rule)
+    plan1 = pallas_dd.stage1_plan(960, 96, 256, 1)
+    assert (plan1.block_t, plan1.group) == (1024, 96)
+    assert {k: sb.kwargs[k] for k in plan1._fields} == plan1._asdict()
     assert sb.args[0].dtype == "bfloat16"        # widened uint8
     # stage 2 in the wrapper's own split: the executor's chunk (76
     # trials run as 38 + 38) as 19-row programs and a fold's series as

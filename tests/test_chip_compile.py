@@ -66,19 +66,24 @@ def _whitening_loops(text, nbins):
             and ("whiten" in line or f"[{nbins}]" in line)]
 
 
-@pytest.mark.parametrize("nsub,T,rows,overhang", [
-    (NSUB, NSAMP, 19, 256),           # Mock ds=1: a 38-row chunk's call
-    (NSUB, NSAMP, 32, 256),
-    (NSUB, NSAMP, 19, 2048),
-    (NSUB, NSAMP, 32, 2048),
-    (64, 4_194_304, 19, 256),         # WAPP ds=1
-    (NSUB, 393_216, 26, 2048),        # Mock ds=10, its real overhang
-    (NSUB, NSAMP, 1, 256),            # one row at full resolution
-    (NSUB, NSAMP, 1, 8192),           # ... at the AOT gate's deepest
-    (NSUB, NSAMP, 1, 16384),          # overhangs: the last two take
-    (NSUB, NSAMP, 1, 32768),          # the subbands in groups
+@pytest.mark.parametrize("nsub,T,rows,overhang,group", [
+    (NSUB, NSAMP, 19, 256, 96),       # Mock ds=1: a 38-row chunk's call
+    (NSUB, NSAMP, 32, 256, 96),
+    (NSUB, NSAMP, 19, 2048, 96),
+    (NSUB, NSAMP, 32, 2048, 96),
+    (64, 4_194_304, 19, 256, 64),     # WAPP ds=1
+    (NSUB, 393_216, 26, 2048, 96),    # Mock ds=10, its real overhang
+    (NSUB, NSAMP, 1, 256, 96),        # one row at full resolution
+    (NSUB, NSAMP, 1, 8192, 96),       # ... at the AOT gate's deepest
+    (NSUB, NSAMP, 1, 16384, 48),      # overhangs: the last two take
+    (NSUB, NSAMP, 1, 32768, 24),      # the subbands in groups
+    (128, 1_361_920, 26, 256, 128),   # GBNCC ds=1, DM 0-0.3
+    (128, 680_960, 26, 8192, 64),     # ... ds=2, DM 52: two groups of
+    (128, 85_120, 26, 8192, 64),      # 64 in a search pass, to ds=16
+    (128, 1_361_920, 26, 16384, 32),  # ... the end of its ds=1 step
 ])
-def test_stage2_dedispersion_kernel(one_chip, nsub, T, rows, overhang):
+def test_stage2_dedispersion_kernel(one_chip, nsub, T, rows, overhang,
+                                    group):
     """pallas_dd._dedisperse_chunk at the geometry pallas_dd.stage2_plan
     derives for the survey's shapes: Mosaic takes it, and the scoped
     VMEM it is given is the plan's own request."""
@@ -94,29 +99,35 @@ def test_stage2_dedispersion_kernel(one_chip, nsub, T, rows, overhang):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert plan.vmem_bytes <= pallas_dd.STAGE2_VMEM_BUDGET
-    assert (plan.group < nsub) == (overhang >= 16384)
+    assert plan.group == group
     # the custom call's scoped-memory request, as XLA prints it
     assert f'"size":"{plan.vmem_bytes}"' in text
 
 
-@pytest.mark.parametrize("nchan,nsub,overhang", [
-    (960, 96, 256), (960, 96, 1024),     # Mock
-    (256, 64, 256),                      # WAPP width
+@pytest.mark.parametrize("nchan,nsub,overhang,want", [
+    (960, 96, 256, (1024, 96)),          # Mock: every subband a step
+    (960, 96, 1024, (1024, 96)),
+    (256, 64, 256, (4096, 64)),          # WAPP width
+    (4096, 128, 256, (4096, 8)),         # GBNCC: groups of 8 subbands
+    (4096, 128, 2048, (4096, 8)),        # ... at its deepest overhang
 ])
-def test_stage1_subband_kernel(one_chip, nchan, nsub, overhang):
+def test_stage1_subband_kernel(one_chip, nchan, nsub, overhang, want):
     """pallas_dd._form_subbands_block on one bf16 slab, at the block
-    length the wrapper's VMEM rule picks for that width."""
+    length and subband group pallas_dd.stage1_plan picks for that
+    width: Mosaic takes it inside the scoped VMEM the plan states."""
     from tpulsar.kernels import pallas_dd
 
-    block_t = pallas_dd.stage1_block_t(nchan, nsub, overhang, 1)
-    n_blocks = 1016
+    plan = pallas_dd.stage1_plan(nchan, nsub, overhang, 1)
+    assert (plan.block_t, plan.group) == want
+    n_blocks = 59 if nchan == 4096 else 1016     # a 2 GB slab's
     compiled = pallas_dd._form_subbands_block.lower(
-        _sds(one_chip, (nchan, n_blocks * block_t + overhang),
+        _sds(one_chip, (nchan, n_blocks * plan.block_t + overhang),
              jnp.bfloat16),
         _sds(one_chip, (nsub, nchan // nsub), jnp.int32),
-        nsub=nsub, block_t=block_t, window=block_t + overhang,
-        interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        nsub=nsub, interpret=False, **plan.kernel_args()).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f'"size":"{plan.vmem_bytes}"' in text
 
 
 @pytest.fixture

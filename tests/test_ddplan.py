@@ -28,6 +28,41 @@ def test_survey_plan_wapp():
     assert abs(steps[-1].hidm - 1725.2) < 1e-9
 
 
+GBNCC_OBS = ddplan.Observation(dt=81.92e-6, fctr=350.0, bw=100.0,
+                               numchan=4096, blocklen=2048)
+
+
+def test_survey_plan_gbncc_is_the_planners_own():
+    """The frozen GBNCC rows are generate_ddplan's answer for the
+    survey's geometry (GUPPI at 350 MHz), row for row: five steps at
+    ds 1-16, 102 DMs a pass, 389 passes, 39,678 trials to DM 504.6."""
+    steps = ddplan.survey_plan("gbncc")
+    assert steps == ddplan.generate_ddplan(GBNCC_OBS, 0, 500, numsub=128)
+    assert [s.downsamp for s in steps] == [1, 2, 4, 8, 16]
+    assert [s.numpasses for s in steps] == [169, 60, 69, 55, 36]
+    assert {s.dms_per_pass for s in steps} == {102}
+    assert ddplan.total_dm_trials(steps) == 39678
+    assert abs(steps[-1].hidm - 504.594) < 1e-9
+    for a, b in zip(steps[:-1], steps[1:]):
+        assert abs(a.hidm - b.lodm) < 1e-9
+
+
+@pytest.mark.parametrize("survey", [None, "gbncc"])
+def test_plan_for_a_gbncc_header_runs_the_frozen_passes(survey):
+    """A header of this geometry with no survey named (a GUPPI back
+    end has no table: plan_for generates) and the survey by name give
+    the executor the same passes."""
+    import types
+
+    si = types.SimpleNamespace(num_channels=4096, dt=81.92e-6, fctr=350.0,
+                               BW=100.0, spectra_per_subint=2048,
+                               backend="GUPPI")
+    steps, obs, nsub = ddplan.plan_for(si, 0.0, 500.0, numsub=128,
+                                       survey=survey)
+    assert (obs, nsub) == (GBNCC_OBS, 128)
+    assert steps == ddplan.survey_plan("gbncc")
+
+
 def test_survey_plan_unknown_backend():
     with pytest.raises(ValueError):
         ddplan.survey_plan("guppi")

@@ -141,7 +141,8 @@ PROGRAMS: tuple[Program, ...] = (
        ("window", "group", "unroll", "vmem_bytes", "interpret")),
     _k("pallas_dd", "_pad_widen", ("pad",)),
     _k("pallas_dd", "_form_subbands_block",
-       ("nsub", "block_t", "window", "interpret")),
+       ("nsub", "block_t", "window", "group", "vmem_bytes",
+        "interpret")),
     # ---- kernels/fourier.py
     _k("fourier", "pad_series", ("nfft",)),
     _k("fourier", "complex_spectrum", ()),
@@ -926,11 +927,11 @@ def _stage1_instances(blk, nsub: int, downsamp: int, pad: int,
                          dict(nsub=nsub, downsamp=downsamp, pad=pad))]
     itemsize = jnp.dtype(blk.dtype).itemsize
     S = pallas_dd.stage_overhang(pad)
-    block_t = pallas_dd.stage1_block_t(nchan, nsub, S, itemsize)
+    plan = pallas_dd.stage1_plan(nchan, nsub, S, itemsize)
     wide = jnp.bfloat16 if itemsize == 1 else blk.dtype
     insts = {}
     for _t0, _ts, take, epad in pallas_dd.stage1_slabs(
-            T, nchan, itemsize, block_t, S):
+            T, nchan, itemsize, plan.block_t, S):
         insts[take, epad] = [
             Instance("pallas_dd._pad_widen",
                      f"pallas_pad_widen {tag} S={S} "
@@ -942,8 +943,8 @@ def _stage1_instances(blk, nsub: int, downsamp: int, pad: int,
                      f"cols={take + epad}",
                      (_sds((nchan, take + epad), wide),
                       _sds((nsub, nchan // nsub), jnp.int32)),
-                     dict(nsub=nsub, block_t=block_t,
-                          window=block_t + S, interpret=False)),
+                     dict(plan.kernel_args(), nsub=nsub,
+                          interpret=False)),
         ]
     return [i for pair in insts.values() for i in pair]
 

@@ -124,14 +124,19 @@ def _kernel_dd(shift_ref, seg_ref, nxt_ref, edge_ref, out_ref, slab, *,
     jax.lax.fori_loop(0, rows, dm_body, 0)
 
 
-def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
+def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, group, cps,
                block_t, window, needs_cast):
-    """Stage-1 subband formation, one grid step: stage the whole
-    (nchan, window) channel block at t0 = i*block_t once, then
+    """Stage-1 subband formation, one grid step (i, g): stage the
+    (group * cps, window) channel block of the g-th group of `group`
+    whole subbands at t0 = i*block_t once, then for each of them
         out[b, :] = sum_c tile[b*cps + c, sh[b,c] : sh[b,c]+block_t]
     with the shifted read expressed as a dynamic lane rotate + static
     slice (Mosaic rejects a dynamic lane-dim slice that is not
-    provably 128-aligned).  Replaces the XLA `lax.map` formulation that serializes
+    provably 128-aligned).  A subband's sum never crosses a group, so
+    the grouping changes no addition: all subbands are one group
+    wherever their tile fits (`stage1_plan`: Mock's 960 channels,
+    WAPP's 256), and a 4096-channel block goes 8 subbands at a time.
+    Replaces the XLA `lax.map` formulation that serializes
     96 subbands and measured 160.6 s of config 1's 176.5 s on-chip
     (bench_runs/rung_cfg1_full.json, 2026-08-01); the same sweep as a
     VMEM-staged Pallas program is the stage-2 kernel that does 12x
@@ -153,9 +158,10 @@ def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
     else:
         tile, sem = scratch
         tile_f32 = tile
-    i = pl.program_id(0)
+    i, g = pl.program_id(0), pl.program_id(1)
     dma = pltpu.make_async_copy(
-        data_hbm.at[:, pl.ds(i * block_t, window)], tile, sem)
+        data_hbm.at[pl.ds(g * (group * cps), group * cps),
+                    pl.ds(i * block_t, window)], tile, sem)
     dma.start()
     dma.wait()
     if needs_cast:
@@ -163,7 +169,7 @@ def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
 
     def sb_body(b, _):
         def ch_body(c, acc):
-            sh = shift_ref[b, c]
+            sh = shift_ref[g * group + b, c]
             row = tile_f32[pl.ds(b * cps + c, 1), :]
             # window - sh, not -sh: roll's contract forbids negative
             # amounts (only checkable for static ints — a traced
@@ -177,7 +183,7 @@ def _kernel_sb(shift_ref, data_hbm, out_ref, *scratch, nsub, cps,
             0, cps, ch_body, acc0)
         return 0
 
-    jax.lax.fori_loop(0, nsub, sb_body, 0)
+    jax.lax.fori_loop(0, group, sb_body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("seg",))
@@ -303,10 +309,12 @@ def stage2_plan(nsub: int, S: int, rows: int, T: int) -> Stage2Plan:
     overhang spans more than 7 segments (the 8 sublanes of the next
     block are all the kernel has): 4096 at S 16384, 8192 at 32768.
     Group: all subbands where their tile fits the VMEM budget (every
-    pass of the survey plans), else the largest divisor of nsub that
-    does (a fold's series at an overhang of 16384 and more; the XLA
-    scan takes 22.4 ms for that one row whatever the overhang, this
-    kernel 9.8-10.0).  Unroll: the most subbands per loop iteration
+    pass of the Mock and WAPP plans), else the largest divisor of nsub
+    that does (the GBNCC plan's 128 subbands in every pass past DM
+    0.3: 64 at an overhang of 8192, 32 at 16384; a fold's series at an
+    overhang of 16384 and more, where the XLA scan takes 22.4 ms for
+    that one row whatever the overhang, this kernel 9.8-10.0).
+    Unroll: the most subbands per loop iteration
     that divide the group, up to 32 (same call: 55.5 ms at 1, 29.9 at
     4, 25.7 at 8, 22.5 at 32, 22.0 at all 96: the rotates of one
     subband wait for nothing of the next); they are unrolled when the
@@ -339,17 +347,67 @@ def stage2_plan(nsub: int, S: int, rows: int, T: int) -> Stage2Plan:
                       vmem_bytes=max(16 << 20, vmem(group)))
 
 
-def stage1_block_t(nchan: int, nsub: int, S: int, itemsize: int) -> int:
-    """Stage-1 time block: the native tile + f32 scratch + out block
-    must fit Mosaic's 16 MB scoped-VMEM stack (960-channel tiles at
-    window 4352 would need ~25 MB across the two scratches)."""
-    block_t = 4096
-    itm = itemsize if itemsize > 1 else 2
-    while block_t > 512 and (
-            (itm + 4) * nchan * (block_t + S)
-            + 4 * nsub * block_t) > 13_000_000:
-        block_t //= 2
-    return block_t
+#: what a stage-1 tile may ask of VMEM: Mosaic's 16 MB scoped default
+#: less room for its own scratch
+STAGE1_VMEM_BUDGET = 13_000_000
+
+#: time blocks a stage-1 grid step may take, longest first
+_STAGE1_BLOCKS = (4096, 2048, 1024, 512)
+
+
+class Stage1Plan(typing.NamedTuple):
+    """Geometry of the stage-1 calls of one pass."""
+    block_t: int     # output samples of a grid step
+    window: int      # staged samples of a step: block_t + overhang
+    group: int       # subbands staged at a time (divides nsub)
+    vmem_bytes: int  # scoped-VMEM request of a call
+
+    def kernel_args(self) -> dict:
+        """`_form_subbands_block`'s static arguments but `nsub` and
+        `interpret`."""
+        return self._asdict()
+
+
+def stage1_plan(nchan: int, nsub: int, S: int, itemsize: int) -> Stage1Plan:
+    """Everything static about stage 1 for a (nchan, T) block of
+    `itemsize`-byte samples and overhang S, from the shapes alone.
+
+    A grid step stages `group` whole subbands' channels over block_t +
+    S samples: the native tile (1-byte samples widened to bf16), its
+    float32 copy and the output block must fit STAGE1_VMEM_BUDGET.
+    All subbands in one step at the longest block that holds them
+    (Mock's 960 channels at S 256: 1024 samples, 7.8 MB; WAPP's 256:
+    4096) — else, where not even 512 samples of every channel fit
+    (4096 channels: 18.9 MB at S 256), the longest block that holds
+    one subband and the largest divisor of nsub that fits beside it
+    (4096 channels in 128 subbands: 8 subbands x 4096 samples at every
+    S to 2048, 6.7-9.4 MB): a step rolls and re-reads its overhang, so
+    a long block of few subbands wastes less than a short block of
+    many (at S 2048, 67% of a step's samples are output at 4096, 20%
+    at 512).  A group is nsub or a multiple of 8 (the output block's
+    sublanes); where nothing fits, the smallest such group at 512
+    samples, with the scoped VMEM it needs stated."""
+    cps = nchan // nsub
+    itm = max(itemsize, 2)
+
+    def tile(group, block_t):
+        return ((itm + 4) * group * cps * (block_t + S)
+                + 4 * group * block_t)
+
+    def fits(group, block_t):
+        return tile(group, block_t) <= STAGE1_VMEM_BUDGET
+
+    # an output block of `group` rows: whole (8, 128) tiles, or all
+    groups = [g for g in range(nsub, 0, -1)
+              if nsub % g == 0 and (g % 8 == 0 or g == nsub)]
+    block_t, group = next(
+        ((t, nsub) for t in _STAGE1_BLOCKS if fits(nsub, t)), None
+    ) or next(
+        ((t, g) for t in _STAGE1_BLOCKS for g in groups if fits(g, t)),
+        (_STAGE1_BLOCKS[-1], groups[-1]))
+    return Stage1Plan(block_t=block_t, window=block_t + S, group=group,
+                      vmem_bytes=max(16 << 20,
+                                     tile(group, block_t) + (4 << 20)))
 
 
 def stage1_slabs(T: int, nchan: int, itemsize: int, block_t: int,
@@ -398,7 +456,8 @@ def dedisperse_subbands_pallas(subbands, sub_shifts,
             interpret=interpret, **plan.kernel_args())
         outs.append(res if res.shape[1] == T else res[:, :T])
     # what ran, on the executor's chunk span (docs/operations.md)
-    trace.annotate("dm_chunk", dd_calls=len(outs), dd_rows=plan.rows)
+    trace.annotate("dm_chunk", dd_calls=len(outs), dd_rows=plan.rows,
+                   dd_groups=nsub // plan.group)
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
@@ -420,15 +479,18 @@ def _pad_widen(data: jnp.ndarray, pad: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("nsub", "block_t", "window",
-                                    "interpret"))
+                   static_argnames=("nsub", "block_t", "window", "group",
+                                    "vmem_bytes", "interpret"))
 def _form_subbands_block(data_padded: jnp.ndarray,
                          shifts: jnp.ndarray, nsub: int,
-                         block_t: int, window: int,
+                         block_t: int, window: int, group: int,
+                         vmem_bytes: int,
                          interpret: bool) -> jnp.ndarray:
     """data_padded: (nchan, n_blocks*block_t + S) native dtype,
-    edge-padded.  shifts: (nsub, cps) int32, all in [0, S].
-    Returns (nsub, n_blocks*block_t) f32 (un-downsampled)."""
+    edge-padded.  shifts: (nsub, cps) int32, all in [0, S].  group:
+    subbands staged at a time (a divisor of nsub; nsub itself wherever
+    they fit).  Returns (nsub, n_blocks*block_t) f32
+    (un-downsampled)."""
     nchan, tp = data_padded.shape
     cps = nchan // nsub
     n_blocks = (tp - (window - block_t)) // block_t
@@ -436,30 +498,34 @@ def _form_subbands_block(data_padded: jnp.ndarray,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_blocks,),
+        grid=(n_blocks, nsub // group),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((nsub, block_t), lambda i, s_ref: (0, i),
+        out_specs=pl.BlockSpec((group, block_t),
+                               lambda i, g, s_ref: (g, i),
                                memory_space=pltpu.VMEM),
         scratch_shapes=(
-            [pltpu.VMEM((nchan, window), data_padded.dtype)]
-            + ([pltpu.VMEM((nchan, window), jnp.float32)]
+            [pltpu.VMEM((group * cps, window), data_padded.dtype)]
+            + ([pltpu.VMEM((group * cps, window), jnp.float32)]
                if needs_cast else [])
             + [pltpu.SemaphoreType.DMA(())]
         ),
     )
     return pl.pallas_call(
-        functools.partial(_kernel_sb, nsub=nsub, cps=cps,
+        functools.partial(_kernel_sb, group=group, cps=cps,
                           block_t=block_t, window=window,
                           needs_cast=needs_cast),
         out_shape=jax.ShapeDtypeStruct((nsub, n_blocks * block_t),
                                        jnp.float32),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
     )(shifts, data_padded)
 
 
 def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
                          block_t: int | None = None,
+                         group: int | None = None,
                          interpret: bool | None = None,
                          slab_bytes: int = 2_000_000_000):
     """Stage-1 Pallas path: (nchan, T) + per-channel shifts ->
@@ -467,7 +533,8 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
     dedisperse._form_subbands_jit (shift clamp to the pad bucket,
     edge-sample padding, floor-truncating sum-downsample) with the
     sweep restructured as one VMEM-staged sliding-window program
-    instead of a 96-step serialized `lax.map`."""
+    instead of a 96-step serialized `lax.map`.  block_t, group: the
+    tests' way to a geometry `stage1_plan` does not choose."""
     interpret = _resolve_interpret(interpret)
     data = jnp.asarray(data)
     nchan, T = data.shape
@@ -477,13 +544,15 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
     # same clamp as the XLA formulation's min(shift, pad) — a no-op
     # while S >= smax, kept so the two paths cannot drift
     shifts_np = np.minimum(shifts_np, S)
-    if block_t is None:
-        block_t = stage1_block_t(nchan, nsub, S, data.dtype.itemsize)
-    window = block_t + S
+    plan = stage1_plan(nchan, nsub, S, data.dtype.itemsize)
+    if block_t is not None:
+        plan = plan._replace(block_t=block_t, window=block_t + S)
+    if group is not None:
+        plan = plan._replace(group=group)
     shifts_dev = jnp.asarray(shifts_np)
     outs = []
     for t0, Ts, take, pad in stage1_slabs(
-            T, nchan, data.dtype.itemsize, block_t, S, slab_bytes):
+            T, nchan, data.dtype.itemsize, plan.block_t, S, slab_bytes):
         slab = _pad_widen(
             jax.lax.slice_in_dim(data, t0, t0 + take, axis=1), pad)
         if len(outs) >= 2:
@@ -495,9 +564,14 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
             # widened, and the DMA of slab k overlaps the compute of
             # slab k-1.
             jax.block_until_ready(outs[-2])
-        res = _form_subbands_block(slab, shifts_dev, nsub, block_t,
-                                   window, interpret)
+        res = _form_subbands_block(slab, shifts_dev, nsub,
+                                   interpret=interpret,
+                                   **plan.kernel_args())
         outs.append(res[:, :Ts])
+    # what ran, on the executor's stage span (docs/operations.md)
+    trace.annotate("subbanding", sb_groups=nsub // plan.group,
+                   sb_block_t=plan.block_t, sb_overhang=S,
+                   sb_slabs=len(outs))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     if downsamp > 1:
         n_ds = (T // downsamp) * downsamp

@@ -126,12 +126,27 @@ _PALFA_WAPP = [
     (205.2, 2.0, 76, 5, 96, 5),
     (965.2, 10.0, 76, 1, 96, 25),
 ]
+# The GBNCC survey's beam (GUPPI at 350 MHz: 100 MHz in 4096 channels,
+# 81.92 us; Stovall et al. 2014, ApJ 791, 67): this planner's own
+# answer for that geometry, frozen —
+# generate_ddplan(Observation(81.92e-6, 350, 100, 4096, 2048), 0, 500,
+# numsub=128), which is what plan_for hands a header of it (no GUPPI
+# back end has a table here); tests/test_ddplan.py ties the two.  Not
+# PRESTO's GBNCC_search.py table, of which no copy was at hand.
+_GBNCC = [
+    (0.0, 0.003, 102, 169, 128, 1),
+    (51.714, 0.005, 102, 60, 128, 2),
+    (82.314, 0.01, 102, 69, 128, 4),
+    (152.694, 0.03, 102, 55, 128, 8),
+    (320.994, 0.05, 102, 36, 128, 16),
+]
 
 
 def survey_plan(backend: str) -> list[DedispStep]:
-    """The hardcoded survey dedispersion plan for a backend ('pdev'
-    a.k.a. Mock, or 'wapp')."""
-    table = {"pdev": _PALFA_MOCK, "mock": _PALFA_MOCK, "wapp": _PALFA_WAPP}
+    """The frozen dedispersion plan of a survey's back end: 'pdev'
+    a.k.a. 'mock' and 'wapp' (PALFA's hardcoded tables), or 'gbncc'."""
+    table = {"pdev": _PALFA_MOCK, "mock": _PALFA_MOCK, "wapp": _PALFA_WAPP,
+             "gbncc": _GBNCC}
     key = backend.lower()
     if key not in table:
         raise ValueError(f"no dedispersion plan for unknown backend {backend!r}")

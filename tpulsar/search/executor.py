@@ -1097,14 +1097,15 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
     # hi_rows: DM rows per hi-accel chunk program as accel_search_batch
     # dispatches them (the planner's own arithmetic), 0 with hi-accel
     # off.  dd_calls x dd_rows: a beam's stage-2 program calls for
-    # this chunk and rows a call; the Pallas wrapper writes what it
-    # dispatched (0 where it did not run: the XLA scan, the tree
-    # family)
+    # this chunk and rows a call, dd_groups the subband groups a call
+    # sums over; the Pallas wrapper writes what it dispatched (0 where
+    # it did not run: the XLA scan, the tree family)
     hi_rows = (_hi_rows(B * n, ps.T_ds, params)
                if trace_mod.enabled() else 0)
     with trace_mod.span("dm_chunk", pass_idx=ps.pass_idx, lo=int(lo),
                         n=int(n), hi_rows=hi_rows, dd_calls=0,
-                        dd_rows=0, family=ps.family, **ps.group):
+                        dd_rows=0, dd_groups=0, family=ps.family,
+                        **ps.group):
         with timers.timing("dedispersing"):
             # on the tree path series and norm are outputs of ONE
             # fused executable, so the fused detrend's wall time lands
