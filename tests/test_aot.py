@@ -304,11 +304,14 @@ def test_gate_compiles_the_stage_family_the_runtime_dispatches(
     assert "dedisperse._dedisperse_subbands_scan" not in tpu
     sb = tpu["pallas_dd._form_subbands_block"]
     assert sb.kwargs["interpret"] is False
-    # 960 channels stage whole at block 1024 (the scoped-VMEM rule)
+    # 960 channels go 8 segments of 4096 samples a step, 48 subbands
+    # at a time (the scoped-VMEM rule)
     plan1 = pallas_dd.stage1_plan(960, 96, 256, 1)
-    assert (plan1.block_t, plan1.group) == (1024, 96)
+    assert (plan1.block_t, plan1.group) == (32768, 48)
     assert {k: sb.kwargs[k] for k in plan1._fields} == plan1._asdict()
-    assert sb.args[0].dtype == "bfloat16"        # widened uint8
+    assert sb.args[0].dtype == "uint8"           # staged as it is
+    assert sb.args[0].shape[1:] == (sb.args[0].shape[1], plan1.seg)
+    assert sb.args[1].shape == (960, 8, plan1.head)
     # stage 2 in the wrapper's own split: the executor's chunk (76
     # trials run as 38 + 38) as 19-row programs and a fold's series as
     # one row, never a call padded up to 32

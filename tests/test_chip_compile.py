@@ -105,24 +105,26 @@ def test_stage2_dedispersion_kernel(one_chip, nsub, T, rows, overhang,
 
 
 @pytest.mark.parametrize("nchan,nsub,overhang,want", [
-    (960, 96, 256, (1024, 96)),          # Mock: every subband a step
-    (960, 96, 1024, (1024, 96)),
-    (256, 64, 256, (4096, 64)),          # WAPP width
-    (4096, 128, 256, (4096, 8)),         # GBNCC: groups of 8 subbands
-    (4096, 128, 2048, (4096, 8)),        # ... at its deepest overhang
+    (960, 96, 256, (32768, 48)),         # Mock: two groups of 48
+    (960, 96, 1024, (32768, 48)),
+    (256, 64, 256, (32768, 64)),         # WAPP width: every subband
+    (4096, 128, 256, (32768, 16)),       # GBNCC: groups of 16 subbands
+    (4096, 128, 2048, (32768, 8)),       # ... 8 at its deepest overhang
 ])
 def test_stage1_subband_kernel(one_chip, nchan, nsub, overhang, want):
-    """pallas_dd._form_subbands_block on one bf16 slab, at the block
-    length and subband group pallas_dd.stage1_plan picks for that
-    width: Mosaic takes it inside the scoped VMEM the plan states."""
+    """pallas_dd._form_subbands_block on one uint8 slab in its segment
+    layout, at the block length and subband group
+    pallas_dd.stage1_plan picks for that width: Mosaic takes it (the
+    int32 widening of 8-bit tiles, the sublane rotates of the slab's
+    fill) inside the scoped VMEM the plan states."""
     from tpulsar.kernels import pallas_dd
 
     plan = pallas_dd.stage1_plan(nchan, nsub, overhang, 1)
     assert (plan.block_t, plan.group) == want
-    n_blocks = 59 if nchan == 4096 else 1016     # a 2 GB slab's
+    n_blocks = 7 if nchan == 4096 else 30        # a 1 GB slab's
     compiled = pallas_dd._form_subbands_block.lower(
-        _sds(one_chip, (nchan, n_blocks * plan.block_t + overhang),
-             jnp.bfloat16),
+        _sds(one_chip, (nchan, n_blocks * 8, plan.seg), jnp.uint8),
+        _sds(one_chip, (nchan, 8, plan.head), jnp.uint8),
         _sds(one_chip, (nsub, nchan // nsub), jnp.int32),
         nsub=nsub, interpret=False, **plan.kernel_args()).compile()
     text = compiled.as_text()

@@ -393,7 +393,7 @@ def test_pallas_form_subbands_matches_xla():
             jnp.asarray(data), jnp.asarray(shifts), nsub, downsamp,
             pad))
         got = np.asarray(pallas_dd.form_subbands_pallas(
-            data, shifts, nsub, downsamp, block_t=256,
+            data, shifts, nsub, downsamp, block_t=1024,
             interpret=True))
         assert got.shape == want.shape, downsamp
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3,
@@ -415,7 +415,7 @@ def test_pallas_form_subbands_edge_clamp():
     want = np.asarray(_form_subbands_jit(
         jnp.asarray(data), jnp.asarray(shifts), nsub, 1, pad))
     got = np.asarray(pallas_dd.form_subbands_pallas(
-        data, shifts, nsub, 1, block_t=128, interpret=True))
+        data, shifts, nsub, 1, block_t=1024, interpret=True))
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
@@ -456,7 +456,7 @@ def test_form_subbands_dispatch_fallback(monkeypatch):
 
 
 def test_pallas_form_subbands_slabbed_matches_single():
-    """The time-slabbed sweep (bounding the widened copy's HBM) must
+    """The time-slabbed sweep (bounding the staged copy's HBM) must
     agree exactly with the single-slab result, including slab
     boundaries where a slab reads its successor's samples and the
     final slab edge-pads."""
@@ -468,18 +468,18 @@ def test_pallas_form_subbands_slabbed_matches_single():
     data = rng.integers(0, 255, size=(nchan, T), dtype=np.uint8)
     shifts = rng.integers(0, 290, size=nchan).astype(np.int32)
     one = np.asarray(pallas_dd.form_subbands_pallas(
-        data, shifts, nsub, 1, block_t=256, interpret=True))
-    # tiny budget -> many slabs (block_t=256, nchan=16: slab_t=256)
+        data, shifts, nsub, 1, block_t=1024, interpret=True))
+    # tiny budget -> many slabs (block_t=1024, nchan=16: slab_t=1024)
     many = np.asarray(pallas_dd.form_subbands_pallas(
-        data, shifts, nsub, 1, block_t=256, interpret=True,
-        slab_bytes=16 * 2 * 256))
+        data, shifts, nsub, 1, block_t=1024, interpret=True,
+        slab_bytes=16 * 1024))
     np.testing.assert_array_equal(one, many)
     # downsampling composes with slabs
     one_ds = np.asarray(pallas_dd.form_subbands_pallas(
-        data, shifts, nsub, 3, block_t=256, interpret=True))
+        data, shifts, nsub, 3, block_t=1024, interpret=True))
     many_ds = np.asarray(pallas_dd.form_subbands_pallas(
-        data, shifts, nsub, 3, block_t=256, interpret=True,
-        slab_bytes=16 * 2 * 256))
+        data, shifts, nsub, 3, block_t=1024, interpret=True,
+        slab_bytes=16 * 1024))
     np.testing.assert_array_equal(one_ds, many_ds)
 
 

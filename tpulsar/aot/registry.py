@@ -139,7 +139,7 @@ PROGRAMS: tuple[Program, ...] = (
     _k("pallas_dd", "_segment_layout", ("seg",)),
     _k("pallas_dd", "_dedisperse_chunk",
        ("window", "group", "unroll", "vmem_bytes", "interpret")),
-    _k("pallas_dd", "_pad_widen", ("pad",)),
+    _k("pallas_dd", "_segment_slab", ("n_blocks", "seg", "head")),
     _k("pallas_dd", "_form_subbands_block",
        ("nsub", "block_t", "window", "group", "vmem_bytes",
         "interpret")),
@@ -928,20 +928,24 @@ def _stage1_instances(blk, nsub: int, downsamp: int, pad: int,
     itemsize = jnp.dtype(blk.dtype).itemsize
     S = pallas_dd.stage_overhang(pad)
     plan = pallas_dd.stage1_plan(nchan, nsub, S, itemsize)
-    wide = jnp.bfloat16 if itemsize == 1 else blk.dtype
     insts = {}
-    for _t0, _ts, take, epad in pallas_dd.stage1_slabs(
+    for slab in pallas_dd.stage1_slabs(
             T, nchan, itemsize, plan.block_t, S):
-        insts[take, epad] = [
-            Instance("pallas_dd._pad_widen",
-                     f"pallas_pad_widen {tag} S={S} "
-                     f"take={take} pad={epad}",
-                     (_sds((nchan, take), blk.dtype),),
-                     dict(pad=epad)),
+        n_blocks = slab.n_blocks
+        body, rest = (b - a for a, b in (slab.body, slab.rest))
+        insts[n_blocks, body, rest] = [
+            Instance("pallas_dd._segment_slab",
+                     f"pallas_segment_slab {tag} S={S} "
+                     f"blocks={n_blocks} body={body} rest={rest}",
+                     (_sds((nchan, body), blk.dtype),
+                      _sds((nchan, rest), blk.dtype)),
+                     dict(n_blocks=n_blocks, seg=plan.seg,
+                          head=plan.head)),
             Instance("pallas_dd._form_subbands_block",
                      f"pallas_subbands {tag} S={S} "
-                     f"cols={take + epad}",
-                     (_sds((nchan, take + epad), wide),
+                     f"blocks={n_blocks}",
+                     (_sds((nchan, n_blocks * 8, plan.seg), blk.dtype),
+                      _sds((nchan, 8, plan.head), blk.dtype),
                       _sds((nsub, nchan // nsub), jnp.int32)),
                      dict(plan.kernel_args(), nsub=nsub,
                           interpret=False)),
