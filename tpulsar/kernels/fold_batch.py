@@ -410,8 +410,12 @@ def fold_candidates_by_pass(data, freqs, dt: float, plan, cand_list,
         ch_sh, _ = dd.plan_pass_shifts(freqs, nsub, ppass.subdm,
                                        np.asarray(ppass.dms), dt,
                                        step.downsamp)
-        with trace.span("fold-device", n=len(group)):
+        with trace.span("fold-device", n=len(group)), \
+                trace.span("fold-subbands", downsamp=int(step.downsamp),
+                           pass_idx=sum(s.numpasses for s in plan[:si])
+                           + pi):
             subb = form_subbands_fn(data, ch_sh, nsub, step.downsamp)
+            trace.fence(subb)
         subrefs = dd.subband_reference_freqs(freqs, nsub)
         dt_ds = dt * step.downsamp
         # tier-group: one batch program per FoldRules geometry

@@ -640,12 +640,18 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
         plan = plan._replace(group=group)
     shifts_dev = jnp.asarray(shifts_np)
     outs = []
-    for slab in stage1_slabs(
-            T, nchan, data.dtype.itemsize, plan.block_t, S, slab_bytes):
-        segs, tail = _segment_slab(
-            jax.lax.slice_in_dim(data, *slab.body, axis=1),
-            jax.lax.slice_in_dim(data, *slab.rest, axis=1),
-            slab.n_blocks, plan.seg, plan.head)
+    slabs = stage1_slabs(T, nchan, data.dtype.itemsize, plan.block_t, S,
+                         slab_bytes)
+
+    def dispatch(k: int):
+        """Slab k's programs, enqueued in the order they always were;
+        -> (the later of its two slices, its segment layout)."""
+        slab = slabs[k]
+        body = jax.lax.slice_in_dim(data, *slab.body, axis=1)
+        rest = jax.lax.slice_in_dim(data, *slab.rest, axis=1)
+        laid = _segment_slab(body, rest, slab.n_blocks, plan.seg,
+                             plan.head)
+        del body
         if len(outs) >= 2:
             # 2-deep backpressure (the executor's pending[-2]
             # pattern): a hard per-slab block serializes the sweep,
@@ -653,19 +659,58 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
             # staged slab copy concurrently — the RESOURCE_EXHAUSTED
             # peak the slabbing bounds.  Two slabs in flight, and the
             # copy of slab k overlaps the compute of slab k-1.
-            jax.block_until_ready(outs[-2])
-        res = _form_subbands_block(segs, tail, shifts_dev, nsub,
+            with trace.span("sb-wait", slab=k):
+                jax.block_until_ready(outs[-2])
+        res = _form_subbands_block(*laid, shifts_dev, nsub,
                                    interpret=interpret,
                                    **plan.kernel_args())
         outs.append(res[:, :slab.cols])
+        return rest, laid
+
+    # A traced run's spans, one a device step (docs/operations.md).
+    # The device runs the steps back to back, so a span ENDS on a
+    # fence of its step's result and reads from the end of the step
+    # before it: under TPULSAR_TRACE_SYNC=1 the step's device time.
+    # Slab k+1 is enqueued before slab k's first fence, inside
+    # `sb-slice`: a fence with nothing queued behind it leaves the
+    # chip idle for the host's round trip (0.8 ms a step on a v5e, a
+    # quarter of GBNCC's stage: PERF.md section 6), and with one slab
+    # in flight `hbm_peak_gib` would read under the untraced loop's
+    # two.  Untraced and unfenced the order of the enqueues is the
+    # plain loop's: slab 0, 1, the wait, 2, ...
+    ahead = None
+    for k, slab in enumerate(slabs):
+        with trace.span("sb-slice", slab=k, cols=slab.cols):
+            rest, laid = ahead or dispatch(k)
+            ahead = dispatch(k + 1) if k + 1 < len(slabs) else None
+            trace.fence(rest)
+        with trace.span("sb-layout", slab=k, n_blocks=slab.n_blocks):
+            trace.fence(*laid)
+        with trace.span("sb-kernel", slab=k):
+            trace.fence(outs[k])
+        del rest, laid
     # what ran, on the executor's stage span (docs/operations.md)
     trace.annotate("subbanding", sb_groups=nsub // plan.group,
                    sb_block_t=plan.block_t, sb_seg=plan.seg,
                    sb_overhang=S, sb_slabs=len(outs))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    if downsamp > 1:
+
+    def downsampled(x):
         n_ds = (T // downsamp) * downsamp
-        out = out[:, :n_ds].reshape(nsub, -1, downsamp).sum(axis=-1)
+        return x[:, :n_ds].reshape(nsub, -1, downsamp).sum(axis=-1)
+
+    # the block's two steps the same way: both dispatched, then read
+    out = outs[0]
+    if len(outs) > 1:
+        with trace.span("sb-join", slabs=len(outs)):
+            out = joined = jnp.concatenate(outs, axis=1)
+            if downsamp > 1:
+                out = downsampled(joined)
+            trace.fence(joined)
+    elif downsamp > 1:
+        out = downsampled(out)
+    if downsamp > 1:
+        with trace.span("sb-downsample", downsamp=downsamp):
+            trace.fence(out)
     return out
 
 

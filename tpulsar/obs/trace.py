@@ -26,6 +26,14 @@ device's operations: a program span can be laid over a device gap.
 This module never imports jax (the orchestrators import it): it takes
 ``sys.modules.get("jax")``.
 
+A call's own sums: inside ``collect(sink)`` every span that closes on
+the thread and is not a stage's own (``StageTimers.timing`` opens
+those, ``_stage=True``) is handed to the sink with the name of the
+stage it closed in.  ``StageTimers.collecting`` is that sink for a
+search call, so ``times`` holds ``"mesh-fetch"`` and
+``"folding/sb-kernel"`` beside the stages, and whoever reads ``times``
+reads inside a stage with nothing threaded through the kernels.
+
 Wall time vs device time: JAX dispatch is async, so a span around an
 enqueue measures dispatch cost, not compute.  Spans are therefore
 wall-clock by default (cheap, safe to leave on), and DEVICE
@@ -128,10 +136,12 @@ def epoch() -> float:
 
 class _Frame:
     """One open span of a thread's stack."""
-    __slots__ = ("name", "id", "call", "attrs")
+    __slots__ = ("name", "id", "call", "attrs", "stage")
 
-    def __init__(self, name: str, id_: int, call: int, attrs: dict):
+    def __init__(self, name: str, id_: int, call: int, attrs: dict,
+                 stage: bool):
         self.name, self.id, self.call, self.attrs = name, id_, call, attrs
+        self.stage = stage
 
 
 def _stack() -> list[_Frame]:
@@ -195,14 +205,34 @@ def _profiler_note(name: str, attrs: dict, id_: int, call: int):
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs):
+def collect(sink):
+    """While the scope is open, every span that closes on THIS thread
+    and is not a stage's own is handed to ``sink(name, stage,
+    seconds)``: ``stage`` is the nearest enclosing stage span's name,
+    '' outside any.  ``StageTimers.collecting`` is the one caller: a
+    search call's timers sum the spans that close under it.  Per
+    thread, so two searches in two threads do not mix; with tracing
+    off no span reaches its on-path and nothing is collected."""
+    prev = getattr(_TLS, "sink", None)
+    _TLS.sink = sink
+    try:
+        yield
+    finally:
+        _TLS.sink = prev
+
+
+@contextlib.contextmanager
+def span(name: str, _stage: bool = False, **attrs):
     """Record a nested Chrome-trace complete event around the scope.
 
     Exception-safe: the span closes (and records ``error``) when the
     body raises.  Nesting is per-thread.  The event carries ``id``,
     ``parent_id`` and ``call`` (a root span's own id, inherited by
     everything under it); the parent's name and the depth ride in args
-    so a flat event list still states the tree."""
+    so a flat event list still states the tree.  ``_stage``: the span
+    is a ``StageTimers.timing`` scope, which keeps its own seconds: it
+    is what ``collect`` files the spans inside it under, and is not
+    collected itself."""
     if not _ON:
         yield
         return
@@ -210,7 +240,8 @@ def span(name: str, **attrs):
     parent = st[-1] if st else None
     depth = len(st)
     sid = next(_IDS)
-    frame = _Frame(name, sid, parent.call if parent else sid, dict(attrs))
+    frame = _Frame(name, sid, parent.call if parent else sid, dict(attrs),
+                   _stage)
     st.append(frame)
     error = ""
     t_begin = time.time()
@@ -224,6 +255,10 @@ def span(name: str, **attrs):
         t_end = time.time()
         if st and st[-1] is frame:
             st.pop()
+        sink = getattr(_TLS, "sink", None)
+        if sink is not None and not _stage:
+            sink(name, next((f.name for f in reversed(st) if f.stage), ""),
+                 t_end - t_begin)
         args = frame.attrs
         if parent:
             args["parent"] = parent.name

@@ -708,8 +708,9 @@ def _search_group(entries: list[dict], params: SearchParams,
         masks.append(mask)
 
     telemetry.beam_batch_occupancy().set(B)
-    with trace_mod.span("search_beam_batch", nbeams=B,
-                        npasses=sum(s.numpasses for s in plan)):
+    with timers.collecting(), \
+            trace_mod.span("search_beam_batch", nbeams=B,
+                           npasses=sum(s.numpasses for s in plan)):
         _plan_loop(beams, freqs, dt, plan, params, nsub, timers,
                    progress_cb)
         telemetry.beam_batch_trials_total().inc(
@@ -729,13 +730,15 @@ def _search_group(entries: list[dict], params: SearchParams,
             finish_base = telemetry.metrics.REGISTRY.snapshot()
             timers_b = StageTimers()
             timers_b.times = dict(timers.times)
-            final, folded, sp_events, num_trials = _sift_fold_finish(
-                beam, freqs, dt, params, nsub, timers_b, None, plan)
-            outcomes.append(_finalize_results(
-                spec.resultsdir, basenm, obj, si, plan, params,
-                spec.zaplist, baryv, beam.data, mask, final,
-                folded, sp_events, num_trials, timers_b, finish_base,
-                metrics_extra=group_delta))
+            timers_b.span_keys = set(timers.span_keys)
+            with timers_b.collecting():
+                final, folded, sp_events, num_trials = _sift_fold_finish(
+                    beam, freqs, dt, params, nsub, timers_b, None, plan)
+                outcomes.append(_finalize_results(
+                    spec.resultsdir, basenm, obj, si, plan, params,
+                    spec.zaplist, baryv, beam.data, mask, final,
+                    folded, sp_events, num_trials, timers_b, finish_base,
+                    metrics_extra=group_delta))
     return outcomes
 
 
@@ -816,6 +819,7 @@ def search_block(data: jnp.ndarray, freqs: np.ndarray, dt: float,
     # search nests under it and carries its id as `call`.
     with trace_mod.profile_session(
             os.environ.get("TPULSAR_PROFILE", "").strip()), \
+            timers.collecting(), \
             trace_mod.span("search_block",
                            npasses=sum(s.numpasses for s in plan)):
         nchan = data.shape[0]
@@ -1261,8 +1265,13 @@ def _sift_fold_finish(beam: _Beam, freqs, dt, params, nsub, timers,
                                         beam.ntrials)
     nfft_full = ddplan.choose_n(data.shape[1])
     T_s_full = nfft_full * dt
-    _series_for = _BoundedCache(
-        lambda dm: _dedisperse_single(data, freqs, nsub, dm, dt))
+
+    def _series(dm: float):
+        # stage 1 over the whole block again, and one stage-2 row
+        with trace_mod.span("refine-series", dm=float(dm)):
+            return _dedisperse_single(data, freqs, nsub, dm, dt)
+
+    _series_for = _BoundedCache(_series)
 
     if sifted_state is not None:
         # resumed past every pass AND past sift/refine: the verified
@@ -1379,8 +1388,10 @@ def _sift_fold_finish(beam: _Beam, freqs, dt, params, nsub, timers,
     def _subbands_for(dm: float):
         ch_sh, sub_sh = dd.plan_pass_shifts(freqs, nsub, dm, [dm],
                                             dt, 1)
-        return (dd.form_subbands(data, jnp.asarray(ch_sh), nsub, 1),
-                sub_sh[0])
+        with trace_mod.span("fold-subbands", dm=float(dm), downsamp=1):
+            subb = dd.form_subbands(data, jnp.asarray(ch_sh), nsub, 1)
+            trace_mod.fence(subb)
+        return subb, sub_sh[0]
 
     with timers.timing("folding"):
         trace_mod.annotate(n=len(to_fold))
@@ -1775,11 +1786,9 @@ def _rescue_refused_chunk(wspec, bank, dm_chunk, params: SearchParams,
     chunk_res = None
     t_rescue = _time.perf_counter()
     if not getattr(exc, "rescue_exhausted", False):
-        with trace_mod.span("accel_chunk_rescue",
-                                  n=len(dm_chunk)):
-            chunk_res = rescue.rescue_accel_chunk(
-                wspec, bank, max_numharm=params.hi_accel_numharm,
-                topk=params.topk_per_stage)
+        chunk_res = rescue.rescue_accel_chunk(
+            wspec, bank, max_numharm=params.hi_accel_numharm,
+            topk=params.topk_per_stage)
     if chunk_res is not None:
         # observed only when the rescue DELIVERED rows — the
         # trials counter and this histogram must describe the
@@ -2014,6 +2023,10 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
                                 devices=n_dm, hi=hi_sharded):
                 out = fn(subb_m, jnp.asarray(padded[s0:s0 + chunk]),
                          keep_arr, bank_arr, taps_arr)
+                # the program's seconds apart from the transfers': the
+                # first fetch would block on the same program anyway
+                with trace_mod.span("mesh-wait", rows=chunk):
+                    jax.block_until_ready(out)
                 sl = slice(s0, s0 + chunk)
                 with trace_mod.span(
                         "mesh-fetch",

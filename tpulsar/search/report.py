@@ -86,7 +86,28 @@ def progress_beat(info: str = "") -> None:
 class StageTimers:
     def __init__(self) -> None:
         self.times: dict[str, float] = {s: 0.0 for s in STAGES}
+        #: the keys of `times` that are no stage's but a span's, summed
+        #: by `collecting` in a traced search (empty with tracing off)
+        self.span_keys: set[str] = set()
         self._t0 = time.time()
+
+    def collecting(self):
+        """The scope of the search call these timers belong to: while
+        tracing is on, every non-stage span that closes on this thread
+        inside it adds its seconds to `times` under its own name
+        ("mesh-fetch") and, inside a stage, under "<stage>/<name>"
+        ("folding/sb-kernel": one function runs in three stages).  So
+        a stage's seconds can be read apart by whoever reads `times`
+        (`progress_cb`'s `stage_s`, a traced `.report`, the
+        benchmark's `stage_timers` reader), and nothing is threaded
+        through the kernels' signatures.  A stage's own entry is
+        `timing`'s, as ever."""
+        return trace.collect(self._add_span)
+
+    def _add_span(self, name: str, stage: str, seconds: float) -> None:
+        for key in (name, f"{stage}/{name}") if stage else (name,):
+            self.times[key] = self.times.get(key, 0.0) + seconds
+            self.span_keys.add(key)
 
     @contextlib.contextmanager
     def timing(self, stage: str):
@@ -101,7 +122,7 @@ class StageTimers:
         start = time.time()
         _CUR_STAGE.append((stage, start))
         try:
-            with trace.span(stage):
+            with trace.span(stage, _stage=True):
                 # beat + stderr trace INSIDE the span: their file/
                 # stream I/O (ms-scale on a loaded host) then counts
                 # toward both instruments identically instead of
@@ -136,8 +157,18 @@ class StageTimers:
                  f"   Total time: {total:.2f} s", ""]
         accounted = 0.0
         for stage, secs in self.times.items():
+            if stage in self.span_keys:
+                continue
             accounted += secs
             lines.append(f"{stage:>18s}: {secs:9.2f} s  ({100*secs/total:5.1f}%)")
+            # a traced search: where the stage's seconds went, by the
+            # spans that closed inside it (not rows of their own: they
+            # are part of the stage's, and "other" keeps its meaning)
+            for key, part in self.times.items():
+                if key in self.span_keys and key.startswith(stage + "/"):
+                    lines.append(
+                        f"{'> ' + key[len(stage) + 1:]:>22s}: "
+                        f"{part:9.2f} s  ({100*part/total:5.1f}%)")
         lines.append(f"{'other':>18s}: {total-accounted:9.2f} s  "
                      f"({100*(total-accounted)/total:5.1f}%)")
         return "\n".join(lines) + "\n"
