@@ -276,6 +276,30 @@ def test_dm_chunk_says_no_hi_rows_with_hi_accel_off(drifting_beam):
     assert not [e for e in events if e["name"] == "accel-dispatch"]
 
 
+def test_dm_chunk_says_the_lo_form(drifting_beam):
+    """dm_chunk carries lo_form / lo_tile, the lo stage's harmonic sums
+    as the dispatched program is lowered: the strided form under
+    JAX_PLATFORMS=cpu (`tiled` and the kernel's tile on a TPU:
+    fourier.lo_dispatch_attrs), docs/operations.md."""
+    from tpulsar.obs import trace
+    from tpulsar.search import executor
+
+    beams, freqs, dt, plan, _T_s = drifting_beam
+    params = executor.SearchParams(
+        nsub=16, run_hi_accel=False, topk_per_stage=16,
+        max_cands_to_fold=0, make_plots=False)
+    trace.reset()
+    trace.start()
+    try:
+        executor.search_block(beams[100.0][1], freqs, dt, plan, params)
+        chunks = [e["args"] for e in trace.events()
+                  if e["name"] == "dm_chunk"]
+    finally:
+        trace.reset()
+    assert chunks and {(a["lo_form"], a["lo_tile"]) for a in chunks} == {
+        ("strided", 0)}
+
+
 def test_dm_chunk_says_stage2_calls_and_rows(drifting_beam, monkeypatch):
     """dm_chunk carries dd_calls x dd_rows, the stage-2 program calls
     for the chunk and the rows of a call as dispatched: no padded row

@@ -270,6 +270,8 @@ def test_dm_sharded_pass_program_on_four_chips(v5e, tpu_accel_branch,
         sds(accel.corr_taps_shape(nz, bank.width), jnp.float32)).compile()
     text = compiled.as_text()
     assert "corr_plane" in text and "harmsum_zmax" in text
+    # the lo stage's kernel at a device's rows, and no decimated copy
+    assert "lo_harmsum" in text and f"f32[{nbins},{rows}]" not in text
     assert "fft_type=IFFT" not in text and "all-gather" in text
     assert not _whitening_loops(text, nbins)    # the solo program's form
     mem = compiled.memory_analysis()        # bytes on each device
@@ -317,6 +319,64 @@ def test_hi_accel_harmsum_kernel(one_chip, nd, nz, ncols, numharm):
         _sds(one_chip, (nd, nz, ncols), jnp.bfloat16), stages=stages,
         nz=nz, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,ncols,tile", [
+    (38, 3_932_162, 1024),       # Mock ds=1: the executor's chunk
+    (38, 4_194_306, 1024),       # WAPP ds=1
+    (64, 1_966_082, 1024),       # Mock ds=2: two row groups of 32
+    (102, 1_361_922, 1024),      # GBNCC ds=1: three groups of 40
+    (6, 3_932_162, 2048),        # a mesh device's rows
+    (1, 3_932_162, 2048),        # periodicity_search's one series
+])
+def test_lo_harmsum_kernel(one_chip, rows, ncols, tile):
+    """fourier._lo_block_maxima at the survey's half-bin grids: Mosaic
+    takes the blocks, the six-pass selection products, the lane
+    rotates of the block maxima and the scoped-VMEM limit that
+    lo_harmsum_plan derives (interpret mode cannot say)."""
+    from tpulsar.kernels import fourier as fr
+
+    stages = tuple(fr.harmonic_stages(16))
+    plan = fr.lo_harmsum_plan(rows, ncols, stages)
+    assert plan.tile == tile
+    text = fr._lo_block_maxima.lower(
+        _sds(one_chip, (rows, ncols), jnp.float32), stages=stages,
+        interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text and "lo_harmsum" in text
+    assert f'"size":"{plan.vmem_limit}"' in text
+
+
+@pytest.mark.parametrize("rows,nbins", [
+    (38, NSAMP // 2 + 1),        # a ds=1 pass chunk
+    (64, NSAMP // 4 + 1),        # a ds=2 pass chunk: two row groups
+])
+def test_lo_stage_program_holds_the_kernel_and_no_gather(one_chip, rows,
+                                                         nbins):
+    """lo_stage_candidates lowered for a v5e at a pass chunk of up to
+    64 rows: the kernel is in it (chosen by lax.platform_dependent, no
+    knob) and the strided form's decimated copies are not, in either
+    layout."""
+    from tpulsar.kernels import fourier as fr
+
+    text = fr.lo_stage_candidates.lower(
+        _sds(one_chip, (rows, nbins), jnp.complex64),
+        tuple(fr.harmonic_stages(16)), 64).compile().as_text()
+    assert "lo_harmsum" in text
+    # no decimated copy (the gathers left are top-k's, of (rows, 64))
+    assert f"f32[{nbins},{rows}]" not in text
+
+
+def test_lo_stage_program_keeps_the_strided_form_past_64_rows(one_chip):
+    """At a GBNCC pass chunk (102 rows) fourier.lo_form leaves the
+    strided form to the TPU: XLA carries the rows on the lanes there,
+    80% of them filled, and the kernel read 51.1 ms a call against
+    39.6 (PERF.md, PR 39)."""
+    from tpulsar.kernels import fourier as fr
+
+    text = fr.lo_stage_candidates.lower(
+        _sds(one_chip, (102, 680_961), jnp.complex64),
+        tuple(fr.harmonic_stages(16)), 64).compile().as_text()
+    assert "lo_harmsum" not in text
 
 
 def test_whitening_program(one_chip):

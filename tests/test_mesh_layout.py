@@ -313,6 +313,17 @@ def test_mesh_chunk_spans_count_the_rows(pair):
     assert all(f["args"]["bytes"] > 0 for f in fetches)
 
 
+def test_mesh_chunk_says_the_lo_form(pair):
+    """mesh_chunk carries the lo stage's harmonic sums as each device's
+    rows got them: the strided form here (a CPU; `tiled` and the
+    kernel's tile on a TPU), docs/operations.md."""
+    chunks = [e["args"] for e in pair["events"]
+              if e["name"] == "mesh_chunk"]
+    assert chunks
+    assert {(a["lo_form"], a["lo_tile"]) for a in chunks} == {
+        ("strided", 0)}
+
+
 def test_the_solo_report_lists_no_mesh_stage():
     """``report.STAGES`` and a solo search's ``.report`` text stay as
     they are: ``StageTimers.timing`` takes a mesh stage when one is

@@ -156,7 +156,12 @@ PROGRAMS: tuple[Program, ...] = (
     _k("fourier", "interbin_powers", ()),
     _k("fourier", "harmonic_sum", ("numharm",)),
     _k("fourier", "blockmax_topk", ("topk", "block_r")),
-    _k("fourier", "stage_candidates", ("numharm", "topk")),
+    _k("fourier", "_lo_block_maxima", ("stages", "interpret"),
+       doc="the lo stage's tiled harmonic-sum kernel (Pallas, "
+           "lo_harmsum): per-stage block maxima; traced inside "
+           "lo_stage_candidates where that is lowered for a TPU, whose "
+           "gate shapes carry it (its tile derives from the array's "
+           "shape: fourier.lo_harmsum_plan)"),
     _k("fourier", "all_stage_candidates", ("stages", "topk")),
     _k("fourier", "lo_stage_candidates", ("stages", "topk")),
     # ---- kernels/singlepulse.py

@@ -44,7 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpulsar.kernels import scopes
+from tpulsar.kernels import decimate, scopes
 from tpulsar.obs import trace
 
 DZ = 2.0  # z-plane step in bins (PRESTO's accelsearch grid spacing)
@@ -270,21 +270,18 @@ def _stage_maxes_strided(plane: jnp.ndarray, stages: tuple[int, ...],
 
 # --- harmonic sums: decimate contiguous tiles on the chip -------------
 # One Pallas kernel computes every stage's (max over z, argmax over z)
-# of the harmonic-summed plane.  The output is tiled over r; for the
-# output tile [j*T, (j+1)*T) harmonic hh needs the CONTIGUOUS source
-# columns [hh*j*T, hh*(j+1)*T): block j, of width hh*T, of the same
-# plane (the plane is passed once per harmonic).  Every hh-th column
-# is taken while the block is in VMEM, by a 0/1 selection matrix on
-# the MXU: for each group of 128 output columns
-# x[:, g*hh*128:(g+1)*hh*128] @ S_hh, S_hh[c, k] = (c == hh*k), with
-# float32 accumulation.  One product by 1.0 and zeros per output: exact.
-# The z rows center + hh*(zi - center), edge-clamped, are then read
-# from the decimated tile by sublane-strided loads, added in float32
-# in the oracle's left-to-right order, and only each stage's
+# of the harmonic-summed plane.  The output is tiled over r; harmonic
+# hh of an output tile is every hh-th column of a CONTIGUOUS block of
+# the same plane (the plane is passed once per harmonic), taken on the
+# MXU while the block is in VMEM: kernels/decimate.py, shared with the
+# lo stage's kernel (fourier._lo_block_maxima).  The z rows
+# center + hh*(zi - center), edge-clamped, are then read from the
+# decimated tile by sublane-strided loads, added in float32 in the
+# oracle's left-to-right order, and only each stage's
 # (max[T], argmax[T]) leaves the kernel: no (nz, L) float32
 # accumulator, no decimated copy and no lane-strided gather in HBM.
 
-_LANES = 128          # output columns per selection matmul
+_LANES = decimate.LANES
 _ZROW_PAD = 16        # z rows per block: a whole packed bf16 tile
 _HARMSUM_TILES = (1024, 512, 256, 128)
 #: block bytes the tile is chosen for / the most the kernel may ask
@@ -294,7 +291,7 @@ _HARMSUM_VMEM_MAX = 96 << 20
 
 
 @dataclasses.dataclass(frozen=True)
-class HarmsumPlan:
+class HarmsumPlan(decimate.StagePlan):
     """Tile, padding and VMEM bytes of the harmonic-sum kernel, derived
     from what it can see of its input: (nz, ncols, stages, dtype)."""
     nz: int
@@ -308,27 +305,13 @@ class HarmsumPlan:
     vmem_bytes: int           # blocks x2 + scratch + live values
     vmem_limit: int           # the scoped-VMEM limit it requests
 
-    @property
-    def numharm(self) -> int:
-        return self.stages[-1]
-
-    def stage_of(self, hh: int) -> int:
-        """Index of the stage whose sum harmonic hh first enters."""
-        return next(i for i, h in enumerate(self.stages) if h >= hh)
-
-
-def _sel_row(hh: int) -> int:
-    """First row of S_hh in the scratch that stacks S_2 .. S_H, each
-    (hh * 128, 128); _sel_row(H + 1) is the scratch's height."""
-    return _LANES * (hh * (hh - 1) // 2 - 1)
-
 
 def _harmsum_vmem_bytes(nzb: int, tile: int, numharm: int,
                         nstages: int, margin: int, itemsize: int) -> int:
     tri = numharm * (numharm + 1) // 2
     blocks = 2 * nzb * tile * itemsize * tri       # inputs, 2 buffers
     outs = 2 * 2 * nstages * 8 * tile * 4          # (1, T) pads to 8 rows
-    sel = _sel_row(numharm + 1) * _LANES * itemsize
+    sel = decimate.sel_bytes(numharm, itemsize)
     scratch = (nzb + (nzb + 2 * margin)) * tile * 4      # acc + decimated
     # live values of one harmonic: the masked block, its stacked
     # copy, the matmul's float32 result
@@ -346,12 +329,7 @@ def harmsum_plan(nz: int, ncols: int, stages: tuple[int, ...],
         raise ValueError(
             f"harmonic-sum kernel: plane dtype {dtype} is not bfloat16 "
             "or float32 (the selection matmul is exact only for those)")
-    stages = tuple(h for h in stages if ncols // h > 0)
-    if not stages or stages[0] != 1 or any(
-            b <= a for a, b in zip(stages, stages[1:])):
-        raise ValueError(
-            f"harmonic-sum kernel: stages {stages} must start at 1 and "
-            f"increase, over a plane with a column (ncols={ncols})")
+    stages = decimate.check_stages(stages, ncols, "harmonic-sum kernel")
     numharm = stages[-1]
     nzb = -(-nz // _ZROW_PAD) * _ZROW_PAD
     margin = 8 * numharm
@@ -380,9 +358,6 @@ def _harmsum_kernel(p: HarmsumPlan, dtype):
     nz, nzb, T, M = p.nz, p.nzb, p.tile, p.margin
     H, G, ns = p.numharm, p.tile // _LANES, len(p.stages)
     center = (nz - 1) // 2
-    # an f32 plane: six bf16 passes carry all 24 bits of x * 1.0
-    precision = (jax.lax.Precision.HIGHEST
-                 if jnp.dtype(dtype) == jnp.float32 else None)
 
     def kernel(*refs):
         x_refs = refs[:H]
@@ -391,35 +366,12 @@ def _harmsum_kernel(p: HarmsumPlan, dtype):
         j = pl.program_id(1)
 
         if H > 1:
-            @pl.when(j == 0)
-            def _selection_matrices():
-                for hh in range(2, H + 1):
-                    shape = (hh * _LANES, _LANES)
-                    c = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-                    sel_ref[_sel_row(hh):_sel_row(hh + 1), :] = (
-                        c == hh * k).astype(jnp.float32).astype(dtype)
+            pl.when(j == 0)(
+                lambda: decimate.write_selection(sel_ref, H, dtype))
 
         def add_harmonic(hh):
-            x_ref = x_refs[hh - 1]
-            W = hh * _LANES
-            # columns past the plane's end hold whatever the DMA left:
-            # 0 x NaN would reach real columns through the matmul
-            limit = p.ncols - j * (hh * T)
-
-            def masked():
-                x = x_ref[...]
-                col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-                return jnp.where(col < limit, x, jnp.zeros_like(x))
-
-            x = jax.lax.cond(limit < hh * T, masked, lambda: x_ref[...])
-            # the G column groups stacked on the rows: one stationary
-            # S_hh serves them all (nzb is whole tiles: no data moves)
-            lhs = x if G == 1 else jnp.concatenate(
-                [x[:, g * W:(g + 1) * W] for g in range(G)], axis=0)
-            dec = jnp.dot(lhs, sel_ref[_sel_row(hh):_sel_row(hh + 1), :],
-                          preferred_element_type=jnp.float32,
-                          precision=precision)
+            dec = decimate.decimated_tile(
+                x_refs[hh - 1], sel_ref, hh, G, p.ncols - j * (hh * T))
             dec_ref[:, M:M + nzb, :] = dec.reshape(G, nzb, _LANES)
             # z rows center + hh*(zi - center), clamped to the grid:
             # zi in [lo_zi, hi_zi] is a strided read, the rest the
@@ -507,7 +459,7 @@ def _harmsum_zmax(planes: jnp.ndarray, stages: tuple[int, ...], nz: int,
         grid=(nd, p.ntiles[0]),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((max(_sel_row(p.numharm + 1), 8), _LANES),
+            pltpu.VMEM((max(decimate.sel_row(p.numharm + 1), 8), _LANES),
                        planes.dtype),
             pltpu.VMEM((T // _LANES, p.nzb, _LANES), jnp.float32),
             pltpu.VMEM((T // _LANES, p.nzb + 2 * p.margin, _LANES),

@@ -15,6 +15,7 @@ host-side sigma conversion (sigma_from_power) exact.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 from functools import partial
@@ -23,9 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from scipy import special as sps
 
-from tpulsar.kernels import scopes
+from tpulsar.kernels import decimate, scopes
 
 
 # ----------------------------------------------------------------- rfft
@@ -367,9 +370,12 @@ def harmonic_stages(max_numharm: int) -> list[int]:
 def harmonic_sum(powers: jnp.ndarray, numharm: int) -> jnp.ndarray:
     """Incoherent harmonic sum: S_n(r) = sum_{h=1..n} P(h*r).
 
-    Uses strided slicing (P[h*r] == P[::h][r]) — no gathers.  Output
-    length nbins//numharm (fundamentals must keep harmonic numharm*r
-    inside the spectrum).
+    Strided slicing (P[h*r] == P[::h][r]): the plain form, which a
+    program lowers OFF the TPU and the tests' oracle of the tiled
+    kernel below.  On a TPU each slice is a gather along the lanes
+    (~1 ns an element: PERF.md, PR 39), so no search runs it there.
+    Output length nbins//numharm (fundamentals must keep harmonic
+    numharm*r inside the spectrum).
     """
     nbins = powers.shape[-1]
     L = nbins // numharm
@@ -388,6 +394,37 @@ def harmonic_sum(powers: jnp.ndarray, numharm: int) -> jnp.ndarray:
 BLOCK_R = 64
 
 
+def _block_maxima(summed: jnp.ndarray, block_r: int):
+    """(max, first-index argmax) of each `block_r` columns of the last
+    axis, the last block padded with -inf: (..., ceil(L / block_r))
+    each."""
+    L = summed.shape[-1]
+    nb = -(-L // block_r)
+    pad = nb * block_r - L
+    if pad:
+        summed = jnp.pad(summed,
+                         ((0, 0),) * (summed.ndim - 1) + ((0, pad),),
+                         constant_values=-jnp.inf)
+    resh = summed.reshape(summed.shape[:-1] + (nb, block_r))
+    return resh.max(axis=-1), resh.argmax(axis=-1).astype(jnp.int32)
+
+
+def _topk_blocks(bmax: jnp.ndarray, barg: jnp.ndarray, topk: int,
+                 block_r: int):
+    """Top-k over block maxima -> (vals, bins), zero-padded to topk
+    where there are fewer blocks."""
+    nb = bmax.shape[-1]
+    k = min(topk, nb)
+    vals, blk = jax.lax.top_k(bmax, k)
+    bins = blk * block_r + jnp.take_along_axis(barg, blk, axis=-1)
+    if k < topk:
+        vals = jnp.pad(vals,
+                       ((0, 0),) * (vals.ndim - 1) + ((0, topk - k),))
+        bins = jnp.pad(bins,
+                       ((0, 0),) * (bins.ndim - 1) + ((0, topk - k),))
+    return vals, bins
+
+
 @partial(jax.jit, static_argnames=("topk", "block_r"))
 def blockmax_topk(summed: jnp.ndarray, topk: int, block_r: int = BLOCK_R):
     """Hierarchical top-k over the last axis: max-reduce fixed r
@@ -400,60 +437,308 @@ def blockmax_topk(summed: jnp.ndarray, topk: int, block_r: int = BLOCK_R):
     candidate per `block_r` bins also deduplicates a peak's shoulder
     bins (replacing the explicit local-max suppression).
     """
-    L = summed.shape[-1]
-    nb = -(-L // block_r)
-    pad = nb * block_r - L
-    if pad:
-        summed = jnp.pad(summed,
-                         ((0, 0),) * (summed.ndim - 1) + ((0, pad),),
-                         constant_values=-jnp.inf)
-    resh = summed.reshape(summed.shape[:-1] + (nb, block_r))
-    bmax = resh.max(axis=-1)
-    barg = resh.argmax(axis=-1)
-    k = min(topk, nb)
-    vals, blk = jax.lax.top_k(bmax, k)
-    bins = blk * block_r + jnp.take_along_axis(barg, blk, axis=-1)
-    if k < topk:
-        vals = jnp.pad(vals,
-                       ((0, 0),) * (vals.ndim - 1) + ((0, topk - k),))
-        bins = jnp.pad(bins,
-                       ((0, 0),) * (bins.ndim - 1) + ((0, topk - k),))
-    return vals, bins
+    return _topk_blocks(*_block_maxima(summed, block_r), topk, block_r)
 
 
-@partial(jax.jit, static_argnames=("numharm", "topk"))
-def stage_candidates(powers: jnp.ndarray, numharm: int, topk: int):
-    """Top-k summed powers for one harmonic stage.
+# --- the lo stage's harmonic sums on a TPU ---------------------------
+# One Pallas kernel computes every stage's block maxima of the
+# harmonic-summed rows.  DM rows on the sublanes, columns on the lanes,
+# a grid over (row groups, column tiles); harmonic hh of an output tile
+# is every hh-th column of a contiguous block of the same array, taken
+# on the MXU (kernels/decimate.py, shared with hi-accel's kernel,
+# accel._harmsum_zmax: this is its sibling with the DM rows where that
+# one has its z rows, the row map the identity and no max over rows).
+# Stage 2h continues stage h's accumulator over its own column range
+# and adds hh = h+1 .. 2h: harmonic_sum's left-to-right float32
+# additions, so its bits, each harmonic decimated once.  The maximum
+# and first-index argmax of every BLOCK_R columns are taken on the
+# accumulator in VMEM (a log-step lane rotate and select), and only
+# those leave the kernel: no summed array, no decimated copy in HBM.
 
-    powers: (ndms, nbins) whitened.  Returns (values, bins) each of
-    shape (ndms, topk); bins are fundamental rfft bin indices.
-    """
-    with scopes.scope("lo/harmsum"):
-        summed = harmonic_sum(powers, numharm)
-    with scopes.scope("lo/topk"):
-        return blockmax_topk(summed, topk)
+#: tiles tried, widest first; the most rows a grid step takes (more
+#: are split evenly over row groups: 40 rows afford a tile of 1024,
+#: 320 stacked rows a selection matrix, and a tile writes 128 lanes of
+#: block maxima whatever its width); the scoped VMEM a tile is chosen
+#: for / the most the kernel may ask of a v5e's 128 MiB
+_LO_TILES = (2048, 1024, 512, 256, 128)
+_LO_ROWS_MAX = 40
+#: the platforms a program is lowered with the kernel for, and the
+#: most rows a call gives it there (lo_form: the one rule that
+#: _stage_block_maxima branches on and lo_dispatch_attrs reports).
+#: Everything else lowers harmonic_sum's strided form: off a TPU it is
+#: the plain form; on one XLA carries 64 rows and more on the LANES,
+#: where a stride is over sublanes and no gather, and the fuller those
+#: lanes the less the kernel has to offer.  The shipped kernel against
+#: the strided form on a v5e, ms a lo_stage_candidates call (PERF.md,
+#: PR 39): 38 rows x 3,932,162 columns 52 / 410; 64 x 1,966,082
+#: 41.1 / 48.4; 76 x 1,310,722 36.0 / 34.7; 102 x 1,361,922
+#: 51.1 / 39.6 (gbncc_steps_noaccel fell 2.1% with the kernel there)
+_LO_TILED_PLATFORMS = ("tpu",)
+_LO_TILED_ROWS = 64
+_LO_VMEM_TARGET = 72 << 20
+_LO_VMEM_MAX = 96 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class LoHarmsumPlan(decimate.StagePlan):
+    """Tile, row group and VMEM bytes of the lo stage's harmonic-sum
+    kernel, derived from what it can see of its input."""
+    rows: int
+    ncols: int
+    stages: tuple[int, ...]   # those with a column to give
+    row_block: int            # rows a grid step (whole sublanes)
+    tile: int                 # output columns a grid step, T
+    ntiles: tuple[int, ...]   # per stage: grid steps that write it
+    vmem_bytes: int           # blocks x2 + scratch + live values
+    vmem_limit: int           # the scoped-VMEM limit it requests
+
+
+def _lo_vmem_bytes(row_block: int, tile: int, numharm: int,
+                   nstages: int) -> int:
+    tri = numharm * (numharm + 1) // 2
+    blocks = 2 * row_block * tile * 4 * tri        # inputs, 2 buffers
+    outs = 2 * 2 * nstages * row_block * decimate.LANES * 4
+    acc = row_block * tile * 4
+    # live values of one harmonic (the masked block, its stacked copy,
+    # the float32 parts the six passes split it into) and of one
+    # stage's block maxima (value and index, rotated and selected)
+    live = row_block * tile * 4 * (3 * numharm + 6)
+    return blocks + outs + decimate.sel_bytes(numharm, 4) + acc + live
+
+
+def lo_harmsum_plan(rows: int, ncols: int,
+                    stages: tuple[int, ...]) -> LoHarmsumPlan:
+    """The kernel's tiling for (rows, ncols) float32 powers.  Stages
+    too high for the array to have a column are dropped.  What the
+    kernel cannot take is refused here, loudly."""
+    stages = decimate.check_stages(stages, ncols, "lo harmonic-sum kernel")
+    if rows < 1:
+        raise ValueError(f"lo harmonic-sum kernel: no rows ({rows})")
+    numharm = stages[-1]
+    groups = -(-rows // _LO_ROWS_MAX)
+    row_block = -(-(-(-rows // groups)) // 8) * 8
+    widest = -(-ncols // decimate.LANES) * decimate.LANES
+    for tile in _LO_TILES:
+        need = _lo_vmem_bytes(row_block, tile, numharm, len(stages))
+        if tile <= widest and need <= _LO_VMEM_TARGET:
+            break
+    if need > _LO_VMEM_MAX:
+        raise ValueError(
+            f"lo harmonic-sum kernel: {numharm} harmonics over "
+            f"{row_block} rows need {need} B of VMEM at the smallest "
+            f"tile, over the {_LO_VMEM_MAX} B the kernel may ask for")
+    ntiles = tuple(-(-(ncols // h) // tile) for h in stages)
+    return LoHarmsumPlan(
+        rows=rows, ncols=ncols, stages=stages,
+        row_block=row_block, tile=tile, ntiles=ntiles, vmem_bytes=need,
+        vmem_limit=max(32 << 20, need + (8 << 20)))
+
+
+def _lo_kernel(p: LoHarmsumPlan):
+    """The kernel body for one plan: refs are the numharm source
+    blocks (hh = 1..numharm), then (block max, argmax) per stage, then
+    the selection matrices and the accumulator."""
+    RB, T, H, ns = p.row_block, p.tile, p.numharm, len(p.stages)
+    G = T // decimate.LANES
+    tile3 = (G, RB, decimate.LANES)
+
+    def block_maxima(acc, ncols, j):
+        """acc (G, RB, 128): columns j*T + g*128 + lane of a stage with
+        `ncols` columns -> (max, argmax in its block) of the tile's
+        2*G blocks, even blocks at lanes [0, G), odd at [64, 64+G)."""
+        g = jax.lax.broadcasted_iota(jnp.int32, tile3, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, tile3, 2)
+        # pad columns read -inf, as blockmax_topk's padding
+        v = jnp.where(j * T + g * decimate.LANES + lane < ncols,
+                      acc, -jnp.inf)
+        i = lane % BLOCK_R
+        # lane l takes over [l, l + 2s) from [l, l + s) and
+        # [l + s, l + 2s): every index of the second is the larger, so
+        # a tie keeps the first (argmax's rule)
+        for s in (1, 2, 4, 8, 16, 32):
+            v2 = pltpu.roll(v, decimate.LANES - s, 2)
+            i2 = pltpu.roll(i, decimate.LANES - s, 2)
+            take = v2 > v
+            v = jnp.where(take, v2, v)
+            i = jnp.where(take, i2, i)
+        # lanes 0 and 64 of group g now hold its two blocks
+        slot = jax.lax.broadcasted_iota(
+            jnp.int32, tile3[1:], 1) % BLOCK_R
+        top, arg = v[0], i[0]
+        for k in range(1, G):
+            top = jnp.where(slot == k, pltpu.roll(v[k], k, 1), top)
+            arg = jnp.where(slot == k, pltpu.roll(i[k], k, 1), arg)
+        return top, arg
+
+    def kernel(*refs):
+        x_refs = refs[:H]
+        out_refs = refs[H:H + 2 * ns]
+        sel_ref, acc_ref = refs[H + 2 * ns:]
+        j = pl.program_id(1)
+
+        if H > 1:
+            pl.when(j == 0)(
+                lambda: decimate.write_selection(sel_ref, H, jnp.float32))
+
+        prev = 0
+        for si, h in enumerate(p.stages):
+            def stage(si=si, h=h, prev=prev):
+                for hh in range(prev + 1, h + 1):
+                    if hh == 1:
+                        acc_ref[...] = decimate.stack_groups(
+                            x_refs[0][...], G, decimate.LANES
+                        ).reshape(tile3)
+                    else:
+                        acc_ref[...] = acc_ref[...] + decimate.decimated_tile(
+                            x_refs[hh - 1], sel_ref, hh, G,
+                            p.ncols - j * (hh * T)).reshape(tile3)
+                top, arg = block_maxima(acc_ref[...], p.ncols // h, j)
+                out_refs[2 * si][...] = top
+                out_refs[2 * si + 1][...] = arg
+            # a tile past a stage's range adds nothing to it; stage
+            # 2h's range lies inside stage h's, so acc carries over
+            pl.when(j < p.ntiles[si])(stage)
+            prev = h
+
+    return kernel
+
+
+@partial(jax.jit, static_argnames=("stages", "interpret"))
+def _lo_block_maxima(powers: jnp.ndarray, stages: tuple[int, ...],
+                     interpret: bool) -> dict:
+    """powers (rows, ncols) float32 -> per stage the (max, argmax) of
+    every BLOCK_R columns of its harmonic sum, each
+    (rows, ceil(ncols // h / BLOCK_R)): the Pallas call itself (a stage
+    the array has no column for is answered empty, outside the call).
+
+    The powers must be finite: see kernels/decimate.py (a whitened,
+    zapped spectrum is; held by tests)."""
+    if powers.dtype != jnp.float32:
+        raise ValueError(
+            f"lo harmonic-sum kernel: powers are {powers.dtype}, not "
+            "float32")
+    rows, ncols = powers.shape
+    p = lo_harmsum_plan(rows, ncols, stages)
+    T, RB = p.tile, p.row_block
+    G = T // decimate.LANES
+
+    def clamped(last):
+        # past a stage's last tile the block index stays: no DMA, and
+        # the finished output block is not touched again
+        return lambda i, j: (i, jnp.minimum(j, last))
+
+    in_specs = [pl.BlockSpec((RB, hh * T),
+                             clamped(p.ntiles[p.stage_of(hh)] - 1))
+                for hh in range(1, p.numharm + 1)]
+    out_specs, out_shape = [], []
+    for nt in p.ntiles:
+        for dt in (jnp.float32, jnp.int32):
+            out_specs.append(pl.BlockSpec(
+                (None, RB, decimate.LANES),
+                lambda i, j, last=nt - 1: (jnp.minimum(j, last), i, 0)))
+            out_shape.append(jax.ShapeDtypeStruct(
+                (nt, rows, decimate.LANES), dt))
+    outs = pl.pallas_call(
+        _lo_kernel(p),
+        grid=(-(-rows // RB), p.ntiles[0]),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((max(decimate.sel_row(p.numharm + 1), 8),
+                        decimate.LANES), jnp.float32),
+            pltpu.VMEM((G, RB, decimate.LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=p.vmem_limit),
+        interpret=interpret, name="lo_harmsum",
+    )(*([powers] * p.numharm))
+
+    def blocks(a, h):
+        # tile t's lanes [0, G) and [64, 64 + G) are its even and odd
+        # blocks: (tiles, rows, 128) -> (rows, blocks)
+        pair = jnp.stack([a[:, :, :G], a[:, :, BLOCK_R:BLOCK_R + G]],
+                         axis=-1)
+        return jnp.moveaxis(pair, 0, 1).reshape(rows, -1)[
+            :, :-(-(ncols // h) // BLOCK_R)]
+
+    out = {h: (blocks(outs[2 * si], h), blocks(outs[2 * si + 1], h))
+           for si, h in enumerate(p.stages)}
+    for h in stages[len(p.stages):]:
+        out[h] = (jnp.zeros((rows, 0), jnp.float32),
+                  jnp.zeros((rows, 0), jnp.int32))
+    return out
+
+
+def lo_form(rows: int, platform: str) -> str:
+    """The form of the lo stage's harmonic sums in a program lowered
+    for `platform` at `rows` rows a call: "tiled" (the kernel) or
+    "strided" (see _LO_TILED_ROWS)."""
+    tiled = platform in _LO_TILED_PLATFORMS and rows <= _LO_TILED_ROWS
+    return "tiled" if tiled else "strided"
+
+
+def _stage_block_maxima(powers: jnp.ndarray,
+                        stages: tuple[int, ...]) -> dict:
+    """Per stage the block maxima of harmonic_sum(powers, h), in the
+    form lo_form names for each platform the program may be lowered
+    for — chosen per lowering, as hi-accel's
+    (accel._harmonic_stage_maxes), and to the same bits (tests hold
+    the kernel, run in Pallas's interpreter, to the strided form's)."""
+    lead = powers.shape[:-1]
+    flat = powers.reshape((-1, powers.shape[-1]))
+
+    def strided(x):
+        return {h: _block_maxima(harmonic_sum(x, h), BLOCK_R)
+                for h in stages}
+
+    def tiled(x):
+        return _lo_block_maxima(x, stages, interpret=False)
+
+    # no branch where no platform takes the kernel at these rows: the
+    # program is then the strided form's own, whatever it is lowered for
+    tiled_on = [plat for plat in _LO_TILED_PLATFORMS
+                if lo_form(flat.shape[0], plat) == "tiled"]
+    out = (jax.lax.platform_dependent(
+        flat, default=strided, **dict.fromkeys(tiled_on, tiled))
+        if tiled_on else strided(flat))
+    return {h: tuple(a.reshape(lead + a.shape[-1:]) for a in pair)
+            for h, pair in out.items()}
+
+
+def lo_dispatch_attrs(rows: int, nbins: int, stages: tuple[int, ...],
+                      platform: str) -> dict:
+    """What a lo_stage_candidates program lowered for `platform` (that
+    of the devices its operands live on) runs for (rows, nbins)
+    spectra, for the chunk's span (docs/operations.md): `lo_form`, by
+    the rule _stage_block_maxima branches on, and `lo_tile`, the
+    kernel's tile (0 for the strided form)."""
+    form = lo_form(rows, platform)
+    tile = (lo_harmsum_plan(rows, 2 * nbins, tuple(stages)).tile
+            if form == "tiled" else 0)
+    return {"lo_form": form, "lo_tile": tile}
 
 
 @partial(jax.jit, static_argnames=("stages", "topk"))
 def all_stage_candidates(powers: jnp.ndarray, stages: tuple[int, ...],
                          topk: int) -> dict:
-    """Every harmonic stage's top-k in ONE compiled program.
-
-    Per-stage jit calls compile once per (shape, numharm) pair — 5
-    stages x 6 plan steps = 30 XLA compilations per beam; fusing the
-    static stage loop cuts that to one per plan step (cold-cache
-    compile time is a real slice of the <60 s beam budget)."""
-    return {h: stage_candidates(powers, h, topk) for h in stages}
+    """Every harmonic stage's top-k in ONE compiled program: per stage
+    (values, bins), each (..., topk); bins are fundamental bin indices
+    of `powers`.  Each harmonic is read once for all stages; on a TPU
+    by the tiled kernel, elsewhere by strided slices
+    (_stage_block_maxima)."""
+    stages = tuple(stages)
+    with scopes.scope("lo/harmsum"):
+        maxima = _stage_block_maxima(powers, stages)
+    with scopes.scope("lo/topk"):
+        return {h: _topk_blocks(*maxima[h], topk, BLOCK_R)
+                for h in stages}
 
 
 @partial(jax.jit, static_argnames=("stages", "topk"))
 def lo_stage_candidates(wspec: jnp.ndarray, stages: tuple[int, ...],
                         topk: int) -> dict:
-    """interbin + every harmonic stage's top-k as ONE program: the
-    interbinned half-bin power grid is (rows, 2*nbins) float32 —
-    ~2.5 GB at survey scale — and fusing keeps it out of HBM as a
-    materialized intermediate between two separately compiled
-    programs."""
+    """interbin + every harmonic stage's top-k as ONE program, from
+    the whitened complex spectrum (rows, nbins); bins are in HALF-BIN
+    units."""
     with scopes.scope("lo/harmsum"):
         powers = interbin_powers(wspec)
     return all_stage_candidates(powers, stages, topk)
@@ -534,8 +819,7 @@ def periodicity_search(series: jnp.ndarray, T_s: float,
     spec = complex_spectrum(series)
     powers, wpow = whitened_powers(spec, keep)
     p2 = interbin_powers(scale_spectrum(spec, powers, wpow))
-    out = {}
-    for h in harmonic_stages(max_numharm):
-        vals, bins = stage_candidates(p2, h, topk)
-        out[h] = (np.asarray(vals), np.asarray(bins))
-    return out, wpow.shape[-1]
+    res = all_stage_candidates(p2, tuple(harmonic_stages(max_numharm)),
+                               topk)
+    return ({h: (np.asarray(v), np.asarray(b))
+             for h, (v, b) in res.items()}, wpow.shape[-1])

@@ -70,8 +70,8 @@ def _local_search(subbands, sub_shifts, keep_mask, spec: SearchStepSpec):
     bins); bins are in HALF-BIN units (the production dr=0.5
     detection grid — fourier.interbin_powers)."""
     from tpulsar.kernels.dedisperse import _dedisperse_subbands_scan
-    from tpulsar.kernels.fourier import (blockmax_topk, harmonic_stages,
-                                         harmonic_sum, interbin_powers,
+    from tpulsar.kernels.fourier import (all_stage_candidates,
+                                         harmonic_stages, interbin_powers,
                                          scale_spectrum, whiten_powers)
 
     pad = spec.dd_pad or subbands.shape[-1]
@@ -92,12 +92,9 @@ def _local_search(subbands, sub_shifts, keep_mask, spec: SearchStepSpec):
     wpow = wpow * keep_mask
     p2 = interbin_powers(scale_spectrum(cspec, powers, wpow))
 
-    out = {}
-    for h in harmonic_stages(spec.max_numharm):
-        summed = harmonic_sum(p2, h)
-        # same hierarchical top-k as the single-device stage_candidates
-        out[h] = blockmax_topk(summed, spec.topk)
-    return out
+    # the single-device lo stage: every harmonic read once
+    return all_stage_candidates(
+        p2, tuple(harmonic_stages(spec.max_numharm)), spec.topk)
 
 
 def sharded_search_step(mesh: Mesh, spec: SearchStepSpec):
@@ -283,12 +280,10 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
         # — identical to the single-device path; bin indices are in
         # half-bin units and the host applies bin_scale=0.5
         wspec = fr.scale_spectrum(cspec, powers, wpow)
-        p2 = fr.interbin_powers(wspec)
-        lo_vals, lo_bins = [], []
-        for h in fr.harmonic_stages(spec.max_numharm):
-            v, b = fr.stage_candidates(p2, h, spec.topk)
-            lo_vals.append(v)
-            lo_bins.append(b)
+        stages_lo = tuple(fr.harmonic_stages(spec.max_numharm))
+        lo = fr.lo_stage_candidates(wspec, stages_lo, spec.topk)
+        lo_vals = [lo[h][0] for h in stages_lo]
+        lo_bins = [lo[h][1] for h in stages_lo]
 
         def g(x, axis):
             return jax.lax.all_gather(x, "dm", axis=axis, tiled=True)

@@ -1103,13 +1103,15 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
     # off.  dd_calls x dd_rows: a beam's stage-2 program calls for
     # this chunk and rows a call, dd_groups the subband groups a call
     # sums over; the Pallas wrapper writes what it dispatched (0 where
-    # it did not run: the XLA scan, the tree family)
+    # it did not run: the XLA scan, the tree family).  lo_form /
+    # lo_tile: the lo stage's harmonic sums as the program was lowered
+    # ("tiled" and the kernel's tile for a TPU, else "strided" and 0)
     hi_rows = (_hi_rows(B * n, ps.T_ds, params)
                if trace_mod.enabled() else 0)
     with trace_mod.span("dm_chunk", pass_idx=ps.pass_idx, lo=int(lo),
                         n=int(n), hi_rows=hi_rows, dd_calls=0,
-                        dd_rows=0, dd_groups=0, family=ps.family,
-                        **ps.group):
+                        dd_rows=0, dd_groups=0, lo_form="", lo_tile=0,
+                        family=ps.family, **ps.group):
         with timers.timing("dedispersing"):
             # on the tree path series and norm are outputs of ONE
             # fused executable, so the fused detrend's wall time lands
@@ -1162,12 +1164,15 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
         with timers.timing("lo-accelsearch"):
             # half-bin detection grid (PRESTO ACCEL_DR=0.5 via
             # interbinning) — bin indices are in half-bin units, hence
-            # bin_scale=0.5 at the pass end; one fused program so the
-            # (rows, 2*nbins) interbinned grid never round-trips HBM
+            # bin_scale=0.5 at the pass end.  One program; on a TPU XLA
+            # writes the interbinned grid once for the sums' kernel
+            lo_stages = tuple(fr.harmonic_stages(params.lo_accel_numharm))
             lo_res = fr.lo_stage_candidates(
-                wspec,
-                tuple(fr.harmonic_stages(params.lo_accel_numharm)),
-                params.topk_per_stage)
+                wspec, lo_stages, params.topk_per_stage)
+            # what ran, on the chunk's span (docs/operations.md)
+            if trace_mod.enabled():
+                trace_mod.annotate("dm_chunk", **fr.lo_dispatch_attrs(
+                    *wspec.shape, lo_stages, wspec.device.platform))
             trace_mod.fence(lo_res)
 
         hi_cands = None
@@ -2020,9 +2025,14 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
             with trace_mod.span("mesh_chunk", pass_idx=pass_idx, lo=c0,
                                 n=nfirst, rows=chunk,
                                 rows_per_device=chunk // n_dm,
-                                devices=n_dm, hi=hi_sharded):
+                                devices=n_dm, hi=hi_sharded,
+                                lo_form="", lo_tile=0):
                 out = fn(subb_m, jnp.asarray(padded[s0:s0 + chunk]),
                          keep_arr, bank_arr, taps_arr)
+                if trace_mod.enabled():     # the lo stage a device's rows
+                    trace_mod.annotate(**fr.lo_dispatch_attrs(
+                        chunk // n_dm, nbins, stages_lo,
+                        mesh.devices.flat[0].platform))
                 # the program's seconds apart from the transfers': the
                 # first fetch would block on the same program anyway
                 with trace_mod.span("mesh-wait", rows=chunk):
