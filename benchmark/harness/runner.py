@@ -325,6 +325,24 @@ def measure(cell, seed: int, seconds: float, trace: bool, *,
     return result
 
 
+def unit_measure(cell):
+    """The unit of work the cell's traffic names: the slice call
+    (`measure`, where it names none), or ``measure`` of the file
+    ``units/<unit>.py`` beside the configurations, which takes the same
+    arguments and gives the same result line."""
+    import importlib.util
+
+    unit = cell.traffic.get("unit")
+    if unit is None:
+        return measure
+    name = "_bench_unit_" + unit
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(cell.bench_dir, "units", unit + ".py"))
+    mod = sys.modules[name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.measure
+
+
 def main(argv=None, t_process: float | None = None) -> int:
     import argparse
 
@@ -357,8 +375,9 @@ def main(argv=None, t_process: float | None = None) -> int:
         print(msg, file=sys.stderr, flush=True)
 
     seeds = [args.seed] + [int(s) for s in args.more_seeds.split(",") if s]
+    run = unit_measure(cell)
     for i, seed in enumerate(seeds):
-        res = measure(cell, seed, args.seconds, bool(args.trace),
+        res = run(cell, seed, args.seconds, bool(args.trace),
                       t_process=t_process if i == 0 else time.time(),
                       warm=(i == 0), control=bool(args.control), log=log,
                       dump_trace=args.dump_trace)

@@ -25,7 +25,7 @@ SCOPES, WHOLE = scopes.known_scopes(os.path.join(ROOT, "benchmark"))
 DEVICE_METRICS = sorted(
     m["name"] for m in BENCH["per_layer"] if os.path.exists(os.path.join(
         ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
-    and m["source"] == "device_trace")
+    and m["source"] == "device_trace" and m["moves"] != "readin_s")
 SPAN_METRICS = ["pass_end_host_s_per_pass", "loop_uncovered_pct",
                 "refine_host_s", "fold_host_s", "cands_folded"]
 
@@ -356,7 +356,8 @@ def test_span_readers_cut_the_programs_events_to_the_windows_calls():
 # ----------------------------------------------------- the toy cells
 
 NEW = {m["name"] for m in BENCH["per_layer"] if os.path.exists(
-    os.path.join(ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))}
+    os.path.join(ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
+    and m["moves"] != "readin_s"}      # the read-in unit's: its own test
 
 
 def test_every_new_entry_has_its_reader_and_names_an_accepted_layer():

@@ -45,7 +45,7 @@ def toy_root(tmp_path_factory):
          "traffic": "toy_steps_noaccel_dm52", "chips": 1, "why": "toy"})
     # attached the way the real cell is: its name appended to the
     # `workloads` of the metrics gbncc_steps_noaccel reports
-    for m in bench["per_layer"]:
+    for m in bench["end_to_end"] + bench["per_layer"]:
         if "gbncc_steps_noaccel" in m.get("workloads", ()):
             m["workloads"] = m["workloads"] + ["toy_gbncc_steps"]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:

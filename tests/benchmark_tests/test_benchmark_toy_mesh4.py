@@ -45,7 +45,7 @@ def toy_root(tmp_path_factory):
          "traffic": "toy_ds1_hiaccel_mesh", "chips": 4, "why": "toy"})
     # attached the way the real mesh cell is: its name appended to the
     # `workloads` of the metrics mock_ds1_hiaccel_mesh4 reports
-    for m in bench["per_layer"]:
+    for m in bench["end_to_end"] + bench["per_layer"]:
         if "mock_ds1_hiaccel_mesh4" in m.get("workloads", ()):
             m["workloads"] = m["workloads"] + ["toy_mesh4"]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:

@@ -58,8 +58,11 @@ def toy_root(tmp_path_factory):
         {"name": "toy_cands", "unit": "count", "better": "higher",
          "source": "program_counter", "layer": "sift", "moves": "finish_s",
          "workloads": toy_cells}]
-    for m in bench["per_layer"]:
-        if "workloads" in m and not m["name"].startswith("toy_"):
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        # every slice-call metric that names its cells (a read-in
+        # cell's are another unit of work's)
+        if "workloads" in m and not m["name"].startswith("toy_") \
+                and "mock_readin" not in m["workloads"]:
             m["workloads"] = m["workloads"] + (
                 ["toy_hi"] if "hiaccel" in m["name"] else toy_cells)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:
@@ -102,7 +105,9 @@ def test_a_cell_added_as_files_only_runs_and_is_correct(hi_run):
     assert cell.bench_dir.endswith("benchmark") and cell.name == "toy_hi"
     assert res["correct"] is True
     assert res["attempted"] == 76 and res["failed"] == 0
-    assert set(res["metrics"]) == {"trials_per_s", "finish_s", "setup_s"}
+    assert set(res["metrics"]) == {m["name"] for m in cell.end_to_end()}
+    assert "trials_per_s" in res["metrics"] and "readin_s" not in \
+        res["metrics"]
     assert all(v["value"] > 0 for v in res["metrics"].values())
     assert res["metrics"]["trials_per_s"]["unit"] == "trials/s"
     assert set(res["device"]) >= {"platform", "kind", "count",
