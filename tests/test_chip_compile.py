@@ -407,3 +407,26 @@ def test_single_pulse_programs(one_chip):
         series, tuple(sp_k.DEFAULT_WIDTHS),
         sp_k.DEFAULT_TOPK).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("nsamp,nchan,dtype", [
+    (NSAMP, 960, jnp.uint8),            # a Mock beam, as read_all_uint8
+    (1_464_320, 4096, jnp.uint8),       # a full GBNCC pointing, 5.6 GiB
+    (262_144, 960, jnp.float32),        # a beam under block_quantize_min
+])
+def test_read_in_transpose_fits_beside_its_input(one_chip, nsamp, nchan,
+                                                 dtype):
+    """The read-in sends the block as read, (T, nchan), and turns it
+    channel-major on the chip: the time-major input (its lanes padded
+    to 128 channels) and the output together stay under what the mask
+    holds later (block + masked block), and under the chip."""
+    from tpulsar.kernels import rfi
+
+    compiled = rfi.channel_major.lower(
+        _sds(one_chip, (nsamp, nchan), dtype)).compile()
+    mem = compiled.memory_analysis()
+    block = nsamp * nchan * jnp.dtype(dtype).itemsize
+    assert mem.output_size_in_bytes == block
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held <= 2.2 * block and held < 15.7 * 2 ** 30

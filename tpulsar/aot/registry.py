@@ -116,6 +116,8 @@ PROGRAMS: tuple[Program, ...] = (
     # ---- kernels/rfi.py
     _k("rfi", "_cell_stats_chan", ("block_len", "chunk"),
        doc="per-cell channel stats for the RFI mask"),
+    _k("rfi", "channel_major",
+       doc="the read-in's transpose of the block as read, on the chip"),
     _k("rfi", "apply_mask_chan", ("block_len",),
        doc="channelwise mask application at block granularity"),
     _k("rfi", "apply_mask", ("block_len", "chunk"),
@@ -469,6 +471,8 @@ def _rfi_instances(ctx: GateContext) -> list[Instance]:
 
     blk = _sds((NCHAN, ctx.nsamp), ctx.blk_dtype)
     return [
+        Instance("rfi.channel_major", "channel_major",
+                 (_sds((ctx.nsamp, NCHAN), ctx.blk_dtype),), {}),
         Instance("rfi._cell_stats_chan", "cell_stats_chan",
                  (blk,), dict(block_len=2048)),
         Instance("rfi.apply_mask_chan", "apply_mask_chan",

@@ -24,6 +24,7 @@ Key behaviors carried over from the reference (cited by file:line into
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import os
 import warnings
@@ -33,6 +34,14 @@ import numpy as np
 from tpulsar.astro import angles
 from tpulsar.constants import SECPERDAY
 from tpulsar.io import fitscore
+from tpulsar.obs import telemetry, trace
+
+# Threads that decode read_all_uint8's row groups side by side (fewer
+# where the process may use fewer cores).  The groups are independent,
+# write disjoint slices of the block and spend their time in a native
+# call that drops the interpreter's lock; past 8 the decode is bound
+# by the host's memory, not by its cores.
+DECODE_THREADS = 8
 
 
 def is_psrfits(path: str) -> bool:
@@ -373,15 +382,41 @@ class SpectraInfo:
                 med = np.median(block[-min(len(block), 1024):], axis=0)
                 pieces.append(np.broadcast_to(
                     med.astype(np.float32), (finfo.num_pad, block.shape[1])).copy())
-        return np.concatenate(pieces, axis=0)
+        block = np.concatenate(pieces, axis=0)
+        telemetry.readin_bytes_total().inc(block.nbytes, form="float32")
+        return block
+
+    def _packed_rows(self, ii: int, lo: int, hi: int):
+        """Subint rows [lo, hi) of file ii as the mapped table gives
+        them, and their packed DATA column (a strided view of the
+        file: nothing is read until it is touched)."""
+        subint_hdu = fitscore.get_hdu(self._files[ii].hdus, "SUBINT")
+        rows = subint_hdu.data[lo:hi]
+        return rows, np.asarray(rows["DATA"])
+
+    def _sampled_groups(self, chunk_subints: int
+                        ) -> list[tuple[int, int, int]]:
+        """(file, lo, hi) of the subint groups read_all_uint8's affine
+        is taken from: the first, middle and last `chunk_subints` rows
+        of each file, so time-varying calibration (per-row DAT_SCL/
+        OFFS/WTS, channels dead early but alive later) is represented.
+        Groups of a short file overlap; their rows then count twice."""
+        groups = []
+        for ii, finfo in enumerate(self._files):
+            picks = {0, finfo.num_subint // 2,
+                     max(0, finfo.num_subint - chunk_subints)}
+            for r0 in sorted(picks):
+                hi = min(r0 + chunk_subints, finfo.num_subint)
+                if hi > r0:
+                    groups.append((ii, r0, hi))
+        return groups
 
     def _quantize_affine(self, target_std_lsb: float,
                          chunk_subints: int
                          ) -> tuple[np.ndarray, np.ndarray]:
-        """(scale, offset) for read_all_uint8, from subint chunks
-        sampled across the WHOLE observation (first/middle/last of
-        each file) so time-varying calibration (per-row DAT_SCL/OFFS/
-        WTS, channels dead early but alive later) is represented.
+        """(scale, offset) for read_all_uint8 from the POOL of the
+        sampled groups' decoded spectra: every file the native 4-bit
+        path does not take, and the oracle of the counts form below.
 
         One SHARED scale for every channel — chosen so the 98th-
         percentile channel noise spans `target_std_lsb` steps — keeps
@@ -390,58 +425,67 @@ class SpectraInfo:
         whiten the bandpass); quieter channels just use fewer steps
         (quantization noise ~(sigma/target)^2/12, well under 1%).
         Only the offset is per channel (median centered at 128)."""
-        samples = []
-        for ii, finfo in enumerate(self._files):
-            picks = {0, finfo.num_subint // 2,
-                     max(0, finfo.num_subint - chunk_subints)}
-            for r0 in sorted(picks):
-                hi = min(r0 + chunk_subints, finfo.num_subint)
-                if hi > r0:
-                    samples.append(self.read_subints(ii, r0, hi))
-        pool = np.concatenate(samples, axis=0)
+        pool = np.concatenate(
+            [self.read_subints(*g)
+             for g in self._sampled_groups(chunk_subints)], axis=0)
         med = np.median(pool, axis=0)
         mad = np.median(np.abs(pool - med), axis=0)
-        sigma = 1.4826 * mad
-        ref = float(np.percentile(sigma, 98))
-        scale = np.float32(max(ref / target_std_lsb, 1e-9))
-        offset = (med - 128.0 * scale).astype(np.float32)
-        return np.full(self.num_channels, scale, np.float32), offset
+        return _affine_from_spread(med, mad, target_std_lsb)
 
-    def _read_quantized_4bit(self, ii: int, lo: int, hi: int,
-                             qscale: np.ndarray, qoffset: np.ndarray,
-                             out_slice: np.ndarray) -> bool:
-        """Single-poln 4-bit fast path for read_all_uint8: the native
-        fused unpack + requantize kernel (unpack.cpp), with per-row
-        calibration and the block affine folded into one per-channel
-        (a, b): q = clip(round(x*a + b)).  Writes into out_slice
-        (ascending-frequency channel order) and returns True, or
-        False if inapplicable (caller uses the NumPy path)."""
-        if not self._fast4_applicable():
-            return False
+    def _quantize_affine_counts(self, target_std_lsb: float,
+                                chunk_subints: int, threads
+                                ) -> tuple[np.ndarray, np.ndarray]:
+        """_quantize_affine's (scale, offset) to the bit, without the
+        pool: a sampled row of a 4-bit file holds at most 16 distinct
+        calibrated values a channel, x * eff_scl + eff_off as the
+        native decode rounds them, so each channel's median and MAD
+        are taken over (value, count) pairs, the counts from a native
+        pass over the packed rows (`threads`: the decode's pool)."""
         from tpulsar import native
-        finfo = self._files[ii]
-        subint_hdu = fitscore.get_hdu(finfo.hdus, "SUBINT")
-        rows = subint_hdu.data[lo:hi]
-        raw = np.asarray(rows["DATA"])
-        nrows = hi - lo
-        nsblk = self.spectra_per_subint
+        nchan, nsblk = self.num_channels, self.spectra_per_subint
+        x = np.arange(16, dtype=np.float32)[:, None]
+        values, raws = [], []
+        for ii, lo, hi in self._sampled_groups(chunk_subints):
+            rows, raw = self._packed_rows(ii, lo, hi)
+            for r in range(hi - lo):
+                eff_scl, eff_off = self._row_effective_affine(
+                    rows, r, nchan)
+                values.append(x * eff_scl + eff_off)
+                raws.append(raw[r])
+        counts = list(threads.map(
+            lambda row: native.count4(row, nsblk, nchan).T, raws))
+        values = np.concatenate(values)        # (rows * 16, nchan)
+        counts = np.concatenate(counts)
+        med = median_from_counts(values, counts)
+        mad = median_from_counts(np.abs(values - med), counts)
+        if self.need_flipband:                 # file order -> ascending
+            med, mad = med[::-1], mad[::-1]
+        return _affine_from_spread(med, mad, target_std_lsb)
+
+    def _decode_group_4bit(self, ii: int, lo: int, hi: int,
+                           qscale: np.float32, qoffset: np.ndarray,
+                           out_slice: np.ndarray) -> None:
+        """One group of read_all_uint8's native 4-bit path: the fused
+        unpack + requantize kernel (unpack.cpp) reads rows [lo, hi) of
+        file ii from the mapped file and writes them once, straight
+        into out_slice, ascending-frequency channel order (the band
+        turned in that write).  Per-row calibration and the block
+        affine fold into one per-channel (a, b): q = clip(round(x*a +
+        b))."""
+        from tpulsar import native
+        rows, raw = self._packed_rows(ii, lo, hi)
         nchan = self.num_channels
-        packed = np.ascontiguousarray(
-            raw.reshape(nrows, nsblk, nchan // 2))
-        qs = float(qscale[0])
         # qoffset is in ascending-frequency order; calibration arrays
         # are in file order
         qoff_file = qoffset[::-1] if self.need_flipband else qoffset
-        for r in range(nrows):
+        a = np.empty((hi - lo, nchan), np.float32)
+        b = np.empty((hi - lo, nchan), np.float32)
+        for r in range(hi - lo):
             eff_scl, eff_off = self._row_effective_affine(rows, r, nchan)
-            a = eff_scl / qs
-            b = (eff_off - qoff_file) / qs
-            res = native.unpack4_quantize(packed[r], a, b)
-            if res is None:
-                return False
-            out_slice[r * nsblk:(r + 1) * nsblk] = \
-                res[:, ::-1] if self.need_flipband else res
-        return True
+            a[r] = eff_scl / qscale
+            b[r] = (eff_off - qoff_file) / qscale
+        native.unpack4_quantize_rows(raw, out_slice, a, b,
+                                     self.need_flipband)
 
     def read_all_uint8(self, target_std_lsb: float = 18.0,
                        chunk_subints: int = 16
@@ -456,43 +500,116 @@ class SpectraInfo:
         98th-percentile channel noise at `target_std_lsb` steps with
         each channel's median at 128 (+-7 sigma of headroom before
         clipping); see _quantize_affine for why the scale is NOT per
-        channel.  Decoding is streamed `chunk_subints` at a time so
-        the float32 transient stays bounded; inter-file padding gets
-        each channel's quantized median from that file's own tail,
-        matching read_all's padding semantics.
-        """
+        channel.  Decoding is streamed `chunk_subints` at a time (the
+        NumPy path's float32 transient stays bounded); inter-file
+        padding gets each channel's quantized median from that file's
+        own tail, matching read_all's padding semantics.
+
+        The host touches each sample ONCE where the file is 4-bit,
+        one polarisation, unsigned and the native library loads: the
+        affine comes from nibble counts of the sampled rows, and the
+        groups are decoded side by side (DECODE_THREADS), each from
+        the mapped file straight into its slice of the block.  Every
+        other file keeps the NumPy decode and the pool, one group
+        after another; both forms give the same bits."""
         nchan = self.num_channels
         nsblk = self.spectra_per_subint
         total = int(sum(f.num_subint * nsblk + f.num_pad
                         for f in self._files))
         out = np.empty((total, nchan), np.uint8)
-        scale, offset = self._quantize_affine(target_std_lsb,
-                                              chunk_subints)
-        pos = 0
+        native4 = self._fast4_applicable()
+        nthreads = min(DECODE_THREADS, _usable_cores()) if native4 else 1
+        groups, pads, pos = [], [], 0
         for ii, finfo in enumerate(self._files):
             file_start = pos
             for r0 in range(0, finfo.num_subint, chunk_subints):
                 hi = min(r0 + chunk_subints, finfo.num_subint)
-                n = (hi - r0) * nsblk
-                if self._read_quantized_4bit(ii, r0, hi, scale, offset,
-                                             out[pos: pos + n]):
-                    pos += n
-                    continue
-                blockf = self.read_subints(ii, r0, hi)
-                q = np.rint((blockf - offset) / scale)
-                out[pos: pos + len(blockf)] = np.clip(
-                    q, 0, 255).astype(np.uint8)
-                pos += len(blockf)
+                groups.append((ii, r0, hi, pos))
+                pos += (hi - r0) * nsblk
             if finfo.num_pad:
-                # pad fill from THIS file's own tail (never the
-                # previous file's pad rows); empty file -> mid-level
-                tail = out[max(file_start, pos - 1024): pos]
-                medq = (np.median(tail, axis=0).astype(np.uint8)
-                        if len(tail) else
-                        np.full(nchan, 128, np.uint8))
-                out[pos: pos + finfo.num_pad] = medq[None, :]
+                pads.append((file_start, pos, finfo.num_pad))
                 pos += finfo.num_pad
+        with concurrent.futures.ThreadPoolExecutor(nthreads) as threads:
+            with trace.span("readin-affine",
+                            form="counts" if native4 else "pool"):
+                scale, offset = (
+                    self._quantize_affine_counts(
+                        target_std_lsb, chunk_subints, threads)
+                    if native4 else
+                    self._quantize_affine(target_std_lsb, chunk_subints))
+
+            def decode(group):
+                ii, lo, hi, at = group
+                out_slice = out[at: at + (hi - lo) * nsblk]
+                if native4:
+                    self._decode_group_4bit(ii, lo, hi, scale[0], offset,
+                                            out_slice)
+                else:
+                    q = np.rint((self.read_subints(ii, lo, hi) - offset)
+                                / scale)
+                    out_slice[...] = np.clip(q, 0, 255).astype(np.uint8)
+
+            form = "native4" if native4 else "numpy"
+            with trace.span("readin-decode", form=form,
+                            groups=len(groups), threads=nthreads):
+                # list(): a group's exception is raised here
+                list(threads.map(decode, groups))
+        for file_start, end, num_pad in pads:
+            # pad fill from THIS file's own tail (never the previous
+            # file's pad rows), taken once the file's groups are done;
+            # empty file -> mid-level
+            tail = out[max(file_start, end - 1024): end]
+            medq = (np.median(tail, axis=0).astype(np.uint8)
+                    if len(tail) else np.full(nchan, 128, np.uint8))
+            out[end: end + num_pad] = medq[None, :]
+        telemetry.readin_bytes_total().inc(out[:pos].nbytes, form=form)
         return out[:pos], scale, offset
+
+
+def _usable_cores() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:              # no affinity off Linux
+        return os.cpu_count() or 1
+
+
+def median_from_counts(values: np.ndarray,
+                       counts: np.ndarray) -> np.ndarray:
+    """np.median(pool, axis=0), to the bit, of the pool whose column c
+    holds values[k, c] counts[k, c] times: NumPy's rule (the middle
+    element of the sorted column; for an even total the float32 mean
+    of the two middle elements; NaN where the column holds one), read
+    off the running counts of the values in ascending order."""
+    order = np.argsort(values, axis=0, kind="stable")
+    vals = np.take_along_axis(values, order, axis=0)
+    cum = np.cumsum(np.take_along_axis(counts, order, axis=0),
+                    axis=0, dtype=np.int64)
+    total = cum[-1]
+    cols = np.arange(values.shape[1])
+
+    def element(k):       # the pool's k-th smallest, column by column
+        return vals[(cum > k).argmax(axis=0), cols]
+
+    lower, upper = element((total - 1) // 2), element(total // 2)
+    with np.errstate(invalid="ignore"):
+        med = np.where(total % 2 == 1, lower,
+                       np.mean(np.stack([lower, upper]), axis=0))
+    held = np.isnan(values) & (counts > 0)
+    return np.where(held.any(axis=0), np.float32(np.nan),
+                    med).astype(values.dtype)
+
+
+def _affine_from_spread(med: np.ndarray, mad: np.ndarray,
+                        target_std_lsb: float
+                        ) -> tuple[np.ndarray, np.ndarray]:
+    """read_all_uint8's (scale, offset) from each channel's median and
+    MAD: one shared scale that puts the 98th-percentile channel noise
+    at `target_std_lsb` steps, each channel's median at 128."""
+    sigma = 1.4826 * mad
+    ref = float(np.percentile(sigma, 98))
+    scale = np.float32(max(ref / target_std_lsb, 1e-9))
+    offset = (med - 128.0 * scale).astype(np.float32)
+    return np.full(len(med), scale, np.float32), offset
 
 
 def unpack_samples(raw: np.ndarray, nbits: int, signed: bool = False) -> np.ndarray:

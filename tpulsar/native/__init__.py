@@ -107,6 +107,22 @@ def load() -> ctypes.CDLL | None:
         lib.tpulsar_unpack4_q8.argtypes = [
             u8p, u8p, ctypes.c_size_t, ctypes.c_size_t, f32p, f32p]
         lib.tpulsar_unpack4_q8.restype = None
+        try:
+            # the read-in's entry points take the packed rows by
+            # ADDRESS (a strided column of the mapped file, read in
+            # place); a library built from an older source tree lacks
+            # them, and then every caller keeps its NumPy path
+            lib.tpulsar_unpack4_q8_rows.argtypes = [
+                ctypes.c_void_p, ctypes.c_size_t, u8p, ctypes.c_size_t,
+                ctypes.c_size_t, ctypes.c_size_t, f32p, f32p,
+                ctypes.c_int]
+            lib.tpulsar_unpack4_q8_rows.restype = None
+            lib.tpulsar_count4.argtypes = [
+                ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+                np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")]
+            lib.tpulsar_count4.restype = None
+        except AttributeError:
+            return None
         i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
         lib.tpulsar_accel_stage_topk.argtypes = [
             f32p, ctypes.c_int64, ctypes.c_int, ctypes.c_int64,
@@ -172,6 +188,52 @@ def unpack4_quantize(raw: np.ndarray, a: np.ndarray,
     out = np.empty((nspec, nchan), dtype=np.uint8)
     lib.tpulsar_unpack4_q8(raw, out, nspec, nchan, a, b)
     return out
+
+
+def _row_stride(raw: np.ndarray, nspec: int, nchan: int) -> int:
+    """Row stride in bytes of `raw`, the packed 4-bit DATA column of
+    some subint rows as the mapped table gives it: (nrows, ...) uint8,
+    each row's nspec * nchan / 2 bytes contiguous, the rows apart by
+    the table's row length.  Raises where it is anything else: the
+    native loops read it by address."""
+    if (raw.dtype != np.uint8 or raw.ndim < 2 or not len(raw)
+            or raw[0].size != nspec * (nchan // 2) or nchan % 2
+            or not raw[0].flags.c_contiguous
+            or (len(raw) > 1 and raw.strides[0] < raw[0].size)):
+        raise ValueError(
+            f"not packed 4-bit rows of {nspec} x {nchan}: dtype "
+            f"{raw.dtype}, shape {raw.shape}, strides {raw.strides}")
+    return raw.strides[0]
+
+
+def unpack4_quantize_rows(raw: np.ndarray, out: np.ndarray,
+                          a: np.ndarray, b: np.ndarray,
+                          flip: bool) -> None:
+    """Fused 4-bit unpack + affine requantization of a group of subint
+    rows, read in place from the mapped file and written straight into
+    `out`, the caller's (nrows * nspec, nchan) uint8 slice of the
+    block: out = clip(round(x * a[r] + b[r])) with row r's own
+    (a, b) in FILE channel order, the channels reversed in the write
+    where `flip`.  The call drops the interpreter's lock: groups that
+    write disjoint slices run side by side."""
+    nrows, nchan = a.shape
+    nspec = out.shape[0] // nrows
+    stride = _row_stride(raw, nspec, nchan)
+    if (len(raw) != nrows or out.shape != (nrows * nspec, nchan)
+            or b.shape != a.shape):
+        raise ValueError(f"{len(raw)} rows of packed spectra for a, b "
+                         f"{a.shape}, {b.shape} and out {out.shape}")
+    load().tpulsar_unpack4_q8_rows(raw.ctypes.data, stride, out, nrows,
+                                   nspec, nchan, a, b, int(flip))
+
+
+def count4(raw_row: np.ndarray, nspec: int, nchan: int) -> np.ndarray:
+    """(nchan, 16) uint32: how often each 4-bit sample value occurs in
+    each channel (FILE order) of one subint row's packed spectra."""
+    _row_stride(raw_row[None], nspec, nchan)
+    counts = np.zeros((nchan, 16), np.uint32)
+    load().tpulsar_count4(raw_row.ctypes.data, nspec, nchan, counts)
+    return counts
 
 
 def accel_stage_topk(plane: np.ndarray, stages, block_r: int,

@@ -98,17 +98,21 @@ void tpulsar_unpack4_cal(const uint8_t* in, float* out, size_t nspec,
     }
 }
 
-// Fused unpack4 + affine requantization to uint8:
-// out[s, c] = clip(round(samples[s, c] * a[c] + b[c]), 0, 255).
+// Fused unpack4 + affine requantization to uint8, one subint row:
+// out[s, j] = clip(round(samples[s, c] * a[c] + b[c]), 0, 255), where
+// j = c, or nchan - 1 - c with `flip` (a band stored descending is
+// turned in this write; a and b stay in FILE channel order).
 // Callers fold calibration and the block quantization map into (a, b)
 // per subint row; with only 16 possible sample values the whole map
-// collapses into a per-channel 16-entry uint8 LUT, so the inner loop
-// is two table reads and two stores per packed byte.
-void tpulsar_unpack4_q8(const uint8_t* in, uint8_t* out, size_t nspec,
-                        size_t nchan, const float* a, const float* b) {
+// collapses into a per-channel 16-entry uint8 LUT (laid out in OUTPUT
+// channel order), so the inner loop is two table reads and two stores
+// per packed byte.
+static void unpack4_q8_row(const uint8_t* in, uint8_t* out, size_t nspec,
+                           size_t nchan, const float* a, const float* b,
+                           bool flip, uint8_t* lut) {
     const size_t nb = nchan / 2;
-    std::vector<uint8_t> lut(nchan * 16);
     for (size_t c = 0; c < nchan; ++c) {
+        uint8_t* e = lut + (flip ? nchan - 1 - c : c) * 16;
         for (int x = 0; x < 16; ++x) {
             // rint (round-half-to-even in the default FP environment)
             // matches the NumPy fallback's np.rint: lround's
@@ -117,17 +121,62 @@ void tpulsar_unpack4_q8(const uint8_t* in, uint8_t* out, size_t nspec,
             // dependent
             const long r = static_cast<long>(
                 rintf(static_cast<float>(x) * a[c] + b[c]));
-            lut[c * 16 + x] =
-                r < 0 ? 0 : (r > 255 ? 255 : static_cast<uint8_t>(r));
+            e[x] = r < 0 ? 0 : (r > 255 ? 255 : static_cast<uint8_t>(r));
         }
     }
+    // packed byte i holds file channels 2i, 2i+1 = output channels
+    // j, j + step: forwards from 0, or backwards from nchan - 1
+    const ptrdiff_t first = flip ? static_cast<ptrdiff_t>(nchan) - 1 : 0;
+    const ptrdiff_t step = flip ? -1 : 1;
     for (size_t s = 0; s < nspec; ++s) {
         const uint8_t* row = in + s * nb;
         uint8_t* orow = out + s * nchan;
         for (size_t i = 0; i < nb; ++i) {
             const uint8_t byte = row[i];
-            orow[2 * i] = lut[(2 * i) * 16 + ((byte >> 4) & 0x0F)];
-            orow[2 * i + 1] = lut[(2 * i + 1) * 16 + (byte & 0x0F)];
+            const ptrdiff_t j = first + 2 * step * static_cast<ptrdiff_t>(i);
+            orow[j] = lut[j * 16 + ((byte >> 4) & 0x0F)];
+            orow[j + step] = lut[(j + step) * 16 + (byte & 0x0F)];
+        }
+    }
+}
+
+void tpulsar_unpack4_q8(const uint8_t* in, uint8_t* out, size_t nspec,
+                        size_t nchan, const float* a, const float* b) {
+    std::vector<uint8_t> lut(nchan * 16);
+    unpack4_q8_row(in, out, nspec, nchan, a, b, false, lut.data());
+}
+
+// A group of `nrows` subint rows in one call, straight from the mapped
+// file into the caller's slice of the block: row r's packed spectra
+// start at in + r * row_stride (the table's row length: the other
+// columns lie between), its (a, b) at a + r * nchan, and its nspec
+// spectra land at out + r * nspec * nchan.  Groups write disjoint
+// slices, so callers run them side by side (ctypes drops the
+// interpreter's lock for the call).
+void tpulsar_unpack4_q8_rows(const uint8_t* in, size_t row_stride,
+                             uint8_t* out, size_t nrows, size_t nspec,
+                             size_t nchan, const float* a,
+                             const float* b, int flip) {
+    std::vector<uint8_t> lut(nchan * 16);
+    for (size_t r = 0; r < nrows; ++r)
+        unpack4_q8_row(in + r * row_stride, out + r * nspec * nchan,
+                       nspec, nchan, a + r * nchan, b + r * nchan,
+                       flip != 0, lut.data());
+}
+
+// How often each of the 16 sample values occurs in each channel of
+// one subint row: counts[c * 16 + x], FILE channel order, added to
+// what the caller zeroed.  What _quantize_affine's medians need of a
+// sampled row, in place of its decoded spectra.
+void tpulsar_count4(const uint8_t* in, size_t nspec, size_t nchan,
+                    uint32_t* counts) {
+    const size_t nb = nchan / 2;
+    for (size_t s = 0; s < nspec; ++s) {
+        const uint8_t* row = in + s * nb;
+        for (size_t i = 0; i < nb; ++i) {
+            const uint8_t byte = row[i];
+            ++counts[(2 * i) * 16 + ((byte >> 4) & 0x0F)];
+            ++counts[(2 * i + 1) * 16 + (byte & 0x0F)];
         }
     }
 }

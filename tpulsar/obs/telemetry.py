@@ -192,6 +192,19 @@ def mesh_bytes_placed_total() -> metrics.Counter:
         "template bank and taps, once a pass")
 
 
+def readin_bytes_total() -> metrics.Counter:
+    return metrics.counter(
+        "tpulsar_readin_bytes_total",
+        "bytes of beam block the read-in decoded on the host "
+        "(io/psrfits.SpectraInfo), by the decode's form: native4 = "
+        "read_all_uint8's native 4-bit path (the affine from nibble "
+        "counts, the row groups decoded side by side), numpy = its "
+        "NumPy decode with the pooled affine (8-bit, two-polarisation "
+        "or signed files; on a Mock file: the native library did not "
+        "load), float32 = read_all (beams under block_quantize_min)",
+        labelnames=("form",))
+
+
 def accel_undispatched_rows_total() -> metrics.Counter:
     return metrics.counter(
         "tpulsar_accel_undispatched_rows_total",

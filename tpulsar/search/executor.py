@@ -372,11 +372,17 @@ def _read_and_mask(si, params, basenm, resultsdir, store, timers):
         block = si.read_all()                 # (T, nchan) ascending freq
         qscale = qoff = None
     with timers.timing("rfifind"):
-        # One host transpose, one transfer: the block lives on device
+        # One transfer of the block as it was read, (T, nchan), and
+        # one transpose ON the chip: the block lives on device
         # channel-major in its native dtype (uint8 beams stay 4x
-        # smaller) and never transposes there again.
-        data = jnp.asarray(np.ascontiguousarray(block.T))  # (nchan, T)
-        del block
+        # smaller) and never transposes again.  The time-major device
+        # copy lives for that one call (2 x the block, under the
+        # block + masked block further down).
+        with trace_mod.span("readin-place", bytes=block.nbytes,
+                            transposed="device"):
+            data = rfi_k.channel_major(jnp.asarray(block))  # (nchan, T)
+            del block
+            trace_mod.fence(data)
         mask_path = os.path.join(resultsdir, f"{basenm}_rfifind.npz")
         payload = store.load("rfi_mask") if store is not None else None
         if payload is not None:
