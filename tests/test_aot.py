@@ -493,8 +493,11 @@ def test_runtime_monitor_emits_compile_telemetry(tmp_path):
     assert warmstart.install_runtime_monitor()
     trace.start(clear=True)
     try:
-        # a fresh closure => guaranteed fresh compile
-        salt = 17
+        # a fresh closure AND a constant from the clock => a fresh
+        # compile whatever a persistent compile cache holds (the same
+        # program twice is a load the second time, not a compile)
+        import time
+        salt = float(time.time_ns() % 1_000_003) + 17.0
 
         @jax.jit
         def _probe(x):

@@ -430,3 +430,114 @@ def test_read_in_transpose_fits_beside_its_input(one_chip, nsamp, nchan,
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert held <= 2.2 * block and held < 15.7 * 2 ** 30
+
+
+# --------------- a beam laid over the four chips by channels (FAST GPPS)
+
+GPPS_NCHAN, GPPS_NSUB, GPPS_NSAMP = 2048, 128, 6_103_040
+
+
+def _collectives(text):
+    return {k for k in ("all-gather", "all-to-all", "all-reduce",
+                        "reduce-scatter", "collective-permute")
+            if f" {k}(" in text or f" {k}-start(" in text}
+
+
+@pytest.mark.parametrize("overhang,group", [(256, 32), (2048, 16)])
+def test_stage1_share_programs_on_four_chips(v5e, overhang, group):
+    """Stage 1 of a 2048-channel beam laid over the four chips of a
+    described v5e 2x2 by channels (pallas_dd._share_programs: the slab's
+    layout and the kernel under shard_map, at a SHARE's geometry, 512
+    channels and 32 subbands): Mosaic takes the kernel at 16 channels a
+    subband, nothing crosses between chips, and a 1 GB slab a chip
+    fits."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpulsar.kernels import pallas_dd
+
+    mesh = Mesh(np.asarray(v5e.devices), ("chan",))
+    plan = pallas_dd.stage1_plan(GPPS_NCHAN // 4, GPPS_NSUB // 4, overhang,
+                                 1)
+    assert (plan.block_t, plan.group) == (32768, group)
+    slab = pallas_dd.stage1_slabs(GPPS_NSAMP, GPPS_NCHAN // 4, 1,
+                                  plan.block_t, overhang)[0]
+    assert slab.n_blocks == 59
+
+    def sds(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    segment, block = pallas_dd._share_programs(
+        mesh, slab.n_blocks, plan.seg, plan.head, GPPS_NSUB // 4, False,
+        tuple(sorted(plan.kernel_args().items())))
+    body = sds((GPPS_NCHAN, slab.body[1] - slab.body[0]), jnp.uint8,
+               "chan", None)
+    rest = sds((GPPS_NCHAN, slab.rest[1] - slab.rest[0]), jnp.uint8,
+               "chan", None)
+    laid = segment.lower(body, rest).compile()
+    assert not _collectives(laid.as_text())
+    segs, tail = jax.eval_shape(segment, body, rest)
+    compiled = block.lower(
+        sds(segs.shape, segs.dtype, "chan", None, None),
+        sds(tail.shape, tail.dtype, "chan", None, None),
+        sds((GPPS_NSUB, GPPS_NCHAN // GPPS_NSUB), jnp.int32, "chan",
+            None)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and not _collectives(text)
+    assert f'"size":"{plan.vmem_bytes}"' in text
+    mem = compiled.memory_analysis()        # bytes on each device
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 2 << 30
+
+
+def test_partial_form_pass_program_on_four_chips(v5e):
+    """The mesh pass of a laid-out beam at FAST's ds=1 (128 subbands
+    of 6,103,040 samples left where stage 1 formed them, 32 a chip;
+    hi-accel off; the rows a device executor._mesh_rows_budget gives):
+    stage 2 is the solo kernel over a chip's own subbands, the partial
+    sums cross in ONE all-to-all a group (a psum_scatter there compiles
+    to an all-reduce of the whole group: twice the bytes and 3 GiB
+    more), and the program fits beside the beam's 2.91 GiB share."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpulsar.kernels import fourier as fr
+    from tpulsar.kernels import pallas_dd
+    from tpulsar.kernels import singlepulse as sp_k
+    from tpulsar.parallel import mesh as pmesh
+    from tpulsar.plan import ddplan
+    from tpulsar.search import executor
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 4), ("beam", "dm"))
+    params = executor.SearchParams(run_hi_accel=False)
+    nfft = ddplan.choose_n(GPPS_NSAMP)
+    nbins = nfft // 2 + 1
+    rows = executor._mesh_rows_budget(nfft, params.spectral_hbm_budget)
+    assert (nfft, rows) == (6_144_000, 13)
+    assert pmesh.partial_groups(rows, 4, GPPS_NSAMP) == rows   # one group
+    spec = pmesh.PassSpec(
+        nfft=nfft, max_numharm=params.lo_accel_numharm,
+        topk=params.topk_per_stage, sp_widths=tuple(params.sp_widths),
+        sp_topk=sp_k.DEFAULT_TOPK,
+        sp_detrend=sp_k.detrend_estimator(params.sp_detrend),
+        whiten_est=fr.whiten_estimator(), hi=False, pallas_dd=True,
+        dd_stage_s=pallas_dd.stage_overhang(93), dd_interpret=False,
+        dd_pad=256, sub_sharded=True)
+
+    def sds(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    compiled = pmesh.sharded_pass_fn(mesh, spec).lower(
+        sds((GPPS_NSUB, GPPS_NSAMP), jnp.float32, "dm", None),
+        sds((4 * rows, GPPS_NSUB), jnp.int32, None, "dm"),
+        sds((nbins,), jnp.float32), sds((1, 1), jnp.complex64),
+        None).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _collectives(text) == {"all-to-all", "all-gather"}
+    assert text.count(" all-to-all(") == 1
+    mem = compiled.memory_analysis()        # bytes on each device
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 8 << 30

@@ -1,5 +1,7 @@
 """Dedispersion plan tests."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -61,6 +63,39 @@ def test_plan_for_a_gbncc_header_runs_the_frozen_passes(survey):
                                        survey=survey)
     assert (obs, nsub) == (GBNCC_OBS, 128)
     assert steps == ddplan.survey_plan("gbncc")
+
+
+GPPS_OBS = ddplan.Observation(dt=49.152e-6, fctr=1250.0, bw=500.0,
+                              numchan=2048, blocklen=2048)
+
+
+def test_survey_plan_gpps_is_the_planners_own():
+    """The frozen FAST GPPS rows are generate_ddplan's answer for the
+    survey's geometry (the 19-beam L-band receiver, 2048 channels over
+    1.0-1.5 GHz at 49.152 us), step by step: six steps at ds 1-32, 102
+    DMs a pass, 229 passes, 23,358 trials to DM 3006.96."""
+    steps = ddplan.survey_plan("gpps")
+    made = ddplan.generate_ddplan(GPPS_OBS, 0, 3000, numsub=128)
+    assert len(steps) == len(made) == 6
+    for frozen, gen in zip(steps, made):
+        # the planner's 0.3 is 0.30000000000000004: the same step, the
+        # same DMs (a pass rounds them to 1e-6)
+        assert frozen.dmstep == pytest.approx(gen.dmstep, abs=1e-12)
+        assert dataclasses.replace(frozen, dmstep=gen.dmstep) == gen
+        assert [p.dms for p in frozen.passes()] == \
+            [p.dms for p in gen.passes()]
+        assert [p.subdm for p in frozen.passes()] == \
+            [p.subdm for p in gen.passes()]
+    assert [s.downsamp for s in steps] == [1, 2, 4, 8, 16, 32]
+    assert [s.numpasses for s in steps] == [71, 27, 37, 39, 30, 25]
+    assert {s.dms_per_pass for s in steps} == {102}
+    assert ddplan.total_dm_trials(steps) == 23358
+    assert abs(steps[-1].hidm - 3006.96) < 1e-9
+    for a, b in zip(steps[:-1], steps[1:]):
+        assert abs(a.hidm - b.lodm) < 1e-9
+    # the first pass lies under the sifter's low-DM cutoff, which is
+    # why the benchmark's slice starts at step 1
+    assert steps[0].passes()[0].dms[-1] < 2.04 < steps[0].passes()[1].dms[1]
 
 
 def test_survey_plan_unknown_backend():

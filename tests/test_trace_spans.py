@@ -495,3 +495,63 @@ def test_the_benchmarks_scope_list_is_the_programs():
     assert tuple(spec["scopes"]) == scopes.SCOPES
     assert set(spec["whole_programs"].values()) <= set(scopes.SCOPES)
 
+
+
+# ------------------------------------- a laid-out beam's spans (PR 43)
+
+def test_a_laid_out_beams_pass_has_its_exchange_by_name(toy_search):
+    """The names docs/operations.md and the benchmark read a laid-out
+    beam's pass by: `pass` says `block_shards`, `subbanding` says
+    `shards`, and `mesh-exchange` (a stage, sibling of `mesh-place`
+    under `pass`, with `bytes`, `form` and `devices`) comes once a pass;
+    `tpulsar_mesh_exchange_bytes_total{form}` counts the same bytes.
+    A block on one device opens none of it."""
+    import dataclasses
+
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpulsar.obs import telemetry
+    from tpulsar.search import executor
+    from tpulsar.search.report import StageTimers
+
+    block, freqs, dt, plan, params = toy_search
+    params = dataclasses.replace(params, dm_shards=4, run_hi_accel=False)
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("chan",))
+    laid = jax.device_put(np.asarray(block),
+                          NamedSharding(mesh, P("chan", None)))
+
+    def exchanged():
+        snap = telemetry.metrics.REGISTRY.snapshot()
+        return sum((snap.get("tpulsar_mesh_exchange_bytes_total")
+                    or {}).get("series", {}).values())
+
+    base = exchanged()
+    timers = StageTimers()
+    trace.start()
+    executor.search_block(laid, freqs, dt, plan, params, timers=timers)
+    events = trace.events()
+    trace.reset()
+    passes = _spans(events, "pass")
+    assert [p["args"]["block_shards"] for p in passes] == [4, 4]
+    for p in passes:
+        kids = [k["name"] for k in _children(events, p)]
+        assert kids[:4] == ["subbanding", "mesh-exchange", "mesh-place",
+                            "sharded-search"]
+    ex = _spans(events, "mesh-exchange")
+    assert len(ex) == 2
+    for e in ex:
+        assert e["args"]["form"] in ("replicate", "partial", "time")
+        assert e["args"]["devices"] == 4 and e["args"]["bytes"] > 0
+    assert {s["args"]["shards"] for s in _spans(events, "subbanding")} == {4}
+    assert timers.times["mesh-exchange"] > 0.0
+    assert exchanged() - base == sum(e["args"]["bytes"] for e in ex)
+
+    trace.start()
+    executor.search_block(block, freqs, dt, plan, params)
+    events = trace.events()
+    trace.reset()
+    assert not _spans(events, "mesh-exchange")
+    assert {p["args"]["block_shards"] for p in _spans(events, "pass")} == {1}
+    assert all("shards" not in s["args"]
+               for s in _spans(events, "subbanding"))

@@ -262,6 +262,18 @@ EXEMPT_SITES: dict[str, str] = {
         "per-mesh distributed-FFT builder",
     "tpulsar/parallel/dist_fft.py::_build_tail_fn":
         "per-mesh distributed spectral-tail builder",
+    "tpulsar/parallel/mesh.py::reshard":
+        "per-sharding identity program (an all-gather / all-to-all "
+        "between two layouts of a laid-out beam's subbands)",
+    "tpulsar/kernels/rfi.py::_cell_stats_shares":
+        "per-mesh shard_map of _cell_stats_chan over a laid-out beam's "
+        "channel shares",
+    "tpulsar/kernels/dedisperse.py::_form_subbands_shares":
+        "per-mesh shard_map of _form_subbands_jit over a laid-out "
+        "beam's channel shares",
+    "tpulsar/kernels/pallas_dd.py::_share_programs":
+        "per-mesh shard_map of the two stage-1 slab programs over a "
+        "laid-out beam's channel shares",
 }
 
 

@@ -1708,7 +1708,7 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--hidm", type=float, default=None)
     sp.add_argument("--numsub", type=int, default=96)
     sp.add_argument("--survey", default=None,
-                    help="use a frozen survey plan (pdev|wapp|gbncc)")
+                    help="use a frozen survey plan (pdev|wapp|gbncc|gpps)")
     sp.add_argument("--png", default=None)
     sp.set_defaults(fn=cmd_plan)
 

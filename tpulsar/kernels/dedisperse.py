@@ -19,6 +19,7 @@ matching the convention the synthesizer and oracle use.
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 
 import jax
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpulsar.constants import KDM, dispersion_delay_s as delays_s
+from tpulsar.obs import trace
 
 
 def shift_samples(dm, freqs_mhz, ref_mhz, dt) -> np.ndarray:
@@ -175,8 +177,37 @@ def form_subbands(data: jnp.ndarray, chan_shifts, nsub: int,
         degraded.note("pallas_sb_disabled",
                       "switched off by env; XLA lax.map subband path")
     pad = _pad_bucket(int(shifts_np.max(initial=0)))
+    from tpulsar.parallel import mesh as pmesh
+
+    shares = pmesh.channel_mesh(data)
+    if shares is not None:
+        # a block laid over several chips by channels: each forms its
+        # own subbands from its own channels, as the Pallas tier does
+        if nsub % shares.size:
+            raise ValueError(
+                f"{nsub} subbands over {shares.size} shares of the "
+                "block: a subband would straddle two chips")
+        trace.annotate("subbanding", shards=shares.size)
+        return _form_subbands_shares(shares, nsub // shares.size,
+                                     downsamp, pad)(
+            data, jnp.asarray(shifts_np))
     return _form_subbands_jit(data, jnp.asarray(shifts_np), nsub,
                               downsamp, pad)
+
+
+@functools.lru_cache(maxsize=None)
+def _form_subbands_shares(mesh, nsub: int, downsamp: int, pad: int):
+    """`_form_subbands_jit` over a block laid over `mesh` by channels:
+    nsub subbands a chip from its own channels, no exchange; the
+    subbands come out laid over the same chips by subband."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    return jax.jit(shard_map(
+        lambda share, shifts: _form_subbands_jit(share, shifts, nsub,
+                                                 downsamp, pad),
+        mesh=mesh, in_specs=(P("chan", None), P("chan")),
+        out_specs=P("chan", None), check_vma=False))
 
 
 @partial(jax.jit, static_argnames=("pad",))

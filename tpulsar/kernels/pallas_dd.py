@@ -607,6 +607,33 @@ def _form_subbands_block(segs: jnp.ndarray, tail: jnp.ndarray,
     return out.reshape(nsub, n_seg * seg)
 
 
+@functools.lru_cache(maxsize=None)
+def _share_programs(mesh, n_blocks: int, seg: int, head: int, nsub: int,
+                    interpret: bool, kernel_args: tuple):
+    """`_segment_slab` and `_form_subbands_block` for a beam laid over
+    `mesh` by channels: each chip lays out its own channels' slab and
+    sums its own nsub subbands from them (a subband never straddles
+    two chips), the kernel and its geometry the solo ones at a
+    share's shapes.  No sample and no sum leaves its chip; the
+    subbands come out laid over the same chips by subband.  One
+    program for all the chips, compiled once."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    rows, cube = P("chan", None), P("chan", None, None)
+    segment = jax.jit(shard_map(
+        lambda body, rest: _segment_slab(body, rest, n_blocks, seg, head),
+        mesh=mesh, in_specs=(rows, rows), out_specs=(cube, cube),
+        check_vma=False))
+    block = jax.jit(shard_map(
+        lambda segs, tail, shifts: _form_subbands_block(
+            segs, tail, shifts, nsub, interpret=interpret,
+            **dict(kernel_args)),
+        mesh=mesh, in_specs=(cube, cube, rows), out_specs=rows,
+        check_vma=False))
+    return segment, block
+
+
 def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
                          block_t: int | None = None,
                          group: int | None = None,
@@ -620,7 +647,17 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
     registers instead of a 96-step serialized `lax.map`.  block_t (a
     multiple of 1024: 8 segments of whole registers, none shorter than
     a seventh of the overhang), group: the tests' way to a geometry
-    `stage1_plan` does not choose."""
+    `stage1_plan` does not choose.
+
+    A block laid over several devices by channels
+    (`parallel.mesh.channel_mesh`) goes through the same sweep share
+    by share: every step below is then one program over all the
+    chips, each on its own channels with the geometry of a share
+    (`_share_programs`), and the subbands come back laid over the
+    same chips by subband.  Sums of bytes are exact in float32, so
+    they are the whole block's rows to the bit."""
+    from tpulsar.parallel import mesh as pmesh
+
     interpret = _resolve_interpret(interpret)
     data = jnp.asarray(data)
     nchan, T = data.shape
@@ -630,7 +667,13 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
     # same clamp as the XLA formulation's min(shift, pad) — a no-op
     # while S >= smax, kept so the two paths cannot drift
     shifts_np = np.minimum(shifts_np, S)
-    plan = stage1_plan(nchan, nsub, S, data.dtype.itemsize)
+    shares = pmesh.channel_mesh(data)
+    n_shares = 1 if shares is None else shares.size
+    if nsub % n_shares:
+        raise ValueError(f"{nsub} subbands over {n_shares} shares of the "
+                         "block: a subband would straddle two chips")
+    plan = stage1_plan(nchan // n_shares, nsub // n_shares, S,
+                       data.dtype.itemsize)
     if block_t is not None:
         plan = plan._replace(block_t=block_t, window=block_t + S)
         if block_t % 1024 or 7 * plan.seg < S + 128:
@@ -640,8 +683,21 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
         plan = plan._replace(group=group)
     shifts_dev = jnp.asarray(shifts_np)
     outs = []
-    slabs = stage1_slabs(T, nchan, data.dtype.itemsize, plan.block_t, S,
-                         slab_bytes)
+    slabs = stage1_slabs(T, nchan // n_shares, data.dtype.itemsize,
+                         plan.block_t, S, slab_bytes)
+
+    def programs(slab):
+        """(the slab's layout, its kernel call): the solo programs, or
+        their twins over the block's shares."""
+        if shares is None:
+            return (functools.partial(_segment_slab, n_blocks=slab.n_blocks,
+                                      seg=plan.seg, head=plan.head),
+                    functools.partial(_form_subbands_block, nsub=nsub,
+                                      interpret=interpret,
+                                      **plan.kernel_args()))
+        return _share_programs(
+            shares, slab.n_blocks, plan.seg, plan.head, nsub // n_shares,
+            interpret, tuple(sorted(plan.kernel_args().items())))
 
     def dispatch(k: int):
         """Slab k's programs, enqueued in the order they always were;
@@ -649,8 +705,8 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
         slab = slabs[k]
         body = jax.lax.slice_in_dim(data, *slab.body, axis=1)
         rest = jax.lax.slice_in_dim(data, *slab.rest, axis=1)
-        laid = _segment_slab(body, rest, slab.n_blocks, plan.seg,
-                             plan.head)
+        segment, block = programs(slab)
+        laid = segment(body, rest)
         del body
         if len(outs) >= 2:
             # 2-deep backpressure (the executor's pending[-2]
@@ -661,9 +717,7 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
             # copy of slab k overlaps the compute of slab k-1.
             with trace.span("sb-wait", slab=k):
                 jax.block_until_ready(outs[-2])
-        res = _form_subbands_block(*laid, shifts_dev, nsub,
-                                   interpret=interpret,
-                                   **plan.kernel_args())
+        res = block(*laid, shifts_dev)
         outs.append(res[:, :slab.cols])
         return rest, laid
 
@@ -690,9 +744,10 @@ def form_subbands_pallas(data, chan_shifts, nsub: int, downsamp: int,
             trace.fence(outs[k])
         del rest, laid
     # what ran, on the executor's stage span (docs/operations.md)
-    trace.annotate("subbanding", sb_groups=nsub // plan.group,
+    trace.annotate("subbanding", sb_groups=nsub // n_shares // plan.group,
                    sb_block_t=plan.block_t, sb_seg=plan.seg,
-                   sb_overhang=S, sb_slabs=len(outs))
+                   sb_overhang=S, sb_slabs=len(outs),
+                   **({"shards": n_shares} if n_shares > 1 else {}))
 
     def downsampled(x):
         n_ds = (T // downsamp) * downsamp

@@ -25,11 +25,14 @@ dtype using a per-channel fill level precomputed at detection time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from tpulsar.obs import trace
 
 
 @dataclasses.dataclass
@@ -126,6 +129,21 @@ def _cell_stats_chan(data: jnp.ndarray, block_len: int, chunk: int = 16):
                  for s in (mean, std, maxpow))
 
 
+@functools.lru_cache(maxsize=None)
+def _cell_stats_shares(mesh, block_len: int):
+    """`_cell_stats_chan` over a block laid over `mesh` by channels:
+    each chip the cells of its own channels, no sample leaving it (the
+    solo program's `lax.map` walks the CHANNEL axis, which the
+    partitioner could serve only by gathering the block)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    return jax.jit(shard_map(
+        lambda share: _cell_stats_chan(share, block_len), mesh=mesh,
+        in_specs=P("chan", None), out_specs=(P(None, "chan"),) * 3,
+        check_vma=False))
+
+
 def cell_stats(data: jnp.ndarray, block_len: int):
     """(T, nchan) row-major entry point -> (mean, std, maxpow), each
     (nblocks, nchan).  Small-array convenience; whole-beam callers use
@@ -157,7 +175,16 @@ def find_rfi_chan(data, dt: float, block_len: int = 2048,
     # cell; without the clamp nblocks=0 and every downstream statistic
     # of the empty mask is NaN.
     block_len = min(block_len, int(data.shape[1]))
-    mean, std, maxpow = _cell_stats_chan(jnp.asarray(data), block_len)
+    from tpulsar.parallel import mesh as pmesh
+
+    shares = pmesh.channel_mesh(data)
+    if shares is None:
+        mean, std, maxpow = _cell_stats_chan(jnp.asarray(data), block_len)
+    else:
+        # a beam laid over several chips by channels: each its own
+        # cells; the (nblocks, nchan) statistics meet on the host
+        mean, std, maxpow = _cell_stats_shares(shares, block_len)(data)
+        trace.annotate("rfifind", shards=shares.size)
     mean, std, maxpow = (np.asarray(x) for x in (mean, std, maxpow))
 
     # Standardize each statistic both across time (catches bursts: a

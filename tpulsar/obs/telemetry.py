@@ -192,6 +192,18 @@ def mesh_bytes_placed_total() -> metrics.Counter:
         "template bank and taps, once a pass")
 
 
+def mesh_exchange_bytes_total() -> metrics.Counter:
+    return metrics.counter(
+        "tpulsar_mesh_exchange_bytes_total",
+        "bytes that crossed between chips to bring a laid-out beam's "
+        "subbands (sharded by subband, as stage 1 leaves them) into "
+        "stage 2's operand, once a pass (`mesh-exchange`), by form: "
+        "replicate = a whole copy to every chip, time = re-sharded "
+        "from subbands to time, partial = the partial sums the chunk "
+        "programs' reduce-scatters move",
+        labelnames=("form",))
+
+
 def readin_bytes_total() -> metrics.Counter:
     return metrics.counter(
         "tpulsar_readin_bytes_total",

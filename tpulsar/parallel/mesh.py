@@ -43,6 +43,70 @@ def make_mesh(n_beam: int = 1, n_dm: int | None = None,
     return Mesh(arr, axis_names=("beam", "dm"))
 
 
+def channel_mesh(x) -> Mesh | None:
+    """The one-axis mesh ("chan",) of the devices an array is laid
+    over by its FIRST axis, in the order of their pieces; None for an
+    array on one device (or no jax.Array at all).  The layout is the
+    operand's: a beam block that arrives as equal, contiguous shares
+    of its channels (a subband block: of its subbands) on several
+    devices is worked on share by share, each chip on its own, and
+    any other way of spreading an array over devices is refused here
+    rather than gathered."""
+    sh = getattr(x, "sharding", None)
+    if sh is None or len(sh.device_set) == 1:
+        return None
+    shards = sorted(x.addressable_shards,
+                    key=lambda s: s.index[0].start or 0)
+    rows = x.shape[0] // len(shards)
+    for k, s in enumerate(shards):
+        if (s.data.shape != (rows,) + tuple(x.shape[1:])
+                or (s.index[0].start or 0) != k * rows):
+            raise ValueError(
+                f"an array of shape {tuple(x.shape)} laid over "
+                f"{len(sh.device_set)} devices as {sh} is no layout by "
+                f"equal shares of its first axis: piece {k} is "
+                f"{s.data.shape} at {s.index}")
+    return Mesh(np.asarray([s.device for s in shards]), ("chan",))
+
+
+def require_same_devices(block_mesh: Mesh | None, mesh: Mesh | None) -> None:
+    """A block laid over several devices is searched by a mesh of
+    exactly those devices, in that order, as its `dm` axis: anything
+    else is an error before a pass starts, never a gather onto one
+    chip.  (No mesh at all, dm_shards = 1, is the one-device pass
+    loop: it reads each pass's subbands, never the block, whole on
+    the block's first device.)"""
+    if block_mesh is None or mesh is None:
+        return
+    have, want = list(block_mesh.devices.flat), list(mesh.devices.flat)
+    if have != want:
+        raise ValueError(
+            f"the block is laid over {len(have)} devices {have} and the "
+            f"search's mesh is {want}: a laid-out beam is searched with "
+            f"dm_shards={len(have)} on the same devices in the same "
+            f"order")
+
+
+def as_dm_rows(mesh: Mesh, x):
+    """A laid-out array's pieces, as they lie, under the search mesh's
+    own sharding P("dm", None): no byte moves (the devices are the
+    mesh's, in its order: `require_same_devices`)."""
+    sharding = NamedSharding(mesh, P("dm", None))
+    by_dev = {s.device: s.data for s in x.addressable_shards}
+    return jax.make_array_from_single_device_arrays(
+        x.shape, sharding,
+        [by_dev[d] for d in sharding.addressable_devices_indices_map(
+            x.shape)])
+
+
+def on_first_device(x):
+    """A laid-out array whole on its first piece's device (the finish
+    reads one candidate's subbands there); an array on one device as
+    it is."""
+    cm = channel_mesh(x)
+    return x if cm is None else _first_copy(x, cm)
+
+
 @dataclasses.dataclass(frozen=True)
 class SearchStepSpec:
     """Static configuration of one sharded search step."""
@@ -183,6 +247,15 @@ class PassSpec:
     #                             the (unchanged) spectral tail.
     #                             Requires dd_pad >= max shift and
     #                             dd_pad <= T'/n_dm.
+    sub_sharded: bool = False   # the subbands arrive laid over the dm
+    #                             axis BY SUBBAND (stage 1 of a beam
+    #                             laid out by channels) and stay so:
+    #                             each chip runs stage 2 over its own
+    #                             subbands for ALL the call's rows, and
+    #                             a reduce-scatter over dm leaves it its
+    #                             own rows' full sums (`_partial_dd`).
+    #                             The shift table comes sharded the
+    #                             same way, by subband
 
 
 def _pallas_dd_local(subb, shifts, stage_s: int, interpret: bool):
@@ -211,6 +284,59 @@ def _pallas_dd_local(subb, shifts, stage_s: int, interpret: bool):
             segs, edge, chunk, interpret=interpret,
             **plan.kernel_args())[:, :T])
     return jnp.concatenate(rows, axis=0) if len(rows) > 1 else rows[0]
+
+
+#: most bytes of partial sums a chip holds for one reduce-scatter of
+#: `_partial_dd` (a group of rows x T float32)
+PARTIAL_GROUP_BYTES = 3 << 29
+
+
+def partial_groups(rows_per_device: int, n_dev: int, T: int) -> int:
+    """Rows a device KEEPS of one group of `_partial_dd`: the largest
+    divisor of its rows whose group (n_dev times as many rows of T
+    float32 partial sums) stays under PARTIAL_GROUP_BYTES; 1 at
+    least."""
+    fits = [q for q in range(1, rows_per_device + 1)
+            if rows_per_device % q == 0
+            and n_dev * q * T * 4 <= PARTIAL_GROUP_BYTES]
+    return max(fits, default=1)
+
+
+def _partial_dd(subb_loc, shifts_loc, spec: PassSpec, n_dev: int):
+    """Stage 2 over subbands that stay where stage 1 left them: this
+    chip's (nsub / n_dev, T) subbands and its columns of the call's
+    whole shift table, (rows, nsub / n_dev) -> its own rows' series,
+    (rows / n_dev, T), rows [d, d + 1) * rows / n_dev on chip d as the
+    row-sharded forms leave them.  The solo stage-2 kernel over the
+    local subbands for every row of a group, then ONE reduce-scatter a
+    group: the partial sums are whole numbers under 2^24 (sums of
+    bytes), so the order of the additions changes no bit.  A group
+    holds q rows of every chip (`partial_groups`), so what a chip
+    holds of partial sums is bounded whatever the call's rows."""
+    from tpulsar.kernels.dedisperse import _dedisperse_subbands_scan
+
+    rows, T = shifts_loc.shape[0], subb_loc.shape[1]
+    per_dev = rows // n_dev
+    q = partial_groups(per_dev, n_dev, T)
+    outs = []
+    for j in range(per_dev // q):
+        take = np.concatenate([d * per_dev + j * q + np.arange(q)
+                               for d in range(n_dev)])
+        shifts = shifts_loc[take]
+        if spec.pallas_dd:
+            part = _pallas_dd_local(subb_loc, shifts, spec.dd_stage_s,
+                                    spec.dd_interpret)
+        else:
+            part = _dedisperse_subbands_scan(
+                subb_loc, shifts, spec.dd_pad or T)
+        # a reduce-scatter spelled as all_to_all + a local sum: the
+        # TPU compiler turns psum_scatter over these row-minor tiles
+        # into an all-reduce of the whole group (twice the bytes, and
+        # the group's buffer alive for the rest of the program)
+        mine = jax.lax.all_to_all(part.reshape(n_dev, q, T), "dm",
+                                  split_axis=0, concat_axis=0, tiled=True)
+        outs.append(mine.sum(axis=0))
+    return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
 def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
@@ -264,6 +390,8 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
     def body(subb, shifts, keep, bank, taps):
         if spec.seq_sharded:
             series = seq_dedisperse_a2a(subb, shifts)
+        elif spec.sub_sharded:
+            series = _partial_dd(subb, shifts, spec, n_dev)
         elif spec.pallas_dd:
             series = _pallas_dd_local(subb, shifts, spec.dd_stage_s,
                                       spec.dd_interpret)
@@ -307,8 +435,12 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
                  (("lo_vals", "lo_bins", "sp_snr", "sp_idx")
                   + (("hi_vals", "hi_rbins", "hi_zidx")
                      if spec.hi else ()))}
-    in_specs = ((P(None, "dm"), P(), P(), P(), P()) if spec.seq_sharded
-                else (P(), P("dm", None), P(), P(), P()))
+    if spec.seq_sharded:
+        in_specs = (P(None, "dm"), P(), P(), P(), P())
+    elif spec.sub_sharded:
+        in_specs = (P("dm", None), P(None, "dm"), P(), P(), P())
+    else:
+        in_specs = (P(), P("dm", None), P(), P(), P())
     return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
@@ -408,3 +540,30 @@ def seq_dist_search(mesh: Mesh, subbands, sub_shifts, dms, dt_ds: float,
         {1: (vals, bins)}, np.asarray(dms), nfft * dt_ds,
         _lo_sigma_fn(nbins), sigma_min=params.sifting.sigma_threshold)
     return cands, events
+
+
+# ------------------------------------------- a laid-out array, re-laid
+
+_RESHARD_FNS: dict = {}
+
+
+def reshard(x, sharding):
+    """An array laid over a mesh's devices, under another sharding of
+    the same devices, by ONE compiled program over all the chips: an
+    all-gather or an all-to-all on the interconnect.  (`jax.device_put`
+    between two layouts copies piece by piece: 1.45 GiB of subbands to
+    every chip of a v5e 2x2 took 3.0 s that way, 2.9 GiB to one chip
+    3.6 s; PERF.md section 6, PR 43.)"""
+    if sharding not in _RESHARD_FNS:
+        _RESHARD_FNS[sharding] = jax.jit(lambda a: a, out_shardings=sharding)
+    return _RESHARD_FNS[sharding](x)
+
+
+def _first_copy(x, cm: Mesh):
+    """`on_first_device` of an array laid over `cm`: gathered onto
+    every chip by the interconnect, the first chip's copy kept (the
+    others' are freed with the gathered array)."""
+    whole = reshard(x, NamedSharding(cm, P()))
+    first = cm.devices.flat[0]
+    return next(s.data for s in whole.addressable_shards
+                if s.device == first)

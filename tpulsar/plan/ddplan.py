@@ -140,13 +140,28 @@ _GBNCC = [
     (152.694, 0.03, 102, 55, 128, 8),
     (320.994, 0.05, 102, 36, 128, 16),
 ]
+# FAST's Galactic Plane Pulsar Snapshot survey (the 19-beam L-band
+# receiver: 1.0-1.5 GHz in 2048 channels, 49.152 us; Han et al. 2021,
+# RAA 21, 107): again this planner's own answer for the geometry,
+# frozen — generate_ddplan(Observation(49.152e-6, 1250, 500, 2048,
+# 2048), 0, 3000, numsub=128); tests/test_ddplan.py ties the two.  Not
+# the survey's own table, of which no copy was at hand.
+_GPPS = [
+    (0.0, 0.02, 102, 71, 128, 1),
+    (144.84, 0.03, 102, 27, 128, 2),
+    (227.46, 0.05, 102, 37, 128, 4),
+    (416.16, 0.1, 102, 39, 128, 8),
+    (813.96, 0.3, 102, 30, 128, 16),
+    (1731.96, 0.5, 102, 25, 128, 32),
+]
 
 
 def survey_plan(backend: str) -> list[DedispStep]:
     """The frozen dedispersion plan of a survey's back end: 'pdev'
-    a.k.a. 'mock' and 'wapp' (PALFA's hardcoded tables), or 'gbncc'."""
+    a.k.a. 'mock' and 'wapp' (PALFA's hardcoded tables), 'gbncc' or
+    'gpps'."""
     table = {"pdev": _PALFA_MOCK, "mock": _PALFA_MOCK, "wapp": _PALFA_WAPP,
-             "gbncc": _GBNCC}
+             "gbncc": _GBNCC, "gpps": _GPPS}
     key = backend.lower()
     if key not in table:
         raise ValueError(f"no dedispersion plan for unknown backend {backend!r}")

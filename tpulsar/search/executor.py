@@ -42,6 +42,7 @@ from tpulsar.io import accelcands, datafile
 from tpulsar.kernels import accel as accel_k
 from tpulsar.obs import telemetry
 from tpulsar.obs import trace as trace_mod
+from tpulsar.parallel import mesh as pmesh
 from tpulsar.kernels import dedisperse as dd
 from tpulsar.kernels import fold as fold_k
 from tpulsar.kernels import tree_dd
@@ -193,8 +194,6 @@ def dm_mesh(dm_shards: int):
     layout is the deployment's, and a beam searched on fewer chips
     than it states is a different deployment, not a fallback."""
     if dm_shards not in _DM_MESHES:
-        from tpulsar.parallel import mesh as pmesh
-
         devs = jax.local_devices()
         if len(devs) < dm_shards:
             raise RuntimeError(
@@ -356,6 +355,41 @@ def _activate_runtime() -> None:
     _warmstart.install_runtime_monitor()
 
 
+#: the largest beam block (bytes, as read) the read-in puts whole on one
+#: chip when the search has a mesh: its transpose there holds two
+#: copies of it, and set-up the block beside the masked block
+READIN_WHOLE_MAX_BYTES = 6 << 30
+
+
+def _readin_devices(block, params) -> list:
+    """The devices the read-in lays the beam over: the search's mesh
+    (`dm_shards` > 1) for a block over one chip's budget, provided the
+    channels and the subbands divide into whole shares; else one."""
+    n = params.dm_shards
+    nchan = block.shape[1]
+    nsub = (params.nsub if nchan % params.nsub == 0
+            else ddplan.largest_divisor_leq(nchan, params.nsub))
+    if n > 1 and block.nbytes > READIN_WHOLE_MAX_BYTES and nsub % n == 0:
+        return list(dm_mesh(n).devices.flat)
+    return [None]
+
+
+def _place_shares(block, devs):
+    """The (T, nchan) block as read -> ONE (nchan, T) array laid over
+    `devs` by channels: each chip is sent its columns of the
+    time-major block and turns them itself (`rfi.channel_major`), so
+    no chip ever holds more than twice its share."""
+    T, nchan = block.shape
+    w = nchan // len(devs)
+    shares = [rfi_k.channel_major(jax.device_put(
+        np.ascontiguousarray(block[:, d * w:(d + 1) * w]), dev))
+        for d, dev in enumerate(devs)]
+    return jax.make_array_from_single_device_arrays(
+        (nchan, T), jax.sharding.NamedSharding(
+            jax.sharding.Mesh(np.asarray(devs), ("chan",)),
+            jax.sharding.PartitionSpec("chan", None)), shares)
+
+
 def _read_and_mask(si, params, basenm, resultsdir, store, timers):
     """Read the beam block and apply the RFI mask (checkpoint-aware):
     returns the masked (nchan, T) device array and the RFIMask.  The
@@ -378,9 +412,13 @@ def _read_and_mask(si, params, basenm, resultsdir, store, timers):
         # smaller) and never transposes again.  The time-major device
         # copy lives for that one call (2 x the block, under the
         # block + masked block further down).
+        devs = _readin_devices(block, params)
         with trace_mod.span("readin-place", bytes=block.nbytes,
-                            transposed="device"):
-            data = rfi_k.channel_major(jnp.asarray(block))  # (nchan, T)
+                            transposed="device", devices=len(devs)):
+            if len(devs) == 1:
+                data = rfi_k.channel_major(jnp.asarray(block))  # (nchan, T)
+            else:
+                data = _place_shares(block, devs)
             del block
             trace_mod.fence(data)
         mask_path = os.path.join(resultsdir, f"{basenm}_rfifind.npz")
@@ -816,6 +854,9 @@ def search_block(data: jnp.ndarray, freqs: np.ndarray, dt: float,
     params = params or SearchParams()
     timers = timers or StageTimers()
     mesh = _layout_mesh(params, mesh)   # too few devices: raises here
+    # a block laid over several devices by channels is searched on
+    # exactly those, share by share: the layout is the operand's
+    pmesh.require_same_devices(pmesh.channel_mesh(data), mesh)
     degraded.reset()   # this run's fallback flags only
     # TPULSAR_PROFILE=<dir>: capture a JAX profiler trace of the whole
     # block search (the TPU-era equivalent of the reference's stage
@@ -940,6 +981,9 @@ def _plan_loop(beams: list[_Beam], freqs, dt, plan, params, nsub,
     B = len(beams)
     group = _group_attrs(B)
     npasses = sum(s.numpasses for s in plan)
+    # devices the (first) beam's block is laid over: 1 = whole on one
+    shares = pmesh.channel_mesh(beams[0].data)
+    block_shards = 1 if shares is None else shares.size
     pass_idx = -1
     for step_idx, step in enumerate(plan):
         for ppass in step.passes():
@@ -954,9 +998,10 @@ def _plan_loop(beams: list[_Beam], freqs, dt, plan, params, nsub,
             with trace_mod.span("pass", pass_idx=pass_idx,
                                 step_idx=step_idx,
                                 downsamp=int(step.downsamp),
-                                ntrials=len(ppass.dms), **group):
+                                ntrials=len(ppass.dms),
+                                block_shards=block_shards, **group):
                 ps = _stage1(beams, freqs, dt, nsub, step, ppass,
-                             pass_idx, timers)
+                             pass_idx, timers, whole=mesh is None)
                 if mesh is not None:
                     _sharded_pass(mesh, ps, beams[0], params, timers)
                 else:
@@ -999,15 +1044,19 @@ def _resume_pass(beams: list[_Beam], pass_idx: int) -> bool:
 
 
 def _stage1(beams, freqs, dt, nsub, step, ppass, pass_idx,
-            timers) -> _Pass:
+            timers, whole: bool = True) -> _Pass:
     """Stage 1 of a pass: each beam's block to subbands at the pass's
-    sub-DM, with the solo program."""
+    sub-DM, with the solo program.  A block laid over several devices
+    leaves its subbands laid over them (share by share); `whole` (the
+    one-device pass loop) brings them to the first of those."""
     dms = np.asarray(ppass.dms)
     with timers.timing("subbanding"):
         chan_shifts, sub_shifts = dd.plan_pass_shifts(
             freqs, nsub, ppass.subdm, dms, dt, step.downsamp)
         subs = [dd.form_subbands(b.data, jnp.asarray(chan_shifts),
                                  nsub, step.downsamp) for b in beams]
+        if whole:
+            subs = [pmesh.on_first_device(s) for s in subs]
     T_ds = int(subs[0].shape[1])
     return _Pass(pass_idx=pass_idx, dms=dms, sub_shifts=sub_shifts,
                  subs=subs, T_ds=T_ds, dt_ds=dt * step.downsamp,
@@ -1178,7 +1227,8 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
             # what ran, on the chunk's span (docs/operations.md)
             if trace_mod.enabled():
                 trace_mod.annotate("dm_chunk", **fr.lo_dispatch_attrs(
-                    *wspec.shape, lo_stages, wspec.device.platform))
+                    *wspec.shape, lo_stages,
+                    next(iter(wspec.devices())).platform))
             trace_mod.fence(lo_res)
 
         hi_cands = None
@@ -1400,7 +1450,7 @@ def _sift_fold_finish(beam: _Beam, freqs, dt, params, nsub, timers,
         ch_sh, sub_sh = dd.plan_pass_shifts(freqs, nsub, dm, [dm],
                                             dt, 1)
         with trace_mod.span("fold-subbands", dm=float(dm), downsamp=1):
-            subb = dd.form_subbands(data, jnp.asarray(ch_sh), nsub, 1)
+            subb = _subbands_on_one(data, ch_sh, nsub, 1)
             trace_mod.fence(subb)
         return subb, sub_sh[0]
 
@@ -1420,9 +1470,7 @@ def _sift_fold_finish(beam: _Beam, freqs, dt, params, nsub, timers,
                     data, freqs, dt, plan,
                     [(k, to_fold[k].period_s, to_fold[k].dm)
                      for k in missing],
-                    nsub,
-                    lambda d, ch_sh, ns, ds: dd.form_subbands(
-                        d, jnp.asarray(ch_sh), ns, ds)))
+                    nsub, _subbands_on_one))
                 for k in missing:
                     _save_fold(k)
             folded = [folded_by_idx[k] for k in range(len(to_fold))]
@@ -1462,6 +1510,18 @@ def _sift_fold_finish(beam: _Beam, freqs, dt, params, nsub, timers,
 
 
 # ------------------------------------------------------------------ helpers
+
+def _mesh_rows_budget(nfft: int, budget: int) -> int:
+    """Most DM rows a device of the mesh takes in one call of the fused
+    pass program (parallel/mesh.sharded_pass_fn) at series length
+    nfft: single pulse, spectra and the lo stage of all its rows are
+    ONE program's temporaries there, 65-85 bytes a sample a row by the
+    chip's compiler (26 rows at nfft 6,144,000 asked 12.4-13.1 GiB of
+    a v5e beside the beam's share; PERF.md section 6, PR 43) against
+    the ~32 that `_budget_dm_chunk` counts for the one-device loop's
+    separate programs."""
+    return max(1, int(budget // (80 * nfft)))
+
 
 def pass_chunk_size(ndms: int, nfft: int, params: SearchParams) -> int:
     """The DM-chunk size a pass actually runs with: the HBM budget and
@@ -1702,11 +1762,20 @@ def _compute_baryv(si) -> float:
         return 0.0
 
 
+def _subbands_on_one(data, chan_shifts, nsub, downsamp):
+    """Stage 1 for the finish: the solo call on a block on one device;
+    a laid-out block's subbands are formed share by share like a
+    pass's and then brought whole to its first device, where refine
+    and fold read them."""
+    return pmesh.on_first_device(dd.form_subbands(
+        data, jnp.asarray(chan_shifts), nsub, downsamp))
+
+
 def _dedisperse_single(data, freqs, nsub, dm, dt):
     """One full-resolution DM series for folding."""
     chan_shifts, sub_shifts = dd.plan_pass_shifts(freqs, nsub, dm, [dm],
                                                   dt, 1)
-    subb = dd.form_subbands(data, jnp.asarray(chan_shifts), nsub, 1)
+    subb = _subbands_on_one(data, chan_shifts, nsub, 1)
     return np.asarray(dd.dedisperse_subbands(
         subb, jnp.asarray(sub_shifts)))[0]
 
@@ -1859,6 +1928,36 @@ def _get_bank(zmax: int) -> accel_k.TemplateBank:
 _SHARDED_FN_CACHE: dict[tuple, object] = {}
 
 
+#: the exchange a laid-out beam's pass takes under seq_shard "auto"
+#: once its subbands are over seq_shard_min_bytes: "partial" or "time"
+#: (PERF.md section 6, PR 43, has both on the chip)
+LAID_OUT_AUTO_FORM = "partial"
+
+
+def _mesh_exchange(mesh, subb, form: str, sharding, rows: int, timers):
+    """The one exchange of a laid-out beam's pass, a stage of its own
+    beside `mesh-place`: stage 1's subbands, laid over the mesh by
+    subband, into the operand stage 2 reads (`_search_pass_sharded`
+    says which form when).  `bytes` is what crosses between chips,
+    summed over them: a copy to each of the others ("replicate"), each
+    piece's other time quarters ("time"), or the partial sums of the
+    pass's `rows` series that the chunk programs' reduce-scatters will
+    move ("partial": no byte moves here, the pieces are handed on
+    under the mesh's own sharding)."""
+    n = int(mesh.shape["dm"])
+    with timers.timing("mesh-exchange"):
+        if form == "partial":
+            out = pmesh.as_dm_rows(mesh, subb)
+            moved = (n - 1) * rows * int(subb.shape[1]) * 4
+        else:
+            out = jax.block_until_ready(pmesh.reshard(subb, sharding))
+            moved = (n - 1) * subb.nbytes // (n if form == "time" else 1)
+        trace_mod.annotate("mesh-exchange", bytes=moved, form=form,
+                           devices=n)
+    telemetry.mesh_exchange_bytes_total().inc(moved, form=form)
+    return out
+
+
 def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
                          params: SearchParams, zaplist, baryv,
                          timers: StageTimers | None = None,
@@ -1890,7 +1989,6 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     has its own proven per-DM fallback.
     """
     from tpulsar.kernels import pallas_dd
-    from tpulsar.parallel import mesh as pmesh
 
     n_dm = int(mesh.shape["dm"])
     T_ds = int(subb.shape[-1])
@@ -1919,9 +2017,22 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     # precedence over the Pallas stage-2 (which needs the replicated
     # block) — it exists for exactly the case where replication is
     # what must be avoided.
+    # Subbands that arrive laid over the mesh BY SUBBAND (stage 1 of
+    # a beam laid out by channels) are brought into stage 2's operand
+    # by ONE exchange a pass, `mesh-exchange`, in one of three forms:
+    # "replicate" (under seq_shard_min_bytes: every chip a whole copy,
+    # gathered from the pieces, then today's program), "time" (the
+    # sequence-parallel front end below, the pieces re-sharded from
+    # subbands to time) or "partial" (they stay where they are: each
+    # chip sums its own subbands for every row and a reduce-scatter
+    # inside the chunk program leaves it its rows, mesh._partial_dd).
+    # seq_shard "on" asks for time, "off" for partial; "auto" over the
+    # bytes takes LAID_OUT_AUTO_FORM, the one the chip prefers.
+    laid_out = pmesh.channel_mesh(subb) is not None
+    over = subb.nbytes > params.seq_shard_min_bytes
     seq = (params.seq_shard == "on"
-           or (params.seq_shard == "auto"
-               and subb.nbytes > params.seq_shard_min_bytes))
+           or (params.seq_shard == "auto" and over
+               and (not laid_out or LAID_OUT_AUTO_FORM == "time")))
     seq_ok = (n_dm > 1 and T_ds % n_dm == 0
               and dd_pad <= T_ds // n_dm)
     # Ultra-long series: when even ONE trial's spectral tail exceeds
@@ -1943,6 +2054,9 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
             f"subband replication", stacklevel=2)
     seq = seq and seq_ok
     use_pallas = use_pallas and not seq
+    form = None if not laid_out else (
+        "time" if seq else
+        "partial" if over or params.seq_shard != "auto" else "replicate")
     stage_s = 0
     if use_pallas:
         stage_s = pallas_dd.stage_overhang(smax)
@@ -1960,7 +2074,7 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         hi_nz=nz if hi_sharded else 0,
         pallas_dd=use_pallas, dd_stage_s=stage_s,
         dd_interpret=use_pallas and not pallas_dd.is_tpu_backend(),
-        dd_pad=dd_pad, seq_sharded=seq)
+        dd_pad=dd_pad, seq_sharded=seq, sub_sharded=form == "partial")
     key = (mesh, spec)
     if key not in _SHARDED_FN_CACHE:
         _SHARDED_FN_CACHE[key] = pmesh.sharded_pass_fn(mesh, spec)
@@ -1976,10 +2090,14 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     # correlates directly (a TPU mesh).
     P = jax.sharding.PartitionSpec
     whole = jax.sharding.NamedSharding(mesh, P())
+    by_time = jax.sharding.NamedSharding(mesh, P(None, "dm"))
+    padded = pmesh.shard_dm_table(np.asarray(sub_shifts), n_dm)
+    if laid_out:
+        subb_m = _mesh_exchange(mesh, subb, form, by_time if seq else whole,
+                                len(padded), timers)
     with timers.timing("mesh-place"):
-        subb_m = jax.device_put(
-            subb, jax.sharding.NamedSharding(mesh, P(None, "dm"))
-            if seq else whole)
+        if not laid_out:
+            subb_m = jax.device_put(subb, by_time if seq else whole)
         keep_arr = jax.device_put(keep.astype(np.float32), whole)
         bank_arr = jax.device_put(
             bank.bank_fft if hi_sharded
@@ -1987,15 +2105,14 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         taps_arr = None
         if hi_sharded and accel_k.corr_form() == "direct":
             taps_arr = jax.device_put(accel_k.corr_taps(bank), whole)
-        placed = [a for a in (subb_m, keep_arr, bank_arr, taps_arr)
-                  if a is not None]
+        placed = [a for a in (None if laid_out else subb_m, keep_arr,
+                              bank_arr, taps_arr) if a is not None]
         jax.block_until_ready(placed)
         nplaced = sum(sh.data.nbytes for a in placed
                       for sh in a.addressable_shards)
         trace_mod.annotate("mesh-place", bytes=nplaced, devices=n_dm)
     telemetry.mesh_bytes_placed_total().inc(nplaced)
 
-    padded = pmesh.shard_dm_table(np.asarray(sub_shifts), n_dm)
     ndms_pad, ndms = len(padded), len(dms)
     # Chunk size: multiple of the dm axis, bounded by the per-device
     # accel-plane HBM budget and the configured DM chunk.
@@ -2007,6 +2124,13 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
             nbins, nz, max_chunk=32))
     chunk = max(n_dm, (chunk // n_dm) * n_dm)
     chunk = min(chunk, ndms_pad)
+    # ... and by the fused program's own working set a device: where a
+    # call's rows would pass it (no accepted cell's do: a long series
+    # with hi-accel off), the pass is split evenly into more calls
+    cap = n_dm * _mesh_rows_budget(nfft, params.spectral_hbm_budget)
+    if chunk > cap:
+        calls = -(-ndms_pad // cap)
+        chunk = n_dm * -(-ndms_pad // (calls * n_dm))
 
     stages_lo = fr.harmonic_stages(params.lo_accel_numharm)
     stages_hi = fr.harmonic_stages(params.hi_accel_numharm) if hi else []
@@ -2095,8 +2219,9 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
             for lo in range(0, ndms, params.max_dms_per_chunk):
                 dm_chunk = dms[lo: lo + params.max_dms_per_chunk]
                 series = dd.dedisperse_subbands(
-                    subb, jnp.asarray(np.asarray(sub_shifts)
-                                      [lo: lo + len(dm_chunk)]))
+                    pmesh.on_first_device(subb),
+                    jnp.asarray(np.asarray(sub_shifts)
+                                [lo: lo + len(dm_chunk)]))
                 # bool mask, NOT float32: the bool-mask program is the
                 # one the AOT gate pre-compiles (whitened_powers casts
                 # internally, so the result is identical)
