@@ -298,6 +298,9 @@ def test_dm_chunk_says_the_lo_form(drifting_beam):
         trace.reset()
     assert chunks and {(a["lo_form"], a["lo_tile"]) for a in chunks} == {
         ("strided", 0)}
+    # ... and sp_form / sp_tile, the boxcar ladder's (the plain chain
+    # here: singlepulse.sp_dispatch_attrs)
+    assert {(a["sp_form"], a["sp_tile"]) for a in chunks} == {("plain", 0)}
 
 
 def test_dm_chunk_says_stage2_calls_and_rows(drifting_beam, monkeypatch):

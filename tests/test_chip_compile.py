@@ -272,6 +272,8 @@ def test_dm_sharded_pass_program_on_four_chips(v5e, tpu_accel_branch,
     assert "corr_plane" in text and "harmsum_zmax" in text
     # the lo stage's kernel at a device's rows, and no decimated copy
     assert "lo_harmsum" in text and f"f32[{nbins},{rows}]" not in text
+    # the boxcar ladder's kernel inside shard_map, at a device's rows
+    assert "sp_boxcar" in text and f",{NSAMP // 32},32]" not in text
     assert "fft_type=IFFT" not in text and "all-gather" in text
     assert not _whitening_loops(text, nbins)    # the solo program's form
     mem = compiled.memory_analysis()        # bytes on each device
@@ -396,17 +398,34 @@ def test_whitening_program(one_chip):
     assert not _whitening_loops(text, nbins) and " gather(" not in text
 
 
-def test_single_pulse_programs(one_chip):
-    """Detrend + boxcar ladder at a ds=1 pass chunk."""
+@pytest.mark.parametrize("rows,T", [
+    (38, NSAMP),                # a Mock ds=1 pass chunk
+    (64, 1_966_080),            # Mock ds=2
+    (76, 167_772),              # WAPP ds=25: the shortest, ragged
+    (102, 1_361_920),           # GBNCC ds=1: three row groups
+    (6, NSAMP),                 # a mesh device's rows
+])
+def test_single_pulse_programs(one_chip, rows, T):
+    """Detrend + boxcar ladder at the cells' pass chunks.  The ladder
+    is ONE pass over the series in the kernel sp_boxcar: no loop the
+    compiler made (the cumulative-sum form re-tiled the series in a
+    `while` a width), no array with a minor dimension of 32 (its
+    blocks on 128 lanes), and what it holds beside its operand is the
+    block maxima (4.2 GB at 38 rows until PR 44)."""
     from tpulsar.kernels import singlepulse as sp_k
 
-    series = _sds(one_chip, (38, NSAMP), jnp.float32)
+    series = _sds(one_chip, (rows, T), jnp.float32)
     sp_k.normalize_series.lower(
         series, estimator=sp_k.detrend_estimator()).compile()
     compiled = sp_k.boxcar_search.lower(
         series, tuple(sp_k.DEFAULT_WIDTHS),
         sp_k.DEFAULT_TOPK).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes > 0
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "sp_boxcar" in text
+    assert " while(" not in text and ",32]{" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.5e9
+    assert temp < 3 * 2 * len(sp_k.DEFAULT_WIDTHS) * rows * (T // 32) * 4
 
 
 @pytest.mark.parametrize("nsamp,nchan,dtype", [

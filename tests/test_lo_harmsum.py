@@ -245,6 +245,24 @@ def test_strided_form_is_lowered_off_the_tpu():
         "lo_form": "strided", "lo_tile": 0}
 
 
+@pytest.mark.parametrize("on", ["operand", "mesh"])
+def test_a_chunks_span_carries_both_programs_forms(on):
+    """dm_chunk / mesh_chunk get lo_form / lo_tile and the boxcar
+    ladder's sp_form / sp_tile from one place, for the platform of the
+    devices the chunk's operand (or the pass's mesh) lives on: a CPU
+    here, so the strided and the plain form."""
+    from tpulsar.kernels import singlepulse as sp_k
+    from tpulsar.search import executor
+
+    where = (jnp.zeros(4) if on == "operand" else
+             jax.sharding.Mesh(np.array(jax.devices()[:4]).reshape(1, 4),
+                               ("beam", "dm")))
+    assert executor._dispatch_attrs(
+        (6, 9000), list(sp_k.DEFAULT_WIDTHS), (6, 3001), list(STAGES),
+        where) == {"sp_form": "plain", "sp_tile": 0,
+                   "lo_form": "strided", "lo_tile": 0}
+
+
 @pytest.mark.parametrize("rows,platform,want", [
     (38, "tpu", "tiled"), (6, "tpu", "tiled"), (63, "tpu", "tiled"),
     (64, "tpu", "tiled"), (65, "tpu", "strided"), (76, "tpu", "strided"),

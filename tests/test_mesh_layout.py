@@ -324,6 +324,15 @@ def test_mesh_chunk_says_the_lo_form(pair):
         ("strided", 0)}
 
 
+def test_mesh_chunk_says_the_sp_form(pair):
+    """... and the boxcar ladder's: the plain chain here (`tiled` and
+    the kernel's tile on a TPU: singlepulse.sp_dispatch_attrs)."""
+    chunks = [e["args"] for e in pair["events"]
+              if e["name"] == "mesh_chunk"]
+    assert chunks
+    assert {(a["sp_form"], a["sp_tile"]) for a in chunks} == {("plain", 0)}
+
+
 def test_the_solo_report_lists_no_mesh_stage():
     """``report.STAGES`` and a solo search's ``.report`` text stay as
     they are: ``StageTimers.timing`` takes a mesh stage when one is

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpulsar.kernels import singlepulse as sp
 
@@ -127,3 +128,29 @@ def test_detrend_tail_uses_own_length():
     np.testing.assert_allclose(out, oracle, rtol=1e-5, atol=1e-5)
     # the step must NOT read as a pulse: tail stays near zero mean
     assert abs(np.asarray(out)[:, 3 * blk:].mean()) < 0.5
+
+
+@pytest.mark.parametrize("widths,platform,want", [
+    (sp.DEFAULT_WIDTHS, "tpu", "tiled"), ((1,), "tpu", "tiled"),
+    ((1, 128), "tpu", "tiled"), ((1, 129), "tpu", "plain"),
+    (sp.DEFAULT_WIDTHS, "cpu", "plain"), ((1, 129), "cpu", "plain"),
+])
+def test_dispatch_attrs_say_what_boxcar_search_lowers(widths, platform,
+                                                      want):
+    """sp_form / sp_tile of a chunk's span name the program as it is
+    LOWERED for the platform of its operand's devices: the kernel's
+    custom call is in boxcar_search's module for a TPU (exported here,
+    nothing runs) exactly where sp_dispatch_attrs says "tiled", and
+    never in the CPU's."""
+    from jax import export
+
+    x = jnp.zeros((6, 9000), jnp.float32)
+    got = sp.sp_dispatch_attrs(6, 9000, widths, platform)
+    assert got == {"sp_form": want,
+                   "sp_tile": sp._SP_TILE if want == "tiled" else 0}
+    if platform == "cpu":
+        text = sp.boxcar_search.lower(x, widths, 16).as_text()
+    else:
+        text = export.export(sp.boxcar_search, platforms=(platform,))(
+            x, widths, 16).mlir_module()
+    assert ("tpu_custom_call" in text) == (want == "tiled")

@@ -169,6 +169,12 @@ PROGRAMS: tuple[Program, ...] = (
     # ---- kernels/singlepulse.py
     _k("singlepulse", "normalize_series", ("detrend_block", "estimator")),
     _k("singlepulse", "boxcar_search", ("widths", "topk")),
+    _k("singlepulse", "_ladder_block_maxima", ("widths", "interpret"),
+       doc="the boxcar ladder's tiled kernel (Pallas, sp_boxcar): every "
+           "width's 32-sample block maxima in one pass; traced inside "
+           "boxcar_search where that is lowered for a TPU, whose gate "
+           "shapes carry it (its row group derives from the series' "
+           "shape: singlepulse.sp_boxcar_plan)"),
     # ---- kernels/fold.py
     _k("fold", "_fold_with_bins", ("nbin", "npart")),
     _k("fold", "_shift_and_sum", ("nbin",)),

@@ -1158,15 +1158,15 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
     # off.  dd_calls x dd_rows: a beam's stage-2 program calls for
     # this chunk and rows a call, dd_groups the subband groups a call
     # sums over; the Pallas wrapper writes what it dispatched (0 where
-    # it did not run: the XLA scan, the tree family).  lo_form /
-    # lo_tile: the lo stage's harmonic sums as the program was lowered
-    # ("tiled" and the kernel's tile for a TPU, else "strided" and 0)
+    # it did not run: the XLA scan, the tree family).  lo_form/lo_tile,
+    # sp_form/sp_tile: the lo stage's harmonic sums and the boxcar ladder
+    # as lowered ("tiled" + the kernel's tile on a TPU; _dispatch_attrs)
     hi_rows = (_hi_rows(B * n, ps.T_ds, params)
                if trace_mod.enabled() else 0)
     with trace_mod.span("dm_chunk", pass_idx=ps.pass_idx, lo=int(lo),
                         n=int(n), hi_rows=hi_rows, dd_calls=0,
                         dd_rows=0, dd_groups=0, lo_form="", lo_tile=0,
-                        family=ps.family, **ps.group):
+                        family=ps.family, sp_form="", sp_tile=0, **ps.group):
         with timers.timing("dedispersing"):
             # on the tree path series and norm are outputs of ONE
             # fused executable, so the fused detrend's wall time lands
@@ -1226,9 +1226,9 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
                 wspec, lo_stages, params.topk_per_stage)
             # what ran, on the chunk's span (docs/operations.md)
             if trace_mod.enabled():
-                trace_mod.annotate("dm_chunk", **fr.lo_dispatch_attrs(
-                    *wspec.shape, lo_stages,
-                    next(iter(wspec.devices())).platform))
+                trace_mod.annotate("dm_chunk", **_dispatch_attrs(
+                    series.shape, params.sp_widths, wspec.shape,
+                    lo_stages, wspec))
             trace_mod.fence(lo_res)
 
         hi_cands = None
@@ -2156,13 +2156,13 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
                                 n=nfirst, rows=chunk,
                                 rows_per_device=chunk // n_dm,
                                 devices=n_dm, hi=hi_sharded,
-                                lo_form="", lo_tile=0):
+                                lo_form="", lo_tile=0, sp_form="", sp_tile=0):
                 out = fn(subb_m, jnp.asarray(padded[s0:s0 + chunk]),
                          keep_arr, bank_arr, taps_arr)
-                if trace_mod.enabled():     # the lo stage a device's rows
-                    trace_mod.annotate(**fr.lo_dispatch_attrs(
-                        chunk // n_dm, nbins, stages_lo,
-                        mesh.devices.flat[0].platform))
+                if trace_mod.enabled():     # the forms a device's rows got
+                    trace_mod.annotate(**_dispatch_attrs(
+                        (chunk // n_dm, T_ds), params.sp_widths,
+                        (chunk // n_dm, nbins), stages_lo, mesh))
                 # the program's seconds apart from the transfers': the
                 # first fetch would block on the same program anyway
                 with trace_mod.span("mesh-wait", rows=chunk):
@@ -2339,3 +2339,19 @@ def _tar_result_classes(resultsdir: str, basenm: str) -> None:
         if suffix in ("_inf.tgz", "_singlepulse.tgz"):
             for f in files:
                 os.remove(f)
+
+
+def _dispatch_attrs(series_shape, sp_widths, spec_shape, lo_stages,
+                    on) -> dict:
+    """The forms a chunk's single-pulse and lo-stage programs were
+    lowered in, for its span (docs/operations.md): sp_form / sp_tile of
+    boxcar_search over a `series_shape` series, lo_form / lo_tile of
+    lo_stage_candidates over a `spec_shape` spectrum, for the platform
+    of the devices `on` (an operand, or the mesh) lives on.  Down here
+    so that no line above a Pallas call site moves (a kernel's
+    compile-cache key holds its call stack)."""
+    platform = (on.devices.flat[0] if isinstance(on, jax.sharding.Mesh)
+                else next(iter(on.devices()))).platform
+    return {**sp_k.sp_dispatch_attrs(*series_shape, tuple(sp_widths),
+                                     platform),
+            **fr.lo_dispatch_attrs(*spec_shape, tuple(lo_stages), platform)}
