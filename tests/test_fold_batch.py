@@ -151,3 +151,94 @@ def test_pass_grouped_driver(tmp_path):
     assert r.reduced_chi2 > 20
     # the fundamental should beat the 2x-period alias
     assert r.reduced_chi2 > results[1].reduced_chi2
+
+
+# ------------------------------------------------- the host's phase bins
+
+def _plain_bins(periods, T, Tp, dt, nbin):
+    """The formula `fb.phase_bins_batch` replaced (PR 46), full-length
+    float64 arrays and all: the plain reference its bins are held to,
+    bit for bit."""
+    bins = np.zeros((len(periods), Tp), np.int32)
+    for i, p in enumerate(periods):
+        bins[i, :T] = np.minimum(
+            (np.mod(np.arange(T) * dt / p, 1.0) * nbin).astype(np.int32),
+            nbin - 1)
+    return bins
+
+
+_B = fb.PHASE_BLOCK
+_DT_MOCK, _DT_WAPP, _DT_GPPS = 65.476e-6, 64e-6, 49.152e-6
+# one period of each `fold_rules` tier (nbin 24, 50, 100, 200) and a
+# second slow one
+_PERIODS = [1.57e-3, 12.3e-3, 0.15, 1.23, 4.7]
+# (id, T, dt, Tp - T): the cells' fold lengths (Mock ds=1, WAPP, Mock
+# ds=2, FAST's pulsar at ds=2), and the block loop's edges
+_LENGTHS = [
+    ("mock_ds1", 3_932_160, _DT_MOCK, 0),
+    ("wapp", 4_194_304, _DT_WAPP, 16),
+    ("mock_ds2", 1_966_080, 2 * _DT_MOCK, 0),
+    ("gpps_ds2", 3_051_520, 2 * _DT_GPPS, 20),
+    ("under_a_block", 1000, _DT_WAPP, 0),
+    ("one_block", _B, _DT_WAPP, 0),
+    ("block_multiple_plus_1", 3 * _B + 1, _DT_MOCK, 0),
+    ("block_multiple_minus_1", 3 * _B - 1, _DT_MOCK, 0),
+    ("padded_tail", 100_003, _DT_MOCK, 17),
+    ("padded_tail_under_a_block", 8191, 5e-4, 29),
+]
+_BIN_CASES = [
+    pytest.param([p], T, T + pad, dt, id=f"{name}-p{p:g}")
+    for name, T, dt, pad in _LENGTHS for p in _PERIODS
+] + [
+    pytest.param([12.3e-3, 12.31e-3], 2 * _B + 5, 2 * _B + 40, _DT_WAPP,
+                 id="two_in_a_chunk"),
+    pytest.param([0.15, 0.1501, 0.31], 5 * _B - 3, 5 * _B + 27,
+                 _DT_MOCK, id="three_in_a_chunk"),
+    pytest.param([1.57e-3, 1.9e-3], 4_194_304, 4_194_350, _DT_WAPP,
+                 id="two_in_a_chunk_wapp"),
+]
+
+
+@pytest.mark.parametrize("periods, T, Tp, dt", _BIN_CASES)
+def test_phase_bins_equal_the_full_length_formula(periods, T, Tp, dt):
+    """Block by block in a reused scratch, the same bits as the
+    full-length float64 arrays: every bin, the padded tail 0."""
+    nbin = fold_k.fold_rules(periods[0]).nbin
+    got = fb.phase_bins_batch(periods, T, Tp, dt, nbin)
+    assert got.dtype == np.int32 and got.shape == (len(periods), Tp)
+    assert np.array_equal(got, _plain_bins(periods, T, Tp, dt, nbin))
+
+
+def test_phase_bins_hold_no_full_length_float64():
+    """At WAPP's T = 2^22 (where a float64 array is a 32 MiB mapping)
+    the host half peaks at `bins` itself and its block scratches."""
+    import tracemalloc
+
+    T, npart = 1 << 22, 40
+    Tp = npart * -(-T // npart)
+    tracemalloc.start()
+    try:
+        bins = fb.phase_bins_batch([12.3e-3], T, Tp, _DT_WAPP, 50)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak <= bins.nbytes + (2 << 20), (peak, bins.nbytes)
+
+
+def test_fold_results_identical_with_the_full_length_formula(monkeypatch):
+    """The fold of the file's pulsar gives the same results, bit for
+    bit, with the bins made block by block (three ragged blocks here)
+    and by the formula they replaced, in one process: nothing recorded
+    that another machine's XLA could miss."""
+    subb, _ = _synth()
+    rules = fold_k.fold_rules(P_TRUE)
+    cands = [(P_TRUE, DM_TRUE), (P_TRUE * 1.001, DM_TRUE + 2.0)]
+    monkeypatch.setattr(fb, "PHASE_BLOCK", 3000)
+    new = fb.fold_subbands_batch(subb, _subrefs(), DT, cands, rules)
+    monkeypatch.setattr(fb, "phase_bins_batch", _plain_bins)
+    old = fb.fold_subbands_batch(subb, _subrefs(), DT, cands, rules)
+    for rn, ro in zip(new, old):
+        assert (rn.period_s, rn.dm, rn.reduced_chi2, rn.pdot) \
+            == (ro.period_s, ro.dm, ro.reduced_chi2, ro.pdot)
+        assert np.array_equal(rn.profile, ro.profile)
+        assert np.array_equal(rn.subints, ro.subints)

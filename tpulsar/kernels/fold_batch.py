@@ -242,6 +242,53 @@ def _sym_grid(extent: int, step: int) -> np.ndarray:
     return np.concatenate([-pos[:0:-1], pos]).astype(np.float64)
 
 
+# Samples a block of the host's phase arithmetic: its four float64
+# scratches (1 MiB) stay in the host's L2.  On the chip's host a
+# candidate of 2^22 samples takes 11 ms at this size, 9-10 ms at
+# 65,536-262,144, 27-49 ms at 2,048 and 174 ms as one full-length block
+# (PERF.md section 6, PR 46); a constant, not a knob.
+PHASE_BLOCK = 32768
+
+
+def phase_bins_batch(periods, T: int, Tp: int, dt: float,
+                     nbin: int) -> np.ndarray:
+    """(len(periods), Tp) int32 phase bins: sample n of candidate i
+    falls in bin min(int(frac((n * dt) / period_i) * nbin), nbin - 1),
+    in float64 (~T/p turns cannot live in float32); the padded tail
+    [T, Tp) is bin 0 (its weight is 0).
+
+    Made block by block in reused scratches, so no full-length float64
+    array exists: at T = 2^22 each would be a fresh 32 MiB mapping,
+    page-faulted in and unmapped again.  The same operations in the
+    same order on the same float64 values as
+    ``np.minimum((np.mod(np.arange(T) * dt / p, 1.0) * nbin)
+    .astype(np.int32), nbin - 1)``: for x >= 0 ``x - floor(x)`` and
+    ``fmod(x, 1.0)`` are both the exact fractional part, so every bin
+    is the same bit (tests/test_fold_batch.py holds it)."""
+    bins = np.empty((len(periods), Tp), np.int32)
+    bins[:, T:] = 0
+    base = np.arange(PHASE_BLOCK, dtype=np.float64)
+    t = np.empty(PHASE_BLOCK, np.float64)
+    x = np.empty(PHASE_BLOCK, np.float64)
+    fl = np.empty(PHASE_BLOCK, np.float64)
+    for lo in range(0, T, PHASE_BLOCK):
+        m = min(PHASE_BLOCK, T - lo)
+        tm, xm, fm = t[:m], x[:m], fl[:m]
+        # lo + n is an exact integer in float64; the candidates of the
+        # chunk share the block's times
+        np.add(base[:m], float(lo), out=tm)
+        np.multiply(tm, dt, out=tm)
+        for i, period in enumerate(periods):
+            out = bins[i, lo:lo + m]
+            np.divide(tm, period, out=xm)
+            np.floor(xm, out=fm)
+            np.subtract(xm, fm, out=xm)
+            np.multiply(xm, nbin, out=xm)
+            np.copyto(out, xm, casting="unsafe")
+            np.minimum(out, nbin - 1, out=out)
+    return bins
+
+
 @dataclasses.dataclass(frozen=True)
 class _TierGeom:
     """Static grid geometry for one period tier (one compile per
@@ -288,9 +335,6 @@ def fold_subbands_batch(subbands, sub_freqs_mhz, dt: float,
     band_span = float(sub_freqs[0] ** -2 - ref_mhz ** -2)
     T_s = T * dt
 
-    # per-candidate host precompute (float64 phase — ~T/p turns
-    # cannot live in float32)
-    t64 = np.arange(T, dtype=np.float64) * dt
     delays_unit = KDM * (sub_freqs ** -2 - ref_mhz ** -2)  # s per DM
 
     out: list[FoldResult] = []
@@ -304,14 +348,10 @@ def fold_subbands_batch(subbands, sub_freqs_mhz, dt: float,
         # (transfers, the fold program, up to its fetches), host half
         # (the results): spans per candidate chunk, never per row
         with trace.span("fold-host", n=nc):
-            bins = np.empty((nc, Tp), np.int32)
+            bins = phase_bins_batch([p for p, _ in chunk], T, Tp, dt,
+                                    rules_nbin)
             r_dm_l, dps_l, dpds_l, ddms_l = [], [], [], []
-            for i, (period, dm) in enumerate(chunk):
-                ph = np.mod(t64 / period, 1.0)
-                b = np.minimum((ph * rules_nbin).astype(np.int32),
-                               rules_nbin - 1)
-                bins[i, :T] = b
-                bins[i, T:] = 0
+            for period, dm in chunk:
                 # grids in profile-bin-drift units (prepfold's unit)
                 dp_unit = period ** 2 / (rules_nbin * T_s)
                 dpd_unit = 2.0 * period ** 2 / (rules_nbin * T_s ** 2)
