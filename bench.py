@@ -957,169 +957,6 @@ def run_aot_gate(timeout: float, accel: bool, scale: float,
     return rec
 
 
-# -------------------------------------------------------------- dedisp bench
-
-def run_dedisp() -> None:
-    """``bench.py --dedisp``: direct-vs-tree stage-2 A/B on ONE
-    representative survey pass — the per-pass ``dm_trials_per_sec``
-    contrast (not the whole-beam aggregate) that justifies the tree
-    family (kernels/tree_dd.py).  Emits one bench/v2 record with an
-    additive ``dedisp`` key; tools/bench_gate.py gates
-    ``dedisp.tree.dm_trials_per_sec`` (and the direct rate, and the
-    speedup) against the committed baseline.
-
-    Knobs: TPULSAR_DEDISP_NSAMP (subband samples, default 1<<17),
-    TPULSAR_DEDISP_STEP / TPULSAR_DEDISP_PASS (survey-plan step and
-    pass index; default step 0 — the largest-Ndm, ds=1 step that
-    dominates the 57-pass plan — mid pass), TPULSAR_DEDISP_REPS
-    (timing repetitions, default 3).  Both families also time their
-    detrend: the direct family's separate normalize_series traversal
-    vs the tree family's fused-in-program detrend."""
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    want = os.environ.get("JAX_PLATFORMS", "").strip()
-    if want:
-        jax.config.update("jax_platforms", want)
-
-    from tpulsar.kernels import dedisperse as dd
-    from tpulsar.kernels import singlepulse as sp_k
-    from tpulsar.kernels import tree_dd
-    from tpulsar.plan import ddplan
-
-    nsamp = int(os.environ.get("TPULSAR_DEDISP_NSAMP", str(1 << 17)))
-    step_idx = int(os.environ.get("TPULSAR_DEDISP_STEP", "0"))
-    reps = max(1, int(os.environ.get("TPULSAR_DEDISP_REPS", "3")))
-    plan = ddplan.survey_plan("pdev")
-    step = plan[min(step_idx, len(plan) - 1)]
-    pass_idx = int(os.environ.get("TPULSAR_DEDISP_PASS",
-                                  str(step.numpasses // 2)))
-    ppass = step.passes()[min(pass_idx, step.numpasses - 1)]
-    freqs = (FCTR - BW / 2) + (np.arange(NCHAN) + 0.5) * (BW / NCHAN)
-    _ch, sub_sh = dd.plan_pass_shifts(
-        freqs, step.numsub, ppass.subdm, np.asarray(ppass.dms),
-        TSAMP, step.downsamp)
-    ndms = sub_sh.shape[0]
-    est = sp_k.detrend_estimator()
-
-    rng = np.random.default_rng(7)
-    subb = jnp.asarray(rng.standard_normal(
-        (step.numsub, nsamp)).astype(np.float32))
-
-    # The headline times DEDISPERSION alone on both sides (the
-    # per-pass dm_trials_per_sec contrast); the fused-detrend variant
-    # is timed separately against direct + its standalone normalize
-    # traversal.  The four measurements INTERLEAVE within each rep
-    # and the medians are reported: this shared-host class of runner
-    # drifts on the seconds timescale, and back-to-back blocks would
-    # let a capacity swing masquerade as (or hide) the family
-    # contrast — the same bracketing discipline as bench --fleet.
-    tplan = tree_dd.plan_for_pass(sub_sh, T=nsamp, family="tree")
-
-    def direct_fn():
-        return jax.block_until_ready(
-            dd.dedisperse_subbands(subb, jnp.asarray(sub_sh)))
-
-    def tree_fn(fuse: bool):
-        parts = tree_dd.tree_levels(subb, tplan)
-        out = tree_dd.residual_series(
-            parts, tplan, 0, tplan.ndms, nsamp, fuse=fuse,
-            estimator=est)
-        jax.block_until_ready(out)
-        return out
-
-    series_d = direct_fn()                       # warm compiles
-
-    def detrend_fn():
-        return jax.block_until_ready(
-            sp_k.normalize_series(series_d, estimator=est))
-
-    measures = {
-        "direct": direct_fn,
-        "tree": lambda: tree_fn(False),
-        "direct_detrend": detrend_fn,
-        "tree_fused": lambda: tree_fn(True),
-    }
-    samples: dict[str, list] = {k: [] for k in measures}
-    outs: dict[str, object] = {}
-    for fn in measures.values():
-        fn()                                     # warm (compiles)
-    for _ in range(reps):
-        for name, fn in measures.items():
-            t0 = time.time()
-            outs[name] = fn()
-            samples[name].append(time.time() - t0)
-
-    import statistics
-
-    direct_s = statistics.median(samples["direct"])
-    direct_det_s = statistics.median(samples["direct_detrend"])
-    tree_s = statistics.median(samples["tree"])
-    fused_s = statistics.median(samples["tree_fused"])
-    series_d, series_t = outs["direct"], outs["tree"]
-    _series_f, norm_t = outs["tree_fused"]
-    norm_d = outs["direct_detrend"]
-
-    # parity: same clamped-gather terms, tree summation order —
-    # agreement is summation-order tight, never approximate
-    err = float(jnp.max(jnp.abs(series_t - series_d)))
-    scale_ref = float(jnp.max(jnp.abs(series_d)))
-    err_norm = float(jnp.max(jnp.abs(norm_t - norm_d)))
-    parity_ok = bool(err <= max(1e-4 * max(scale_ref, 1.0), 1e-3)
-                     and err_norm <= 1e-3)
-
-    rec = {
-        "metric": "dedisp_ab_tree_dm_trials_per_sec",
-        "value": round(ndms / tree_s, 2),
-        "unit": "trials/s",
-        "vs_baseline": round((ndms / tree_s)
-                             / max(ndms / direct_s, 1e-9), 3),
-        "device": str(jax.devices()[0]),
-        "dedisp": {
-            "nsamp": nsamp, "step": step_idx,
-            "pass": min(pass_idx, step.numpasses - 1),
-            "ndms": ndms, "nsub": step.numsub,
-            "downsamp": step.downsamp, "reps": reps,
-            "estimator": est,
-            "direct": {
-                "seconds": round(direct_s, 4),
-                "detrend_seconds": round(direct_det_s, 4),
-                "dm_trials_per_sec": round(ndms / direct_s, 2),
-            },
-            "tree": {
-                "seconds": round(tree_s, 4),
-                # fused into the residual program: the detrend's
-                # marginal cost is the fused-minus-plain delta, not a
-                # separate series traversal
-                "fused_seconds": round(fused_s, 4),
-                "detrend_seconds": round(max(fused_s - tree_s, 0.0),
-                                         4),
-                "dm_trials_per_sec": round(ndms / tree_s, 2),
-                "depth": tplan.depth,
-                "groups": tplan.groups,
-                "pad": tplan.pad,
-                "cost_rows": tplan.cost_rows,
-                "direct_cost_rows": ddplan.dedisp_cost_direct(
-                    ndms, step.numsub),
-                "residual_fraction": round(tplan.residual_fraction,
-                                           4),
-            },
-            # dedispersion-stage contrast AND the end-to-end one the
-            # fusion buys (fused tree already detrended; direct still
-            # owes its standalone normalize traversal)
-            "speedup": round(direct_s / tree_s, 3),
-            "speedup_with_detrend": round(
-                (direct_s + direct_det_s) / fused_s, 3),
-            "parity_max_abs_err": err,
-            "parity_norm_max_abs_err": err_norm,
-            "parity_ok": parity_ok,
-        },
-    }
-    _emit(rec)
-
-
 # --------------------------------------------------------------- accel bench
 
 def run_accel_ab() -> None:
@@ -1140,9 +977,8 @@ def run_accel_ab() -> None:
     z-chunked consumer when the toolchain allows).  The batched
     side's plane-construction seconds are measured separately so the
     record carries the plane-vs-fused-top-k split.  Measurements
-    interleave within each rep and medians are reported (the
-    bench --dedisp bracketing discipline: shared-host capacity drift
-    must not masquerade as the path contrast).
+    interleave within each rep and medians are reported (shared-host
+    capacity drift must not masquerade as the path contrast).
 
     Knobs: TPULSAR_ACCEL_AB_NBINS (spectrum bins, default 1<<15),
     TPULSAR_ACCEL_AB_NDMS (DM trials, default 24),
@@ -2921,9 +2757,6 @@ def main() -> None:
         return
     if "--serve" in sys.argv:
         run_serve()
-        return
-    if "--dedisp" in sys.argv:
-        run_dedisp()
         return
     if "--accel" in sys.argv:
         run_accel_ab()

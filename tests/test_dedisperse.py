@@ -6,6 +6,28 @@ import pytest
 
 from tpulsar.io import synth
 from tpulsar.kernels import dedisperse as dd
+from tpulsar.plan import ddplan
+
+# The Mock survey plan's own passes at the benchmark's beam geometry:
+# the first and the deepest ds=1 pass (shifts to 119 and 3387 samples)
+# and one pass of each deeper downsampling class.  Random tables miss
+# what real ones have: monotone ramps, long runs of equal shifts,
+# shifts near the series' length.
+SURVEY_GEOMS = [(0, 0), (0, 27), (1, 3), (3, 4), (5, 0)]
+SURVEY_FREQS = (1375.5 - 322.617 / 2) + (np.arange(960) + 0.5) * (
+    322.617 / 960)
+SURVEY_DT = 65.476e-6
+
+
+def _survey_pass(step_idx, pass_idx):
+    """(step, pass, channel shifts, subband shifts) of one pass of
+    ddplan.survey_plan("pdev")."""
+    step = ddplan.survey_plan("pdev")[step_idx]
+    ppass = step.passes()[pass_idx]
+    ch_sh, sub_sh = dd.plan_pass_shifts(
+        SURVEY_FREQS, step.numsub, ppass.subdm, np.asarray(ppass.dms),
+        SURVEY_DT, step.downsamp)
+    return step, ppass, ch_sh, sub_sh
 
 
 def _beam(nchan=32, nsamp=4096, dm=50.0, period=0.2, snr=3.0, seed=3):
@@ -62,20 +84,52 @@ def test_two_stage_matches_numpy_oracle():
     np.testing.assert_allclose(out, oracle, rtol=2e-4, atol=2e-3)
 
 
-def test_two_stage_close_to_exact_at_subdm():
-    """At DM == subdm the two-stage signal must track the exact
-    single-stage oracle closely (double rounding costs at most one
-    sample per channel, decorrelating only the per-channel noise)."""
+def _toy_pass():
+    """A 32-channel beam with a pulsar, one trial at the pulsar's DM:
+    (data, freqs, dt, nsub, downsamp, subdm, dms, least correlation)."""
     spec, psr, data = _beam()
-    freqs = synth.channel_freqs(spec)
-    subdm = psr.dm
-    out = dd.dedisperse_pass(jnp.asarray(data), freqs, nsub=8,
-                             subdm=subdm, dms=[subdm], dt=spec.tsamp_s,
-                             downsamp=1)
-    oracle = dd.dedisperse_exact(data, freqs, [subdm], spec.tsamp_s)
-    valid = data.shape[1] - dd.max_shift_samples(freqs, subdm, spec.tsamp_s) - 1
-    a, b = np.asarray(out)[0, :valid], oracle[0, :valid]
-    assert np.corrcoef(a, b)[0, 1] > 0.95
+    return (data, synth.channel_freqs(spec), spec.tsamp_s, 8, 1,
+            psr.dm, np.array([psr.dm]), 0.95)
+
+
+def _survey_noise_pass(step_idx, pass_idx, T=4096):
+    """White noise at the Mock beam's channels under one pass of the
+    survey plan.  On white noise the two roundings (channel within
+    subband, subband within band) cost a channel up to a sample, which
+    decorrelates per-sample values but not by much; a wrong table
+    would leave nothing."""
+    step, ppass, _ch_sh, _sub_sh = _survey_pass(step_idx, pass_idx)
+    rng = np.random.default_rng(11 + step_idx)
+    data = rng.standard_normal(
+        (960, T * step.downsamp)).astype(np.float32)
+    return (data, SURVEY_FREQS, SURVEY_DT, step.numsub, step.downsamp,
+            ppass.subdm, np.asarray(ppass.dms), 0.7)
+
+
+@pytest.mark.parametrize("make,args", [
+    pytest.param(_toy_pass, (), id="toy-beam")] + [
+    pytest.param(_survey_noise_pass, g, id="survey-step%d-pass%d" % g)
+    for g in SURVEY_GEOMS])
+def test_two_stage_close_to_exact_at_subdm(make, args):
+    """At the trial that IS the pass's sub-DM, stage 1 and the XLA
+    scan must track the exact single-stage oracle closely (double
+    rounding costs at most one sample per channel, decorrelating only
+    the per-channel noise): a toy beam, and the survey plan's own
+    tables."""
+    data, freqs, dt, nsub, ds, subdm, dms, least = make(*args)
+    i_sub = int(np.argmin(np.abs(dms - subdm)))
+    assert dms[i_sub] == pytest.approx(subdm, abs=1e-9)
+    ch_sh, sub_sh = dd.plan_pass_shifts(freqs, nsub, subdm, dms, dt, ds)
+    subb = dd.form_subbands(jnp.asarray(data), jnp.asarray(ch_sh),
+                            nsub, ds)
+    got = np.asarray(dd._dedisperse_subbands_scan(
+        subb, jnp.asarray(sub_sh[i_sub:i_sub + 1]),
+        dd._pad_bucket(int(sub_sh[i_sub].max()))))[0]
+    oracle = dd.dedisperse_exact(data, freqs, [subdm], dt, ds)[0]
+    valid = (data.shape[1]
+             - dd.max_shift_samples(freqs, subdm, dt) - 1) // ds
+    assert valid > 500
+    assert np.corrcoef(got[:valid], oracle[:valid])[0, 1] > least
 
 
 def test_dedispersed_pulse_recovery():
@@ -215,38 +269,62 @@ def _sequential_sum(subb, shifts):
     return out
 
 
-# (rows, nsub, T, largest shift), named for what the case is there for
-_STAGE2_CASES = [
-    pytest.param(19, 96, 1100, 119, id="19-rows-mock-subbands"),
-    pytest.param(32, 96, 1100, 119, id="a-full-call"),
-    pytest.param(19, 64, 1100, 100, id="19-rows-wapp-subbands"),
-    pytest.param(1, 16, 3000, 128, id="one-row"),
-    pytest.param(3, 8, 1500, 700, id="overhang-of-several-segments"),
-    pytest.param(5, 8, 1237, 256, id="ragged-T-and-shift-equal-to-S"),
-    pytest.param(3, 4, 400, 350, id="shifts-that-clamp-at-the-edge"),
-    pytest.param(4, 6, 20000, 1000, id="segment-2048-odd-unroll"),
-    pytest.param(38, 8, 1200, 200, id="two-calls-of-19"),
-    pytest.param(76, 8, 1100, 300, id="three-calls-26-26-24"),
-    pytest.param(2, 4, 40000, 16384, id="segment-4096-in-column-pieces"),
-]
-
-
-@pytest.mark.parametrize("rows,nsub,T,smax", _STAGE2_CASES)
-def test_pallas_dedisperse_equals_sequential_sum(rows, nsub, T, smax):
-    """The stage-2 Pallas kernel (interpret mode off-TPU) against the
-    sequential float32 sum in subband order, EQUAL element for
-    element: the golden candidate lists hang on these bits."""
-    from tpulsar.kernels import pallas_dd
-
+def _random_table(rows, nsub, T, smax):
     rng = np.random.default_rng(rows * 1000 + nsub)
     subb = rng.standard_normal((nsub, T)).astype(np.float32)
     shifts = rng.integers(0, smax + 1, size=(rows, nsub)).astype(np.int32)
     shifts[0, 0] = smax
     shifts[-1, -1] = 0
     shifts[rows // 2, :] = smax
+    return subb, shifts
+
+
+def _survey_table(step_idx, pass_idx, T=4096):
+    _step, _ppass, _ch_sh, shifts = _survey_pass(step_idx, pass_idx)
+    rng = np.random.default_rng(3)
+    subb = rng.standard_normal((shifts.shape[1], T)).astype(np.float32)
+    return subb, shifts.astype(np.int32)
+
+
+# random tables of (rows, nsub, T, largest shift), named for what the
+# case is there for, then the survey plan's own
+_RANDOM_TABLES = {
+    "19-rows-mock-subbands": (19, 96, 1100, 119),
+    "a-full-call": (32, 96, 1100, 119),
+    "19-rows-wapp-subbands": (19, 64, 1100, 100),
+    "one-row": (1, 16, 3000, 128),
+    "overhang-of-several-segments": (3, 8, 1500, 700),
+    "ragged-T-and-shift-equal-to-S": (5, 8, 1237, 256),
+    "shifts-that-clamp-at-the-edge": (3, 4, 400, 350),
+    "segment-2048-odd-unroll": (4, 6, 20000, 1000),
+    "two-calls-of-19": (38, 8, 1200, 200),
+    "three-calls-26-26-24": (76, 8, 1100, 300),
+    "segment-4096-in-column-pieces": (2, 4, 40000, 16384),
+}
+_STAGE2_CASES = [pytest.param(_random_table, args, id=name)
+                 for name, args in _RANDOM_TABLES.items()] + [
+    pytest.param(_survey_table, g, id="survey-step%d-pass%d" % g)
+    for g in SURVEY_GEOMS]
+
+
+@pytest.mark.parametrize("table,args", _STAGE2_CASES)
+def test_pallas_dedisperse_equals_sequential_sum(table, args):
+    """The chip's stage-2 form (the Pallas kernel, interpret mode
+    off-TPU) and the one XLA form (the scan every other platform
+    runs) against the sequential float32 sum in subband order, EQUAL
+    element for element: the golden candidate lists hang on these
+    bits."""
+    from tpulsar.kernels import pallas_dd
+
+    subb, shifts = table(*args)
+    want = _sequential_sum(subb, shifts)
     got = np.asarray(pallas_dd.dedisperse_subbands_pallas(
         subb, shifts, interpret=True))
-    np.testing.assert_array_equal(got, _sequential_sum(subb, shifts))
+    np.testing.assert_array_equal(got, want)
+    scan = np.asarray(dd._dedisperse_subbands_scan(
+        jnp.asarray(subb), jnp.asarray(shifts),
+        dd._pad_bucket(int(shifts.max()))))
+    np.testing.assert_array_equal(scan, want)
 
 
 def test_pallas_dedisperse_in_subband_groups_equals_sequential_sum(

@@ -175,18 +175,17 @@ def _cand_key(c):
             round(c.sigma, 5))
 
 
-@pytest.mark.parametrize("seq_shard, min_bytes, forms, hi", [
-    ("on", 2 << 30, ["time", "time"], False),
-    ("off", 2 << 30, ["partial", "partial"], False),
-    # both branches of "auto" in one call: ds=1 over the bytes, ds=2
-    # under; and with the hi stage behind the exchange
-    ("auto", 1 << 20, [executor.LAID_OUT_AUTO_FORM, "replicate"], False),
-    ("auto", 1 << 20, [executor.LAID_OUT_AUTO_FORM, "replicate"], True)])
+@pytest.mark.parametrize("min_bytes, forms, hi", [
+    (0, ["partial", "partial"], False),
+    # both sides of the threshold in one call: ds=1 over the bytes,
+    # ds=2 under; and with the hi stage behind the exchange
+    (1 << 20, ["partial", "replicate"], False),
+    (1 << 20, ["partial", "replicate"], True)])
 def test_a_laid_out_block_searches_as_the_same_bits_on_one_device(
-        beam, seq_shard, min_bytes, forms, hi):
+        beam, min_bytes, forms, hi):
     blk, plan, params = beam
     params = dataclasses.replace(
-        params, seq_shard=seq_shard, seq_shard_min_bytes=min_bytes,
+        params, seq_shard_min_bytes=min_bytes,
         run_hi_accel=hi, hi_accel_zmax=8, topk_per_stage=8)
     whole = jax.device_put(blk, jax.devices()[0])
     (c1, f1, e1, n1), ev1 = _search(whole, plan, params)
@@ -224,17 +223,12 @@ def test_the_exchange_counts_its_bytes_by_form(beam):
                      or {}).get("series", {}))
 
     base = totals()
-    params = dataclasses.replace(params, seq_shard="auto",
-                                 seq_shard_min_bytes=1 << 40)
+    params = dataclasses.replace(params, seq_shard_min_bytes=1 << 40)
     executor.search_block(lay_out(blk), FREQS, DT, plan[:1], params)
     got = {k: v - base.get(k, 0.0) for k, v in totals().items()}
     nbytes = NSUB * blk.shape[1] * 4
     assert got["replicate"] == 3 * nbytes      # a copy to each other chip
-    params = dataclasses.replace(params, seq_shard="on")
-    executor.search_block(lay_out(blk), FREQS, DT, plan[:1], params)
-    got = {k: v - base.get(k, 0.0) for k, v in totals().items()}
-    assert got["time"] == 3 * nbytes // 4      # each piece's other quarters
-    params = dataclasses.replace(params, seq_shard="off")
+    params = dataclasses.replace(params, seq_shard_min_bytes=0)
     executor.search_block(lay_out(blk), FREQS, DT, plan[:1], params)
     got = {k: v - base.get(k, 0.0) for k, v in totals().items()}
     # the partial sums of the 12 padded rows, three quarters of each

@@ -139,8 +139,17 @@ def test_search_params_rejects_bad_mode_values():
 
     with pytest.raises(ValueError, match="block_quantize"):
         executor.SearchParams(block_quantize="always")
-    with pytest.raises(ValueError, match="seq_shard"):
-        executor.SearchParams(seq_shard="true")
+
+
+def test_search_params_has_no_seq_shard_mode():
+    """The mesh's exchange is chosen from the operand's layout and its
+    bytes (seq_shard_min_bytes); there is no mode to ask for."""
+    import pytest
+
+    from tpulsar.search import executor
+
+    with pytest.raises(TypeError, match="seq_shard"):
+        executor.SearchParams(seq_shard="auto")
 
 
 def test_band_flip(tmp_path):

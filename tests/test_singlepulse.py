@@ -115,8 +115,8 @@ def test_detrend_tail_uses_own_length():
     T = 3 * blk + 137          # non-divisible length -> 137-sample tail
     series = rng.standard_normal((2, T)).astype(np.float32)
     series[:, 3 * blk:] += 50.0   # tail level steps far off the blocks
-    out = np.asarray(sp.detrend_normalize(jnp.asarray(series),
-                                          detrend_block=blk))
+    out = np.asarray(sp.normalize_series(jnp.asarray(series),
+                                         detrend_block=blk))
     # numpy oracle of the fixed behavior
     body = series[:, :3 * blk].reshape(2, 3, blk)
     baseline = np.repeat(np.median(body, axis=-1), blk, axis=-1)

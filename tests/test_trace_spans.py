@@ -217,8 +217,8 @@ def test_a_groups_tree_is_the_solos_with_nbeams(tmp_path, monkeypatch):
         assert p["args"]["nbeams"] == 2 and p["args"]["ntrials"] == 10
         under = _children(events, p)
         chunks = [k for k in under if k["name"] == "dm_chunk"]
-        assert [(c["args"]["n"], c["args"]["nbeams"], c["args"]["family"])
-                for c in chunks] == [(5, 2, "direct")] * 2
+        assert [(c["args"]["n"], c["args"]["nbeams"])
+                for c in chunks] == [(5, 2)] * 2
         assert {"hi_rows", "dd_calls", "dd_rows", "lo", "pass_idx"} \
             <= set(chunks[0]["args"])
         for c in chunks:
@@ -428,7 +428,7 @@ def test_spans_land_in_a_profiler_session_with_their_attrs(tmp_path):
     jax.profiler.start_trace(d)
     try:
         with trace.span("pass", pass_idx=3, downsamp=2):
-            with trace.span("dm_chunk", n=38, family="direct"):
+            with trace.span("dm_chunk", n=38, lo_form="strided"):
                 jnp.arange(8).sum().block_until_ready()
             trace.complete("retro", 0.001)    # cannot annotate: not there
     finally:
@@ -440,7 +440,7 @@ def test_spans_land_in_a_profiler_session_with_their_attrs(tmp_path):
     assert (st["pass_idx"], st["downsamp"]) == (3, 2)
     assert st["id"] == by["pass"]["id"] == st["call"]
     s_c, d_c, st_c = notes["dm_chunk"]
-    assert (st_c["n"], st_c["family"]) == (38, "direct")
+    assert (st_c["n"], st_c["lo_form"]) == (38, "strided")
     assert st_c["id"] == by["dm_chunk"]["id"]
     assert st_c["call"] == by["pass"]["id"]
     # on the profiler's clock: the child lies inside its parent there too

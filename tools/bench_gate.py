@@ -60,10 +60,6 @@ DEFAULT_KEYS = (
     ("fleet.speedup_vs_one_worker_warm", "higher"),
     ("fleet.two_worker.aggregate_warm_beams_per_s", "higher"),
     ("fleet.scaling_efficiency_vs_host_ceiling", "higher"),
-    ("dedisp.tree.dm_trials_per_sec", "higher"),
-    ("dedisp.direct.dm_trials_per_sec", "higher"),
-    ("dedisp.speedup", "higher"),
-    ("dedisp.speedup_with_detrend", "higher"),
     ("accel.batched.dm_trials_per_sec", "higher"),
     ("accel.per_dm.dm_trials_per_sec", "higher"),
     ("accel.speedup", "higher"),

@@ -49,18 +49,6 @@ _NON_STAGE_ROWS = ("Total time", "other")
 _STAGE_ROW = re.compile(
     r"^\s*([\w./ -]+?):\s+(\d+(?:\.\d+)?) s\s+\(\s*\d+(?:\.\d+)?%\)")
 
-#: trace spans FOLDED into a .report stage when the report carries no
-#: row of their own.  The tree dedispersion family fuses the SP
-#: detrend into its residual program, so its wall time belongs to
-#: the dedispersing stage; any path that times the detrend in a
-#: standalone "detrend" span (an unfused A/B, a future split
-#: program) while its report keeps the combined dedispersing row
-#: would otherwise fail the 5% gate on a pure attribution
-#: difference.  Spans that DO appear as report rows are never
-#: folded (that would double-count them).
-_FOLDED_SPANS = {"dedispersing": ("detrend",)}
-
-
 def parse_report_stages(report_path: str) -> dict[str, float]:
     """Stage seconds out of a .report: every row in the
     '<stage>: <secs> s  (pct%)' shape except the non-stage rows."""
@@ -87,9 +75,6 @@ def compare(summary: dict, report_path: str,
     report_stages = parse_report_stages(report_path)
     for stage, rep_s in report_stages.items():
         got_s = roll.get(stage, {}).get("seconds", 0.0)
-        for span in _FOLDED_SPANS.get(stage, ()):
-            if span not in report_stages:
-                got_s += roll.get(span, {}).get("seconds", 0.0)
         if abs(got_s - rep_s) > max(tolerance * rep_s, 0.05):
             problems.append(
                 f"{stage}: trace {got_s:.2f} s vs report "

@@ -13,7 +13,7 @@ Layout (mirrors SURVEY.md section 7):
   io/          PSRFITS + data formats, synthetic beam generator
   plan/        dedispersion planning (DDplan) + survey plans
   kernels/     JAX/Pallas compute kernels (the PRESTO-C replacements)
-  parallel/    mesh construction, sharded search, distributed FFT
+  parallel/    mesh construction, sharded search, a beam laid over chips
   search/      the per-beam search executor, sifting, reports
   orchestrate/ job tracker, job pool, queue managers, downloader, uploader
   config/      typed validated configuration

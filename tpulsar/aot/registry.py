@@ -129,14 +129,6 @@ PROGRAMS: tuple[Program, ...] = (
     _k("dedisperse", "_dedisperse_subbands_scan", ("pad",),
        doc="stage-2 XLA-scan dedispersion over DM trials"),
     _k("dedisperse", "dedisperse_window_scan", ("out_len",)),
-    # ---- kernels/tree_dd.py (the log-depth shift-tree family)
-    _k("tree_dd", "_tree_levels_jit", ("moffs", "pad"),
-       doc="shared merge levels of a tree pass — run once, reused by "
-           "every DM trial's residual gather"),
-    _k("tree_dd", "_tree_residual_jit",
-       ("T", "fuse", "detrend_block", "estimator"),
-       doc="per-dm_chunk residual layer with the SP detrend fused "
-           "into the same program"),
     # ---- kernels/pallas_dd.py (the stage-1/2 tiers of a TPU backend)
     _k("pallas_dd", "_segment_layout", ("seg",)),
     _k("pallas_dd", "_dedisperse_chunk",
@@ -260,17 +252,9 @@ EXEMPT_SITES: dict[str, str] = {
         "per-mesh shard_map closure (jit(step) captures the Mesh)",
     "tpulsar/parallel/mesh.py::sharded_pass_fn":
         "per-mesh shard_map closure over PassSpec",
-    "tpulsar/parallel/mesh.py::seq_dist_search":
-        "per-mesh single-pulse shard_map closure",
-    "tpulsar/parallel/seq_dedisperse.py::seq_dedisperse":
-        "per-mesh halo-exchange closure",
-    "tpulsar/parallel/dist_fft.py::_build_fft_fn":
-        "per-mesh distributed-FFT builder",
-    "tpulsar/parallel/dist_fft.py::_build_tail_fn":
-        "per-mesh distributed spectral-tail builder",
     "tpulsar/parallel/mesh.py::reshard":
-        "per-sharding identity program (an all-gather / all-to-all "
-        "between two layouts of a laid-out beam's subbands)",
+        "per-sharding identity program (an all-gather between two "
+        "layouts of a laid-out beam's subbands)",
     "tpulsar/kernels/rfi.py::_cell_stats_shares":
         "per-mesh shard_map of _cell_stats_chan over a laid-out beam's "
         "channel shares",
@@ -914,8 +898,6 @@ def _headline_groups(ctx: GateContext,
     groups.append(("refinement/fold prep (single-DM, full "
                    "resolution):", insts))
 
-    groups += _tree_groups(ctx, geoms, fast=fast)
-
     # Dense sweep over the single-DM pad buckets: pad buckets are
     # powers of two, so the LOW buckets occupy DM intervals much
     # narrower than a coarse sample spacing (the (256, 512) pair
@@ -1011,74 +993,3 @@ def _stage2_instances(nsub: int, T: int, rows: int, pad: int,
                  (segs, edge, _sds((n, nsub), jnp.int32)),
                  dict(plan.kernel_args(), interpret=False))
         for n in sorted(set(plan.call_rows(rows)), reverse=True)]
-
-
-def _tree_groups(ctx: GateContext, geoms,
-                 fast: bool) -> list[tuple[str, list[Instance]]]:
-    """Tree-family gate instances: for every pass the RUNTIME cost
-    model routes to the shift tree (tree_dd.plan_for_pass — the same
-    call the executor's pass loop makes, so gate and child cannot
-    disagree on the family), one levels program per distinct plan
-    geometry and one fused residual program per distinct chunk shape.
-    The level-row/offset quanta (tree_dd.ROW_QUANT/OFF_QUANT) exist
-    exactly so the 57 passes dedupe to a handful of signatures here.
-    ``fast`` keeps only the ds=1 step (maximal footprint, same
-    dominance argument as the block programs)."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tpulsar.kernels import dedisperse as dd
-    from tpulsar.kernels import singlepulse as sp_k
-    from tpulsar.kernels import tree_dd
-
-    est = sp_k.detrend_estimator()
-    if fast:
-        geoms = [g for g in geoms if g[0].downsamp == 1][:1]
-    lvl_seen: dict[tuple, Instance] = {}
-    res_seen: dict[tuple, Instance] = {}
-    for step, T_ds, ndms, _pads, nfft, chunk in geoms:
-        for ppass in step.passes():
-            _ch, sub_sh = dd.plan_pass_shifts(
-                ctx.freqs, step.numsub, ppass.subdm,
-                np.asarray(ppass.dms), TSAMP, step.downsamp)
-            plan = tree_dd.plan_for_pass(sub_sh, T=T_ds)
-            if plan is None:
-                continue
-            key = (plan.geom(), T_ds, step.numsub)
-            if key not in lvl_seen:
-                idx_sds = tuple(
-                    (_sds((len(lv.a),), jnp.int32),) * 4
-                    + (_sds((len(lv.carry),), jnp.int32),)
-                    for lv in plan.levels)
-                lvl_seen[key] = Instance(
-                    "tree_dd._tree_levels_jit",
-                    f"tree_levels ds={step.downsamp} "
-                    f"depth={plan.depth} pad={plan.pad} "
-                    f"#{len(lvl_seen)}",
-                    (_sds((step.numsub, T_ds), jnp.float32),
-                     idx_sds),
-                    dict(moffs=plan.moffs, pad=plan.pad))
-            L_cut = plan.cut_len(T_ds)
-            sizes = [min(chunk, ndms)]
-            if chunk < ndms and ndms % chunk:
-                sizes.append(ndms % chunk)
-            for rows in sizes:
-                rkey = (plan.rows_out, plan.groups, L_cut, rows, T_ds)
-                if rkey in res_seen:
-                    continue
-                res_seen[rkey] = Instance(
-                    "tree_dd._tree_residual_jit",
-                    f"tree_residual ds={step.downsamp} rows={rows} "
-                    f"G={plan.groups} #{len(res_seen)}",
-                    (_sds((plan.rows_out, L_cut), jnp.float32),
-                     _sds((rows, plan.groups), jnp.int32),
-                     _sds((rows, plan.groups), jnp.int32)),
-                    dict(T=T_ds, fuse=True,
-                         detrend_block=tree_dd.DETREND_BLOCK,
-                         estimator=est))
-    if not lvl_seen:
-        return []
-    return [(f"tree dedispersion family "
-             f"({len(lvl_seen)} level plans, "
-             f"{len(res_seen)} residual shapes):",
-             list(lvl_seen.values()) + list(res_seen.values()))]

@@ -135,9 +135,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "refs from at stage-in and push result artifacts to — the "
        "spool-less data plane; unset keeps the shared-filesystem "
        "path contract"),
-    _k("TPULSAR_DD_FAMILY", "enum(auto|direct|tree)", "auto",
-       "stage-2 dedispersion kernel family; auto = the per-pass "
-       "cost-model dispatch"),
     _k("TPULSAR_FAULTS", "spec", "unset",
        "deterministic fault-injection spec: point:mode[:k=v,..] "
        "(';'-separated); unknown points/modes fail loudly at parse"),
@@ -203,10 +200,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
     _k("TPULSAR_TRACE_SYNC", "enum(1)", "off",
        "1 fences chunk scopes with block_until_ready for device "
        "attribution (serializes the pipeline it measures)"),
-    _k("TPULSAR_TREE_BUDGET", "int (bytes)", "2147483648 (2 GiB)",
-       "tree-dedispersion level working-set budget; the governor "
-       "cuts the merge tree shallower when level tensors would "
-       "exceed it"),
     _k("TPULSAR_WHITEN_ESTIMATOR", "enum(median|clipped_mean)",
        "median",
        "FFT whitening noise estimator (clipped_mean is the "

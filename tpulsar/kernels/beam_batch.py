@@ -15,8 +15,8 @@ The acceptance contract is *exact* per-beam candidate parity and
 solo, which decides how the beam axis rides the device programs (the
 executor's one pass loop, ``search/executor.py::_plan_loop``):
 
-  * stage 1 (subbanding) and stage 2 (dedispersion, the tree's levels
-    included) run PER BEAM with the solo programs — the only form
+  * stage 1 (subbanding) and stage 2 (dedispersion) run PER BEAM
+    with the solo programs — the only form
     whose per-beam float arithmetic is the solo path's on every
     platform;
   * the spectral stages (fused SP detrend/boxcar, FFT/whiten, lo

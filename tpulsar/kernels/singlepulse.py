@@ -59,40 +59,8 @@ def _baseline_stat(x: jnp.ndarray, estimator: str) -> jnp.ndarray:
     raise ValueError(f"unknown SP detrend estimator {estimator!r}")
 
 
-@scopes.scope("sp/detrend")
-def detrend_normalize(series: jnp.ndarray, detrend_block: int = 1000,
-                      estimator: str = "median"):
-    """The detrend/normalize BODY (traceable, not itself jitted).
-
-    One implementation shared by two jitted programs:
-    ``normalize_series`` below (the standalone SP detrend pass) and
-    the tree dedispersion family's fused residual program
-    (kernels/tree_dd.py), which inlines the detrend into the same
-    device program as the final shift layer so the (ndms, T) series
-    never makes an extra HBM round-trip just to be baselined."""
-    ndms, T = series.shape
-    detrend_block = min(detrend_block, T)
-    nblk = max(1, T // detrend_block)
-    usable = nblk * detrend_block
-    blocks = series[:, :usable].reshape(ndms, nblk, detrend_block)
-    med = _baseline_stat(blocks, estimator)
-    baseline = jnp.repeat(med, detrend_block, axis=-1)
-    if T > usable:
-        # A tail shorter than detrend_block gets a baseline estimated
-        # from its own samples (its own length as the denominator) —
-        # reusing the last full block's baseline inflates tail sigmas
-        # whenever the local level drifts across the block boundary.
-        tail_med = _baseline_stat(series[:, usable:], estimator)
-        baseline = jnp.concatenate(
-            [baseline,
-             jnp.repeat(tail_med[:, None], T - usable, axis=-1)],
-            axis=-1)
-    detrended = series - baseline
-    std = jnp.maximum(jnp.std(detrended, axis=-1, keepdims=True), 1e-9)
-    return detrended / std
-
-
 @partial(jax.jit, static_argnames=("detrend_block", "estimator"))
+@scopes.scope("sp/detrend")
 def normalize_series(series: jnp.ndarray, detrend_block: int = 1000,
                      estimator: str = "median"):
     """Remove a piecewise-constant baseline and scale to unit
@@ -116,7 +84,26 @@ def normalize_series(series: jnp.ndarray, detrend_block: int = 1000,
     for the on-chip A/B; the default stays exact-median until a TPU
     measurement justifies switching.
     """
-    return detrend_normalize(series, detrend_block, estimator)
+    ndms, T = series.shape
+    detrend_block = min(detrend_block, T)
+    nblk = max(1, T // detrend_block)
+    usable = nblk * detrend_block
+    blocks = series[:, :usable].reshape(ndms, nblk, detrend_block)
+    med = _baseline_stat(blocks, estimator)
+    baseline = jnp.repeat(med, detrend_block, axis=-1)
+    if T > usable:
+        # A tail shorter than detrend_block gets a baseline estimated
+        # from its own samples (its own length as the denominator) —
+        # reusing the last full block's baseline inflates tail sigmas
+        # whenever the local level drifts across the block boundary.
+        tail_med = _baseline_stat(series[:, usable:], estimator)
+        baseline = jnp.concatenate(
+            [baseline,
+             jnp.repeat(tail_med[:, None], T - usable, axis=-1)],
+            axis=-1)
+    detrended = series - baseline
+    std = jnp.maximum(jnp.std(detrended, axis=-1, keepdims=True), 1e-9)
+    return detrended / std
 
 
 _ESTIMATORS = ("median", "median_sub4", "clipped_mean")

@@ -60,27 +60,7 @@ def dm_trials_total() -> metrics.Counter:
 def dedisp_trials_total() -> metrics.Counter:
     return metrics.counter(
         "tpulsar_dedisp_trials_total",
-        "DM trials dedispersed, by stage-2 kernel family (direct "
-        "shift-and-sum vs log-depth shift tree)",
-        labelnames=("family",))
-
-
-def dedisp_tree_depth() -> metrics.Gauge:
-    return metrics.gauge(
-        "tpulsar_dedisp_tree_depth",
-        "merge-level depth of the most recent pass's tree plan (0 = "
-        "the plan cut at the leaves, i.e. direct-equivalent; the "
-        "budget governor cuts shallower when level tensors would "
-        "exceed TPULSAR_TREE_BUDGET)")
-
-
-def dedisp_residual_fraction() -> metrics.Gauge:
-    return metrics.gauge(
-        "tpulsar_dedisp_residual_fraction",
-        "fraction of the most recent tree pass's row-ops spent in "
-        "the per-trial residual layer (the rest is the shared "
-        "merge levels every trial reuses); near 1.0 means the grid "
-        "shares almost nothing and direct would do as well")
+        "DM trials dedispersed by the one-device chunk loop's stage 2")
 
 
 def retry_attempts_total() -> metrics.Counter:
@@ -198,9 +178,8 @@ def mesh_exchange_bytes_total() -> metrics.Counter:
         "bytes that crossed between chips to bring a laid-out beam's "
         "subbands (sharded by subband, as stage 1 leaves them) into "
         "stage 2's operand, once a pass (`mesh-exchange`), by form: "
-        "replicate = a whole copy to every chip, time = re-sharded "
-        "from subbands to time, partial = the partial sums the chunk "
-        "programs' reduce-scatters move",
+        "replicate = a whole copy to every chip, partial = the "
+        "partial sums the chunk programs' reduce-scatters move",
         labelnames=("form",))
 
 

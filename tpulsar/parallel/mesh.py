@@ -238,15 +238,6 @@ class PassSpec:
     dd_pad: int = 0             # static stage-2 shift bound for the
     #                             XLA scan path (>= max sub_shift);
     #                             0 = pad by the full series length
-    seq_sharded: bool = False   # sequence-parallel front end: subbands
-    #                             arrive TIME-sharded over the dm axis,
-    #                             dedispersion runs on the local time
-    #                             chunk with a ring halo exchange, and
-    #                             one tiled all_to_all reshards the
-    #                             series to DM-sharded full length for
-    #                             the (unchanged) spectral tail.
-    #                             Requires dd_pad >= max shift and
-    #                             dd_pad <= T'/n_dm.
     sub_sharded: bool = False   # the subbands arrive laid over the dm
     #                             axis BY SUBBAND (stage 1 of a beam
     #                             laid out by channels) and stay so:
@@ -363,34 +354,12 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
     from tpulsar.kernels import accel as ak
     from tpulsar.kernels import fourier as fr
     from tpulsar.kernels import singlepulse as sp_k
-    from tpulsar.kernels.dedisperse import (_dedisperse_subbands_scan,
-                                            dedisperse_window_scan)
+    from tpulsar.kernels.dedisperse import _dedisperse_subbands_scan
 
     n_dev = int(mesh.shape["dm"])
 
-    def seq_dedisperse_a2a(subb_loc, shifts):
-        """Sequence-parallel dedispersion: (nsub, chunk) local time
-        shard + replicated (ndms, nsub) shifts -> (ndms/n_dev, T) DM
-        shard.  The halo is the first dd_pad samples of the right
-        neighbour (ring ppermute over ICI); the last device clamps by
-        replicating its final sample, matching the single-device edge
-        semantics.  One tiled all_to_all then switches the sharded
-        axis from time to DM — the Ulysses-style reshard (SURVEY.md
-        section 5.7: the DM axis is this pipeline's 'heads')."""
-        from tpulsar.parallel.seq_dedisperse import halo_extend
-
-        chunk = subb_loc.shape[1]
-        S = spec.dd_pad
-        ext = halo_extend(subb_loc, S, "dm", n_dev)
-        series_loc = dedisperse_window_scan(
-            ext, jnp.minimum(shifts, S), chunk)     # (ndms, chunk)
-        return jax.lax.all_to_all(series_loc, "dm", split_axis=0,
-                                  concat_axis=1, tiled=True)
-
     def body(subb, shifts, keep, bank, taps):
-        if spec.seq_sharded:
-            series = seq_dedisperse_a2a(subb, shifts)
-        elif spec.sub_sharded:
+        if spec.sub_sharded:
             series = _partial_dd(subb, shifts, spec, n_dev)
         elif spec.pallas_dd:
             series = _pallas_dd_local(subb, shifts, spec.dd_stage_s,
@@ -435,9 +404,7 @@ def sharded_pass_fn(mesh: Mesh, spec: PassSpec):
                  (("lo_vals", "lo_bins", "sp_snr", "sp_idx")
                   + (("hi_vals", "hi_rbins", "hi_zidx")
                      if spec.hi else ()))}
-    if spec.seq_sharded:
-        in_specs = (P(None, "dm"), P(), P(), P(), P())
-    elif spec.sub_sharded:
+    if spec.sub_sharded:
         in_specs = (P("dm", None), P(None, "dm"), P(), P(), P())
     else:
         in_specs = (P(), P("dm", None), P(), P(), P())
@@ -459,87 +426,6 @@ def shard_dm_table(sub_shifts: np.ndarray, n_dm: int) -> np.ndarray:
         pad = np.repeat(sub_shifts[-1:], rem, axis=0)
         sub_shifts = np.concatenate([sub_shifts, pad], axis=0)
     return sub_shifts
-
-
-# --------------------------------------------- ultra-long-series dist pass
-
-def seq_dist_search(mesh: Mesh, subbands, sub_shifts, dms, dt_ds: float,
-                    nfft: int, params, axis_name: str = "dm"):
-    """One pass over DM trials whose per-trial spectral tail exceeds a
-    device (parallel/dist_fft.spectral_bytes_per_trial > the HBM
-    budget): the seq-shard all_to_all reshard to whole per-device
-    series is impossible, so the series STAYS time-sharded end to end
-    and the spectrum is computed with the distributed four-step FFT —
-    only top-k candidate bins ever leave the mesh (SURVEY.md
-    section 5.7's 'FFT of a series that exceeds one chip').
-
-    Returns (candidates, sp_events) like the sharded pass.
-
-    Documented deviations from the single-device tail (this mode only
-    engages beyond single-chip scale, far outside the golden
-    scenarios): whitening block medians are estimated from each
-    device's comb sample of the block (unbiased, not bit-identical);
-    single-pulse normalization is per time-chunk; periodicity reports
-    FUNDAMENTAL (numharm=1) candidates — harmonic summing across
-    transposed shards is future work; zaplists are not applied.
-    """
-    from tpulsar.kernels import singlepulse as sp_k
-    from tpulsar.parallel import dist_fft as dfft
-    from tpulsar.parallel.seq_dedisperse import halo_extend, seq_dedisperse
-    from tpulsar.search import degraded, sifting
-    from tpulsar.search.executor import _lo_sigma_fn
-
-    n_dev = int(mesh.shape[axis_name])
-    nsub, T = subbands.shape
-    ndms = len(dms)
-    chunk = T // n_dev
-    degraded.note("seq_dist_spectral",
-                  "per-trial spectrum beyond one device: distributed "
-                  "FFT tail, fundamental-only, no zaplist")
-
-    series = seq_dedisperse(subbands, np.asarray(sub_shifts)[:ndms],
-                            mesh, axis_name=axis_name)  # (ndms, T) sharded
-
-    # single-pulse: local-chunk boxcars with a right halo so no pulse
-    # straddling a shard boundary is lost; halo hits are the right
-    # neighbour's to report (mask them out here)
-    sp_halo = max(params.sp_widths)
-
-    def sp_body(series_loc):
-        ext = halo_extend(series_loc, sp_halo, axis_name, n_dev)
-        norm = sp_k.normalize_series(
-            ext, estimator=sp_k.detrend_estimator(params.sp_detrend))
-        snr, idx = sp_k.boxcar_search(norm, tuple(params.sp_widths),
-                                      sp_k.DEFAULT_TOPK)
-        local = idx < chunk
-        snr = jnp.where(local, snr, -jnp.inf)
-        idx = idx + jax.lax.axis_index(axis_name) * chunk
-        return (jax.lax.all_gather(snr, axis_name, axis=2, tiled=True),
-                jax.lax.all_gather(idx, axis_name, axis=2, tiled=True))
-
-    from jax import shard_map
-    sp_fn = jax.jit(shard_map(
-        sp_body, mesh=mesh, in_specs=P(None, axis_name),
-        out_specs=(P(), P()), check_vma=False))
-    sp_snr, sp_idx = sp_fn(series)
-    events = sp_k.events_from_topk(
-        np.asarray(sp_snr), np.asarray(sp_idx), np.asarray(dms), dt_ds,
-        threshold=params.sp_threshold, widths=tuple(params.sp_widths))
-
-    # periodicity: per-trial distributed spectral top-k (fundamental)
-    nbins = nfft // 2 + 1
-    topk = params.topk_per_stage
-    vals = np.empty((ndms, topk), np.float32)
-    bins = np.empty((ndms, topk), np.int64)
-    for i in range(ndms):
-        x = jnp.pad(series[i], (0, nfft - T)).astype(jnp.complex64)
-        v, b = dfft.dist_spectral_topk(x, mesh, axis_name, nfft,
-                                       topk=topk)
-        vals[i], bins[i] = v, b
-    cands = sifting.make_candidates(
-        {1: (vals, bins)}, np.asarray(dms), nfft * dt_ds,
-        _lo_sigma_fn(nbins), sigma_min=params.sifting.sigma_threshold)
-    return cands, events
 
 
 # ------------------------------------------- a laid-out array, re-laid

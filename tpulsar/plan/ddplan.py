@@ -23,7 +23,6 @@ Smearing model (all in seconds):
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import numpy as np
 
@@ -412,67 +411,6 @@ def plot_plan(steps: list[DedispStep], obs: Observation, path: str) -> str:
     fig.savefig(path, dpi=100)
     plt.close(fig)
     return path
-
-
-# ------------------------------------------------ dedispersion family
-
-#: minimum predicted row-op advantage before the tree family replaces
-#: the direct kernel for a pass.  Measured on CPU (2026-08-03,
-#: survey-pass A/B): a row-op ratio r delivers ~0.7*r wall-clock, so
-#: 2.0 predicts ~1.4x at the break-even edge and ~3x on the survey
-#: steps (ratio ~4).  Below it the direct kernel's simpler scan wins.
-TREE_WIN_RATIO = 2.0
-
-#: passes with fewer trials than this always use the direct kernel:
-#: the tree's shared levels amortize over trials, and tiny passes
-#: (fold prep, the golden scenarios) have nothing to amortize —
-#: keeping them direct also keeps their float summation order (and
-#: the frozen golden candidate lists) untouched.
-TREE_MIN_NDMS = 32
-
-_DD_FAMILIES = ("auto", "direct", "tree")
-
-
-def dedisp_family_override() -> str:
-    """TPULSAR_DD_FAMILY: 'direct'/'tree' pin the stage-2 family for
-    every pass (the bench A/B knob); 'auto' (default) defers to the
-    per-pass cost model."""
-    val = os.environ.get("TPULSAR_DD_FAMILY", "").strip() or "auto"
-    if val not in _DD_FAMILIES:
-        raise ValueError(
-            f"TPULSAR_DD_FAMILY must be one of {_DD_FAMILIES}, "
-            f"got {val!r}")
-    return val
-
-
-def dedisp_cost_direct(ndms: int, nsub: int) -> int:
-    """Direct shift-and-sum cost in row-ops (one shifted row add of
-    ~T samples each): every trial re-sums every subband."""
-    return int(ndms) * int(nsub)
-
-
-def choose_dedisp_family(ndms: int, nsub: int,
-                         tree_cost_rows: int | None = None,
-                         win_ratio: float | None = None) -> str:
-    """Per-pass direct-vs-tree decision on predicted row-ops.
-
-    ``tree_cost_rows`` is the tree plan's total row-op count
-    (kernels/tree_dd.py TreeDDPlan.cost_rows: merge-level rows plus
-    the ndms x groups residual gathers).  None — no plan built, or
-    the pass's grid made one pointless — keeps direct.  The tree
-    wins only when the pass is large enough to amortize the shared
-    levels (TREE_MIN_NDMS) AND the predicted advantage clears
-    TREE_WIN_RATIO; irregular DM grids produce ~ndms patterns per
-    group at every level, fail the ratio, and stay direct — the
-    direct kernel is the oracle and the unconditional fallback."""
-    if tree_cost_rows is None or tree_cost_rows <= 0:
-        return "direct"
-    if ndms < TREE_MIN_NDMS:
-        return "direct"
-    ratio = dedisp_cost_direct(ndms, nsub) / float(tree_cost_rows)
-    if ratio >= (TREE_WIN_RATIO if win_ratio is None else win_ratio):
-        return "tree"
-    return "direct"
 
 
 def total_dm_trials(steps: list[DedispStep]) -> int:

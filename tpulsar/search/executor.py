@@ -45,7 +45,6 @@ from tpulsar.obs import trace as trace_mod
 from tpulsar.parallel import mesh as pmesh
 from tpulsar.kernels import dedisperse as dd
 from tpulsar.kernels import fold as fold_k
-from tpulsar.kernels import tree_dd
 from tpulsar.kernels import fourier as fr
 from tpulsar.kernels import rfi as rfi_k
 from tpulsar.kernels import singlepulse as sp_k
@@ -101,14 +100,13 @@ class SearchParams:
     #                                 beam at 128 trials would need
     #                                 ~11 GB of transients)
     spectral_hbm_budget: int = 6 << 30
-    seq_shard: str = "auto"         # sequence-parallel dedispersion on
-    #                                 a multi-chip mesh: "on" forces it,
-    #                                 "off" disables, "auto" switches
-    #                                 when replicating the subband block
-    #                                 per device would cost more than
-    #                                 seq_shard_min_bytes (SURVEY.md
-    #                                 section 5.7 long-sequence mapping)
-    seq_shard_min_bytes: int = 2 << 30
+    seq_shard_min_bytes: int = 2 << 30  # a beam laid over the mesh:
+    #                                 a pass's subbands at or under this
+    #                                 many bytes go to every chip whole
+    #                                 ("replicate"), over it each chip
+    #                                 dedisperses its own subbands for
+    #                                 every trial ("partial"); a whole
+    #                                 block's subbands always replicate
     dm_shards: int = 1              # the layout: each pass's DM trials
     #                                 sharded over a (beam=1, dm=N) mesh
     #                                 of the first N local devices
@@ -137,11 +135,10 @@ class SearchParams:
     #                                 -l/-d args); dm_max 0 = no cap
 
     def __post_init__(self):
-        for field in ("seq_shard", "block_quantize"):
-            v = getattr(self, field)
-            if v not in ("on", "off", "auto"):
-                raise ValueError(
-                    f"{field} must be 'on'/'off'/'auto', got {v!r}")
+        if self.block_quantize not in ("on", "off", "auto"):
+            raise ValueError(
+                f"block_quantize must be 'on'/'off'/'auto', got "
+                f"{self.block_quantize!r}")
         if int(self.dm_shards) != self.dm_shards or self.dm_shards < 1:
             raise ValueError(
                 f"dm_shards must be a whole number >= 1, got "
@@ -930,9 +927,6 @@ class _Pass:
     #                         plan step keeps compile signatures bounded
     chunk_sz: int = 0
     keeps: list | None = None       # per beam: (nbins,) zap keep mask
-    tree_plan: object = None
-    tree_parts: list | None = None  # per beam: the tree's levels
-    sp_est: str = ""
 
     @property
     def group(self) -> dict:
@@ -945,10 +939,6 @@ class _Pass:
     @property
     def T_s(self) -> float:
         return self.nfft * self.dt_ds
-
-    @property
-    def family(self) -> str:
-        return "tree" if self.tree_parts is not None else "direct"
 
 
 def _group_attrs(nbeams: int) -> dict:
@@ -1079,7 +1069,7 @@ def _sharded_pass(mesh, ps: _Pass, beam: _Beam, params, timers) -> None:
 def _chunked_pass(ps: _Pass, beams, params, timers) -> None:
     """One pass on one device: DM chunks dispatched with two in
     flight, then one drain and the host halves."""
-    _plan_chunks(ps, beams, params, timers)
+    _plan_chunks(ps, beams, params)
     # SP and lo-stage device outputs are DEFERRED to one device_get
     # per pass (_drain_pass): a per-chunk blocking np.asarray cost one
     # host<->device round-trip per output.  Only top-k-sized blocks
@@ -1102,36 +1092,17 @@ def _chunked_pass(ps: _Pass, beams, params, timers) -> None:
                 jax.block_until_ready(pending[-2].lo_res)
         pending.append(_dispatch_chunk(ps, lo, params, timers))
     _drain_pass(ps, beams, pending, params, timers)
-    telemetry.dedisp_trials_total().inc(len(beams) * len(ps.dms),
-                                        family=ps.family)
+    telemetry.dedisp_trials_total().inc(len(beams) * len(ps.dms))
 
 
-def _plan_chunks(ps: _Pass, beams, params, timers) -> None:
-    """How the pass's DM chunks run: their size, each beam's zap keep
-    mask at this pass's spectrum length, and the stage-2 family.  The
-    ddplan cost model picks the log-depth shift tree
-    (kernels/tree_dd.py) when the pass's DM grid lets the shared merge
-    levels amortize across its trials, and keeps the direct
-    shift-and-sum — the oracle — for small or irregular grids, under
-    the TPULSAR_DD_FAMILY override.  A tree pass runs its levels ONCE
-    here, per beam (the solo program, so the family's summation order
-    is untouched); each DM chunk then only pays its residual layer,
-    with the SP detrend fused into the same program."""
+def _plan_chunks(ps: _Pass, beams, params) -> None:
+    """How the pass's DM chunks run: their size, and each beam's zap
+    keep mask at this pass's spectrum length."""
     ps.chunk_sz = pass_chunk_size(len(ps.dms), ps.nfft, params)
-    ps.sp_est = sp_k.detrend_estimator(params.sp_detrend)
     if any(b.zaplist is not None for b in beams):
         ps.keeps = [fr.zap_mask(ps.nbins, ps.T_s, b.zaplist, b.baryv)
                     if b.zaplist is not None
                     else np.ones(ps.nbins, bool) for b in beams]
-    ps.tree_plan = tree_dd.plan_for_pass(ps.sub_shifts, T=ps.T_ds)
-    if ps.tree_plan is not None:
-        with timers.timing("dedispersing"):
-            ps.tree_parts = [tree_dd.tree_levels(s, ps.tree_plan)
-                             for s in ps.subs]
-            trace_mod.fence(ps.tree_parts)
-        telemetry.dedisp_tree_depth().set(ps.tree_plan.depth)
-        telemetry.dedisp_residual_fraction().set(
-            round(ps.tree_plan.residual_fraction, 4))
 
 
 def _beam_major(parts: list):
@@ -1158,7 +1129,7 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
     # off.  dd_calls x dd_rows: a beam's stage-2 program calls for
     # this chunk and rows a call, dd_groups the subband groups a call
     # sums over; the Pallas wrapper writes what it dispatched (0 where
-    # it did not run: the XLA scan, the tree family).  lo_form/lo_tile,
+    # it did not run: the XLA scan).  lo_form/lo_tile,
     # sp_form/sp_tile: the lo stage's harmonic sums and the boxcar ladder
     # as lowered ("tiled" + the kernel's tile on a TPU; _dispatch_attrs)
     hi_rows = (_hi_rows(B * n, ps.T_ds, params)
@@ -1166,36 +1137,19 @@ def _dispatch_chunk(ps: _Pass, lo: int, params, timers) -> _Chunk:
     with trace_mod.span("dm_chunk", pass_idx=ps.pass_idx, lo=int(lo),
                         n=int(n), hi_rows=hi_rows, dd_calls=0,
                         dd_rows=0, dd_groups=0, lo_form="", lo_tile=0,
-                        family=ps.family, sp_form="", sp_tile=0, **ps.group):
+                        sp_form="", sp_tile=0, **ps.group):
         with timers.timing("dedispersing"):
-            # on the tree path series and norm are outputs of ONE
-            # fused executable, so the fused detrend's wall time lands
-            # inside 'dedispersing' in the report AND the trace
-            norm = None
-            if ps.tree_parts is not None:
-                pairs = [tree_dd.residual_series(
-                    tp, ps.tree_plan, lo, n, T=ps.T_ds, fuse=True,
-                    estimator=ps.sp_est) for tp in ps.tree_parts]
-                series = _beam_major([p[0] for p in pairs])
-                norm = _beam_major([p[1] for p in pairs])
-            else:
-                shifts = jnp.asarray(ps.sub_shifts[lo: lo + n])
-                series = _beam_major([dd.dedisperse_subbands(s, shifts)
-                                      for s in ps.subs])
-            trace_mod.fence(series if norm is None else (series, norm))
+            shifts = jnp.asarray(ps.sub_shifts[lo: lo + n])
+            series = _beam_major([dd.dedisperse_subbands(s, shifts)
+                                  for s in ps.subs])
+            trace_mod.fence(series)
 
         with timers.timing("single-pulse"):
-            # the device half of single_pulse_search; on the tree path
-            # the detrend already ran fused into the residual program,
-            # so only the boxcar ladder remains here.  The host half
-            # (events_from_topk) runs at pass end either way.
-            if norm is not None:
-                sp_pair = sp_k.boxcar_search(
-                    norm, tuple(params.sp_widths), sp_k.DEFAULT_TOPK)
-            else:
-                sp_pair = sp_k.device_search(
-                    series, tuple(params.sp_widths),
-                    estimator=params.sp_detrend)
+            # the device half of single_pulse_search; the host half
+            # (events_from_topk) runs at pass end
+            sp_pair = sp_k.device_search(
+                series, tuple(params.sp_widths),
+                estimator=params.sp_detrend)
             trace_mod.fence(sp_pair)
 
         with timers.timing("FFT"):
@@ -1928,30 +1882,23 @@ def _get_bank(zmax: int) -> accel_k.TemplateBank:
 _SHARDED_FN_CACHE: dict[tuple, object] = {}
 
 
-#: the exchange a laid-out beam's pass takes under seq_shard "auto"
-#: once its subbands are over seq_shard_min_bytes: "partial" or "time"
-#: (PERF.md section 6, PR 43, has both on the chip)
-LAID_OUT_AUTO_FORM = "partial"
-
-
-def _mesh_exchange(mesh, subb, form: str, sharding, rows: int, timers):
+def _mesh_exchange(mesh, subb, form: str, whole, rows: int, timers):
     """The one exchange of a laid-out beam's pass, a stage of its own
     beside `mesh-place`: stage 1's subbands, laid over the mesh by
     subband, into the operand stage 2 reads (`_search_pass_sharded`
     says which form when).  `bytes` is what crosses between chips,
-    summed over them: a copy to each of the others ("replicate"), each
-    piece's other time quarters ("time"), or the partial sums of the
-    pass's `rows` series that the chunk programs' reduce-scatters will
-    move ("partial": no byte moves here, the pieces are handed on
-    under the mesh's own sharding)."""
+    summed over them: a copy to each of the others ("replicate"), or
+    the partial sums of the pass's `rows` series that the chunk
+    programs' reduce-scatters will move ("partial": no byte moves
+    here, the pieces are handed on under the mesh's own sharding)."""
     n = int(mesh.shape["dm"])
     with timers.timing("mesh-exchange"):
         if form == "partial":
             out = pmesh.as_dm_rows(mesh, subb)
             moved = (n - 1) * rows * int(subb.shape[1]) * 4
         else:
-            out = jax.block_until_ready(pmesh.reshard(subb, sharding))
-            moved = (n - 1) * subb.nbytes // (n if form == "time" else 1)
+            out = jax.block_until_ready(pmesh.reshard(subb, whole))
+            moved = (n - 1) * subb.nbytes
         trace_mod.annotate("mesh-exchange", bytes=moved, form=form,
                            devices=n)
     telemetry.mesh_exchange_bytes_total().inc(moved, form=form)
@@ -2011,52 +1958,29 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     use_pallas = pallas_dd.use_pallas()
     smax = int(np.asarray(sub_shifts).max(initial=0))
     dd_pad = dd._pad_bucket(smax)
-    # Sequence-parallel front end: shard the subband block's TIME axis
-    # instead of replicating it per device, when the mesh and the halo
-    # geometry allow it (halo depth <= per-device chunk).  Takes
-    # precedence over the Pallas stage-2 (which needs the replicated
-    # block) — it exists for exactly the case where replication is
-    # what must be avoided.
+    # a trial's series is searched whole on one device: its spectral
+    # tail (complex spectrum, powers, whitened copy, padded series)
+    # has to fit there, and nothing here splits ONE series
+    tail = 16 * nbins + 4 * nfft
+    if tail > params.spectral_hbm_budget:
+        raise ValueError(
+            f"one DM trial's spectral tail at nfft={nfft} takes {tail} "
+            f"bytes a device, over spectral_hbm_budget="
+            f"{params.spectral_hbm_budget}: a series this long cannot "
+            f"be searched on this mesh")
     # Subbands that arrive laid over the mesh BY SUBBAND (stage 1 of
     # a beam laid out by channels) are brought into stage 2's operand
-    # by ONE exchange a pass, `mesh-exchange`, in one of three forms:
-    # "replicate" (under seq_shard_min_bytes: every chip a whole copy,
-    # gathered from the pieces, then today's program), "time" (the
-    # sequence-parallel front end below, the pieces re-sharded from
-    # subbands to time) or "partial" (they stay where they are: each
-    # chip sums its own subbands for every row and a reduce-scatter
-    # inside the chunk program leaves it its rows, mesh._partial_dd).
-    # seq_shard "on" asks for time, "off" for partial; "auto" over the
-    # bytes takes LAID_OUT_AUTO_FORM, the one the chip prefers.
+    # by ONE exchange a pass, `mesh-exchange`, in one of two forms:
+    # "replicate" (at or under seq_shard_min_bytes: every chip a whole
+    # copy, gathered from the pieces, then a whole block's program) or
+    # "partial" (they stay where they are: each chip sums its own
+    # subbands for every row and a reduce-scatter inside the chunk
+    # program leaves it its rows, mesh._partial_dd).  A whole block's
+    # subbands are replicated by `mesh-place`.
     laid_out = pmesh.channel_mesh(subb) is not None
-    over = subb.nbytes > params.seq_shard_min_bytes
-    seq = (params.seq_shard == "on"
-           or (params.seq_shard == "auto" and over
-               and (not laid_out or LAID_OUT_AUTO_FORM == "time")))
-    seq_ok = (n_dm > 1 and T_ds % n_dm == 0
-              and dd_pad <= T_ds // n_dm)
-    # Ultra-long series: when even ONE trial's spectral tail exceeds
-    # the per-device budget, the seq-shard reshard to whole per-device
-    # series is impossible — the spectrum itself must be distributed
-    # (parallel/dist_fft four-step FFT; SURVEY.md section 5.7).
-    from tpulsar.parallel.dist_fft import spectral_bytes_per_trial
-    if (seq_ok and params.seq_shard != "off"
-            and spectral_bytes_per_trial(nfft)
-            > params.spectral_hbm_budget):
-        return pmesh.seq_dist_search(
-            mesh, subb, sub_shifts, dms, dt_ds, nfft, params)
-    if seq and not seq_ok and params.seq_shard == "on":
-        import warnings
-        warnings.warn(
-            f"seq_shard='on' cannot be honoured for this pass "
-            f"(n_dm={n_dm}, T'={T_ds}, halo={dd_pad} vs chunk="
-            f"{T_ds // max(n_dm, 1)}); falling back to per-device "
-            f"subband replication", stacklevel=2)
-    seq = seq and seq_ok
-    use_pallas = use_pallas and not seq
     form = None if not laid_out else (
-        "time" if seq else
-        "partial" if over or params.seq_shard != "auto" else "replicate")
+        "partial" if subb.nbytes > params.seq_shard_min_bytes
+        else "replicate")
     stage_s = 0
     if use_pallas:
         stage_s = pallas_dd.stage_overhang(smax)
@@ -2074,7 +1998,7 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         hi_nz=nz if hi_sharded else 0,
         pallas_dd=use_pallas, dd_stage_s=stage_s,
         dd_interpret=use_pallas and not pallas_dd.is_tpu_backend(),
-        dd_pad=dd_pad, seq_sharded=seq, sub_sharded=form == "partial")
+        dd_pad=dd_pad, sub_sharded=form == "partial")
     key = (mesh, spec)
     if key not in _SHARDED_FN_CACHE:
         _SHARDED_FN_CACHE[key] = pmesh.sharded_pass_fn(mesh, spec)
@@ -2085,19 +2009,17 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         if zaplist is not None else np.ones(nbins, bool)
     # the operands every device reads whole, placed once a pass (left
     # to the jitted program, device 0's 1.4 GiB subband block is
-    # copied to the mesh at every chunk call); sequence-parallel
-    # subbands go time-sharded.  The hi stage's taps only where it
-    # correlates directly (a TPU mesh).
-    P = jax.sharding.PartitionSpec
-    whole = jax.sharding.NamedSharding(mesh, P())
-    by_time = jax.sharding.NamedSharding(mesh, P(None, "dm"))
+    # copied to the mesh at every chunk call).  The hi stage's taps
+    # only where it correlates directly (a TPU mesh).
+    whole = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec())
     padded = pmesh.shard_dm_table(np.asarray(sub_shifts), n_dm)
     if laid_out:
-        subb_m = _mesh_exchange(mesh, subb, form, by_time if seq else whole,
-                                len(padded), timers)
+        subb_m = _mesh_exchange(mesh, subb, form, whole, len(padded),
+                                timers)
     with timers.timing("mesh-place"):
         if not laid_out:
-            subb_m = jax.device_put(subb, by_time if seq else whole)
+            subb_m = jax.device_put(subb, whole)
         keep_arr = jax.device_put(keep.astype(np.float32), whole)
         bank_arr = jax.device_put(
             bank.bank_fft if hi_sharded
