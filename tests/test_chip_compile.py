@@ -185,6 +185,8 @@ def test_hi_accel_programs(one_chip, tpu_accel_branch, program):
     (50.0, 8, 2_097_153, 1),     # WAPP ds=1 (wapp_ds1_hiaccel is owed)
     (50.0, 8, 1_966_081, 6),     # what a mesh device's hi stage holds
     (200.0, 16, 1_966_081, 2),
+    (50.0, 8, 737_281, 1),       # GBNCC's 120 s at ds=1: gbncc120_hiaccel
+    (50.0, 8, 3_072_001, 4),     # FAST GPPS ds=1, a mesh device's rows
 ])
 def test_hi_accel_chunk_program_at_full_width(one_chip, tpu_accel_branch,
                                               zmax, numharm, nbins, rows):
@@ -560,3 +562,111 @@ def test_partial_form_pass_program_on_four_chips(v5e):
     mem = compiled.memory_analysis()        # bytes on each device
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 8 << 30
+
+
+@pytest.mark.parametrize("nchan,nsamp,devices", [
+    (4096, 1_464_320, 1),        # GBNCC's 120 s pointing, 5.59 GiB
+    (960, NSAMP, 1),             # a Mock beam
+    (GPPS_NCHAN, GPPS_NSAMP, 4),  # FAST GPPS laid over four chips
+])
+def test_masking_a_beam_holds_the_input_and_the_output_only(
+        v5e, nchan, nsamp, devices):
+    """rfi.apply_mask_chan at a beam's size: no temporary beside the
+    block and its masked copy (one fused select over the reshaped
+    block compiles with a third copy: 3 x 5.59 GiB at GBNCC's 120 s,
+    which ran out of memory on the chip, PR 48), and a block laid over
+    the chips by channels is masked share by share, no collective."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpulsar.kernels import rfi
+
+    mesh = Mesh(np.asarray(v5e.devices[:devices]), ("chan",))
+
+    def sds(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    compiled = rfi.apply_mask_chan.lower(
+        sds((nchan, nsamp), jnp.uint8, "chan", None),
+        sds((nsamp // 2048, nchan), jnp.bool_),
+        sds((nchan,), jnp.float32), block_len=2048).compile()
+    mem = compiled.memory_analysis()        # bytes on each device
+    share = nchan * nsamp // devices
+    assert mem.output_size_in_bytes >= share
+    assert mem.temp_size_in_bytes < share // 100
+    assert not _collectives(compiled.as_text())
+
+
+@pytest.mark.parametrize("downsamp,form,rows,smax", [
+    (2, "replicate", 8, 3420), (1, "partial", 4, 93)])
+def test_hi_accel_over_the_laid_out_beam_on_four_chips(
+        v5e, tpu_accel_branch, downsamp, form, rows, smax):
+    """gpps_hiaccel_mesh4's two fused pass programs (FAST GPPS's first
+    ds=2 pass, subbands a whole copy a chip, and its first ds=1 pass,
+    subbands left where stage 1 formed them) with the hi stage inside,
+    at the rows a device executor._search_pass_sharded gives them: the
+    least of plane_dm_chunk's count and _mesh_rows_budget's.  The two
+    hi kernels are there beside stage 2's, the partial form's sums
+    cross in one all-to-all, and each program fits a chip beside the
+    beam's 2.91 GiB share and the pass's subbands."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpulsar.kernels import dedisperse as dd
+    from tpulsar.kernels import fourier as fr
+    from tpulsar.kernels import pallas_dd
+    from tpulsar.kernels import singlepulse as sp_k
+    from tpulsar.parallel import mesh as pmesh
+    from tpulsar.plan import ddplan
+    from tpulsar.search import executor
+
+    accel = tpu_accel_branch
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 4), ("beam", "dm"))
+    params = executor.SearchParams()
+    bank = accel.build_template_bank(ZMAX)
+    nz = len(bank.zs)
+    T = GPPS_NSAMP // downsamp
+    nfft = ddplan.choose_n(T)
+    nbins = nfft // 2 + 1
+    assert rows == min(
+        accel.plane_dm_chunk(nbins, nz, max_chunk=32),
+        executor._mesh_rows_budget(nfft, params.spectral_hbm_budget))
+    assert (GPPS_NSUB * T * 4 > params.seq_shard_min_bytes) == \
+        (form == "partial")
+    spec = pmesh.PassSpec(
+        nfft=nfft, max_numharm=params.lo_accel_numharm,
+        topk=params.topk_per_stage, sp_widths=tuple(params.sp_widths),
+        sp_topk=sp_k.DEFAULT_TOPK,
+        sp_detrend=sp_k.detrend_estimator(params.sp_detrend),
+        whiten_est=fr.whiten_estimator(), hi=True,
+        hi_numharm=params.hi_accel_numharm, hi_seg=bank.seg,
+        hi_step=bank.step, hi_width=bank.width, hi_nz=nz, pallas_dd=True,
+        dd_stage_s=pallas_dd.stage_overhang(smax), dd_interpret=False,
+        dd_pad=dd._pad_bucket(smax), sub_sharded=form == "partial")
+
+    def sds(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    if form == "partial":
+        subb = sds((GPPS_NSUB, T), jnp.float32, "dm", None)
+        table = sds((4 * rows, GPPS_NSUB), jnp.int32, None, "dm")
+    else:
+        subb = sds((GPPS_NSUB, T), jnp.float32)
+        table = sds((4 * rows, GPPS_NSUB), jnp.int32, "dm", None)
+    compiled = pmesh.sharded_pass_fn(mesh, spec).lower(
+        subb, table, sds((nbins,), jnp.float32),
+        sds(bank.bank_fft.shape, jnp.complex64),
+        sds(accel.corr_taps_shape(nz, bank.width), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "corr_plane" in text and "harmsum_zmax" in text
+    assert "lo_harmsum" in text and "sp_boxcar" in text
+    assert "fft_type=IFFT" not in text
+    assert _collectives(text) == (
+        {"all-to-all", "all-gather"} if form == "partial"
+        else {"all-gather"})
+    mem = compiled.memory_analysis()        # bytes on each device
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 9 << 30
+

@@ -366,3 +366,27 @@ def test_bounded_cache_is_lru_not_fifo():
     assert calls == ["A", "B", "C", "B"]
     assert cache("A") == "A" * 10   # A survived both evictions
     assert calls == ["A", "B", "C", "B"]
+
+
+@pytest.mark.parametrize("nsamp, ndms, downsamp, want", [
+    (3_932_160, 76, 1, 38),      # Mock ds=1 (and zmax 200): 38 + 38
+    (3_932_160, 64, 2, 64),      # Mock ds=2: one chunk of 64
+    (4_194_304, 76, 1, 38),      # WAPP ds=1
+    (1_361_920, 102, 1, 102),    # GBNCC's 665 subints
+    (1_464_320, 102, 1, 102),    # GBNCC's whole 120 s (gbncc120_hiaccel)
+    (1_464_320, 102, 2, 102),
+    (6_103_040, 102, 1, 34),     # FAST GPPS on one device: 34 + 34 + 34
+    (6_103_040, 102, 2, 51),
+])
+def test_the_rows_a_hi_accel_pass_takes_a_chunk(nsamp, ndms, downsamp,
+                                                want):
+    """The chunk shapes the benchmark's hi-accel cells compile, by the
+    spectral budget (30 bytes a sample a trial with hi-accel on): the
+    AOT gate and the cells' warm-ups hold exactly these, and a budget
+    that moved them would recompile every cell."""
+    nfft = ddplan.choose_n(nsamp // downsamp)
+    params = executor.SearchParams(run_hi_accel=True)
+    got = executor.pass_chunk_size(ndms, nfft, params)
+    assert got == want
+    assert got * 30 * nfft <= params.spectral_hbm_budget
+    assert -(-ndms // got) * got - ndms < -(-ndms // got)   # even split

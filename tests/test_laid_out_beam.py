@@ -251,6 +251,96 @@ def test_the_mesh_splits_a_pass_whose_rows_would_not_fit_a_call():
     assert executor._mesh_rows_budget(3_932_160, 6 << 30) >= 6
 
 
+# ------------------------------- (c') hi-accel behind the exchange, ds 2 first
+
+def _hi_rows():
+    snap = telemetry.metrics.REGISTRY.snapshot()
+    return dict((snap.get("tpulsar_mesh_hi_rows_total")
+                 or {}).get("series", {}))
+
+
+def _hi_params(params, min_bytes=1 << 20):
+    return dataclasses.replace(
+        params, seq_shard_min_bytes=min_bytes, run_hi_accel=True,
+        hi_accel_zmax=8, topk_per_stage=8)
+
+
+def test_hi_accel_over_a_laid_out_beam_in_the_ds2_ds1_order(beam):
+    """The order the benchmark's hi-accel cells list their steps in
+    (the downsampled pass, the pulsar's, first): the laid-out beam's
+    candidates are the whole block's on the same mesh, the exchange is
+    `replicate` then `partial`, every call ran the hi stage in the
+    fused program, and a traced `mesh_chunk` says by which form its
+    subbands came and what its rows' planes hold."""
+    from tpulsar.kernels import accel as accel_k
+
+    blk, plan, params = beam
+    plan, params = plan[::-1], _hi_params(params)
+    base = _hi_rows()
+    whole = jax.device_put(blk, jax.devices()[0])
+    (c1, _f1, e1, n1), ev1 = _search(whole, plan, params)
+    (c2, _f2, e2, n2), ev2 = _search(lay_out(blk), plan, params)
+    assert n1 == n2 == 20
+    assert any(c.z != 0 for c in c1)
+    assert sorted(map(_cand_key, c1)) == sorted(map(_cand_key, c2))
+    assert np.array_equal(e1, e2)
+    assert [e["args"]["form"] for e in ev2
+            if e["name"] == "mesh-exchange"] == ["replicate", "partial"]
+    got = {k: v - base.get(k, 0.0) for k, v in _hi_rows().items()}
+    assert got == {"fused": 40.0}           # both searches, no fallback
+    for events, forms in ((ev1, ["none", "none"]),
+                          (ev2, ["replicate", "partial"])):
+        calls = [e["args"] for e in events if e["name"] == "mesh_chunk"]
+        assert [a["form"] for a in calls] == forms
+        assert all(a["hi"] for a in calls)
+        for a, T_ds in zip(calls, (blk.shape[1] // 2, blk.shape[1])):
+            nbins = ddplan.choose_n(T_ds) // 2 + 1
+            assert a["plane_bytes"] == a["rows_per_device"] * \
+                accel_k.plane_row_bytes(nbins, len(accel_k.z_grid(8)),
+                                        accel_k.corr_z_pieces())
+
+
+@pytest.mark.parametrize("min_bytes, outcome", [
+    (1 << 40, "falls back"), (0, "refused")])
+def test_the_hi_fallback_on_a_laid_out_beam_fits_or_is_refused(
+        beam, monkeypatch, min_bytes, outcome):
+    """The batched path pinned off.  Subbands every chip was given
+    whole (`replicate`) go down the single-device route in the
+    one-device loop's chunks and find what the fused program finds;
+    subbands that stayed where they lie (`partial`: too large for a
+    whole copy on any chip) are not gathered onto one: refused."""
+    from tpulsar.kernels import accel as accel_k
+    from tpulsar.search import degraded
+
+    blk, plan, params = beam
+    plan, params = plan[:1], _hi_params(params, min_bytes)
+    if outcome == "falls back":
+        (good, _f, _e, _n), _ = _search(lay_out(blk), plan, params)
+    monkeypatch.setattr(accel_k, "_BATCH_OK", False)
+    base = _hi_rows()
+    sizes = []
+    sound = executor._hi_accel_chunk
+    monkeypatch.setattr(
+        executor, "_hi_accel_chunk",
+        lambda wspec, dm_chunk, *a: sizes.append(len(dm_chunk))
+        or sound(wspec, dm_chunk, *a))
+    monkeypatch.setattr(executor, "pass_chunk_size", lambda *a: 4)
+    if outcome == "refused":
+        with pytest.raises(ValueError, match="single-device route on a "
+                           "laid-out beam.*seq_shard_min_bytes=0"):
+            executor.search_block(lay_out(blk), FREQS, DT, plan, params)
+        assert not sizes and _hi_rows() == base
+        return
+    (cands, _f, _e, n), events = _search(lay_out(blk), plan, params)
+    assert n == 10 and sizes == [4, 4, 2]
+    assert sorted(map(_cand_key, cands)) == sorted(map(_cand_key, good))
+    got = {k: v - base.get(k, 0.0) for k, v in _hi_rows().items()}
+    assert got.get("fallback") == 10.0 and not got.get("fused")
+    assert "sharded_hi_fallback" in degraded.snapshot()
+    assert not any(e["args"]["hi"] for e in events
+                   if e["name"] == "mesh_chunk")
+
+
 # --------------------------------------------- (d) devices that do not match
 
 @pytest.mark.parametrize("pick, says", [

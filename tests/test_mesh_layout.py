@@ -324,6 +324,24 @@ def test_mesh_chunk_says_the_lo_form(pair):
         ("strided", 0)}
 
 
+def test_mesh_chunk_says_the_exchange_form_and_its_planes_bytes(pair):
+    """... and how its subbands came (a whole block: no exchange,
+    `mesh-place` replicated them) and what a device's rows hold of
+    hi-accel planes, by the count plane_dm_chunk sized the rows with."""
+    from tpulsar.kernels import accel
+    from tpulsar.plan import ddplan
+
+    chunks = [e["args"] for e in pair["events"]
+              if e["name"] == "mesh_chunk"]
+    assert {a["form"] for a in chunks} == {"none"}
+    cell = pair["cell"]
+    nbins = ddplan.choose_n(cell.nsamp) // 2 + 1
+    row = accel.plane_row_bytes(nbins, len(accel.z_grid(50)),
+                                accel.corr_z_pieces())
+    assert all(a["hi"] and a["plane_bytes"] == a["rows_per_device"] * row
+               for a in chunks)
+
+
 def test_mesh_chunk_says_the_sp_form(pair):
     """... and the boxcar ladder's: the plain chain here (`tiled` and
     the kernel's tile on a TPU: singlepulse.sp_dispatch_attrs)."""

@@ -107,3 +107,39 @@ def test_mask_quantization_roundtrip(tmp_path):
     # the mask itself still round-trips
     m2 = RFIMask.load(p)
     np.testing.assert_array_equal(m2.chan_fill, mask.chan_fill)
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("dtype, T, block_len", [
+    (np.uint8, 4096, 512),       # whole blocks, the beams' dtype
+    (np.uint8, 4096 + 300, 512),  # samples past the last whole block
+    (np.float32, 2048, 256),     # an unquantized beam
+    (np.uint8, 700, 700),        # one block, clamped to the observation
+    (np.uint8, 1536, 512),       # three blocks: not a power of two
+])
+def test_apply_mask_chan_is_the_select_block_by_block(dtype, T, block_len):
+    """Channel-major masking against the plain NumPy select: masked
+    cells take the channel's fill (rounded for integer data), every
+    other sample and the tail past the last whole block stay to the
+    bit, and the output is the input's dtype and shape."""
+    rng = np.random.default_rng(5)
+    nchan, nblocks = 12, T // block_len
+    data = (rng.integers(0, 16, (nchan, T)).astype(dtype)
+            if dtype == np.uint8
+            else rng.standard_normal((nchan, T)).astype(dtype))
+    cell_mask = rng.random((nblocks, nchan)) < 0.3
+    cell_mask[0, 0] = True
+    fill = rng.uniform(2.0, 12.0, nchan).astype(np.float32)
+    want = data.copy()
+    fillv = np.round(fill).astype(dtype) if dtype == np.uint8 \
+        else fill.astype(dtype)
+    for b in range(nblocks):
+        for c in np.flatnonzero(cell_mask[b]):
+            want[c, b * block_len:(b + 1) * block_len] = fillv[c]
+    got = rfi.apply_mask_chan(jnp.asarray(data), jnp.asarray(cell_mask),
+                              jnp.asarray(fill), block_len)
+    assert got.dtype == dtype and got.shape == data.shape
+    assert np.array_equal(np.asarray(got), want)
+    assert not np.array_equal(want, data)

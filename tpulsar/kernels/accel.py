@@ -737,9 +737,9 @@ def plane_dm_chunk(nbins: int, nz: int,
     batch planner's ladder (accel_batch.quantize_batch: what
     plan_batches would make of it anyway, so that the count says what
     a program is given).  A TPU's chunk program gets 1 row at every
-    depth; the DM-sharded mesh program, whose rows share every stage of
-    one program, asks with max_chunk=32 and gets 6 a device at the
-    survey's nz = 51 (Mock and WAPP ds=1 widths) and 2 at nz = 201.
+    depth; the DM-sharded mesh program, whose rows share one program,
+    asks with max_chunk=32: at nz 51, 6 a device at Mock's and WAPP's
+    ds=1 widths, 4 / 8 at FAST GPPS's ds=1 / ds=2; 2 at nz 201.
 
     Where not even one row fits, a TPU program is refused here,
     loudly: the budget is device memory there, and a row reckoned too
@@ -747,7 +747,7 @@ def plane_dm_chunk(nbins: int, nz: int,
     RAM and get 1."""
     from tpulsar.kernels.accel_batch import quantize_batch
 
-    zc = None if corr_form() == "direct" else z_chunk()
+    zc = corr_z_pieces()
     if max_chunk is None:
         max_chunk = PLANE_ROWS_DIRECT if zc is None else 32
     row = plane_row_bytes(nbins, nz, zc)
@@ -1917,3 +1917,10 @@ def normalize_spectrum(spectrum: jnp.ndarray) -> jnp.ndarray:
 
     powers, wpow = whitened_powers(spectrum)
     return scale_spectrum(spectrum, powers, wpow)
+
+
+def corr_z_pieces() -> int | None:
+    """z rows a piece of the correlation this process dispatches
+    (corr_form): None for the direct form, z_chunk() for the FFT
+    form's pieces.  plane_row_bytes' third argument."""
+    return None if corr_form() == "direct" else z_chunk()

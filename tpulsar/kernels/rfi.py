@@ -243,22 +243,30 @@ def apply_mask_chan(data: jnp.ndarray, cell_mask: jnp.ndarray,
     """Replace masked cells of channel-major (nchan, T) data with the
     mask's per-channel fill level.
 
-    A fused elementwise select in the input's dtype: peak HBM is the
-    input plus the output (uint8 beams stay uint8; nothing inflates to
-    float32 and no transpose or index matrix is materialized).
+    One select a block of `block_len` samples, written in place into a
+    copy of the input, in the input's dtype: peak HBM is the input plus
+    the output and nothing else (uint8 beams stay uint8; nothing
+    inflates to float32 and no transpose or index matrix is
+    materialized).  ONE fused select over the block reshaped to
+    (nchan, nblocks, block_len) costs a THIRD copy on a TPU: its
+    compiler turns the block channel-minor for the mask's broadcast
+    and back, 5.59 GiB of temporaries beside 2 x 5.59 at GBNCC's 120 s
+    pointing, which a v5e cannot hold (PERF.md section 6, PR 48).
+    Samples past the last whole block stay as they are.
     """
-    nchan, T = data.shape
     nblocks = cell_mask.shape[0]
-    usable = nblocks * block_len
-    cells = data[:, :usable].reshape(nchan, nblocks, block_len)
     if jnp.issubdtype(data.dtype, jnp.integer):
         fill = jnp.round(fill)
-    fillv = fill.astype(data.dtype)
-    out = jnp.where(cell_mask.T[:, :, None], fillv[:, None, None],
-                    cells).reshape(nchan, usable)
-    if usable < T:
-        out = jnp.concatenate([out, data[:, usable:]], axis=1)
-    return out
+    fillv = fill.astype(data.dtype)[:, None]
+
+    def one_block(b, out):
+        cols = jax.lax.dynamic_slice_in_dim(out, b * block_len, block_len,
+                                            axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(cell_mask[b][:, None], fillv, cols),
+            b * block_len, axis=1)
+
+    return jax.lax.fori_loop(0, nblocks, one_block, data)
 
 
 @partial(jax.jit, static_argnames=("block_len", "chunk"))

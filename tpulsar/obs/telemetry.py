@@ -183,6 +183,19 @@ def mesh_exchange_bytes_total() -> metrics.Counter:
         labelnames=("form",))
 
 
+def mesh_hi_rows_total() -> metrics.Counter:
+    return metrics.counter(
+        "tpulsar_mesh_hi_rows_total",
+        "DM trials of the DM-sharded mesh pass whose hi-accel stage "
+        "ran (executor._search_pass_sharded), by path: fused = inside "
+        "the mesh's one program a call, on the chip that searched the "
+        "trial; fallback = down the single-device route after the "
+        "pass (the batched path pinned off: the `sharded_hi_fallback` "
+        "degraded mode, one chip working and the others idle).  "
+        "fused sums to the hi-accel passes' trials on a healthy mesh",
+        labelnames=("path",))
+
+
 def readin_bytes_total() -> metrics.Counter:
     return metrics.counter(
         "tpulsar_readin_bytes_total",

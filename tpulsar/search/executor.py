@@ -1927,13 +1927,13 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     table is padded to the mesh): tpulsar_mesh_rows_total counts the
     rows that were a trial's first search, and the rest.
 
-    Robustness gates carry over from the single-device path: stage-2
-    dedispersion uses the Pallas sliding-window kernel exactly when
-    dedisperse_subbands would, and the hi z-template correlation only
-    runs sharded while the process's batched-path verdict holds
-    (accel._batch_path_usable) — when it does not,
-    the hi stage drops to the single-device accel_search_batch, which
-    has its own proven per-DM fallback.
+    Robustness gates carry over from the single-device path: stage 2
+    is the Pallas kernel exactly when dedisperse_subbands would take
+    it, and the hi stage runs sharded only while the process's batched
+    path holds (accel._batch_path_usable); when it does not, the hi
+    stage goes down the single-device route after the pass, in the
+    one-device loop's chunks (pass_chunk_size), and is refused where a
+    laid-out beam's subbands stayed where they lie (form "partial").
     """
     from tpulsar.kernels import pallas_dd
 
@@ -2040,15 +2040,15 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
     # accel-plane HBM budget and the configured DM chunk.
     chunk = params.max_dms_per_chunk
     if hi_sharded:
-        # as many rows a device as the budget holds: here they share
-        # one program's every stage, not the hi stage's alone
+        # as many rows a device as the plane budget holds (6 at Mock's
+        # ds=1, 8 / 4 at FAST GPPS's ds=2 / ds=1), all in ONE program
         chunk = min(chunk, n_dm * accel_k.plane_dm_chunk(
             nbins, nz, max_chunk=32))
     chunk = max(n_dm, (chunk // n_dm) * n_dm)
     chunk = min(chunk, ndms_pad)
     # ... and by the fused program's own working set a device: where a
-    # call's rows would pass it (no accepted cell's do: a long series
-    # with hi-accel off), the pass is split evenly into more calls
+    # call's rows would pass it (FAST GPPS's ds=1 pass with hi-accel
+    # off: 26 rows a device, two calls of 13), the pass is split evenly
     cap = n_dm * _mesh_rows_budget(nfft, params.spectral_hbm_budget)
     if chunk > cap:
         calls = -(-ndms_pad // cap)
@@ -2084,7 +2084,9 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
                 if trace_mod.enabled():     # the forms a device's rows got
                     trace_mod.annotate(**_dispatch_attrs(
                         (chunk // n_dm, T_ds), params.sp_widths,
-                        (chunk // n_dm, nbins), stages_lo, mesh))
+                        (chunk // n_dm, nbins), stages_lo, mesh),
+                        **_mesh_call_attrs(form, hi_sharded, nbins, nz,
+                                           chunk // n_dm))
                 # the program's seconds apart from the transfers': the
                 # first fetch would block on the same program anyway
                 with trace_mod.span("mesh-wait", rows=chunk):
@@ -2104,6 +2106,8 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
             telemetry.mesh_rows_total().inc(nfirst, kind="searched")
             telemetry.mesh_rows_total().inc(chunk - nfirst,
                                             kind="recomputed")
+            if hi_sharded:
+                telemetry.mesh_hi_rows_total().inc(nfirst, path="fused")
     del subb_m
 
     with timers.timing("mesh-candidates"):
@@ -2133,15 +2137,31 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
         # single-device route (accel_search_batch -> its own proven
         # per-DM fallback), re-dedispersing in chunks.  Slower, but
         # correct on runtimes that reject the batched shapes.
+        if form == "partial":
+            # subbands the exchange left where they lie because no chip
+            # is given a whole copy of them are not gathered onto one
+            # chip here either, beside its share of the beam
+            raise ValueError(
+                f"hi-accel's single-device route on a laid-out beam "
+                f"would bring the pass's {subb.nbytes} bytes of subbands "
+                f"whole onto one chip, over seq_shard_min_bytes="
+                f"{params.seq_shard_min_bytes}: with the batched path "
+                f"pinned off (accel._batch_path_usable, or a fault spec "
+                f"naming accel.) a beam laid out at this size has no "
+                f"hi-accel search")
         degraded.note("sharded_hi_fallback",
                       "batched accel path pinned off on the mesh path; hi "
                       "stage re-dedisperses per chunk (2x stage-2 "
                       "cost)")
+        # the one-device loop's own chunk (its spectral budget), the
+        # subbands brought whole to the first chip once
+        rows = pass_chunk_size(ndms, nfft, params)
+        subb_one = pmesh.on_first_device(subb)
         with timers.timing("sharded-search"):
-            for lo in range(0, ndms, params.max_dms_per_chunk):
-                dm_chunk = dms[lo: lo + params.max_dms_per_chunk]
+            for lo in range(0, ndms, rows):
+                dm_chunk = dms[lo: lo + rows]
                 series = dd.dedisperse_subbands(
-                    pmesh.on_first_device(subb),
+                    subb_one,
                     jnp.asarray(np.asarray(sub_shifts)
                                 [lo: lo + len(dm_chunk)]))
                 # bool mask, NOT float32: the bool-mask program is the
@@ -2151,6 +2171,8 @@ def _search_pass_sharded(mesh, subb, sub_shifts, dms, dt_ds,
                     series, jnp.asarray(keep), nfft=nfft)
                 cands.extend(_hi_accel_chunk(wspec, dm_chunk, 1, T_s,
                                              params)[0])
+                telemetry.mesh_hi_rows_total().inc(len(dm_chunk),
+                                                   path="fallback")
     return cands, events
 
 
@@ -2277,3 +2299,18 @@ def _dispatch_attrs(series_shape, sp_widths, spec_shape, lo_stages,
     return {**sp_k.sp_dispatch_attrs(*series_shape, tuple(sp_widths),
                                      platform),
             **fr.lo_dispatch_attrs(*spec_shape, tuple(lo_stages), platform)}
+
+
+def _mesh_call_attrs(form, hi_sharded: bool, nbins: int, nz: int,
+                     rows_per_device: int) -> dict:
+    """What a traced `mesh_chunk` says of its pass beyond the rows:
+    the exchange `form` its subbands came by ("none" for a whole
+    block, which `mesh-place` replicates) and, where the hi stage ran
+    in the call, `plane_bytes`: what a device's rows hold live there by
+    `accel.plane_row_bytes`' count, the count that `plane_dm_chunk`
+    sized the rows with (so a pass of many small calls says why)."""
+    attrs = {"form": form or "none"}
+    if hi_sharded:
+        attrs["plane_bytes"] = rows_per_device * accel_k.plane_row_bytes(
+            nbins, nz, accel_k.corr_z_pieces())
+    return attrs
