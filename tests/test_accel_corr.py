@@ -24,13 +24,22 @@ def banks():
             for zmax, _, _ in BANKS}
 
 
-def _spectra(rows, nbins=NBINS, seed=3):
+def _spectra(rows, nbins=NBINS, seed=3, kind="complex"):
+    """Noise and two tones.  The kinds other than "complex" have real
+    and imaginary parts far from equal: where the componentwise error
+    of a complex product made of three real ones (on the smaller of re
+    and im) would show, if the power felt it."""
     rng = np.random.default_rng(seed)
-    s = (rng.standard_normal((rows, nbins))
-         + 1j * rng.standard_normal((rows, nbins))).astype(np.complex64)
-    s[:, nbins // 3] += 20.0
-    s[:, nbins - 3] += 30.0        # a tone in the last `width` bins
-    return jnp.asarray(s)
+    re, im = rng.standard_normal((2, rows, nbins)).astype(np.float32)
+    re[:, nbins // 3] += 20.0
+    re[:, nbins - 3] += 30.0       # a tone in the last `width` bins
+    if kind == "im_1e-4_re":
+        im = (1e-4 * np.abs(re) * np.sign(im)).astype(np.float32)
+    elif kind != "complex":
+        im = np.zeros_like(re)
+    if kind == "imaginary":
+        re, im = im, re
+    return jnp.asarray((re + 1j * im).astype(np.complex64))
 
 
 def _fft_plane(specs, bank):
@@ -39,13 +48,18 @@ def _fft_plane(specs, bank):
         bank.width, len(bank.zs)))
 
 
-@pytest.mark.parametrize("rows", [1, 2, 3])
+@pytest.mark.parametrize("rows,kind", [
+    (1, "complex"), (2, "complex"), (3, "complex"),
+    (1, "real"), (1, "imaginary"), (1, "im_1e-4_re")])
 @pytest.mark.parametrize("zmax,nz,width", BANKS)
-def test_corr_plane_is_the_fft_forms_plane(banks, zmax, nz, width, rows):
+def test_corr_plane_is_the_fft_forms_plane(banks, zmax, nz, width, rows,
+                                           kind):
+    """The bound is on the POWER, which the larger of re and im sets:
+    the lopsided kinds keep it too."""
     bank = banks[zmax]
     assert (len(bank.zs), bank.width) == (nz, width)
     assert NBINS % accel._CORR_B
-    specs = _spectra(rows)
+    specs = _spectra(rows, kind=kind)
     want = _fft_plane(specs, bank)
     got = np.asarray(accel._corr_plane(
         *accel._split_block(specs), jnp.asarray(accel.corr_taps(bank)),
@@ -62,6 +76,63 @@ def test_corr_plane_is_the_fft_forms_plane(banks, zmax, nz, width, rows):
     tail = got[:, (nz - 1) // 2, -2 * width:]
     assert np.all(tail.argmax(axis=1) == 2 * width - 6)
     assert tail.max() > 0.5 * top
+
+
+@pytest.mark.parametrize("zmax,nz,width", BANKS)
+def test_corr_plane_over_several_grid_steps(banks, monkeypatch, zmax, nz,
+                                            width):
+    """The survey's spectra take tens of grid steps along the bins, the
+    toy's one: with 8 blocks a step at most the toy takes three, the
+    blocks shared evenly, and a step's last windows reach into the next
+    step's first rows (the halo).  Same plane."""
+    monkeypatch.setattr(accel, "_CORR_BLOCKS", 8)
+    p = accel.corr_plan(NBINS, nz, width, 2)
+    assert (p.ntiles, p.blocks) == (3, 8)
+    bank = banks[zmax]
+    specs = _spectra(2, seed=9)
+    want = _fft_plane(specs, bank)
+    got = np.asarray(accel._corr_plane.__wrapped__(
+        *accel._split_block(specs), jnp.asarray(accel.corr_taps(bank)),
+        width, nz, interpret=True))
+    assert np.abs(got - want).max() <= 2e-6 * want.max()
+    assert np.all(got[:, :, :width] == 0)
+
+
+def _dots_of(jaxpr):
+    """Every dot_general of a jaxpr, the ones inside its calls, loops
+    and kernels included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _dots_of(inner)
+
+
+@pytest.mark.parametrize("zmax,nz,width", BANKS)
+def test_the_kernel_takes_three_float32_products_a_z(banks, zmax, nz, width):
+    """Gauss's identity: three real products a (tile, z) where the
+    complex product's four quadrants took four, each of float32
+    operands at Precision.HIGHEST accumulated in float32 — no bf16
+    operand, no lower precision, no hand split."""
+    part = jax.ShapeDtypeStruct((2, NBINS), jnp.float32)
+    taps = jax.ShapeDtypeStruct(accel.corr_taps_shape(nz, width), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda r, i, t: accel._corr_plane(r, i, t, width, nz,
+                                          interpret=False))(part, part, taps)
+    dots = list(_dots_of(jaxpr.jaxpr))
+    p = accel.corr_plan(NBINS, nz, width, 2)
+    assert len(dots) == 3
+    for eqn in dots:
+        lhs, rhs = (v.aval for v in eqn.invars)
+        assert lhs.dtype == rhs.dtype == jnp.float32
+        assert lhs.shape == (p.blocks, p.kdim)
+        assert rhs.shape == (p.kdim, 2 * accel._CORR_B)
+        assert eqn.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.outvars[0].aval.dtype == jnp.float32
 
 
 @pytest.fixture
@@ -245,19 +316,29 @@ def test_corr_taps_are_the_rows_bank_fft_transforms(banks, zmax, nz, width):
     (2_097_153, 51, 128, 6),        # WAPP ds=1
     (1_966_081, 201, 256, 2),       # zmax 200
     (983_041, 51, 128, 6),          # Mock ds=2
+    (737_281, 51, 128, 1),          # GBNCC's 120 s pointing at ds=1
     (3001, 9, 64, 2),               # a toy bank: a window of 1.5 blocks
+    (2_097_153, 901, 1024, 1),      # the widest tiled: 8 blocks of reach,
+                                    # the halo the kernel fetches
 ])
 def test_corr_plan_covers_every_bin_within_vmem(nbins, nz, width, rows):
     p = accel.corr_plan(nbins, nz, width, rows)
     B = accel._CORR_B
     assert p.blocks % 8 == 0 and p.blocks <= accel._CORR_BLOCKS
     assert (p.ntiles - 1) * p.blocks * B < nbins <= p.ntiles * p.blocks * B
+    # the steps share the blocks evenly, each an odd count of 8: under
+    # 16 blocks a step overhang
+    assert p.blocks // 8 % 2 == 1
+    assert p.ntiles * p.blocks - -(-nbins // B) < 16 * p.ntiles
     # a block's window: its own bins and `width` more, inside S blocks
     assert (p.shifts - 1) * B >= width and p.kdim >= B + width
     # the padded spectrum: width/2 zeros, the bins, the last tile's halo
     assert p.rows_in * B >= width // 2 + nbins + width // 2
     assert p.rows_in == p.ntiles * p.blocks + accel._CORR_HALO
-    assert p.vmem_bytes < p.vmem_limit <= 100 << 20
+    # three window panels, the summed taps and three products of the
+    # largest step: inside a v5e's 128 MiB at every width tiled
+    assert p.shifts - 1 <= accel._CORR_HALO
+    assert p.vmem_bytes < p.vmem_limit <= (100 if width <= 256 else 120) << 20
 
 
 @pytest.mark.parametrize("nbins,nz,width,rows,why", [

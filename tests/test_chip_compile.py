@@ -286,12 +286,14 @@ def test_dm_sharded_pass_program_on_four_chips(v5e, tpu_accel_branch,
 @pytest.mark.parametrize("rows,zmax,nbins", [
     (2, 50.0, 1_966_081), (1, 200.0, 1_966_081), (2, 50.0, 2_097_153),
     (2, 8.0, 65_537),            # a toy bank 64 bins wide (chip_smoke's)
+    (1, 900.0, 1_966_081),       # the widest corr_plan tiles: 1024 bins
 ])
 def test_hi_accel_correlation_kernel(one_chip, tpu_accel_branch, rows,
                                      zmax, nbins):
     """accel._corr_plane alone: Mosaic takes the shifted window reads,
-    the float32 product, the sublane-strided relayout and the scoped
-    VMEM corr_plan asks for, at every width a bank has."""
+    the three float32 products, the sublane-strided relayout and the
+    scoped VMEM corr_plan asks for, at every width a bank has and at
+    the widest it does not refuse."""
     accel = tpu_accel_branch
     width, nz = accel.template_width(zmax), len(accel.z_grid(zmax))
     plan = accel.corr_plan(nbins, nz, width, rows)

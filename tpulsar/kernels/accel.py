@@ -16,8 +16,9 @@ Realization: templates are generated host-side once per (zmax,
 segment) signature.  A chunk program lowered for a TPU (and the hi
 stage of the DM-sharded mesh program there) correlates in the bin
 domain, one Pallas kernel on the MXU (corr_plane: blocks of the
-spectrum times a block-Toeplitz matrix of the templates' taps,
-float32, the plane written once).  Everywhere else — the CPU's
+spectrum times a block-Toeplitz matrix of the templates' taps, three
+real float32 products a complex one, the plane written once).
+Everywhere else — the CPU's
 programs, the per-DM fallback — the correlation runs as overlap-save
 on an FFT-domain bank: segment FFTs of the spectrum, a broadcast
 complex multiply against all templates at once, and a batched inverse
@@ -874,24 +875,36 @@ def _correlate_zpieces(specs: jnp.ndarray, bank_fft: jnp.ndarray,
 # takes `width` complex taps; even and odd columns are two polyphase
 # filters over the same bins).  For a block of B bins starting at q0 —
 # plane columns [2*q0, 2*q0 + 2B) — every tap falls on the B + width
-# bins from q0 - width/2, so the block is one real matrix product of
+# bins from q0 - width/2, so the block is one complex matrix product of
 # those bins (a row) with a block-Toeplitz matrix of the taps that does
 # not depend on q0:  A_z[i, n] = h_z[n - 2i + 2*width - 1] (0 outside
 # the taps), i the bin within the window, n the column within the
-# block.  corr_taps lays A_z out as [Re A_z | Im A_z]; the kernel
-# multiplies the blocks' windows, real parts over imaginary parts, by
-# it — the taps stationary in the MXU while the blocks stream — and
-# combines the four quadrants into re and im.  Rows = blocks, so the
-# product has the blocks on the sublanes and (z, column) on the lanes;
-# the plane wants z on the sublanes: each z's powers go to a VMEM
-# scratch and come back by sublane-strided reads, 16 z rows of one
-# block at a time, which is one packed bf16 tile of the plane.
+# block.  corr_taps lays A_z out as [Re A_z | Im A_z] = [Are | Aim].
+# The complex product W A_z of the blocks' windows W = Wre + i Wim is
+# THREE real matrix products, not four (Gauss's identity):
+#
+#   k1 = Wre (Are + Aim),  k2 = (Wim - Wre) Are,  k3 = (Wre + Wim) Aim
+#   re = k1 - k3 = Wre Are - Wim Aim,   im = k1 + k2 = Wre Aim + Wim Are
+#
+# each a float32 product at Precision.HIGHEST accumulated in float32 —
+# the taps stationary in the MXU while the blocks stream.  The windows'
+# two combinations are float32 additions made once a grid step on the
+# bins the windows are read from (shared by the step's 16 z), the taps'
+# one (Are + Aim) once a z in VMEM; the error is the four-product
+# form's normwise (Higham 1992), and the power re^2 + im^2 is set by the
+# larger part.  Rows = blocks, so the products have the blocks on the
+# sublanes and (z, column) on the lanes; the plane wants z on the
+# sublanes: each z's powers go to a VMEM scratch and come back by
+# sublane-strided reads, 16 z rows of one block at a time, which is one
+# packed bf16 tile of the plane.
 
 _CORR_B = 128           # spectrum bins per block: 256 plane columns
 _CORR_ZG = 16           # z rows per grid step: a whole packed bf16 tile
-_CORR_BLOCKS = 256      # blocks per grid step, at most
+_CORR_BLOCKS = 504      # blocks per grid step, at most: a product's rows,
+                        # streamed past each 128 x 128 tile of the taps
+                        # that the MXU is loaded with; an ODD count of 8
 _CORR_HALO = 8          # rows of the next tile a window may reach into:
-                        # widths to 1024, 90 MB of a v5e's 128 MiB of VMEM
+                        # widths to 1024, 106 MB of a v5e's 128 MiB of VMEM
 
 
 @dataclasses.dataclass(frozen=True)
@@ -938,15 +951,24 @@ def corr_plan(nbins: int, nz: int, width: int, rows: int) -> CorrPlan:
             f"{shifts - 1} blocks of {_CORR_B} bins past its own, over "
             f"the {_CORR_HALO} the kernel fetches")
     nblocks = -(-nbins // _CORR_B)
-    blocks = min(_CORR_BLOCKS, -(-nblocks // 8) * 8)
-    ntiles = -(-nblocks // blocks)
+    ntiles = -(-nblocks // _CORR_BLOCKS)
+    # the steps share the blocks evenly (no step mostly overhang), in an
+    # odd count of 8: the plane's tiles are read back from the powers by
+    # z at a sublane stride of `blocks` rows, and a stride of an even
+    # count of 8-row tiles (256: 32, 512: 64) lands the 8 sublanes of a
+    # read on few of VMEM's banks
+    per_step = -(-nblocks // ntiles)
+    blocks = 8 * (-(-per_step // 8) | 1)
     item = plane_itemsize()
+    bins = (blocks + _CORR_HALO) * _CORR_B * 4
     need = (2 * _CORR_ZG * kdim * 2 * ncol * 4       # taps, 2 buffers
+            + kdim * ncol * 4                        # Are + Aim of one z
             + 2 * _CORR_ZG * blocks * ncol * item    # plane tile, 2
             + _CORR_ZG * blocks * ncol * 4           # powers by z
-            + 2 * 2 * (blocks + _CORR_HALO) * _CORR_B * 4 * 2  # bins in
-            + 2 * blocks * kdim * 4                  # the windows
-            + 3 * 2 * blocks * 2 * ncol * 4)         # product, re, im
+            + (2 * 2 + 3) * bins                     # bins in, 2 buffers;
+                                                     # staged, 3 panels
+            + 3 * blocks * kdim * 4                  # the windows, 3
+            + 6 * blocks * ncol * 4)                 # k1 k2 k3, re im, power
     return CorrPlan(nbins=nbins, nz=nz, width=width, rows=rows,
                     shifts=shifts, blocks=blocks, ntiles=ntiles,
                     kdim=kdim, rows_in=ntiles * blocks + _CORR_HALO,
@@ -1001,28 +1023,36 @@ def _corr_taps_on_device(bank: TemplateBank) -> jnp.ndarray:
 def _corr_kernel(p: CorrPlan, dtype):
     """The kernel body for one plan: the tile's bins (re over im), the
     first rows of the next tile, 16 z of taps; the plane tile; the
-    staged bins, the windows, and the powers by z."""
+    staged bins (re, im - re, re + im), their windows, and the powers by
+    z.  Three float32 products a z (Gauss's identity, above)."""
     B, S, M, ZG = _CORR_B, p.shifts, p.blocks, _CORR_ZG
     ncol = 2 * B
+
+    def dot(a, b):
+        return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
 
     def kernel(x_ref, halo_ref, taps_ref, out_ref, bins_ref, win_ref,
                pow_ref):
         g, j = pl.program_id(0), pl.program_id(2)
-        bins_ref[:, :M, :] = x_ref[...]
-        bins_ref[:, M:, :] = halo_ref[...]
+        for src, rows in ((x_ref, slice(0, M)), (halo_ref, slice(M, None))):
+            re, im = src[0], src[1]
+            bins_ref[0, rows, :] = re
+            bins_ref[1, rows, :] = im - re
+            bins_ref[2, rows, :] = re + im
         # block b's window is rows b .. b + S - 1 of the bins: the
-        # shifted reads side by side on the lanes, re over im
-        for c in range(2):
+        # shifted reads side by side on the lanes, one panel over another
+        for c in range(3):
             for s in range(S):
                 win_ref[c * M:(c + 1) * M, s * B:(s + 1) * B] = (
                     bins_ref[c, s:s + M, :])
 
         def one_z(z, carry):
-            y = jnp.dot(win_ref[...], taps_ref[z],
-                        preferred_element_type=jnp.float32,
-                        precision=jax.lax.Precision.HIGHEST)
-            re = y[:M, :ncol] - y[M:, ncol:]
-            im = y[:M, ncol:] + y[M:, :ncol]
+            are, aim = taps_ref[z, :, :ncol], taps_ref[z, :, ncol:]
+            k1 = dot(win_ref[:M, :], are + aim)
+            k2 = dot(win_ref[M:2 * M, :], are)
+            k3 = dot(win_ref[2 * M:, :], aim)
+            re, im = k1 - k3, k1 + k2
             power = re * re + im * im
             rows = pl.ds(pl.multiple_of(z * M, 8), M)
             for k in range(ncol // _LANES):
@@ -1078,8 +1108,8 @@ def _corr_plane(re: jnp.ndarray, im: jnp.ndarray, taps: jnp.ndarray,
                                lambda g, d, j: (d, g, j)),
         out_shape=jax.ShapeDtypeStruct((nd, nz, 2 * nbins), dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, M + _CORR_HALO, B), jnp.float32),
-            pltpu.VMEM((2 * M, p.kdim), jnp.float32),
+            pltpu.VMEM((3, M + _CORR_HALO, B), jnp.float32),
+            pltpu.VMEM((3 * M, p.kdim), jnp.float32),
             pltpu.VMEM((2 * B // _LANES, ZG * M, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=p.vmem_limit),
